@@ -34,7 +34,7 @@ def main() -> None:
     from photon_tpu.utils.compile_cache import enable_compilation_cache
 
     os.makedirs(args.dir, exist_ok=True)
-    enable_compilation_cache(os.path.join(args.dir, "xla_cache"))
+    enable_compilation_cache()
 
     import jax
     import jax.numpy as jnp

@@ -174,10 +174,6 @@ def main() -> None:
             hbm_budget_bytes=int(args.hbm_budget_gb * 2**30),
             objective_chunk_rows=args.objective_chunk_rows,
             evaluators=["AUC"],
-            # one cache across every run/tag (per-run output dirs would
-            # each get a fresh default cache and defeat the 2nd-run story)
-            compilation_cache_dir=os.path.join(
-                os.path.abspath(args.out_dir), "xla_cache"),
         )
 
     import json

@@ -11,7 +11,7 @@ Method: one random-effect coordinate (2000 entities x 8 rows), 4-lane
 reg-weight grids of three difficulty profiles, vectorized (lane-axis) vs
 sequential (per-lane adaptive) paths, warm wall-clock best-of-N.
 
-Run: PHOTON_BENCH_CPU=1 python benches/re_grid_masking.py
+Run: JAX_PLATFORMS=cpu python benches/re_grid_masking.py
 """
 from __future__ import annotations
 
@@ -24,12 +24,6 @@ import argparse
 import time
 
 import numpy as np
-
-if os.environ.get("PHOTON_BENCH_CPU"):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 
 def main() -> None:
     p = argparse.ArgumentParser()

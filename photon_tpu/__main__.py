@@ -6,7 +6,11 @@
 
 Runs the thirteen per-package selftests as subprocesses (each CLI
 self-provisions its 8-device CPU platform, so results match CI exactly
-and one crashed subsystem cannot take the others down):
+and one crashed subsystem cannot take the others down). A SELF-TEST, NOT
+CHIP EVIDENCE: the children default to the CPU backend (a chip belongs to
+one process at a time, and Pallas kernels run interpreted in the kernels
+suite). Whether the program runs on the chip is `chip_smoke.py`'s job;
+whether a kernel compiles for it is tests/test_chip_compile.py's.
 
 - ``analysis``   — `python -m photon_tpu.analysis --json` (the full
                    contract registry traces clean; exit 1 on drift)

@@ -9,7 +9,7 @@ audited the analogous facts on Spark's plan inspection (shuffle
 boundaries); our IR is the jaxpr, and this package is the auditor:
 
 - `walker`   — recursive traversal over ClosedJaxpr (descends scan/while/
-               cond/pjit/shard_map/custom_vjp sub-jaxprs).
+               cond/jit/shard_map/custom_vjp sub-jaxprs).
 - `rules`    — the five contract rules (collective budget, transfer lint,
                dtype policy, const bloat, retrace hazard) + the
                trace-signature registry.
@@ -32,6 +32,7 @@ from photon_tpu.analysis.walker import (  # noqa: F401
     collective_sites,
     const_bytes,
     count_primitives,
+    hlo_all_reduce_count,
     sites,
     sub_jaxprs,
 )
@@ -57,7 +58,8 @@ __all__ = [
     "COLLECTIVE_PRIMITIVES", "LOOP_PRIMITIVES", "SCATTER_ADD_PRIMITIVES",
     "SCATTER_PRIMITIVES",
     "TRANSFER_PRIMITIVES", "Site", "collective_counts", "collective_sites",
-    "const_bytes", "count_primitives", "sites", "sub_jaxprs",
+    "const_bytes", "count_primitives", "hlo_all_reduce_count", "sites",
+    "sub_jaxprs",
     "RULES", "TracedContract", "TraceSignatureLog", "Violation",
     "trace_signature", "weak_type_drift",
     "REGISTRY", "ContractSpec", "check_contract", "check_registry",

@@ -235,7 +235,7 @@ def trace_signature(tree) -> tuple:
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     sig = []
     for leaf in leaves:
-        aval = jax.core.get_aval(leaf)
+        aval = jax.typeof(leaf)
         sig.append((tuple(getattr(aval, "shape", ())),
                     str(getattr(aval, "dtype", type(leaf).__name__)),
                     bool(getattr(aval, "weak_type", False))))

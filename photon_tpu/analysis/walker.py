@@ -5,16 +5,22 @@ inside hot loops, f32 accumulation, no captured-scalar retraces) live in
 the traced program, not in any single source file — so the checker walks
 the jaxpr IR, the XLA analog of the reference Photon-ML auditing its Spark
 plans for shuffle boundaries. The walker descends into every sub-jaxpr an
-equation carries (`scan`/`while`/`cond` branches, `pjit`, `shard_map`,
+equation carries (`scan`/`while`/`cond` branches, `jit`, `shard_map`,
 `custom_vjp`/`custom_jvp`, remat, ...): any param value that IS a jaxpr —
 or a tuple/list of them, as `cond`'s ``branches`` is — is recursed into,
 so new higher-order primitives are covered without enumeration.
 
-Counting collectives HERE, at trace level, is deliberately backend-
-independent: the CPU test backend's missing all-reduce combiner splits one
-variadic `lax.psum` into several compiled ``all-reduce`` HLO ops, which is
-a lowering detail — the contract is the single psum *equation*
-(tests/test_multihost.py pins exactly this).
+Collectives are counted HERE, at trace level, so a contract needs no
+compile. Under `shard_map`'s varying-axes tracking a `lax.psum` of varying
+operands binds ``psum_invariant``, and a VARIADIC psum — the (value,
+gradient) pair every evaluation closes with — binds one equation PER LEAF.
+The law this repo pins is one all-reduce per evaluation: XLA's all-reduce
+combiner merges such a run of adjacent, mutually independent, same-axes
+reductions into ONE tuple ``all-reduce`` (counted on HLO compiled for the
+described v5e:2x2 topology by tests/test_chip_compile.py, and on the chip
+by ``chip_smoke.py --chips 4``). So the walker reports the run — not each
+leaf — as one ``psum``: `Site.merged` marks the equations that ride the
+run head's all-reduce, and the counters skip them.
 """
 from __future__ import annotations
 
@@ -23,13 +29,19 @@ from collections import Counter
 from typing import Iterable, Iterator, Optional
 
 import numpy as np
-from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 # Cross-device communication primitives (jax.lax.parallel binds).
 COLLECTIVE_PRIMITIVES = frozenset({
     "psum", "pmax", "pmin", "ppermute", "pbroadcast", "all_gather",
     "all_to_all", "reduce_scatter", "pgather", "psum_invariant",
 })
+# `Site.name` reports a primitive under its canonical name: the psum of
+# varying operands is still the design's ``psum``.
+_CANONICAL_NAMES = {"psum_invariant": "psum"}
+# Type casts shard_map inserts between the leaves of one variadic psum
+# (invariant -> varying): they move no data and do not end a run.
+_VARYING_CASTS = frozenset({"pvary"})
 
 # Primitives that move data across the host/device boundary (or call back
 # into Python) from INSIDE a traced program.
@@ -77,7 +89,7 @@ def as_jaxpr(jaxpr) -> Jaxpr:
 def sub_jaxprs(eqn) -> Iterator:
     """Every jaxpr carried by one equation's params, in param order.
 
-    Yields ClosedJaxpr | Jaxpr. Handles scalar params (`pjit`/`scan`'s
+    Yields ClosedJaxpr | Jaxpr. Handles scalar params (`jit`/`scan`'s
     ``jaxpr``, `while`'s ``cond_jaxpr``/``body_jaxpr``, `shard_map`'s body,
     `custom_vjp_call_jaxpr`'s ``fun_jaxpr``) and sequence params (`cond`'s
     ``branches``) uniformly.
@@ -95,10 +107,14 @@ class Site:
     eqn: object
     path: tuple[str, ...]  # primitive names of the enclosing eqns
     loop_depth: int  # enclosing scan/while bodies (×N execution)
+    # a later leaf of one variadic psum: rides the run head's all-reduce
+    # (module docstring), so the counters skip it
+    merged: bool = False
 
     @property
     def name(self) -> str:
-        return self.eqn.primitive.name
+        name = self.eqn.primitive.name
+        return _CANONICAL_NAMES.get(name, name)
 
     @property
     def where(self) -> str:
@@ -108,9 +124,22 @@ class Site:
 def sites(jaxpr, _path: tuple = (), _loops: int = 0) -> Iterator[Site]:
     """Depth-first walk over every equation of ``jaxpr`` and all its
     sub-jaxprs. Accepts a ClosedJaxpr or Jaxpr."""
+    run_key, run_outs = None, set()  # the open variadic-psum run
     for eqn in as_jaxpr(jaxpr).eqns:
-        yield Site(eqn, _path, _loops)
         name = eqn.primitive.name
+        merged = False
+        if name == "psum_invariant":
+            key = (eqn.params.get("axes"),
+                   eqn.params.get("axis_index_groups"))
+            merged = key == run_key and not any(
+                v in run_outs for v in eqn.invars
+                if not hasattr(v, "val"))  # Literals are unhashable
+            if not merged:
+                run_key, run_outs = key, set()
+            run_outs.update(eqn.outvars)
+        elif name not in _VARYING_CASTS:
+            run_key, run_outs = None, set()
+        yield Site(eqn, _path, _loops, merged)
         deeper = _loops + (1 if name in LOOP_PRIMITIVES else 0)
         for sub in sub_jaxprs(eqn):
             yield from sites(sub, _path + (name,), deeper)
@@ -122,20 +151,28 @@ def count_primitives(jaxpr, names: Optional[Iterable[str]] = None) -> Counter:
     wanted = None if names is None else frozenset(names)
     out: Counter = Counter()
     for site in sites(jaxpr):
-        if wanted is None or site.name in wanted:
+        if not site.merged and (wanted is None or site.name in wanted):
             out[site.name] += 1
     return out
 
 
 def collective_counts(jaxpr) -> Counter:
-    """How many of each collective primitive the program traces to —
-    the jaxpr-level communication pattern (see module docstring for why
-    this, not compiled-HLO text, is the pinnable quantity)."""
+    """How many of each collective the program traces to, a variadic
+    psum counted once (module docstring) — the jaxpr-level communication
+    pattern."""
     return count_primitives(jaxpr, COLLECTIVE_PRIMITIVES)
 
 
+def hlo_all_reduce_count(hlo_text: str) -> int:
+    """all-reduce ops in compiled HLO text (``compiled.as_text()``) — where
+    the one-all-reduce-per-evaluation law is settled (module docstring)."""
+    return sum(" all-reduce(" in line or " all-reduce-start(" in line
+               for line in hlo_text.splitlines())
+
+
 def collective_sites(jaxpr) -> list[Site]:
-    return [s for s in sites(jaxpr) if s.name in COLLECTIVE_PRIMITIVES]
+    return [s for s in sites(jaxpr)
+            if s.name in COLLECTIVE_PRIMITIVES and not s.merged]
 
 
 def iter_consts(jaxpr, _path: tuple = ()) -> Iterator[tuple]:

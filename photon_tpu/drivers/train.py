@@ -146,7 +146,7 @@ class TrainingParams:
     streamed_objective: Optional[bool] = None
     # Per-chip HBM budget for the auto-trip (pooled budget = this × mesh
     # size). None detects the reported limit of the mesh's (addressable)
-    # devices and falls back to 16 GiB (v5e).
+    # devices (the device table's row where a device reports none).
     hbm_budget_bytes: Optional[int] = None
     # Rows per host chunk of a streamed-objective shard. Bigger chunks
     # amortize per-chunk dispatch and keep transfers long (good for PCIe);
@@ -226,11 +226,11 @@ class TrainingParams:
     # incremental_coordinates (per-point fits would drift the priors).
     resume: bool = False
     # Persistent XLA compilation cache (utils/compile_cache.py): ""
-    # disables, an explicit path wins (relative → under output_dir), None
-    # defers to $JAX_COMPILATION_CACHE_DIR and otherwise defaults to
-    # <output_dir>/xla_cache — so a re-run of the same job shapes in a
-    # fresh process skips most of its XLA compiles (the reference's JVM
-    # pays startup once per application; measured in docs/PERF.md).
+    # disables; otherwise $JAX_COMPILATION_CACHE_DIR when set (no path
+    # here overrides it), else this path (relative → under output_dir),
+    # else the fixed <checkout>/.jax_cache — so a re-run of the same job
+    # shapes in a fresh process skips most of its XLA compiles (the
+    # reference's JVM pays startup once per application).
     compilation_cache_dir: Optional[str] = None
     # Crash-consistent checkpoint/restore (photon_tpu/checkpoint;
     # docs/ELASTICITY.md). A directory (relative → under output_dir)
@@ -343,13 +343,11 @@ def run_training(params: TrainingParams, mesh=None) -> TrainingOutput:
     task = TaskType[params.task]
     mode = DataValidationType(params.data_validation)
 
-    from photon_tpu.utils.compile_cache import (enable_compilation_cache,
-                                                resolve_cache_dir)
+    from photon_tpu.utils.compile_cache import enable_compilation_cache
 
-    cache_dir = resolve_cache_dir(params.compilation_cache_dir,
-                                  params.output_dir)
+    cache_dir = enable_compilation_cache(params.compilation_cache_dir,
+                                         params.output_dir)
     if cache_dir is not None:
-        enable_compilation_cache(cache_dir)
         log.info("persistent XLA compilation cache at %s", cache_dir)
 
     with timers("read"):
@@ -800,12 +798,16 @@ def _streamable_shards(params: TrainingParams) -> set:
 
 def _detect_hbm_budget(mesh=None) -> int:
     """Per-chip HBM budget of the mesh ACTUALLY in use: the smallest
-    reported bytes_limit over the mesh's addressable devices (other
-    processes' devices cannot be queried; a mesh is homogeneous in
-    practice), else 16 GiB (a v5e chip). Without a mesh: the default
+    ``bytes_limit`` over the mesh's addressable devices (other processes'
+    devices cannot be queried; a mesh is homogeneous in practice) — every
+    device of the mesh, not the first. A device that reports no limit
+    takes its `profiling.ledger.DEVICE_PEAKS` row; a device kind that is
+    not in the table is an error, not 16 GiB. Without a mesh: the default
     device. The caller multiplies by the mesh size for the POOLED
     budget."""
     import jax
+
+    from photon_tpu.profiling.ledger import device_peaks
 
     if mesh is not None:
         proc = jax.process_index()
@@ -813,16 +815,8 @@ def _detect_hbm_budget(mesh=None) -> int:
                    if d.process_index == proc]
     else:
         devices = jax.devices()[:1]
-    limits = []
-    for d in devices:
-        try:
-            stats = d.memory_stats() or {}
-            limit = int(stats.get("bytes_limit", 0))
-            if limit > 0:
-                limits.append(limit)
-        except Exception:
-            pass
-    return min(limits) if limits else 16 << 30
+    return min(int((d.memory_stats() or {}).get("bytes_limit", 0))
+               or device_peaks(d).hbm_bytes for d in devices)
 
 
 def _estimate_device_bytes(n_rows: int, index_maps: dict,

@@ -23,18 +23,22 @@ closes that loop with two fused Pallas kernels (`kernels/blocked_ell.py`):
 DISPATCH SEAM (`data/matrix.py::BlockedEllRows.{matvec,rmatvec}` route
 through `tail_matvec` / `bucket_rmatvec` here):
 
-- ``PHOTON_TPU_KERNELS`` env knob: ``on`` forces the kernels (Pallas
-  ``interpret=True`` off-TPU — the bit-level parity test mode), ``off``
-  forces the XLA path, ``auto`` (default) enables them on a TPU backend
-  only.
+- ``PHOTON_TPU_KERNELS`` env knob: ``on`` dispatches the kernels — they
+  compile for the attached device or the run fails with the compiler's
+  message; ``off`` forces the XLA path; ``auto`` (default) is the XLA
+  path too, because the v5e's compiler refuses every kernel of this
+  package today (arbitrary in-kernel table gathers: "Only 2D gather is
+  supported" — tests/test_chip_compile.py pins each message, PERF.md
+  records them). Pallas interpret mode is a TEST harness only
+  (`interpreted`); no product path interprets a kernel.
 - `OptimizerConfig.kernels` threads the same three-state knob through
   `models/training.py` and `optim/streamed.py` per solve (None =
   inherit the env/auto default).
-- The XLA path stays the always-available fallback: kernels also step
-  aside per call when a layout has no tail or exceeds the VMEM budget
-  (``PHOTON_TPU_KERNELS_VMEM``) — never an error, never a different
-  answer (interpret-mode parity is BITWISE, pinned by
-  tests/test_kernels.py and the `blocked_ell_kernel_x_passes` contract).
+- Kernels step aside per call — a stated trace-time rule (`route`) —
+  when a layout has no tail or exceeds the VMEM budget
+  (``PHOTON_TPU_KERNELS_VMEM``); interpret-mode parity with the XLA path
+  is pinned by tests/test_kernels.py and the
+  `blocked_ell_kernel_x_passes` contract.
 
 Flipping the effective mode mid-process clears jit caches (the
 `telemetry.taps` arming precedent): the dispatch branch is a trace-time
@@ -65,7 +69,8 @@ from photon_tpu.kernels.blocked_ell import (  # noqa: F401
 
 __all__ = [
     "ENV_KNOB", "ENV_VMEM", "ENV_TILE", "KERNEL_SIGNATURES", "mode",
-    "active", "interpret", "vmem_budget", "tile_override", "scope",
+    "active", "interpret", "interpreted", "vmem_budget", "tile_override",
+    "scope",
     "route", "tail_matvec", "bucket_rmatvec", "tail_matvec_tiled",
     "bucket_rmatvec_tiled", "kernel_feasible", "tiled_feasible",
 ]
@@ -107,29 +112,50 @@ def mode() -> str:
     return _canon(env_knobs.get_raw(ENV_KNOB, "auto"))
 
 
+_INTERPRETED = False
+
+
 def interpret() -> bool:
-    """True off-TPU: kernels run via Pallas ``interpret=True`` — the
-    CPU bit-parity mode the test matrix pins."""
+    """Whether kernels run via Pallas ``interpret=True``: only inside a
+    test harness's `interpreted` block. Everywhere else a dispatched
+    kernel compiles for the attached device — or the run fails with the
+    compiler's message; no backend check turns interpretation on."""
+    return _INTERPRETED
+
+
+@contextlib.contextmanager
+def interpreted():
+    """TEST HARNESS ONLY (tests/conftest.py, the ``--selftest`` CLIs): run
+    every Pallas kernel of the repo — this package's and `ops/fused.py`'s
+    — in interpret mode for the duration, so CPU tests can pin kernel
+    arithmetic against the XLA path. Clears jit caches on entry and exit
+    (the flag is a trace-time fact, like `scope`)."""
     import jax
 
-    return jax.default_backend() != "tpu"
+    global _INTERPRETED
+    before, _INTERPRETED = _INTERPRETED, True
+    if not before:
+        jax.clear_caches()
+    try:
+        yield
+    finally:
+        _INTERPRETED = before
+        if not before:
+            jax.clear_caches()
 
 
 def active() -> bool:
-    """Whether the dispatch seam routes to the Pallas kernels right now
-    (``on`` → yes, ``off`` → no, ``auto`` → TPU backend only)."""
-    m = mode()
-    if m == "on":
-        return True
-    if m == "off":
-        return False
-    return not interpret()
+    """Whether the dispatch seam routes to the Pallas kernels right now:
+    only under ``on``. ``auto`` does not route to a kernel the chip's
+    compiler refuses, and today it refuses all of this package's (module
+    docstring) — the default route is the XLA path that compiles."""
+    return mode() == "on"
 
 
 def vmem_budget() -> int | None:
     """Per-call VMEM byte budget for the single-fused-kernel form; a
     layout whose operands exceed it routes to the grid-tiled forms (see
-    `route`). Off-TPU (interpret mode) there is no VMEM, so the budget
+    `route`). In interpret mode (tests) there is no VMEM, so the budget
     is unbounded unless ``PHOTON_TPU_KERNELS_VMEM`` pins one.
 
     A malformed knob raises ``ValueError`` naming it HERE, at the knob

@@ -3,9 +3,12 @@
     python -m photon_tpu.kernels --selftest            # one line, exit != 0
     python -m photon_tpu.kernels --selftest --json     # machine report
 
-Runs the Pallas-kernel dispatch seam end to end on the CPU backend
-(Pallas ``interpret=True`` — the bit-parity regime; the umbrella
-``python -m photon_tpu --selfcheck`` wires this in as the 9th suite):
+Runs the Pallas-kernel dispatch seam end to end on the CPU backend inside
+`kernels.interpreted()` — Pallas interpret mode, a self-test of the
+kernels' arithmetic against the XLA path, NOT evidence about the chip
+(whether a kernel compiles for the v5e is tests/test_chip_compile.py's
+job). The umbrella ``python -m photon_tpu --selfcheck`` wires this in as
+the 9th suite:
 
 - ``parity``     — kernel-vs-XLA matvec/rmatvec/lanes/sq_rmatvec
   BITWISE across a multi-width blocked-ELL layout, f32 and bf16 storage.
@@ -42,6 +45,13 @@ def _default_env() -> None:
 
 
 def run_selftest() -> dict:
+    from photon_tpu import kernels as K
+
+    with K.interpreted():
+        return _run_checks()
+
+
+def _run_checks() -> dict:
     import numpy as np
 
     import jax

@@ -1,5 +1,12 @@
 """The two Pallas TPU kernels behind the blocked-ELL dispatch seam.
 
+STATUS (PR 21): none of the four forms below compiles for the TPU v5e —
+Mosaic refuses the in-kernel table gathers (`wt[pc]`, `r[br]`: "Only 2D
+gather is supported"; tests/test_chip_compile.py pins the messages at the
+flagship layout's shapes). `kernels.active()` therefore keeps mode
+``auto`` on the XLA path; what follows describes the design and its
+interpret-mode (tests-only) behaviour, not something a chip has run.
+
 Both kernels mirror `data/matrix.py`'s XLA ops PRIMITIVE FOR PRIMITIVE —
 the same `_bell_compute` dtype recipe (bf16 storage multiplies in bf16),
 the same ``einsum(..., preferred_element_type=f32)`` accumulation, the
@@ -463,7 +470,8 @@ def _contract_X(bf16: bool = True):
 @register_contract(
     name="blocked_ell_kernel_x_passes",
     description="BlockedEllRows matvec + rmatvec with the Pallas kernels "
-                "dispatched (interpret off-TPU): gather-fused tail and "
+                "dispatched (trace-level law; refused by the v5e's "
+                "compiler today): gather-fused tail and "
                 "occurrence buckets INSIDE one pallas_call each, ZERO "
                 "scatters of any kind, every sparse dot/einsum "
                 "accumulating f32 — the walker checks the kernel body's "

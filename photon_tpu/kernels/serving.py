@@ -1,5 +1,12 @@
 """The fused int8 serving-rung Pallas kernel (round 20).
 
+STATUS (PR 21): this kernel does not compile for the TPU v5e — Mosaic
+refuses the per-entity row gather (`q[eids]`: "Shape mismatch in input,
+indices and output"; tests/test_chip_compile.py pins it at the flagship
+store's shapes). `kernels.active()` keeps mode ``auto`` on the XLA rung;
+what follows describes the design and its interpret-mode (tests-only)
+behaviour, not something a chip has run.
+
 The quantized serving rungs (`serving/programs.py::_build_score_fn`,
 ``quantize="int8"``) lower through generic XLA as separate ops: per
 coordinate, a dequant (``q.astype(f32) * scale``), then a fixed-effect
@@ -169,7 +176,8 @@ from photon_tpu.analysis.walker import SCATTER_PRIMITIVES  # noqa: E402
 @register_contract(
     name="serving_kernel_fused_rung",
     description="one int8 serving rung routed through the FUSED Pallas "
-                "kernel (kernels.scope('on'), interpret off-TPU): the "
+                "kernel (kernels.scope('on'); trace-level law — the "
+                "v5e's compiler refuses the kernel today): the "
                 "whole dequant + fixed matvec + per-entity gather-dot "
                 "inside one pallas_call, ZERO collectives, ZERO "
                 "scatters, every dot/einsum accumulating f32 — the "

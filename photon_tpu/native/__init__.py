@@ -2,12 +2,15 @@
 
 Compiled on first use with g++ (no pybind11 in this image; pure C ABI).
 ``available()`` gates every fast path — all callers keep a pure-Python
-fallback, so a missing/failed toolchain degrades to the slow path, never to
-an error.
+fallback, so a host without a toolchain still runs, ~20x slower on ingest;
+the failed build is LOGGED with the compiler's message, never silent. A run
+that must not take the slow road (`chip_smoke.py`) calls `rebuild()`, which
+builds from ``src/`` and raises on failure.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -24,15 +27,20 @@ _lib = None
 _tried = False
 
 
-def _compile() -> bool:
+def _compile() -> None:
+    """Build the library from source; raises RuntimeError carrying the
+    compiler's message (or the missing-toolchain/timeout reason)."""
     _LIB_PATH.parent.mkdir(exist_ok=True)
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
            str(_SRC), "-o", str(_LIB_PATH)]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        return False
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"g++ failed building {_SRC.name}: "
+            f"{e.stderr.decode(errors='replace')[-2000:]}") from e
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise RuntimeError(f"cannot build {_SRC.name}: {e}") from e
 
 
 def _bind(lib) -> None:
@@ -96,19 +104,38 @@ def get_lib():
         try:
             fresh = (_LIB_PATH.exists()
                      and _LIB_PATH.stat().st_mtime >= _SRC.stat().st_mtime)
-            # photon: allow(blocking_under_lock, the first-use compile MUST serialize under _lock — two threads racing g++ onto the same .so is the actual bug; hold time is bounded by the compile timeout and later callers hit the memoized fast path)
-            if not fresh and not _compile():
-                return None
+            if not fresh:
+                # photon: allow(blocking_under_lock, the first-use compile MUST serialize under _lock — two threads racing g++ onto the same .so is the actual bug; hold time is bounded by the compile timeout and later callers hit the memoized fast path)
+                _compile()
             lib = ctypes.CDLL(str(_LIB_PATH))
             _bind(lib)
             _lib = lib
-        except Exception:
+        except (RuntimeError, OSError, AttributeError) as e:
+            logging.getLogger("photon_tpu.native").warning(
+                "native runtime unavailable, pure-Python fallbacks serve "
+                "(ingest roughly 20x slower): %s", e)
             _lib = None
         return _lib
 
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def rebuild() -> None:
+    """Discard any library on disk — a prebuilt ``.so`` may predate the
+    source or come from another machine — build from ``src/`` and load
+    it; raises (with the compiler's message) instead of degrading. Call
+    before first use: a library already loaded stays loaded."""
+    global _lib, _tried
+    with _lock:
+        _LIB_PATH.unlink(missing_ok=True)
+        # photon: allow(blocking_under_lock, same first-use serialization as get_lib: one builder at a time onto the one .so)
+        _compile()
+        _lib, _tried = None, False
+    if get_lib() is None:
+        raise RuntimeError("native runtime built but failed to load "
+                           "(see the photon_tpu.native log)")
 
 
 def _as_u8p(arr: np.ndarray):

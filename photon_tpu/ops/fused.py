@@ -21,13 +21,15 @@ dim); margins/cotangents are (1, rows) row vectors and the gradient a
 (1, d) row vector, so no in-kernel transposes are needed.
 
 Two lowerings of the same math:
-- compiled TPU path: grid=1, X stays in HBM (`memory_space=ANY`) and the
+- compiled TPU path (compiles for the v5e at (524288, 256) f32 and
+  (2^21, 1024) bf16 — tests/test_chip_compile.py): grid=1, X stays in HBM (`memory_space=ANY`) and the
   kernel double-buffers row chunks HBM→VMEM with explicit async DMAs,
   overlapping the next chunk's copy with the current chunk's compute. (The
   obvious alternative — a 1-D grid over row tiles with auto-pipelining —
   lowers to Mosaic in O(grid²) Python time in this JAX version, minutes for
   billion-row shapes; the manual-DMA kernel lowers in O(1).)
-- interpreter path (CPU tests): small auto-pipelined grid, no manual DMA.
+- interpreter path (tests only, `kernels.interpreted`): small
+  auto-pipelined grid, no manual DMA.
 
 Used automatically by Objective(fused=True) for dense, unnormalized batches;
 everything else falls back to the jnp path.
@@ -185,18 +187,25 @@ def _fused_call(task, X, w, y, weights, offsets, interpret):
     return loss[0, 0], grad[0, :]
 
 
-def can_fuse(X) -> bool:
-    """Dense 2-D X whose row count has a usable power-of-two chunk.
-    (train_glm pads dense batches so this holds; see models/training.py.)
+def lowering_available(d: int) -> bool:
+    """Whether a lowering of the fused kernel exists here for feature
+    width ``d``: the compiled DMA path is a TPU kernel and needs the
+    feature dim lane-aligned (Mosaic memref row-slices require the minor
+    dim to be a multiple of the 128-lane tile); the interpreter lowering
+    runs only inside a test harness's `kernels.interpreted` block. Any
+    other backend or width takes the jnp objective."""
+    from photon_tpu import kernels
 
-    The compiled DMA path additionally needs the feature dim lane-aligned:
-    Mosaic memref row-slices require the minor dim to be a multiple of the
-    128-lane tile, so on TPU d % 128 != 0 falls back to the jnp objective.
-    """
+    return kernels.interpret() or (jax.default_backend() == "tpu"
+                                   and d % 128 == 0)
+
+
+def can_fuse(X) -> bool:
+    """Dense 2-D X with an available lowering whose row count has a usable
+    power-of-two chunk. (train_glm pads dense batches so the latter holds;
+    see models/training.py.)"""
     if (isinstance(X, (SparseRows, HybridRows)) or not hasattr(X, "ndim")
-            or X.ndim != 2):
-        return False
-    if jax.default_backend() == "tpu" and X.shape[1] % 128 != 0:
+            or X.ndim != 2 or not lowering_available(X.shape[1])):
         return False
     return pick_chunk(X.shape[0], X.shape[1], X.dtype.itemsize) is not None
 
@@ -204,7 +213,10 @@ def can_fuse(X) -> bool:
 def fused_value_and_grad(task: TaskType, X, w, y, weights, offsets):
     """(Σᵢ wᵢ·loss(zᵢ, yᵢ), Xᵀ(w∘d1)) — LOCAL sums (caller psums).
 
-    Compiled manual-DMA pallas on TPU; interpreter mode elsewhere (tests).
+    Compiled manual-DMA pallas (callers gate on `can_fuse`); the
+    interpreter lowering only inside `kernels.interpreted` (tests).
     """
-    interpret = jax.default_backend() != "tpu"
-    return _fused_call(task, X, w, y, weights, offsets, interpret)
+    from photon_tpu import kernels
+
+    return _fused_call(task, X, w, y, weights, offsets,
+                       kernels.interpret())

@@ -40,9 +40,10 @@ class OptimizerConfig:
     # tests/test_lane_solver.py::test_lane_grid_bf16_history_quality).
     lane_history_dtype: str | None = None
     # Pallas-kernel dispatch for the blocked-ELL X passes
-    # (photon_tpu/kernels): "on" forces the fused kernels (interpret mode
-    # off-TPU — the parity-test regime), "off" forces the XLA path,
-    # "auto" enables them on a TPU backend only. None (default) inherits
+    # (photon_tpu/kernels): "on" dispatches the kernels (they compile for
+    # the attached device or the solve fails with the compiler's
+    # message), "off" forces the XLA path, "auto" is the XLA path too
+    # while the v5e's compiler refuses them. None (default) inherits
     # the process-wide PHOTON_TPU_KERNELS env knob. A per-solve value
     # that FLIPS the effective mode clears jit caches on entry/exit (the
     # dispatch branch is a trace-time fact) — set the env knob for

@@ -18,6 +18,7 @@ from jax import lax
 
 from photon_tpu.optim.linesearch import wolfe_line_search
 from photon_tpu.optim.tracker import OptResult
+from photon_tpu.parallel.mesh import vary_like
 # Opt-in per-iteration telemetry from inside the jitted loop: a pure
 # no-op (absent from the jaxpr) unless a Run(resident_tap=True) is
 # attached at trace time — the telemetry_off_is_free contract pins that.
@@ -64,7 +65,8 @@ def two_loop(g, S, Y, rho, idx, count, sy, yy):
         q = q - jnp.where(valid, alpha, 0.0) * Y[slot]
         return q, alphas.at[slot].set(alpha)
 
-    q, alphas = lax.fori_loop(0, m, bwd, (g, jnp.zeros((m,), g.dtype)))
+    q, alphas = lax.fori_loop(
+        0, m, bwd, (g, vary_like(jnp.zeros((m,), g.dtype), g)))
 
     gamma = jnp.where(count > 0, sy / jnp.maximum(yy, 1e-20), 1.0)
     r = gamma * q
@@ -181,7 +183,7 @@ def minimize_lbfgs(
         )
 
     solver_tap("lbfgs", 0, f0, g0norm)
-    init = _State(
+    init = vary_like(_State(
         w=w0, f=f0, g=g0,
         S=jnp.zeros((m, d), dtype), Y=jnp.zeros((m, d), dtype),
         rho=jnp.zeros((m,), dtype),
@@ -193,7 +195,7 @@ def minimize_lbfgs(
         failed=jnp.zeros((), bool),
         hist=hist0,
         ghist=ghist0,
-    )
+    ), w0, g0)
     out = lax.while_loop(cond, body, init)
     return OptResult(
         w=out.w, value=out.f, grad_norm=jnp.linalg.norm(out.g),
@@ -329,7 +331,7 @@ def minimize_lbfgs_margin(
         )
 
     solver_tap("lbfgs_margin", 0, f0, g0norm)
-    init = _MarginState(
+    init = vary_like(_MarginState(
         w=w0, z=z0, f=f0, g=g0,
         S=jnp.zeros((m, d), dtype), Y=jnp.zeros((m, d), dtype),
         rho=jnp.zeros((m,), dtype),
@@ -341,7 +343,7 @@ def minimize_lbfgs_margin(
         failed=jnp.zeros((), bool),
         hist=hist0,
         ghist=ghist0,
-    )
+    ), w0, g0)
     out = lax.while_loop(cond, body, init)
     return OptResult(
         w=out.w, value=out.f, grad_norm=jnp.linalg.norm(out.g),

@@ -14,6 +14,8 @@ from typing import Callable, NamedTuple
 import jax.numpy as jnp
 from jax import lax
 
+from photon_tpu.parallel.mesh import vary_like
+
 C1 = 1e-4
 C2 = 0.9
 
@@ -134,7 +136,7 @@ def wolfe_line_search(
     def cond(s: LSState):
         return (~s.done) & (s.i < max_evals)
 
-    init = LSState(
+    init = vary_like(LSState(
         phase=jnp.zeros((), jnp.int32), done=jnp.zeros((), bool),
         failed=jnp.zeros((), bool), i=jnp.zeros((), jnp.int32),
         a=jnp.asarray(a_init, dtype),
@@ -143,7 +145,7 @@ def wolfe_line_search(
         a_hi=jnp.asarray(jnp.inf, dtype), f_hi=jnp.asarray(jnp.inf, dtype),
         d_hi=jnp.asarray(jnp.inf, dtype),
         a_star=zero, f_star=f0,
-    )
+    ), f0, dphi0)
     out = lax.while_loop(cond, body, init)
     ok = out.done | (out.a_star > 0.0)
     return out.a_star, out.f_star, ok

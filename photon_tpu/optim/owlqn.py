@@ -23,6 +23,7 @@ from jax import lax
 
 from photon_tpu.optim.lbfgs import two_loop, _push
 from photon_tpu.optim.tracker import OptResult
+from photon_tpu.parallel.mesh import vary_like
 # Opt-in in-loop iteration telemetry; compiled out by default (see
 # optim/lbfgs.py and the telemetry_off_is_free contract).
 from photon_tpu.telemetry.taps import solver_tap
@@ -132,8 +133,9 @@ def minimize_owlqn(
 
         ls = lax.while_loop(
             ls_cond, ls_body,
-            LS(a=jnp.asarray(a0, dtype), F=s.F, ok=jnp.zeros((), bool),
-               i=jnp.zeros((), jnp.int32)),
+            vary_like(LS(a=jnp.asarray(a0, dtype), F=s.F,
+                         ok=jnp.zeros((), bool),
+                         i=jnp.zeros((), jnp.int32)), s.w, s.g),
         )
         w_new = project(s.w + ls.a * direction)
         f_new, g_new = value_and_grad(w_new)
@@ -178,7 +180,7 @@ def minimize_owlqn(
         )
 
     solver_tap("owlqn", 0, F0, pg0norm)
-    init = _State(
+    init = vary_like(_State(
         w=w0, f=f0, F=F0, g=g0,
         S=jnp.zeros((m, d), dtype), Y=jnp.zeros((m, d), dtype),
         rho=jnp.zeros((m,), dtype),
@@ -187,7 +189,7 @@ def minimize_owlqn(
         it=jnp.zeros((), jnp.int32),
         done=pg0norm <= 1e-14, converged=pg0norm <= 1e-14,
         failed=jnp.zeros((), bool), hist=hist0, ghist=ghist0,
-    )
+    ), w0, g0)
     out = lax.while_loop(cond, body, init)
     pg_fin = pseudo_gradient(out.w, out.g, l1_weight, mask)
     return OptResult(

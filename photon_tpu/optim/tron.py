@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_tpu.optim.tracker import OptResult
+from photon_tpu.parallel.mesh import vary_like
 # Opt-in in-loop iteration telemetry; compiled out by default (see
 # optim/lbfgs.py and the telemetry_off_is_free contract).
 from photon_tpu.telemetry.taps import solver_tap
@@ -78,11 +79,11 @@ def _cg_trust(hvp, g, delta, max_cg: int, tol_factor=0.1):
         )
 
     r0 = -g
-    init = _CGState(
+    init = vary_like(_CGState(
         p=jnp.zeros_like(g), r=r0, dvec=r0, rsq=jnp.dot(r0, r0),
         it=jnp.zeros((), jnp.int32), done=jnp.zeros((), bool),
         boundary=jnp.zeros((), bool),
-    )
+    ), g)
     out = lax.while_loop(cond, body, init)
     return out.p, out.boundary
 
@@ -187,12 +188,12 @@ def minimize_tron(
         )
 
     solver_tap("tron", 0, f0, g0norm)
-    init = _State(
+    init = vary_like(_State(
         w=w0, f=f0, g=g0, delta=jnp.maximum(g0norm, 1.0).astype(dtype),
         it=jnp.zeros((), jnp.int32),
         done=g0norm <= 1e-14, converged=g0norm <= 1e-14,
         failed=jnp.zeros((), bool), hist=hist0, ghist=ghist0,
-    )
+    ), w0, g0)
     out = lax.while_loop(cond, body, init)
     return OptResult(
         w=out.w, value=out.f, grad_norm=jnp.linalg.norm(out.g),
@@ -249,11 +250,11 @@ def _cg_trust_margin(obj, w, z, batch, g, delta, max_cg: int,
         )
 
     r0 = -g
-    init = _CGZState(
+    init = vary_like(_CGZState(
         p=jnp.zeros_like(g), zp=jnp.zeros_like(z), r=r0, dvec=r0,
         dz=obj.direction_margin(r0, batch), rsq=jnp.dot(r0, r0),
         it=jnp.zeros((), jnp.int32), done=jnp.zeros((), bool),
-    )
+    ), g)
     out = lax.while_loop(cond, body, init)
     return out.p, out.zp, out.r
 
@@ -350,13 +351,13 @@ def minimize_tron_margin(
         )
 
     solver_tap("tron_margin", 0, f0, g0norm)
-    init = _MarginState(
+    init = vary_like(_MarginState(
         w=w0, z=z0, f=f0, g=g0,
         delta=jnp.maximum(g0norm, 1.0).astype(dtype),
         it=jnp.zeros((), jnp.int32),
         done=g0norm <= 1e-14, converged=g0norm <= 1e-14,
         failed=jnp.zeros((), bool), hist=hist0, ghist=ghist0,
-    )
+    ), w0, g0)
     out = lax.while_loop(cond, body, init)
     return OptResult(
         w=out.w, value=out.f, grad_norm=jnp.linalg.norm(out.g),

@@ -13,6 +13,15 @@ psum are EXACTLY the programs a real pod runs, and (via the gloo
 collectives `initialize_distributed` pins on CPU) the results are
 bit-identical across process counts.
 
+CPU ONLY, by construction: every child is pinned to the CPU backend in
+code (`_child_main`), because a chip belongs to one process at a time —
+the parent, or the first child, would hold it and the rest would fail or
+hang. So this launcher checks the multi-process PROGRAM (cluster
+formation, row slots, the hierarchical psum) and can never measure a
+chip: `bench.py`'s multihost leg that rides it is a parity check, not a
+device number, and `chip_smoke.py` leaves it out (its four-chip path is
+one process driving four devices).
+
 The child protocol, in order, before any jax import can touch a backend:
 
 1. ``JAX_PLATFORMS`` / ``XLA_FLAGS`` (device count) exported;

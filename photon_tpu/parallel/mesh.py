@@ -29,19 +29,30 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # Re-exported from ONE place so every photon_tpu module (and the tests)
-# gets a jax-version-stable shard_map.
-try:  # jax >= 0.5 exports shard_map at the top level
-    from jax import shard_map  # noqa: F401
-except ImportError:
-    # 0.4.x: the experimental home. Its replication checker predates a
-    # rule for `while` (every solver is a lax.while_loop), so default it
-    # off — the modern top-level shard_map handles this case natively,
-    # and check_rep is a static validity check, not a semantics change.
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
+# imports the same shard_map.
+from jax import shard_map  # noqa: F401
 
-    def shard_map(f, /, **kwargs):  # noqa: F811
-        kwargs.setdefault("check_rep", False)
-        return _shard_map_exp(f, **kwargs)
+
+def vary_like(tree, *refs):
+    """Cast every leaf of ``tree`` to vary over the manual (`shard_map`)
+    axes that any leaf of ``refs`` varies over; identity outside shard_map.
+
+    `shard_map` types each value by the manual axes it varies over, and
+    `while_loop`/`scan` require a carry to keep its type. A carry built
+    from fresh constants (`jnp.zeros`) is typed invariant, so a solve
+    whose state differs per shard (the entity-sharded random-effect
+    buckets) hands back a varying value the loop rejects. Solvers cast the
+    initial carry HERE, where it is built, to the type the loop will hold."""
+    want = frozenset().union(
+        *(jax.typeof(r).vma for r in jax.tree_util.tree_leaves(refs)))
+    if not want:
+        return tree
+
+    def cast(x):
+        need = tuple(sorted(want - jax.typeof(x).vma))
+        return jax.lax.pcast(x, need, to="varying") if need else x
+
+    return jax.tree_util.tree_map(cast, tree)
 
 
 def make_mesh(data_axis: str = "data", n_devices: int | None = None,
@@ -183,21 +194,15 @@ def cluster_barrier(tag: str, timeout_s: float = 60.0) -> float:
 
 
 def _pin_cpu_collectives() -> None:
-    """CPU backend only: select gloo for cross-process collectives BEFORE
-    the backend initializes. jax 0.4's default CPU client refuses
-    multi-process computations ("Multiprocess computations aren't
-    implemented on the CPU backend"); the gloo ring executes them — and,
-    because its reduction order depends only on the GLOBAL rank count,
-    the same 8-device mesh produces bit-identical psums whether it is
-    split 1, 2, or 4 ways (the multihost_e2e acceptance bar). No-op on
-    TPU backends and on jax builds without the option."""
+    """CPU backend only: pin gloo for cross-process collectives BEFORE the
+    backend initializes. Its ring's reduction order depends only on the
+    GLOBAL rank count, so the same 8-device mesh produces bit-identical
+    psums whether it is split 1, 2, or 4 ways (the multihost_e2e
+    acceptance bar). No-op on TPU backends."""
     platforms = os.environ.get("JAX_PLATFORMS", "")
     if platforms and "cpu" not in platforms:
         return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def _cluster_detectable() -> bool:

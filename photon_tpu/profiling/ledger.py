@@ -53,35 +53,65 @@ __all__ = [
     "Ledger", "ProgramRecord", "start_ledger", "finish_ledger", "ledger",
     "current_ledger", "enabled", "measure", "attribute", "note_program",
     "needs_note", "dispatch", "record_signature", "sample_hbm",
-    "ledger_disabled", "resolve_peaks",
+    "ledger_disabled", "resolve_peaks", "DevicePeaks", "DEVICE_PEAKS",
+    "device_peaks",
 ]
 
-# Modeled per-chip roofline ceilings by backend family: (FLOP/s, B/s).
-# TPU: a v5e-class chip (bf16 matmul peak, HBM bandwidth); CPU: a
-# generous many-core host. Overridable by env — the denominator of a
-# utilization FRACTION, so only its order of magnitude matters.
-_BACKEND_PEAKS = {
-    "tpu": (1.97e14, 8.2e11),
-    "cpu": (1.0e11, 5.0e10),
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """One chip's ceilings: the denominators of every utilization and the
+    fallback HBM size for devices that do not report ``bytes_limit``."""
+
+    flops_per_s: float
+    hbm_bytes_per_s: float
+    hbm_bytes: int
+    source: str
+
+
+# THE device table, keyed by ``jax.Device.device_kind``. A device that is
+# not here is an ERROR on any path that reports a device metric — never a
+# default: a utilization priced against another machine's peak is wrong
+# by an unknown factor.
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        1.97e14, 8.19e11, 16 << 30,
+        "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+        "819 GB/s HBM bandwidth, 16 GB HBM per chip"),
+    # TEST STAND-IN, describes no machine: lets the CPU suite exercise
+    # the utilization arithmetic and the HBM-budget auto-trip.
+    "cpu": DevicePeaks(1.0e11, 5.0e10, 16 << 30,
+                       "test stand-in (not a measurement)"),
 }
-_DEFAULT_PEAKS = (1.0e11, 5.0e10)
+
+
+def device_peaks(device=None) -> DevicePeaks:
+    """`DEVICE_PEAKS` row of ``device`` (default: ``jax.devices()[0]``);
+    an unknown ``device_kind`` raises."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise LookupError(
+            f"device kind {device.device_kind!r} is not in "
+            f"profiling.ledger.DEVICE_PEAKS (known: "
+            f"{sorted(DEVICE_PEAKS)}); add its published peaks with "
+            f"their source before reporting a device metric on it"
+        ) from None
 
 
 def resolve_peaks() -> tuple[float, float]:
     """(peak_flops_per_s, peak_bytes_per_s): env override first, else
-    the current backend's modeled ceiling."""
+    the attached device's `DEVICE_PEAKS` row (unknown device: error)."""
     env_f = env_knobs.get_raw("PHOTON_TPU_PEAK_FLOPS")
     env_b = env_knobs.get_raw("PHOTON_TPU_PEAK_BYTES_PER_S")
-    backend_f, backend_b = _DEFAULT_PEAKS
-    try:
-        import jax
-
-        backend_f, backend_b = _BACKEND_PEAKS.get(
-            jax.default_backend(), _DEFAULT_PEAKS)
-    except Exception:  # noqa: BLE001 — peaks must never take a run down
-        pass
-    return (float(env_f) if env_f else backend_f,
-            float(env_b) if env_b else backend_b)
+    if env_f and env_b:
+        return float(env_f), float(env_b)
+    peaks = device_peaks()
+    return (float(env_f) if env_f else peaks.flops_per_s,
+            float(env_b) if env_b else peaks.hbm_bytes_per_s)
 
 
 @dataclasses.dataclass

@@ -12,7 +12,7 @@ estimate is marked a lower bound).
 
 Two deliberate conventions:
 
-- **Per-device view.** Higher-order call eqns (`pjit`, `scan`, `while`,
+- **Per-device view.** Higher-order call eqns (`jit`, `scan`, `while`,
   `cond`, `shard_map`, custom-derivative wrappers) contribute nothing
   themselves — only their leaf equations are costed — so a `shard_map`
   body is costed at its per-device shapes. Roofline utilization is a
@@ -344,7 +344,7 @@ def xla_cost(fn, args) -> Optional[dict]:
     ``memory_analysis()`` sizes. This LOWERS AND COMPILES (unlike
     everything else in this module) — the ledger only calls it from
     explicit compile probes, never from hot paths. Returns None when the
-    backend provides no analysis (some plugin backends)."""
+    backend provides no analysis."""
     import jax
 
     try:

@@ -22,13 +22,14 @@ __all__ = ["KNOB_DOCS", "get_raw", "declared"]
 KNOB_DOCS = {
     "PHOTON_TPU_KERNELS": (
         "Pallas-kernel dispatch for the blocked-ELL X passes: on | off | "
-        "auto (TPU backend only, the default). Owner: photon_tpu.kernels "
+        "auto (the default: the XLA path while the v5e's compiler refuses "
+        "the kernels). Owner: photon_tpu.kernels "
         "(mode(); OptimizerConfig.kernels overrides per solve)."),
     "PHOTON_TPU_KERNELS_VMEM": (
         "Per-call VMEM byte budget for the single-fused-kernel form; a "
         "layout whose operands exceed it routes to the grid-tiled forms "
-        "(and past even those, the XLA path). Default 12 MiB on TPU, "
-        "unbounded in interpret mode. Owner: photon_tpu.kernels "
+        "(and past even those, the XLA path). Default 12 MiB, "
+        "unbounded in the tests' interpret mode. Owner: photon_tpu.kernels "
         "(vmem_budget())."),
     "PHOTON_TPU_KERNELS_TILE": (
         "Row-tile override for the grid-tiled kernel forms: a positive "
@@ -37,11 +38,11 @@ KNOB_DOCS = {
         "else DEFAULT_TILE. Owner: photon_tpu.kernels (tile_override())."),
     "PHOTON_TPU_PEAK_FLOPS": (
         "Modeled per-chip FLOP/s ceiling for roofline-utilization "
-        "denominators (overrides the backend default). Owner: "
+        "denominators (overrides the device_kind table's row). Owner: "
         "photon_tpu.profiling.ledger (resolve_peaks())."),
     "PHOTON_TPU_PEAK_BYTES_PER_S": (
         "Modeled per-chip HBM bytes/s ceiling for roofline-utilization "
-        "denominators (overrides the backend default). Owner: "
+        "denominators (overrides the device_kind table's row). Owner: "
         "photon_tpu.profiling.ledger (resolve_peaks())."),
     "PHOTON_TPU_LOG_LEVEL": (
         "Process-wide logging level override (a name like DEBUG or a "

@@ -39,7 +39,7 @@ def _violations(build, rule=None, **spec_kw):
 
 # ------------------------------------------------------------------ walker
 class TestWalker:
-    def test_nested_scan_in_while_in_pjit(self):
+    def test_nested_scan_in_while_in_jit(self):
         """The canonical solver nesting: jit(while(scan(...))) — the
         walker finds primitives at every level and reports loop depth."""
 
@@ -60,11 +60,11 @@ class TestWalker:
         assert counts["sin"] == 1 and counts["cos"] == 1 \
             and counts["tanh"] == 1
         depth = {s.name: s.loop_depth for s in sites(jaxpr)}
-        assert depth["tanh"] == 0  # pjit does not multiply execution
+        assert depth["tanh"] == 0  # jit does not multiply execution
         assert depth["cos"] == 1  # while body
         assert depth["sin"] == 2  # scan inside while
         paths = {s.name: s.path for s in sites(jaxpr)}
-        assert paths["sin"] == ("pjit", "while", "scan")
+        assert paths["sin"] == ("jit", "while", "scan")
 
     def test_cond_branches(self):
         """`cond` carries its branches as a TUPLE param — both must be
@@ -142,12 +142,14 @@ class TestRuleFires:
 
         def build():
             def body(v):
-                return jnp.sum(lax.all_gather(v, "data")) + lax.psum(
-                    jnp.sum(v), "data")
+                # all_gather's result is typed varying, so the output
+                # stays per-shard (out_specs=P("data"))
+                return v * (jnp.sum(lax.all_gather(v, "data")) + lax.psum(
+                    jnp.sum(v), "data"))
 
             fn = lambda x: shard_map(body, mesh=mesh8,  # noqa: E731
                                      in_specs=P("data"),
-                                     out_specs=P())(x)
+                                     out_specs=P("data"))(x)
             return fn, (jnp.ones(16),)
 
         out = _violations(build, "collective-budget",
@@ -188,13 +190,11 @@ class TestRuleFires:
         assert _violations(build, "transfer-lint")
 
     def test_dtype_policy_f64_leak(self):
-        from jax.experimental import enable_x64
-
         def build():
             fn = lambda x: jnp.sum(x.astype(jnp.float64))  # noqa: E731
             return fn, (jnp.ones(4),)
 
-        with enable_x64():
+        with jax.enable_x64(True):
             out = _violations(build, "dtype-policy")
         assert out and "float64" in out[0].message
 

@@ -129,13 +129,12 @@ def test_blocked_ell_specs_are_registered():
 
 
 def test_blocked_ell_contracts_hold_on_cpu_backend():
-    """The ADVICE.md cpu_parity_drift triage forward: the 6 tolerance
-    failures are value-level CPU reduction-order drift, but the NEW
-    sparse programs' STRUCTURAL contracts (scatter-free, f32 accumulation,
-    one psum) must hold on the CPU backend too — the parity-drift escape
-    hatch does not widen to the blocked-ELL layout. (This whole module
-    runs on the CPU backend; this test makes the blocked-ELL subset's
-    zero-violation status an explicit named assertion.)"""
+    """The blocked-ELL sparse programs' STRUCTURAL contracts
+    (scatter-free, f32 accumulation, one psum) hold on the CPU backend —
+    structure is a trace fact, independent of any backend's reduction
+    order. (This whole module runs on the CPU backend; this test makes
+    the blocked-ELL subset's zero-violation status an explicit named
+    assertion.)"""
     import jax
 
     assert jax.default_backend() == "cpu"

@@ -236,18 +236,29 @@ def test_bell_train_glm_parity(rng, task):
     X, B = _power_law_sparse(rng, n=400, d=400, k=8, d_dense=24)
     if task is TaskType.LOGISTIC_REGRESSION:
         y = _labels(rng, X)
-        rtol, atol = 1e-5, 5e-3
+        rtol = 1e-5
     else:
         # abs-normal responses: a harder-conditioned fit whose two solves
-        # stop at slightly different points of the same flat optimum —
-        # value parity is the tight pin, coefficients follow looser
+        # stop at slightly different points of the same flat optimum
         y = jnp.asarray(np.abs(rng.normal(size=400)).astype(np.float32))
-        rtol, atol = 5e-4, 5e-2
-    cfg = OptimizerConfig(max_iters=60, tolerance=1e-6, reg=l2(),
-                          reg_weight=0.1, history=5)
+        rtol = 5e-4
+    tol, lam = 1e-6, 0.1
+    # max_iters leaves room to CONVERGE (70-170 iterations here): at a cap
+    # of 60 neither solve had stopped on its tolerance, and the test
+    # compared two truncated paths, which differ by reduction-order noise
+    cfg = OptimizerConfig(max_iters=400, tolerance=tol, reg=l2(),
+                          reg_weight=lam, history=5)
     m_b, r_b = train_glm(make_batch(B, y), task, cfg)
     m_s, r_s = train_glm(make_batch(X, y), task, cfg)
+    assert bool(r_b.converged) and bool(r_s.converged)
+    # value parity is the tight pin
     np.testing.assert_allclose(float(r_b.value), float(r_s.value), rtol=rtol)
+    # ... coefficients follow as far as the stopping rule reaches: a
+    # relative-decrease stop at tol leaves a gap of order tol*f, and in a
+    # lam-strongly-convex objective a gap g allows a distance
+    # sqrt(2g/lam) from the optimum — twice, for two solves. (The chip's
+    # verdict on the same comparison: chip_smoke.py's `parity` phase.)
+    atol = 2.0 * np.sqrt(2.0 * tol * float(r_s.value) / lam)
     np.testing.assert_allclose(np.asarray(m_b.coefficients.means),
                                np.asarray(m_s.coefficients.means), atol=atol)
     # model scoring translates to permuted space internally

@@ -94,17 +94,25 @@ class TestTrainingDriver:
         assert set(model.names()) == {"fixed", "perUser"}
         assert "read" in out.timings and "train" in out.timings
 
-    def test_compilation_cache_knob(self, job_dirs, tmp_path):
-        """Default: persistent XLA cache lands under output_dir; "" turns
-        it off; an explicit relative path lands under output_dir too."""
+    def test_compilation_cache_knob(self, job_dirs, tmp_path, monkeypatch):
+        """The one placement rule (utils/compile_cache.py): "" turns the
+        cache off; JAX_COMPILATION_CACHE_DIR beats any configured path;
+        unset, an explicit path is honoured (relative → under
+        output_dir) and the default is the fixed in-checkout dir."""
         import jax
 
-        from photon_tpu.utils.compile_cache import resolve_cache_dir
+        from photon_tpu.utils import compile_cache as cc
 
-        assert resolve_cache_dir(None, "/o") == "/o/xla_cache"
-        assert resolve_cache_dir("", "/o") is None
-        assert resolve_cache_dir("cc", "/o") == "/o/cc"
-        assert resolve_cache_dir("/abs/cc", "/o") == "/abs/cc"
+        monkeypatch.delenv(cc.ENV_VAR, raising=False)
+        assert cc.resolve_cache_dir(None, "/o") == cc.DEFAULT_CACHE_DIR
+        assert cc.DEFAULT_CACHE_DIR.endswith(".jax_cache")
+        assert cc.resolve_cache_dir("", "/o") is None
+        assert cc.resolve_cache_dir("cc", "/o") == "/o/cc"
+        assert cc.resolve_cache_dir("/abs/cc", "/o") == "/abs/cc"
+        monkeypatch.setenv(cc.ENV_VAR, "/env/cc")
+        assert cc.resolve_cache_dir(None, "/o") == "/env/cc"
+        assert cc.resolve_cache_dir("/abs/cc", "/o") == "/env/cc"
+        assert cc.resolve_cache_dir("", "/o") is None
 
         root, *_ = job_dirs
         out_dir = tmp_path / "cache_job"
@@ -115,14 +123,17 @@ class TestTrainingDriver:
             coordinates={"fixed": COORDINATES["fixed"]},
             entity_fields=["userId"],
             n_sweeps=1,
+            compilation_cache_dir="ignored_under_env",
         )
+        env_dir = tmp_path / "env_cache"
+        monkeypatch.setenv(cc.ENV_VAR, str(env_dir))
         prev = jax.config.jax_compilation_cache_dir
         prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
         try:
             run_training(params)
-            assert jax.config.jax_compilation_cache_dir == str(
-                out_dir / "xla_cache")
-            assert (out_dir / "xla_cache").is_dir()
+            assert jax.config.jax_compilation_cache_dir == str(env_dir)
+            assert env_dir.is_dir()
+            assert not (out_dir / "ignored_under_env").exists()
         finally:  # both knobs: the rest of the session must not keep
             # persisting every compile into a deleted tmpdir
             jax.config.update("jax_compilation_cache_dir", prev)

@@ -497,7 +497,6 @@ class TestVectorizedFixedGrid:
             np.float32)
         return GameData.build(y, shards={"fixed": X}, entity_ids={})
 
-    @pytest.mark.cpu_parity_drift
     def test_matches_sequential_path(self, rng):
         data = self._data(rng)
         val = self._data(rng, n=300)

@@ -153,6 +153,29 @@ class TestParallelDecode:
         for a, b in zip(ref, got):
             _chunks_equal(a, b)
 
+    def test_workers_never_initialise_a_jax_backend(self, dataset,
+                                                   monkeypatch):
+        """One process per chip: a parent that holds the chip starts the
+        decode workers, so a worker that initialised a backend would fail
+        or hang there. The workers import `jax.numpy` (feature_bags) but
+        must never touch a device: spawned with a JAX_PLATFORMS naming no
+        backend — any backend initialisation raises — they still decode
+        every chunk, bit-identically, with no worker death."""
+        from photon_tpu import telemetry
+
+        root, config, scan, ref = dataset
+        monkeypatch.setenv("JAX_PLATFORMS", "no_such_platform")
+        with telemetry.run("workers") as run:
+            _, c = iter_game_chunks_parallel(
+                str(root), config, scan.index_maps, chunk_rows=300,
+                sparse_k=4, workers=2, mode="process")
+            got = list(c)
+        assert len(got) == len(ref)
+        for a, b in zip(ref, got):
+            _chunks_equal(a, b)
+        assert run.counters.get("ingest.worker_deaths", 0) == 0
+        assert run.counters.get("ingest.worker_chunks", 0) == len(ref)
+
     def test_worker_kill_matrix(self, dataset):
         """An injected ingest_worker kill at the FIRST / a MIDDLE / the
         LAST retired task degrades that chunk to in-process decode: no

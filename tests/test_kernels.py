@@ -192,11 +192,29 @@ class TestDispatchSeam:
         assert K.mode() == "on" and K.active()
         monkeypatch.setenv(K.ENV_KNOB, "off")
         assert not K.active()
+        # auto never routes to a kernel the chip's compiler refuses — and
+        # it refuses all of this package's (tests/test_chip_compile.py)
         monkeypatch.setenv(K.ENV_KNOB, "auto")
-        assert K.active() == (jax.default_backend() == "tpu")
+        assert not K.active()
         monkeypatch.setenv(K.ENV_KNOB, "bogus")
         with pytest.raises(ValueError, match="PHOTON_TPU_KERNELS"):
             K.mode()
+
+    def test_on_never_interprets_outside_the_harness(self, rng, monkeypatch):
+        """Interpret mode is the tests' (conftest's `kernels.interpreted`
+        block), not a backend fallback: with it off, mode ``on`` on a
+        device that is not a TPU raises the lowering's own error instead
+        of quietly interpreting, and the default mode takes the XLA path."""
+        monkeypatch.setattr(K, "_INTERPRETED", False)
+        X = M._contract_blocked_ell(bf16=False)
+        w = jnp.asarray(rng.normal(size=X.shape[1]).astype(np.float32))
+        assert not K.interpret() and K.route(X, w) is None
+        ref = np.asarray(M.matvec(X, w))
+        with K.scope("on"):
+            assert K.route(X, w) == "fused"
+            with pytest.raises(ValueError, match="interpret mode"):
+                jax.block_until_ready(M.matvec(X, w))
+        np.testing.assert_array_equal(np.asarray(M.matvec(X, w)), ref)
 
     def test_scope_nesting_and_restore(self):
         base = K.active()

@@ -70,7 +70,6 @@ def test_lane_grid_matches_sequential_sparse(rng, task):
     _grid_vs_sequential(batch, task, cfg, [1e-2, 1e-1, 1.0, 10.0])
 
 
-@pytest.mark.cpu_parity_drift
 def test_lane_grid_matches_sequential_hybrid(rng):
     X, y = _sparse_problem(rng, n=600, d=500, k=10)
     H = to_hybrid(X, 64)
@@ -231,7 +230,6 @@ def test_lane_grid_owlqn_variance_fallback_vmap_path(rng):
                                    rtol=2e-2, atol=1e-4)
 
 
-@pytest.mark.cpu_parity_drift
 def test_lane_grid_owlqn_sharded_hybrid(rng, mesh8):
     from photon_tpu.data.dataset import shard_hybrid_batch
 

@@ -116,6 +116,50 @@ def test_single_all_reduce_per_evaluation(rng, mesh8):
         f"traced {dict(counts)}"
 
 
+@pytest.mark.parametrize("solver", ["lbfgs", "owlqn", "tron"])
+def test_entity_sharded_solve_under_shard_map(rng, mesh8, solver):
+    """The vmapped per-entity solve traces — and agrees with plain jit —
+    under a shard_map that shards the ENTITY axis: every loop carry a
+    solver builds from fresh constants is cast to vary over the manual
+    axes its state varies over (`parallel.mesh.vary_like`), which
+    shard_map's varying-axes typing requires of `while_loop`/`scan`
+    carries. (The data-sharded solves never needed it: their state is
+    psum'd, hence invariant.)"""
+    from photon_tpu.data.dataset import GLMBatch
+    from photon_tpu.models.training import make_objective, solve
+    from photon_tpu.optim.config import OptimizerConfig, OptimizerType
+    from photon_tpu.optim.regularization import l1, l2
+
+    E, m, d = 16, 8, 5
+    batch = GLMBatch(
+        X=jnp.asarray(rng.normal(size=(E, m, d)), jnp.float32),
+        y=jnp.asarray(rng.uniform(size=(E, m)) < 0.5, jnp.float32),
+        weights=jnp.ones((E, m), jnp.float32),
+        offsets=jnp.zeros((E, m), jnp.float32))
+    w0 = jnp.zeros((E, d), jnp.float32)
+    cfg = {
+        "lbfgs": OptimizerConfig(max_iters=4, reg=l2(), reg_weight=0.3,
+                                 history=3),
+        "owlqn": OptimizerConfig(max_iters=4, reg=l1(), reg_weight=0.3,
+                                 history=3),
+        "tron": OptimizerConfig(optimizer=OptimizerType.TRON, max_iters=4,
+                                reg=l2(), reg_weight=0.3),
+    }[solver]
+    obj = make_objective(TaskType.LOGISTIC_REGRESSION, cfg, d)
+
+    def one(b, w):
+        return solve(obj, b, w, cfg).w
+
+    ent = P("data")
+    sharded = jax.jit(shard_map(
+        jax.vmap(one), mesh=mesh8,
+        in_specs=(jax.tree_util.tree_map(lambda _: ent, batch), ent),
+        out_specs=ent))
+    np.testing.assert_array_equal(
+        np.asarray(sharded(batch, w0)),
+        np.asarray(jax.jit(jax.vmap(one))(batch, w0)))
+
+
 def test_padding_divides_hybrid_mesh(hybrid_mesh):
     n_dev = hybrid_mesh.devices.size
     assert pad_to_multiple(1000, n_dev) % n_dev == 0

@@ -122,7 +122,6 @@ def test_perm_empty_tail(rng):
         np.asarray(rmatvec(X, r)), rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.cpu_parity_drift
 def test_perm_train_glm_parity(rng):
     X, P = _power_law_sparse(rng)
     wt = rng.normal(size=X.n_features).astype(np.float32) * 0.5
@@ -383,7 +382,6 @@ class TestShardedPermuted:
             total, np.asarray(rmatvec(SP, jnp.asarray(r_full))),
             rtol=2e-5, atol=1e-4)
 
-    @pytest.mark.cpu_parity_drift
     def test_train_glm_mesh_matches_single_device(self, rng, mesh8):
         from photon_tpu.data.dataset import shard_permuted_batch
 

@@ -350,8 +350,8 @@ class TestPooledBudget:
 
         per_chip = _detect_hbm_budget(mesh8)
         assert per_chip > 0
-        # CPU test devices either report a limit or fall back to 16 GiB;
-        # either way the mesh path must agree with itself
+        # CPU test devices report no limit and take the device table's
+        # stand-in row; the mesh path must agree with itself
         assert per_chip == _detect_hbm_budget(mesh8)
 
     def test_resolution_pools_budget_and_logs(self, rng, mesh8, caplog):
