@@ -1,0 +1,6 @@
+"""Layer: ingest. The driver's ``train.read`` span, per traced fit."""
+from benchmark.lib.harness import span_seconds_per_unit
+
+
+def read(ctx):
+    return span_seconds_per_unit(ctx, "train.read")
