@@ -31,6 +31,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import argparse
+import contextlib
 import time
 
 import numpy as np
@@ -94,8 +95,8 @@ def main() -> None:
                         "delta + snapshot bytes/s) and print its JSON "
                         "line")
     p.add_argument("--xprof-dir", default=None,
-                   help="wrap each driver run in jax.profiler.start_trace/"
-                        "stop_trace writing an XProf capture here, so the "
+                   help="wrap each driver run in telemetry.device_trace, "
+                        "writing an XProf capture here, so the "
                         "telemetry spans (mirrored to TraceAnnotation) and "
                         "the attribution ledger's phases line up with the "
                         "device timeline on real TPUs")
@@ -197,19 +198,11 @@ def main() -> None:
         trun = telemetry.start_run(f"flagship_r{run}", jsonl_path=jsonl,
                                    append=args.resume)
         profiling.start_ledger(f"flagship_r{run}")
-        if args.xprof_dir:
-            import jax
-
-            jax.profiler.start_trace(args.xprof_dir)
         t0 = time.perf_counter()
-        try:
+        with (telemetry.device_trace(args.xprof_dir) if args.xprof_dir
+              else contextlib.nullcontext()):
             out = run_training(params(fd.COORDINATES, f"game_r{run}"),
                                mesh=mesh)
-        finally:
-            if args.xprof_dir:
-                import jax
-
-                jax.profiler.stop_trace()
         total = time.perf_counter() - t0
         telemetry.finish_run()
         ledger_report = profiling.finish_ledger()

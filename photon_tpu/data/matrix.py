@@ -21,6 +21,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# named device scopes (op metadata only): a profiler trace reads the X
+# pass's share of a compiled solve by these names
+from photon_tpu.telemetry import device_scope
+
 
 @partial(
     jax.tree_util.register_dataclass,
@@ -1382,14 +1386,15 @@ def _bell_tail(X, w):
     """
     lanes = w.ndim == 2
     sharded = isinstance(X, ShardedBlockedEllRows)
-    wt = w[X.d_sel:X.n_prefix]
     parts = []
-    for pc, pv in zip(X.ell_pcols, X.ell_vals):
-        v, g = _bell_compute(pv, wt[pc])      # ([S,] r_b, W_b[, G])
-        eq = ("srw,srwg->srg" if lanes else "srw,srw->sr") if sharded \
-            else ("rw,rwg->rg" if lanes else "rw,rw->r")
-        parts.append(jnp.einsum(eq, v, g,
-                                preferred_element_type=jnp.float32))
+    with device_scope("xpass.fwd.tail"):
+        wt = w[X.d_sel:X.n_prefix]
+        for pc, pv in zip(X.ell_pcols, X.ell_vals):
+            v, g = _bell_compute(pv, wt[pc])      # ([S,] r_b, W_b[, G])
+            eq = ("srw,srwg->srg" if lanes else "srw,srw->sr") if sharded \
+                else ("rw,rwg->rg" if lanes else "rw,rw->r")
+            parts.append(jnp.einsum(eq, v, g,
+                                    preferred_element_type=jnp.float32))
     return parts
 
 
@@ -1416,20 +1421,24 @@ def _bell_matvec(X: BlockedEllRows, w):
     tail term routes through the Pallas kernels when the kernels seam is
     active (`photon_tpu.kernels.tail_matvec`, grid-tiled past the VMEM
     budget; both bitwise-equal)."""
-    hot = jnp.matmul(X.dense, w[:X.d_sel].astype(X.dense.dtype),
-                     preferred_element_type=jnp.float32)
+    with device_scope("xpass.fwd.hot"):
+        hot = jnp.matmul(X.dense, w[:X.d_sel].astype(X.dense.dtype),
+                         preferred_element_type=jnp.float32)
     if X.ell_vals:
         rt = _kernel_route(X, w)
         if rt is not None:
             from photon_tpu import kernels
 
-            tail = (kernels.tail_matvec(X, w) if rt == "fused"
-                    else kernels.tail_matvec_tiled(X, w))
+            with device_scope("xpass.fwd.tail"):
+                tail = (kernels.tail_matvec(X, w) if rt == "fused"
+                        else kernels.tail_matvec_tiled(X, w))
             return hot + tail
     lanes = w.ndim == 2
-    zero = jnp.zeros((1, w.shape[1]) if lanes else (1,), jnp.float32)
-    cat = jnp.concatenate(_bell_tail(X, w) + [zero], axis=0)
-    return hot + cat[X.row_pos]
+    parts = _bell_tail(X, w)
+    with device_scope("xpass.fwd.reassemble"):
+        zero = jnp.zeros((1, w.shape[1]) if lanes else (1,), jnp.float32)
+        tail = jnp.concatenate(parts + [zero], axis=0)[X.row_pos]
+    return hot + tail
 
 
 def _bell_rmatvec(X: BlockedEllRows, r, square: bool = False):
@@ -1440,29 +1449,33 @@ def _bell_rmatvec(X: BlockedEllRows, r, square: bool = False):
     grid-tiled past the VMEM budget; both bitwise-equal)."""
     f32 = jnp.float32
     lanes = r.ndim == 2
-    dense = X.dense * X.dense if square else X.dense
-    parts = [jnp.matmul(dense.T, r.astype(X.dense.dtype),
-                        preferred_element_type=f32)]
+    with device_scope("xpass.t.hot"):
+        dense = X.dense * X.dense if square else X.dense
+        parts = [jnp.matmul(dense.T, r.astype(X.dense.dtype),
+                            preferred_element_type=f32)]
     rt = _kernel_route(X, r) if X.bucket_vals else None
     if rt is not None:
         from photon_tpu import kernels
 
-        parts.append(kernels.bucket_rmatvec(X, r, square=square)
-                     if rt == "fused"
-                     else kernels.bucket_rmatvec_tiled(X, r, square=square))
+        with device_scope("xpass.t.tail"):
+            parts.append(
+                kernels.bucket_rmatvec(X, r, square=square)
+                if rt == "fused"
+                else kernels.bucket_rmatvec_tiled(X, r, square=square))
         pad = X.n_features - X.n_prefix
         if pad:
             parts.append(jnp.zeros(
                 (pad, r.shape[1]) if lanes else (pad,), f32))
         return jnp.concatenate(parts, axis=0)
-    for br, bv in zip(X.bucket_rows, X.bucket_vals):
-        if square:
-            v = bv.astype(f32)
-            v, g = v * v, r[br].astype(f32)
-        else:
-            v, g = _bell_compute(bv, r[br])
-        eq = "ck,ckg->cg" if lanes else "ck,ck->c"
-        parts.append(jnp.einsum(eq, v, g, preferred_element_type=f32))
+    with device_scope("xpass.t.tail"):
+        for br, bv in zip(X.bucket_rows, X.bucket_vals):
+            if square:
+                v = bv.astype(f32)
+                v, g = v * v, r[br].astype(f32)
+            else:
+                v, g = _bell_compute(bv, r[br])
+            eq = "ck,ckg->cg" if lanes else "ck,ck->c"
+            parts.append(jnp.einsum(eq, v, g, preferred_element_type=f32))
     pad = X.n_features - X.n_prefix
     if pad:
         parts.append(jnp.zeros((pad, r.shape[1]) if lanes else (pad,), f32))
@@ -1474,14 +1487,19 @@ def _sbell_matvec(X: ShardedBlockedEllRows, w):
     per-shard bucket einsums carry the shard axis, the reassembly gather
     vmaps over shards. Inside shard_map the solver never reaches this —
     `local()` routes to the single-device ops."""
-    hot = jnp.matmul(X.dense, w[:X.d_sel].astype(X.dense.dtype),
-                     preferred_element_type=jnp.float32)
+    with device_scope("xpass.fwd.hot"):
+        hot = jnp.matmul(X.dense, w[:X.d_sel].astype(X.dense.dtype),
+                         preferred_element_type=jnp.float32)
     lanes = w.ndim == 2
     S = X.n_shards
-    zero = jnp.zeros((S, 1, w.shape[1]) if lanes else (S, 1), jnp.float32)
-    cat = jnp.concatenate(_bell_tail(X, w) + [zero], axis=1)
-    tail = jax.vmap(lambda c, rp: c[rp])(cat, jnp.asarray(X.row_pos))
-    return hot + tail.reshape((X.dense.shape[0],) + w.shape[1:])
+    parts = _bell_tail(X, w)
+    with device_scope("xpass.fwd.reassemble"):
+        zero = jnp.zeros((S, 1, w.shape[1]) if lanes else (S, 1),
+                         jnp.float32)
+        cat = jnp.concatenate(parts + [zero], axis=1)
+        tail = jax.vmap(lambda c, rp: c[rp])(cat, jnp.asarray(X.row_pos))
+        tail = tail.reshape((X.dense.shape[0],) + w.shape[1:])
+    return hot + tail
 
 
 def _sbell_rmatvec(X: ShardedBlockedEllRows, r, square: bool = False):
@@ -1491,26 +1509,29 @@ def _sbell_rmatvec(X: ShardedBlockedEllRows, r, square: bool = False):
     f32 = jnp.float32
     S, n_local = X.n_shards, X.n_local
     lanes = r.ndim == 2
-    dense = X.dense * X.dense if square else X.dense
-    parts = [jnp.matmul(dense.T, r.astype(X.dense.dtype),
-                        preferred_element_type=f32)]
-    r2 = r.reshape((S, n_local) + r.shape[1:])
-    s_idx = jnp.arange(S)[:, None, None]
-    for br, bv in zip(X.bucket_rows, X.bucket_vals):
-        g = r2[s_idx, br]                      # (S, c_b, k_b[, G])
-        if square:
-            v = bv.astype(f32)
-            v, g = v * v, g.astype(f32)
-        else:
-            v, g = _bell_compute(bv, g)
-        eq = "sck,sckg->cg" if lanes else "sck,sck->c"
-        parts.append(jnp.einsum(eq, v, g, preferred_element_type=f32))
+    with device_scope("xpass.t.hot"):
+        dense = X.dense * X.dense if square else X.dense
+        parts = [jnp.matmul(dense.T, r.astype(X.dense.dtype),
+                            preferred_element_type=f32)]
+    with device_scope("xpass.t.tail"):
+        r2 = r.reshape((S, n_local) + r.shape[1:])
+        s_idx = jnp.arange(S)[:, None, None]
+        for br, bv in zip(X.bucket_rows, X.bucket_vals):
+            g = r2[s_idx, br]                      # (S, c_b, k_b[, G])
+            if square:
+                v = bv.astype(f32)
+                v, g = v * v, g.astype(f32)
+            else:
+                v, g = _bell_compute(bv, g)
+            eq = "sck,sckg->cg" if lanes else "sck,sck->c"
+            parts.append(jnp.einsum(eq, v, g, preferred_element_type=f32))
     pad = X.n_features - X.n_prefix
     if pad:
         parts.append(jnp.zeros((pad, r.shape[1]) if lanes else (pad,), f32))
     return jnp.concatenate(parts, axis=0)
 
 
+@device_scope("xpass.fwd")
 def matvec(X: Matrix, w: jax.Array) -> jax.Array:
     """X @ w -> (n,). The GLM margin hot path.
 
@@ -1557,6 +1578,7 @@ def matvec(X: Matrix, w: jax.Array) -> jax.Array:
     return jnp.matmul(X, w.astype(X.dtype), preferred_element_type=jnp.float32)
 
 
+@device_scope("xpass.t")
 def rmatvec(X: Matrix, r: jax.Array) -> jax.Array:
     """X^T @ r -> (d,). The gradient aggregation hot path (f32 accumulation,
     bf16-storage aware like matvec)."""
@@ -1591,6 +1613,7 @@ def rmatvec(X: Matrix, r: jax.Array) -> jax.Array:
     return jnp.matmul(X.T, r.astype(X.dtype), preferred_element_type=jnp.float32)
 
 
+@device_scope("xpass.fwd")
 def matvec_lanes(X: Matrix, W: jax.Array) -> jax.Array:
     """X @ W -> (n, G) for LANE-MINOR stacked coefficients W: (d, G).
 
@@ -1634,6 +1657,7 @@ def matvec_lanes(X: Matrix, W: jax.Array) -> jax.Array:
     return jnp.matmul(X, W.astype(X.dtype), preferred_element_type=jnp.float32)
 
 
+@device_scope("xpass.t")
 def rmatvec_lanes(X: Matrix, R: jax.Array) -> jax.Array:
     """X^T @ R -> (d, G) for lane-minor per-row cotangents R: (n, G).
 
@@ -1674,6 +1698,7 @@ def rmatvec_lanes(X: Matrix, R: jax.Array) -> jax.Array:
     return jnp.matmul(X.T, R.astype(X.dtype), preferred_element_type=jnp.float32)
 
 
+@device_scope("xpass.t")
 def sq_rmatvec(X: Matrix, r: jax.Array) -> jax.Array:
     """(X∘X)^T @ r -> (d,): Hessian diagonal building block.
 

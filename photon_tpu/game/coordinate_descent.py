@@ -453,6 +453,11 @@ def coordinate_descent(
                                 coeffs0, base, others, obj, lam,
                                 blocks_args, ds.X,
                                 jnp.asarray(ds.entity_dense), y, weights)
+                            # the ONE dispatch solved every block of the
+                            # coordinate (the pipelined loop in
+                            # RandomEffectCoordinate.train counts its own)
+                            telemetry.count("game_re.blocks",
+                                            len(blocks_args))
                             models[name] = RandomEffectModel(
                                 entity_name=ds.entity_name,
                                 feature_shard=ds.shard_name,

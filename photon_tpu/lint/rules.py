@@ -168,7 +168,9 @@ def telemetry_sync(ctx: Context) -> list:
     """Three-way sync between emitted counter/gauge/span literals, the
     ``telemetry.TELEMETRY_REGISTRY`` literal, and the telemetry
     docstring: emitted ⊆ registry, registry ⊆ emitted (no orphans), and
-    every registry name appears in the docstring."""
+    every registry name appears in the docstring. ``count_device`` is a
+    counter; the ``device_scope(...)`` literals are held to the
+    registry's ``device_scopes`` the same three ways."""
     tele_rel = "photon_tpu/telemetry/__init__.py"
     tele = ctx.get(tele_rel)
     if tele is None:
@@ -179,8 +181,10 @@ def telemetry_sync(ctx: Context) -> list:
     counters = tuple(registry.get("counters", ()))
     gauges = tuple(registry.get("gauges", ()))
     families = tuple(registry.get("span_families", ()))
+    scopes = tuple(registry.get("device_scopes", ()))
     out = []
     hit: dict = {e: False for e in counters + gauges}
+    scope_hit: dict = {e: False for e in scopes}
     fam_hit: dict = {f: False for f in families}
 
     def match(name: str, entries: tuple, prefix: bool) -> bool:
@@ -204,9 +208,22 @@ def telemetry_sync(ctx: Context) -> list:
                 lit = _str_const(pref_kw)
                 if lit and lit.split(".", 1)[0] in fam_hit:
                     fam_hit[lit.split(".", 1)[0]] = True
+            if name in ("device_scope", "telemetry.device_scope"):
+                lit = _str_const(call.args[0]) if call.args else None
+                if lit in scope_hit:
+                    scope_hit[lit] = True
+                elif lit is not None:
+                    out.append(Finding(
+                        "telemetry_sync", rel, call.lineno,
+                        f"device scope {lit!r} is not in "
+                        "TELEMETRY_REGISTRY['device_scopes']",
+                        key=f"scope:{lit}"))
+                continue
             kind = None
             if name in ("telemetry.count", "telemetry.gauge"):
                 kind = name.split(".")[1]
+            elif name == "telemetry.count_device":
+                kind = "count"
             elif in_tele_pkg and name in ("count", "gauge",
                                           "self.count", "self.gauge"):
                 kind = name.split(".")[-1]
@@ -272,6 +289,19 @@ def telemetry_sync(ctx: Context) -> list:
                 "telemetry/__init__ docstring — the documented registry "
                 "of counter names",
                 key=f"doc:{e}"))
+    for e in scopes:
+        if not scope_hit[e]:
+            out.append(Finding(
+                "telemetry_sync", tele_rel,
+                tele.literal_line("TELEMETRY_REGISTRY", e),
+                f"device scope {e!r} is registered but entered nowhere "
+                "in the package", key=f"scopeorphan:{e}"))
+        if e not in doc:
+            out.append(Finding(
+                "telemetry_sync", tele_rel,
+                tele.literal_line("TELEMETRY_REGISTRY", e),
+                f"device scope {e!r} does not appear in the "
+                "telemetry/__init__ docstring", key=f"scopedoc:{e}"))
     for fam in families:
         if not fam_hit[fam]:
             out.append(Finding(

@@ -271,6 +271,16 @@ def _matrix_dim(X) -> int:
             else X.shape[1])
 
 
+@partial(jax.jit, static_argnames=("scope",))
+def _reorder_columns(v, order, scope):
+    """``v[..., order]``: the move between model space and a permuted
+    layout's column order, (d,) or lane-major (G, d). The gather the eager
+    indexing ran, as a program of its own so that it carries a device
+    scope (a 10M-element gather is tens of milliseconds of every solve)."""
+    with telemetry.device_scope(scope):
+        return v[..., order]
+
+
 def _permuted_prep(X: PermutedHybridRows, w0, prior_mean, prior_precision,
                    norm):
     """Translate original-space side inputs into the permuted feature space
@@ -281,7 +291,8 @@ def _permuted_prep(X: PermutedHybridRows, w0, prior_mean, prior_precision,
     original space after `to_model_space`)."""
     import dataclasses as _dc
 
-    w0 = X.from_model_space(w0)
+    w0 = _reorder_columns(jnp.asarray(w0), jnp.asarray(X.perm_cols),
+                          "solve.prologue")
     if prior_mean is not None:
         prior_mean = X.from_model_space(prior_mean)
     if prior_precision is not None:
@@ -364,11 +375,22 @@ def _mesh_prep(batch: GLMBatch, w0, mesh: Mesh):
 def _lane_result(res) -> OptResult:
     """Transpose a lane-minor solver result (w (d, G), histories (T+1, G))
     to the public lane-MAJOR convention shared with the vmap path."""
-    return OptResult(
-        w=res.w.T, value=res.value, grad_norm=res.grad_norm,
-        iterations=res.iterations, converged=res.converged,
-        failed=res.failed, loss_history=res.loss_history.T,
-        grad_norm_history=res.grad_norm_history.T)
+    with telemetry.device_scope("solve.epilogue"):
+        return res._replace(w=res.w.T, loss_history=res.loss_history.T,
+                            grad_norm_history=res.grad_norm_history.T)
+
+
+def _count_solve(res: OptResult) -> None:
+    """The resident solves' `solver.*` counters, with the meanings the
+    streamed loops give them (optim/streamed.py): iterations in lock step
+    (the largest over lanes) and line-search trials. The values stay on
+    the device, and no op is dispatched to reduce them — `count_device`
+    reads them back and reduces on the host only when the run's report is
+    asked for, so a solve stays one asynchronous dispatch."""
+    telemetry.count_device("solver.iterations", res.iterations, reduce="max")
+    if res.evaluations is not None:
+        telemetry.count_device("solver.linesearch_trials", res.evaluations,
+                               reduce="max")
 
 
 def _lane_solve(obj, batch, w0, l2s, l1s, config):
@@ -666,12 +688,13 @@ def train_glm_grid(
             else:
                 res, var = _train_run_grid(batch, w0, obj, l2s, l1s,
                                            static_cfg, variance)
+    _count_solve(res)
     if permuted:
         # Back to original column order (one (G, d) device gather for the
         # whole sweep) before normalization unfolds / models assemble;
         # device_results callers get original-order coefficients too.
         inv = jnp.asarray(batch.X.inv_perm)
-        res = res._replace(w=res.w[:, inv])
+        res = res._replace(w=_reorder_columns(res.w, inv, "solve.epilogue"))
         if var is not None:
             var = var[:, inv]
     if device_results:
@@ -689,7 +712,10 @@ def train_glm_grid(
         if V is not None:
             V = norm.variances_to_original_space(V)
     for i in range(len(weights)):
-        lane = jax.tree_util.tree_map(lambda x, i=i: x[i], res)
+        # the lane-minor solvers' lock-step `evaluations` is one scalar
+        # for the sweep: every lane's result carries it whole
+        lane = jax.tree_util.tree_map(
+            lambda x, i=i: x[i] if x.ndim else x, res)
         model = GeneralizedLinearModel(
             Coefficients(W[i], None if V is None else V[i]), task)
         out.append((model, lane))
@@ -1010,11 +1036,13 @@ def train_glm(
                                 (batch, w0, obj, _l1_lam(config))):
             res, var = _train_run(batch, w0, obj, _l1_lam(config),
                                   _static_config(config), variance)
+    _count_solve(res)
     if permuted:
         # Back to original column order (one device gather) BEFORE the
         # normalization unfold — elementwise transforms commute with the
         # permutation, so the original-space context applies unchanged.
-        res = res._replace(w=batch.X.to_model_space(res.w))
+        res = res._replace(w=_reorder_columns(
+            res.w, jnp.asarray(batch.X.inv_perm), "solve.epilogue"))
         if var is not None:
             var = batch.X.to_model_space(var)
     w_out = res.w

@@ -31,6 +31,7 @@ from photon_tpu.data.dataset import GLMBatch
 from photon_tpu.data.matrix import matvec_lanes, rmatvec_lanes
 from photon_tpu.ops.losses import loss_fns
 from photon_tpu.ops.objective import Objective
+from photon_tpu.telemetry import device_scope
 
 
 def supports_lanes(obj: Objective) -> bool:
@@ -102,12 +103,13 @@ def phi_at_ray_lanes(obj: Objective, z, dz, a, coeffs, batch: GLMBatch):
     """(φ(a), φ'(a)) per lane from cached margins — one (n, G) elementwise
     pass + two (G,)-vector psums; zero passes over X. ``a``: (G,)."""
     loss, d1, _ = loss_fns(obj.task)
-    za = z + a[None, :] * dz
-    y = batch.y[:, None]
-    wt = batch.weights[:, None]
-    wl = wt * loss(za, y)
-    wd = wt * d1(za, y) * dz
-    f, dphi = obj._psum_many(jnp.sum(wl, axis=0), jnp.sum(wd, axis=0))
+    with device_scope("objective.loss"):
+        za = z + a[None, :] * dz
+        y = batch.y[:, None]
+        wt = batch.weights[:, None]
+        wl = wt * loss(za, y)
+        wd = wt * d1(za, y) * dz
+        f, dphi = obj._psum_many(jnp.sum(wl, axis=0), jnp.sum(wd, axis=0))
     c0, c1, c2 = coeffs
     return f + c0 + a * (c1 + 0.5 * a * c2), dphi + c1 + a * c2
 
@@ -137,9 +139,10 @@ def value_at_margin_lanes(obj: Objective, l2s, W, z, batch: GLMBatch):
     iteration), so paying value_and_grad's Xᵀ pass per trial would double
     the line search's X traffic for nothing."""
     loss, _, _ = loss_fns(obj.task)
-    y = batch.y[:, None]
-    wt = batch.weights[:, None]
-    value = obj._psum_many(jnp.sum(wt * loss(z, y), axis=0))[0]
+    with device_scope("objective.loss"):
+        y = batch.y[:, None]
+        wt = batch.weights[:, None]
+        value = obj._psum_many(jnp.sum(wt * loss(z, y), axis=0))[0]
     rv, _ = _reg_terms_lanes(obj, l2s, W)
     return value + rv
 
@@ -147,7 +150,8 @@ def value_at_margin_lanes(obj: Objective, l2s, W, z, batch: GLMBatch):
 def grad_at_margin_lanes(obj: Objective, l2s, W, z, batch: GLMBatch):
     """Per-lane gradient from cached margins — ONE lane-stacked Xᵀ pass."""
     _, d1, _ = loss_fns(obj.task)
-    r = batch.weights[:, None] * d1(z, batch.y[:, None])
+    with device_scope("objective.loss"):
+        r = batch.weights[:, None] * d1(z, batch.y[:, None])
     gX, gsum = _backprop_lanes(obj, batch, r)
     grad = _finish_backprop_lanes(obj, *obj._psum_many(gX, gsum))
     _, rg = _reg_terms_lanes(obj, l2s, W)
@@ -158,12 +162,13 @@ def value_and_grad_at_margin_lanes(obj: Objective, l2s, W, z,
                                    batch: GLMBatch):
     """(f (G,), g (d, G)) from cached margins."""
     loss, d1, _ = loss_fns(obj.task)
-    y = batch.y[:, None]
-    wt = batch.weights[:, None]
-    r = wt * d1(z, y)
+    with device_scope("objective.loss"):
+        y = batch.y[:, None]
+        wt = batch.weights[:, None]
+        r = wt * d1(z, y)
+        local_value = jnp.sum(wt * loss(z, y), axis=0)
     gX, gsum = _backprop_lanes(obj, batch, r)
-    value, gX, gsum = obj._psum_many(
-        jnp.sum(wt * loss(z, y), axis=0), gX, gsum)
+    value, gX, gsum = obj._psum_many(local_value, gX, gsum)
     grad = _finish_backprop_lanes(obj, gX, gsum)
     rv, rg = _reg_terms_lanes(obj, l2s, W)
     return value + rv, grad + rg

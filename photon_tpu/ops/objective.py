@@ -28,6 +28,7 @@ from photon_tpu.data.dataset import GLMBatch
 from photon_tpu.data.matrix import matvec, rmatvec, sq_rmatvec, weighted_gram
 from photon_tpu.ops.fused import can_fuse, fused_value_and_grad
 from photon_tpu.ops.losses import TaskType, loss_fns
+from photon_tpu.telemetry import device_scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -224,17 +225,19 @@ class Objective:
         a line-search trial is O(n) elementwise + scalars, with NO (d,)
         work at all."""
         loss, d1, _ = loss_fns(self.task)
-        za = z + a * dz
-        wl = batch.weights * loss(za, batch.y)
-        wd = batch.weights * d1(za, batch.y) * dz
-        f, dphi = self._psum_many(jnp.sum(wl), jnp.sum(wd))
+        with device_scope("objective.loss"):
+            za = z + a * dz
+            wl = batch.weights * loss(za, batch.y)
+            wd = batch.weights * d1(za, batch.y) * dz
+            f, dphi = self._psum_many(jnp.sum(wl), jnp.sum(wd))
         c0, c1, c2 = coeffs
         return f + c0 + a * (c1 + 0.5 * a * c2), dphi + c1 + a * c2
 
     def value_at_margin(self, w, z, batch: GLMBatch):
         """f(w) from a cached margin — elementwise only, no pass over X."""
         loss, _, _ = loss_fns(self.task)
-        value = self._psum(jnp.sum(batch.weights * loss(z, batch.y)))
+        with device_scope("objective.loss"):
+            value = self._psum(jnp.sum(batch.weights * loss(z, batch.y)))
         rv, _ = self._reg_terms(w)
         return value + rv
 
@@ -254,7 +257,8 @@ class Objective:
     def grad_at_margin(self, w, z, batch: GLMBatch):
         """Full gradient from a cached margin — ONE pass over X (Xᵀr)."""
         _, d1, _ = loss_fns(self.task)
-        r = batch.weights * d1(z, batch.y)
+        with device_scope("objective.loss"):
+            r = batch.weights * d1(z, batch.y)
         gX, gsum = self._backprop(batch, r)
         grad = self._finish_backprop(*self._psum_many(gX, gsum))
         _, rg = self._reg_terms(w)
@@ -263,10 +267,11 @@ class Objective:
     def value_and_grad_at_margin(self, w, z, batch: GLMBatch):
         """(f, g) from a cached margin — one elementwise pass + one Xᵀr."""
         loss, d1, _ = loss_fns(self.task)
-        r = batch.weights * d1(z, batch.y)
+        with device_scope("objective.loss"):
+            r = batch.weights * d1(z, batch.y)
+            local_value = jnp.sum(batch.weights * loss(z, batch.y))
         gX, gsum = self._backprop(batch, r)
-        value, gX, gsum = self._psum_many(
-            jnp.sum(batch.weights * loss(z, batch.y)), gX, gsum)
+        value, gX, gsum = self._psum_many(local_value, gX, gsum)
         grad = self._finish_backprop(gX, gsum)
         rv, rg = self._reg_terms(w)
         return value + rv, grad + rg

@@ -35,6 +35,7 @@ from photon_tpu.ops import lane_objective as lo
 from photon_tpu.optim.lbfgs import _convergence
 from photon_tpu.optim.linesearch import C1, C2, _cubic_min
 from photon_tpu.optim.tracker import OptResult
+from photon_tpu.telemetry import device_scope
 
 _Z_REFRESH = 64  # as optim.lbfgs: margin re-derivation period
 
@@ -64,7 +65,8 @@ def wolfe_line_search_lanes(
     """Per-lane strong-Wolfe search, lock-step: every loop iteration
     evaluates phi once for ALL lanes (one (n, G) elementwise pass); lanes
     that satisfy Wolfe freeze while the rest keep bracketing/zooming.
-    Returns (alpha, f_alpha, ok), each (G,).
+    Returns (alpha, f_alpha, ok), each (G,), and the lock-step number of
+    `phi` evaluations taken, ().
 
     ``done0``: lanes already finished in the OUTER solver — seeded as done
     so a converged lane's frozen state can't drag every remaining search to
@@ -154,9 +156,10 @@ def wolfe_line_search_lanes(
     # Seeded-done lanes stay ok=False (alpha 0, nothing accepted) — the
     # caller's own done mask is what keeps them frozen.
     ok = (out.done & ~done_init) | (out.a_star > 0.0)
-    return out.a_star, out.f_star, ok
+    return out.a_star, out.f_star, ok, out.i
 
 
+@device_scope("lbfgs.two_loop")
 def two_loop_lanes(g, S, Y, rho, valid, idx, sy, yy):
     """H·g per lane over the rotating history. g: (d, G); S/Y: (m, d, G);
     rho/valid/sy/yy: (m, G); idx: () next write slot. Invalid (slot, lane)
@@ -211,6 +214,7 @@ def two_loop_lanes(g, S, Y, rho, valid, idx, sy, yy):
     return lax.fori_loop(0, m, fwd, r)
 
 
+@device_scope("lbfgs.push")
 def _push_lanes(S, Y, rho, valid, idx, s, y, accept, SY, YY):
     """Write (s, y) into the rotating slot for lanes where ``accept`` holds
     AND the curvature condition passes; other lanes' slot goes invalid. The
@@ -246,6 +250,7 @@ class _LaneState(NamedTuple):
     valid: jax.Array   # (m, G)
     idx: jax.Array     # () rotating write slot
     it: jax.Array      # () global iteration counter
+    evals: jax.Array   # () lock-step line-search evaluations so far
     its: jax.Array     # (G,) per-lane iterations taken
     done: jax.Array    # (G,)
     converged: jax.Array
@@ -285,41 +290,45 @@ def minimize_lbfgs_margin_lanes(
     dtype = W0.dtype
     hdtype = jnp.dtype(history_dtype) if history_dtype is not None else dtype
 
-    z0 = lo.margin_lanes(obj, W0, batch)
-    f0, g0 = lo.value_and_grad_at_margin_lanes(obj, l2s, W0, z0, batch)
-    g0norm = jnp.sqrt(jnp.sum(g0 * g0, axis=0))
-
-    hist0 = jnp.full((max_iters + 1, G), jnp.nan, dtype).at[0].set(f0)
-    ghist0 = jnp.full((max_iters + 1, G), jnp.nan, dtype).at[0].set(g0norm)
+    with device_scope("solve.prologue"):
+        z0 = lo.margin_lanes(obj, W0, batch)
+        f0, g0 = lo.value_and_grad_at_margin_lanes(obj, l2s, W0, z0, batch)
+        g0norm = jnp.sqrt(jnp.sum(g0 * g0, axis=0))
+        hist0 = jnp.full((max_iters + 1, G), jnp.nan, dtype).at[0].set(f0)
+        ghist0 = jnp.full((max_iters + 1, G), jnp.nan,
+                          dtype).at[0].set(g0norm)
 
     def cond(s: _LaneState):
         return jnp.any(~s.done) & (s.it < max_iters)
 
     def body(s: _LaneState):
         active = ~s.done
-        D = -two_loop_lanes(s.g, s.S, s.Y, s.rho, s.valid, s.idx,
+        hg = two_loop_lanes(s.g, s.S, s.Y, s.rho, s.valid, s.idx,
                             s.sy, s.yy)
-        dphi0 = jnp.sum(D * s.g, axis=0)
-        bad_dir = dphi0 >= 0.0
-        D = jnp.where(bad_dir[None, :], -s.g, D)
-        dphi0 = jnp.where(bad_dir, -jnp.sum(s.g * s.g, axis=0), dphi0)
+        with device_scope("lbfgs.direction"):
+            D = -hg
+            dphi0 = jnp.sum(D * s.g, axis=0)
+            bad_dir = dphi0 >= 0.0
+            D = jnp.where(bad_dir[None, :], -s.g, D)
+            dphi0 = jnp.where(bad_dir, -jnp.sum(s.g * s.g, axis=0), dphi0)
+            has_hist = jnp.any(s.valid, axis=0)
+            dnorm = jnp.sqrt(jnp.sum(D * D, axis=0))
+            a_init = jnp.where(has_hist, 1.0, 1.0 / jnp.maximum(dnorm, 1.0))
+            ray = lo.ray_reg_coeffs_lanes(obj, l2s, s.W, D)
 
         dz = lo.direction_margin_lanes(obj, D, batch)      # X pass 1
-        ray = lo.ray_reg_coeffs_lanes(obj, l2s, s.W, D)
 
         def phi(a):
             return lo.phi_at_ray_lanes(obj, s.z, dz, a, ray, batch)
 
-        has_hist = jnp.any(s.valid, axis=0)
-        dnorm = jnp.sqrt(jnp.sum(D * D, axis=0))
-        a_init = jnp.where(has_hist, 1.0, 1.0 / jnp.maximum(dnorm, 1.0))
-        alpha, f_star, ok = wolfe_line_search_lanes(phi, s.f, dphi0, a_init,
-                                                    max_ls_evals,
-                                                    done0=s.done)
+        with device_scope("lbfgs.linesearch"):
+            alpha, f_star, ok, ls_evals = wolfe_line_search_lanes(
+                phi, s.f, dphi0, a_init, max_ls_evals, done0=s.done)
 
-        step = active & ok
-        W_new = jnp.where(step[None, :], s.W + alpha[None, :] * D, s.W)
-        z_new = jnp.where(step[None, :], s.z + alpha[None, :] * dz, s.z)
+        with device_scope("lbfgs.update"):
+            step = active & ok
+            W_new = jnp.where(step[None, :], s.W + alpha[None, :] * D, s.W)
+            z_new = jnp.where(step[None, :], s.z + alpha[None, :] * dz, s.z)
         # Periodic margin re-derivation (f32 drift control): a scalar-pred
         # cond — this solver is never vmapped, so the branch stays a real
         # branch and non-refresh iterations pay nothing.
@@ -328,45 +337,54 @@ def minimize_lbfgs_margin_lanes(
             lambda: lo.margin_lanes(obj, W_new, batch),
             lambda: z_new,
         )
-        f_new = jnp.where(step, f_star, s.f)
-        g_new = jnp.where(                                  # X pass 2
-            step[None, :],
-            lo.grad_at_margin_lanes(obj, l2s, W_new, z_new, batch), s.g)
+        g_new = lo.grad_at_margin_lanes(obj, l2s, W_new, z_new,
+                                        batch)              # X pass 2
+        with device_scope("lbfgs.update"):
+            f_new = jnp.where(step, f_star, s.f)
+            g_new = jnp.where(step[None, :], g_new, s.g)
 
         S, Y, rho, valid, idx, sy, yy = _push_lanes(
             s.S, s.Y, s.rho, s.valid, s.idx, W_new - s.W, g_new - s.g, step,
             s.sy, s.yy)
 
-        gnorm = jnp.sqrt(jnp.sum(g_new * g_new, axis=0))
-        converged = _convergence(ok, s.f, f_new, gnorm, g0norm, dphi0,
-                                 tolerance, dtype)
-        it = s.it + 1
-        its = jnp.where(active, s.its + 1, s.its)
-        return _LaneState(
-            W=W_new, z=z_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho,
-            sy=sy, yy=yy, valid=valid, idx=idx, it=it, its=its,
-            done=s.done | (active & (converged | ~ok)),
-            converged=jnp.where(active, converged, s.converged),
-            failed=s.failed | (active & ~ok & ~converged),
-            hist=s.hist.at[it].set(jnp.where(active, f_new, s.hist[it])),
-            ghist=s.ghist.at[it].set(jnp.where(active, gnorm, s.ghist[it])),
-        )
+        with device_scope("lbfgs.update"):
+            gnorm = jnp.sqrt(jnp.sum(g_new * g_new, axis=0))
+            converged = _convergence(ok, s.f, f_new, gnorm, g0norm, dphi0,
+                                     tolerance, dtype)
+            it = s.it + 1
+            its = jnp.where(active, s.its + 1, s.its)
+            return _LaneState(
+                W=W_new, z=z_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho,
+                sy=sy, yy=yy, valid=valid, idx=idx, it=it,
+                evals=s.evals + ls_evals, its=its,
+                done=s.done | (active & (converged | ~ok)),
+                converged=jnp.where(active, converged, s.converged),
+                failed=s.failed | (active & ~ok & ~converged),
+                hist=s.hist.at[it].set(
+                    jnp.where(active, f_new, s.hist[it])),
+                ghist=s.ghist.at[it].set(
+                    jnp.where(active, gnorm, s.ghist[it])),
+            )
 
-    init = _LaneState(
-        W=W0, z=z0, f=f0, g=g0,
-        S=jnp.zeros((m, d, G), hdtype), Y=jnp.zeros((m, d, G), hdtype),
-        rho=jnp.zeros((m, G), dtype), sy=jnp.zeros((m, G), dtype),
-        yy=jnp.zeros((m, G), dtype), valid=jnp.zeros((m, G), bool),
-        idx=jnp.zeros((), jnp.int32), it=jnp.zeros((), jnp.int32),
-        its=jnp.zeros((G,), jnp.int32),
-        done=g0norm <= 1e-14, converged=g0norm <= 1e-14,
-        failed=jnp.zeros((G,), bool),
-        hist=hist0, ghist=ghist0,
-    )
+    with device_scope("solve.prologue"):
+        init = _LaneState(
+            W=W0, z=z0, f=f0, g=g0,
+            S=jnp.zeros((m, d, G), hdtype), Y=jnp.zeros((m, d, G), hdtype),
+            rho=jnp.zeros((m, G), dtype), sy=jnp.zeros((m, G), dtype),
+            yy=jnp.zeros((m, G), dtype), valid=jnp.zeros((m, G), bool),
+            idx=jnp.zeros((), jnp.int32), it=jnp.zeros((), jnp.int32),
+            evals=jnp.zeros((), jnp.int32),
+            its=jnp.zeros((G,), jnp.int32),
+            done=g0norm <= 1e-14, converged=g0norm <= 1e-14,
+            failed=jnp.zeros((G,), bool),
+            hist=hist0, ghist=ghist0,
+        )
     out = lax.while_loop(cond, body, init)
-    return OptResult(
-        w=out.W, value=out.f,
-        grad_norm=jnp.sqrt(jnp.sum(out.g * out.g, axis=0)),
-        iterations=out.its, converged=out.converged, failed=out.failed,
-        loss_history=out.hist, grad_norm_history=out.ghist,
-    )
+    with device_scope("solve.epilogue"):
+        return OptResult(
+            w=out.W, value=out.f,
+            grad_norm=jnp.sqrt(jnp.sum(out.g * out.g, axis=0)),
+            iterations=out.its, converged=out.converged, failed=out.failed,
+            loss_history=out.hist, grad_norm_history=out.ghist,
+            evaluations=out.evals,
+        )

@@ -69,6 +69,7 @@ class _LaneState(NamedTuple):
     valid: jax.Array   # (m, G)
     idx: jax.Array     # () rotating write slot
     it: jax.Array
+    evals: jax.Array   # () lock-step line-search evaluations so far
     its: jax.Array     # (G,)
     done: jax.Array    # (G,)
     converged: jax.Array
@@ -210,7 +211,8 @@ def minimize_owlqn_lanes(
         its = jnp.where(active, s.its + 1, s.its)
         return _LaneState(
             W=W_new, z=z_new, f=f_new, F=F_new, g=g_new, S=S, Y=Y, rho=rho,
-            sy=sy, yy=yy, valid=valid, idx=idx, it=it, its=its,
+            sy=sy, yy=yy, valid=valid, idx=idx, it=it,
+            evals=s.evals + ls.i, its=its,
             done=s.done | (active & (converged | ~ls.succ)),
             converged=jnp.where(active, converged, s.converged),
             failed=s.failed | (active & ~ls.succ & ~converged),
@@ -224,6 +226,7 @@ def minimize_owlqn_lanes(
         rho=jnp.zeros((m, G), dtype), sy=jnp.zeros((m, G), dtype),
         yy=jnp.zeros((m, G), dtype), valid=jnp.zeros((m, G), bool),
         idx=jnp.zeros((), jnp.int32), it=jnp.zeros((), jnp.int32),
+        evals=jnp.zeros((), jnp.int32),
         its=jnp.zeros((G,), jnp.int32),
         done=pg0norm <= 1e-14, converged=pg0norm <= 1e-14,
         failed=jnp.zeros((G,), bool),
@@ -236,4 +239,5 @@ def minimize_owlqn_lanes(
         grad_norm=jnp.sqrt(jnp.sum(pg_fin * pg_fin, axis=0)),
         iterations=out.its, converged=out.converged, failed=out.failed,
         loss_history=out.hist, grad_norm_history=out.ghist,
+        evaluations=out.evals,
     )

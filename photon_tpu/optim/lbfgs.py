@@ -23,6 +23,9 @@ from photon_tpu.parallel.mesh import vary_like
 # no-op (absent from the jaxpr) unless a Run(resident_tap=True) is
 # attached at trace time — the telemetry_off_is_free contract pins that.
 from photon_tpu.telemetry.taps import solver_tap
+# Named device scopes (op metadata only, always on): a profiler trace puts
+# device time to these phases inside the one compiled solve.
+from photon_tpu.telemetry import device_scope
 # Opt-in resident last-iterate checkpoint tap: same compiled-out-by-
 # default story (the checkpoint_off_is_free contract pins it).
 from photon_tpu.checkpoint.taps import snapshot_tap
@@ -40,6 +43,7 @@ class _State(NamedTuple):
     idx: jax.Array  # next slot to write
     count: jax.Array  # valid pairs
     it: jax.Array
+    evals: jax.Array  # line-search evaluations taken so far
     done: jax.Array
     converged: jax.Array
     failed: jax.Array
@@ -47,6 +51,7 @@ class _State(NamedTuple):
     ghist: jax.Array
 
 
+@device_scope("lbfgs.two_loop")
 def two_loop(g, S, Y, rho, idx, count, sy, yy):
     """H·g approximation via the two-loop recursion over a circular buffer.
     Invalid slots are masked, so shapes never change.
@@ -81,6 +86,7 @@ def two_loop(g, S, Y, rho, idx, count, sy, yy):
     return lax.fori_loop(0, m, fwd, r)
 
 
+@device_scope("lbfgs.push")
 def _push(S, Y, rho, idx, count, s, y, sy_c, yy_c):
     """Append an (s, y) pair; skip it if the curvature condition fails
     (sᵀy too small), as Breeze does. ``sy_c``/``yy_c`` carry the newest
@@ -129,79 +135,91 @@ def minimize_lbfgs(
     dtype = w0.dtype
     d = w0.shape[0]
     m = history
-    f0, g0 = value_and_grad(w0)
-    g0norm = jnp.linalg.norm(g0)
-
-    hist0 = jnp.full((max_iters + 1,), jnp.nan, dtype).at[0].set(f0)
-    ghist0 = jnp.full((max_iters + 1,), jnp.nan, dtype).at[0].set(g0norm)
+    with device_scope("solve.prologue"):
+        f0, g0 = value_and_grad(w0)
+        g0norm = jnp.linalg.norm(g0)
+        hist0 = jnp.full((max_iters + 1,), jnp.nan, dtype).at[0].set(f0)
+        ghist0 = jnp.full((max_iters + 1,), jnp.nan,
+                          dtype).at[0].set(g0norm)
 
     def cond(s: _State):
         return (~s.done) & (s.it < max_iters)
 
     def body(s: _State):
-        direction = -two_loop(s.g, s.S, s.Y, s.rho, s.idx, s.count,
-                              s.sy, s.yy)
-        dphi0 = jnp.dot(direction, s.g)
-        # Safeguard: fall back to steepest descent if not a descent direction.
-        bad_dir = dphi0 >= 0.0
-        direction = jnp.where(bad_dir, -s.g, direction)
-        dphi0 = jnp.where(bad_dir, -jnp.dot(s.g, s.g), dphi0)
+        hg = two_loop(s.g, s.S, s.Y, s.rho, s.idx, s.count, s.sy, s.yy)
+        with device_scope("lbfgs.direction"):
+            direction = -hg
+            dphi0 = jnp.dot(direction, s.g)
+            # Safeguard: fall back to steepest descent if not a descent
+            # direction.
+            bad_dir = dphi0 >= 0.0
+            direction = jnp.where(bad_dir, -s.g, direction)
+            dphi0 = jnp.where(bad_dir, -jnp.dot(s.g, s.g), dphi0)
+            a_init = jnp.where(
+                s.count > 0, 1.0,
+                1.0 / jnp.maximum(jnp.linalg.norm(direction), 1.0))
 
         def phi(a):
             f, g = value_and_grad(s.w + a * direction)
             return f, jnp.dot(g, direction)
 
-        a_init = jnp.where(s.count > 0, 1.0,
-                           1.0 / jnp.maximum(jnp.linalg.norm(direction), 1.0))
-        alpha, _, ok = wolfe_line_search(phi, s.f, dphi0, a_init, max_ls_evals)
+        with device_scope("lbfgs.linesearch"):
+            alpha, _, ok, ls_evals = wolfe_line_search(
+                phi, s.f, dphi0, a_init, max_ls_evals)
 
-        w_new = s.w + alpha * direction
-        f_new, g_new = value_and_grad(w_new)
-        # A failed line search keeps the iterate and terminates (the
-        # reference surfaces Breeze's line-search failure the same way).
-        w_new = jnp.where(ok, w_new, s.w)
-        f_new = jnp.where(ok, f_new, s.f)
-        g_new = jnp.where(ok, g_new, s.g)
+        with device_scope("lbfgs.update"):
+            w_new = s.w + alpha * direction
+            f_new, g_new = value_and_grad(w_new)
+            # A failed line search keeps the iterate and terminates (the
+            # reference surfaces Breeze's line-search failure the same way).
+            w_new = jnp.where(ok, w_new, s.w)
+            f_new = jnp.where(ok, f_new, s.f)
+            g_new = jnp.where(ok, g_new, s.g)
 
         S, Y, rho, idx, count, sy, yy = _push(
             s.S, s.Y, s.rho, s.idx, s.count, w_new - s.w, g_new - s.g,
             s.sy, s.yy
         )
 
-        gnorm = jnp.linalg.norm(g_new)
-        converged = _convergence(ok, s.f, f_new, gnorm, g0norm, dphi0,
-                                 tolerance, dtype)
-        it = s.it + 1
-        solver_tap("lbfgs", it, f_new, gnorm, jnp.where(ok, alpha, 0.0))
-        snapshot_tap("lbfgs", it, w_new, f_new, gnorm)
-        return _State(
-            w=w_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho, sy=sy, yy=yy,
-            idx=idx, count=count, it=it, done=converged | ~ok,
-            converged=converged, failed=s.failed | (~ok & ~converged),
-            hist=s.hist.at[it].set(f_new),
-            ghist=s.ghist.at[it].set(gnorm),
-        )
+        with device_scope("lbfgs.update"):
+            gnorm = jnp.linalg.norm(g_new)
+            converged = _convergence(ok, s.f, f_new, gnorm, g0norm, dphi0,
+                                     tolerance, dtype)
+            it = s.it + 1
+            solver_tap("lbfgs", it, f_new, gnorm, jnp.where(ok, alpha, 0.0))
+            snapshot_tap("lbfgs", it, w_new, f_new, gnorm)
+            return _State(
+                w=w_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho, sy=sy, yy=yy,
+                idx=idx, count=count, it=it, evals=s.evals + ls_evals,
+                done=converged | ~ok,
+                converged=converged, failed=s.failed | (~ok & ~converged),
+                hist=s.hist.at[it].set(f_new),
+                ghist=s.ghist.at[it].set(gnorm),
+            )
 
     solver_tap("lbfgs", 0, f0, g0norm)
-    init = vary_like(_State(
-        w=w0, f=f0, g=g0,
-        S=jnp.zeros((m, d), dtype), Y=jnp.zeros((m, d), dtype),
-        rho=jnp.zeros((m,), dtype),
-        sy=jnp.zeros((), dtype), yy=jnp.zeros((), dtype),
-        idx=jnp.zeros((), jnp.int32), count=jnp.zeros((), jnp.int32),
-        it=jnp.zeros((), jnp.int32),
-        done=g0norm <= 1e-14,
-        converged=g0norm <= 1e-14,
-        failed=jnp.zeros((), bool),
-        hist=hist0,
-        ghist=ghist0,
-    ), w0, g0)
+    with device_scope("solve.prologue"):
+        init = vary_like(_State(
+            w=w0, f=f0, g=g0,
+            S=jnp.zeros((m, d), dtype), Y=jnp.zeros((m, d), dtype),
+            rho=jnp.zeros((m,), dtype),
+            sy=jnp.zeros((), dtype), yy=jnp.zeros((), dtype),
+            idx=jnp.zeros((), jnp.int32), count=jnp.zeros((), jnp.int32),
+            it=jnp.zeros((), jnp.int32), evals=jnp.zeros((), jnp.int32),
+            done=g0norm <= 1e-14,
+            converged=g0norm <= 1e-14,
+            failed=jnp.zeros((), bool),
+            hist=hist0,
+            ghist=ghist0,
+        ), w0, g0)
     out = lax.while_loop(cond, body, init)
-    return OptResult(
-        w=out.w, value=out.f, grad_norm=jnp.linalg.norm(out.g),
-        iterations=out.it, converged=out.converged, failed=out.failed,
-        loss_history=out.hist, grad_norm_history=out.ghist,
-    )
+    with device_scope("solve.epilogue"):
+        return OptResult(
+            w=out.w, value=out.f, grad_norm=jnp.linalg.norm(out.g),
+            iterations=out.it, converged=out.converged, failed=out.failed,
+            loss_history=out.hist, grad_norm_history=out.ghist,
+            evaluations=out.evals,
+        )
 
 
 # Refresh the chained margin from w every this many iterations (f32 drift
@@ -222,6 +240,7 @@ class _MarginState(NamedTuple):
     idx: jax.Array
     count: jax.Array
     it: jax.Array
+    evals: jax.Array  # line-search evaluations taken so far
     done: jax.Array
     converged: jax.Array
     failed: jax.Array
@@ -258,39 +277,45 @@ def minimize_lbfgs_margin(
     dtype = w0.dtype
     d = w0.shape[0]
     m = history
-    z0 = obj.margin(w0, batch)
-    f0, g0 = obj.value_and_grad_at_margin(w0, z0, batch)
-    g0norm = jnp.linalg.norm(g0)
-
-    hist0 = jnp.full((max_iters + 1,), jnp.nan, dtype).at[0].set(f0)
-    ghist0 = jnp.full((max_iters + 1,), jnp.nan, dtype).at[0].set(g0norm)
+    with device_scope("solve.prologue"):
+        z0 = obj.margin(w0, batch)
+        f0, g0 = obj.value_and_grad_at_margin(w0, z0, batch)
+        g0norm = jnp.linalg.norm(g0)
+        hist0 = jnp.full((max_iters + 1,), jnp.nan, dtype).at[0].set(f0)
+        ghist0 = jnp.full((max_iters + 1,), jnp.nan,
+                          dtype).at[0].set(g0norm)
 
     def cond(s: _MarginState):
         return (~s.done) & (s.it < max_iters)
 
     def body(s: _MarginState):
-        direction = -two_loop(s.g, s.S, s.Y, s.rho, s.idx, s.count,
-                              s.sy, s.yy)
-        dphi0 = jnp.dot(direction, s.g)
-        bad_dir = dphi0 >= 0.0
-        direction = jnp.where(bad_dir, -s.g, direction)
-        dphi0 = jnp.where(bad_dir, -jnp.dot(s.g, s.g), dphi0)
+        hg = two_loop(s.g, s.S, s.Y, s.rho, s.idx, s.count, s.sy, s.yy)
+        with device_scope("lbfgs.direction"):
+            direction = -hg
+            dphi0 = jnp.dot(direction, s.g)
+            bad_dir = dphi0 >= 0.0
+            direction = jnp.where(bad_dir, -s.g, direction)
+            dphi0 = jnp.where(bad_dir, -jnp.dot(s.g, s.g), dphi0)
+            a_init = jnp.where(
+                s.count > 0, 1.0,
+                1.0 / jnp.maximum(jnp.linalg.norm(direction), 1.0))
+
+            # One O(d) pass for the regularizer's ray coefficients; every
+            # Wolfe trial below is then O(n) elementwise with zero (d,) work.
+            ray = obj.ray_reg_coeffs(s.w, direction)
 
         dz = obj.direction_margin(direction, batch)  # X pass 1
-        # One O(d) pass for the regularizer's ray coefficients; every Wolfe
-        # trial below is then O(n) elementwise with zero (d,) work.
-        ray = obj.ray_reg_coeffs(s.w, direction)
 
         def phi(a):
             return obj.phi_at_ray(s.z, dz, a, ray, batch)
 
-        a_init = jnp.where(s.count > 0, 1.0,
-                           1.0 / jnp.maximum(jnp.linalg.norm(direction), 1.0))
-        alpha, f_star, ok = wolfe_line_search(phi, s.f, dphi0, a_init,
-                                              max_ls_evals)
+        with device_scope("lbfgs.linesearch"):
+            alpha, f_star, ok, ls_evals = wolfe_line_search(
+                phi, s.f, dphi0, a_init, max_ls_evals)
 
-        w_new = jnp.where(ok, s.w + alpha * direction, s.w)
-        z_new = jnp.where(ok, s.z + alpha * dz, s.z)
+        with device_scope("lbfgs.update"):
+            w_new = jnp.where(ok, s.w + alpha * direction, s.w)
+            z_new = jnp.where(ok, s.z + alpha * dz, s.z)
         # The chained z accumulates f32 drift vs margin(w); refresh it from
         # w periodically (one extra X pass every _Z_REFRESH iters) so long
         # tight-tolerance solves converge on the true objective. lax.cond
@@ -305,48 +330,54 @@ def minimize_lbfgs_margin(
                 lambda: obj.margin(w_new, batch),
                 lambda: z_new,
             )
-        f_new = jnp.where(ok, f_star, s.f)
-        g_new = jnp.where(ok, obj.grad_at_margin(w_new, z_new, batch),  # X pass 2
-                          s.g)
+        g_new = obj.grad_at_margin(w_new, z_new, batch)  # X pass 2
+        with device_scope("lbfgs.update"):
+            f_new = jnp.where(ok, f_star, s.f)
+            g_new = jnp.where(ok, g_new, s.g)
 
         S, Y, rho, idx, count, sy, yy = _push(
             s.S, s.Y, s.rho, s.idx, s.count, w_new - s.w, g_new - s.g,
             s.sy, s.yy
         )
 
-        gnorm = jnp.linalg.norm(g_new)
-        converged = _convergence(ok, s.f, f_new, gnorm, g0norm, dphi0,
-                                 tolerance, dtype)
-        it = s.it + 1
-        solver_tap("lbfgs_margin", it, f_new, gnorm,
-                   jnp.where(ok, alpha, 0.0))
-        snapshot_tap("lbfgs_margin", it, w_new, f_new, gnorm)
-        return _MarginState(
-            w=w_new, z=z_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho,
-            sy=sy, yy=yy, idx=idx,
-            count=count, it=it, done=converged | ~ok,
-            converged=converged, failed=s.failed | (~ok & ~converged),
-            hist=s.hist.at[it].set(f_new),
-            ghist=s.ghist.at[it].set(gnorm),
-        )
+        with device_scope("lbfgs.update"):
+            gnorm = jnp.linalg.norm(g_new)
+            converged = _convergence(ok, s.f, f_new, gnorm, g0norm, dphi0,
+                                     tolerance, dtype)
+            it = s.it + 1
+            solver_tap("lbfgs_margin", it, f_new, gnorm,
+                       jnp.where(ok, alpha, 0.0))
+            snapshot_tap("lbfgs_margin", it, w_new, f_new, gnorm)
+            return _MarginState(
+                w=w_new, z=z_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho,
+                sy=sy, yy=yy, idx=idx,
+                count=count, it=it, evals=s.evals + ls_evals,
+                done=converged | ~ok,
+                converged=converged, failed=s.failed | (~ok & ~converged),
+                hist=s.hist.at[it].set(f_new),
+                ghist=s.ghist.at[it].set(gnorm),
+            )
 
     solver_tap("lbfgs_margin", 0, f0, g0norm)
-    init = vary_like(_MarginState(
-        w=w0, z=z0, f=f0, g=g0,
-        S=jnp.zeros((m, d), dtype), Y=jnp.zeros((m, d), dtype),
-        rho=jnp.zeros((m,), dtype),
-        sy=jnp.zeros((), dtype), yy=jnp.zeros((), dtype),
-        idx=jnp.zeros((), jnp.int32), count=jnp.zeros((), jnp.int32),
-        it=jnp.zeros((), jnp.int32),
-        done=g0norm <= 1e-14,
-        converged=g0norm <= 1e-14,
-        failed=jnp.zeros((), bool),
-        hist=hist0,
-        ghist=ghist0,
-    ), w0, g0)
+    with device_scope("solve.prologue"):
+        init = vary_like(_MarginState(
+            w=w0, z=z0, f=f0, g=g0,
+            S=jnp.zeros((m, d), dtype), Y=jnp.zeros((m, d), dtype),
+            rho=jnp.zeros((m,), dtype),
+            sy=jnp.zeros((), dtype), yy=jnp.zeros((), dtype),
+            idx=jnp.zeros((), jnp.int32), count=jnp.zeros((), jnp.int32),
+            it=jnp.zeros((), jnp.int32), evals=jnp.zeros((), jnp.int32),
+            done=g0norm <= 1e-14,
+            converged=g0norm <= 1e-14,
+            failed=jnp.zeros((), bool),
+            hist=hist0,
+            ghist=ghist0,
+        ), w0, g0)
     out = lax.while_loop(cond, body, init)
-    return OptResult(
-        w=out.w, value=out.f, grad_norm=jnp.linalg.norm(out.g),
-        iterations=out.it, converged=out.converged, failed=out.failed,
-        loss_history=out.hist, grad_norm_history=out.ghist,
-    )
+    with device_scope("solve.epilogue"):
+        return OptResult(
+            w=out.w, value=out.f, grad_norm=jnp.linalg.norm(out.g),
+            iterations=out.it, converged=out.converged, failed=out.failed,
+            loss_history=out.hist, grad_norm_history=out.ghist,
+            evaluations=out.evals,
+        )

@@ -66,7 +66,8 @@ def wolfe_line_search(
     a_init=1.0,
     max_evals: int = 12,
 ):
-    """Returns (alpha, f_alpha, ok). alpha = 0 and ok = False on failure."""
+    """Returns (alpha, f_alpha, ok, evals). alpha = 0 and ok = False on
+    failure; evals is the number of `phi` evaluations taken."""
     f0 = jnp.asarray(f0)
     dtype = f0.dtype
     dphi0 = jnp.asarray(dphi0, dtype)
@@ -148,4 +149,4 @@ def wolfe_line_search(
     ), f0, dphi0)
     out = lax.while_loop(cond, body, init)
     ok = out.done | (out.a_star > 0.0)
-    return out.a_star, out.f_star, ok
+    return out.a_star, out.f_star, ok, out.i

@@ -52,6 +52,7 @@ class _State(NamedTuple):
     idx: jax.Array
     count: jax.Array
     it: jax.Array
+    evals: jax.Array  # line-search evaluations taken so far
     done: jax.Array
     converged: jax.Array
     failed: jax.Array
@@ -173,7 +174,8 @@ def minimize_owlqn(
         return _State(
             w=w_new, f=f_new, F=F_new, g=g_new, S=S, Y=Y, rho=rho,
             sy=sy, yy=yy, idx=idx,
-            count=count, it=it, done=converged | ~ok, converged=converged,
+            count=count, it=it, evals=s.evals + ls.i,
+            done=converged | ~ok, converged=converged,
             failed=s.failed | (~ok & ~converged),
             hist=s.hist.at[it].set(F_new),
             ghist=s.ghist.at[it].set(pgnorm),
@@ -186,7 +188,7 @@ def minimize_owlqn(
         rho=jnp.zeros((m,), dtype),
         sy=jnp.zeros((), dtype), yy=jnp.zeros((), dtype),
         idx=jnp.zeros((), jnp.int32), count=jnp.zeros((), jnp.int32),
-        it=jnp.zeros((), jnp.int32),
+        it=jnp.zeros((), jnp.int32), evals=jnp.zeros((), jnp.int32),
         done=pg0norm <= 1e-14, converged=pg0norm <= 1e-14,
         failed=jnp.zeros((), bool), hist=hist0, ghist=ghist0,
     ), w0, g0)
@@ -196,4 +198,5 @@ def minimize_owlqn(
         w=out.w, value=out.F, grad_norm=jnp.linalg.norm(pg_fin),
         iterations=out.it, converged=out.converged, failed=out.failed,
         loss_history=out.hist, grad_norm_history=out.ghist,
+        evaluations=out.evals,
     )

@@ -11,7 +11,7 @@ line-search failure (FailedLineSearch) from convergence.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -26,6 +26,11 @@ class OptResult(NamedTuple):
     failed: jax.Array  # abnormal stop (line search / trust region failure)
     loss_history: jax.Array  # (max_iters + 1,), NaN-padded
     grad_norm_history: jax.Array  # (max_iters + 1,), NaN-padded
+    # line-search evaluations taken over the whole solve (the searches' own
+    # trial counts, summed; lock-step for a lane solver, so one scalar for
+    # all lanes). Stays on the device like every other field; None where
+    # the solver has no line search (TRON) or does not count (host loops).
+    evaluations: Optional[jax.Array] = None
 
     def history(self) -> np.ndarray:
         h = np.asarray(self.loss_history)

@@ -96,6 +96,26 @@ resident solvers via `Run(resident_tap=True)` (a `jax.debug.callback`
 compiled out by default; the registered `telemetry_off_is_free`
 ContractSpec enforces exactly that).
 
+**Device scopes** (`DEVICE_SCOPES`, entered with `device_scope(name)` =
+`jax.named_scope`): names written into the compiled programs' op metadata
+(the HLO ``op_name`` path), always on and free at run time, so a profiler
+trace can put device time to a phase INSIDE one jitted solve. Flat dotted
+names, grouped by prefix: the X pass — xpass.fwd / xpass.t (the public
+`data/matrix.py` dispatchers, forward and transposed), xpass.fwd.hot /
+xpass.t.hot (the hot-block matmuls), xpass.fwd.tail / xpass.t.tail (the
+blocked-ELL gathers), xpass.fwd.reassemble (the `row_pos` reassembly);
+objective.loss (loss value / derivative at cached margins); the L-BFGS
+phases lbfgs.two_loop, lbfgs.push, lbfgs.linesearch, lbfgs.direction
+(descent test and ``dphi0``), lbfgs.update (accepted step, convergence,
+history write); solve.prologue / solve.epilogue (before and after the
+`while_loop`, and the lane-minor → lane-major transpose). The resident
+solves report the `solver.*` pair iterations / linesearch_trials through
+`count_device` — a counter whose value is still a device array: the
+attached `Run` keeps the reference and resolves every pending array in
+ONE `device_get` when a report is asked for, never at the call site.
+`device_trace(dir)` is the one way to take a profiler trace (Python
+tracer off, host tracer at the level that keeps `TraceAnnotation`s).
+
 The multi-process spine's `parallel.*` span family holds one timed
 barrier span — ``parallel.barrier_wait``, opened by
 `parallel/mesh.py::cluster_barrier` — whose per-rank totals are what
@@ -156,8 +176,9 @@ from photon_tpu.telemetry.taps import (  # noqa: F401
 __all__ = [
     "Run", "Span", "read_jsonl", "load_report",
     "start_run", "finish_run", "run", "current_run", "enabled",
-    "span", "count", "gauge", "iteration", "event", "record_signature",
-    "sample_device_memory",
+    "span", "count", "count_device", "gauge", "iteration", "event",
+    "record_signature", "sample_device_memory",
+    "DEVICE_SCOPES", "device_scope", "device_trace",
     "solver_tap", "tap_enabled", "set_resident_tap", "tap_disabled",
 ]
 
@@ -251,6 +272,17 @@ def count(name: str, value: float = 1.0) -> None:
         r.count(name, value)
 
 
+def count_device(name: str, value, reduce: str = "sum") -> None:
+    """`count` for a value that is still a DEVICE array, reduced over its
+    elements by ``reduce`` ("sum" or "max") on the host. The run keeps the
+    reference and reads every pending array back in one `device_get` when
+    a report is asked for — never here, so the dispatch that produced
+    ``value`` stays asynchronous."""
+    r = _CURRENT
+    if r is not None:
+        r.count_device(name, value, reduce)
+
+
 def gauge(name: str, value) -> None:
     r = _CURRENT
     if r is not None:
@@ -283,6 +315,38 @@ def sample_device_memory(tag: str = "") -> None:
         r.sample_device_memory(tag)
 
 
+def device_scope(name: str):
+    """`jax.named_scope(name)` for a name of `DEVICE_SCOPES`: context
+    manager or decorator. The name lands in the HLO ``op_name`` of every
+    op traced inside (metadata only: no primitive, no run-time cost), and
+    is checked here, at trace time, so a trace reader can rely on the
+    registry being the whole vocabulary."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(f"device scope {name!r} is not in "
+                         "telemetry.DEVICE_SCOPES")
+    import jax
+
+    return jax.named_scope(name)
+
+
+@contextlib.contextmanager
+def device_trace(log_dir: str):
+    """A profiler trace of the enclosed region under ``log_dir``, with the
+    Python tracer off (its events swamp the device's) and the host tracer
+    at the lowest level that still records `TraceAnnotation`s — so this
+    package's spans and the `DEVICE_SCOPES` share the trace's one clock."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(log_dir, profiler_options=options)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
 # The machine-readable twin of the docstring's name registry (a pure
 # literal: photon_tpu.lint reads it by AST, without importing jax).
 # Entries ending in ".*" / "_*" are prefix globs for dynamically
@@ -291,6 +355,7 @@ def sample_device_memory(tag: str = "") -> None:
 # prefix (before the first dot) of every `telemetry.span(...)` name the
 # package opens — `utils.timing.PhaseTimers(span_prefix=...)` routes the
 # drivers' phase blocks into the "train" and "score" families.
+# `device_scopes` is the whole vocabulary of `device_scope(...)`.
 TELEMETRY_REGISTRY = {
     "counters": (
         "faults.injected_kills", "faults.injected_errors",
@@ -351,4 +416,14 @@ TELEMETRY_REGISTRY = {
         "game", "game_re", "serving", "checkpoint", "continual",
         "tuning", "parallel",
     ),
+    "device_scopes": (
+        "xpass.fwd", "xpass.fwd.hot", "xpass.fwd.tail",
+        "xpass.fwd.reassemble",
+        "xpass.t", "xpass.t.hot", "xpass.t.tail",
+        "objective.loss",
+        "lbfgs.two_loop", "lbfgs.push", "lbfgs.linesearch",
+        "lbfgs.direction", "lbfgs.update",
+        "solve.prologue", "solve.epilogue",
+    ),
 }
+DEVICE_SCOPES = TELEMETRY_REGISTRY["device_scopes"]

@@ -32,9 +32,12 @@ The HOT-PATH contract: every instrumentation point in data/optim/game
 first does a module-level ``if _CURRENT is None: return`` (see
 `__init__.py`), so a run-less process pays one global load + one branch
 per call site and never touches jax, locks, or files. Nothing here ever
-adds a device transfer or collective: spans/counters are host bookkeeping
-around already-host-side loops, and the resident tap exists only in
-programs traced while it is armed.
+adds a device transfer or collective at a call site: spans/counters are
+host bookkeeping around already-host-side loops, the resident tap exists
+only in programs traced while it is armed, and `count_device` keeps a
+REFERENCE to a device array — the one `device_get` that resolves all of
+them happens when a report is asked for (`report`, `report_compact`,
+`close`).
 """
 from __future__ import annotations
 
@@ -143,6 +146,8 @@ class Run:
         self._tls = threading.local()
         self.spans: list[Span] = []
         self.counters: dict[str, float] = {}
+        # (name, device array, reduce) of count_device, not yet read back
+        self._pending_device: list[tuple[str, Any, str]] = []
         self.gauges: dict[str, Any] = {}
         self.iterations: list[dict] = []
         self._iter_cap = int(keep_iterations)
@@ -205,6 +210,31 @@ class Run:
     def count(self, name: str, value: float = 1.0) -> None:
         with self._lock:
             self.counters[name] = self.counters.get(name, 0.0) + value
+
+    def count_device(self, name: str, value, reduce: str = "sum") -> None:
+        """A counter bump whose value is still on the device: kept by
+        reference, read back and reduced over its elements ("sum" or
+        "max") by `_resolve_device_counts` when a report is asked for. No
+        transfer and no dispatch happens here."""
+        if reduce not in ("sum", "max"):
+            raise ValueError(f"count_device: reduce is 'sum' or 'max', "
+                             f"not {reduce!r}")
+        with self._lock:
+            self._pending_device.append((name, value, reduce))
+
+    def _resolve_device_counts(self) -> None:
+        """ONE `device_get` for every pending `count_device` array."""
+        with self._lock:
+            pending, self._pending_device = self._pending_device, []
+        if not pending:
+            return
+        import jax
+        import numpy as np
+
+        values = jax.device_get([v for _, v, _ in pending])
+        for (name, _, reduce), v in zip(pending, values):
+            self.count(name, float(np.max(v) if reduce == "max"
+                                   else np.sum(v)))
 
     def gauge(self, name: str, value) -> None:
         with self._lock:
@@ -290,6 +320,7 @@ class Run:
     def report(self) -> dict:
         """The in-memory run report — everything the JSONL stream carries,
         as one dict (bench.py embeds a compact subset in its JSON line)."""
+        self._resolve_device_counts()
         with self._lock:
             counters = dict(self.counters)
             gauges = dict(self.gauges)
@@ -316,6 +347,7 @@ class Run:
     def report_compact(self) -> dict:
         """Counters + span totals + duration: the piece small enough to
         embed in a one-line bench JSON."""
+        self._resolve_device_counts()
         with self._lock:
             counters = {k: round(v, 6) for k, v in
                         sorted(self.counters.items())}
@@ -355,6 +387,7 @@ class Run:
             return self.report()
         self._closed = True
         self._end_ns = time.perf_counter_ns()
+        self._resolve_device_counts()
         self.sample_device_memory("final")
         with self._lock:
             snapshot = {"type": "run_end",

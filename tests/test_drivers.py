@@ -556,10 +556,11 @@ class TestProfiling:
 
         import jax.numpy as jnp
 
-        from photon_tpu.utils.profiling import annotate, trace
+        from photon_tpu import telemetry
 
-        with trace(str(tmp_path)):
-            with annotate("tiny-matmul"):
+        with telemetry.run("profiled"), \
+                telemetry.device_trace(str(tmp_path)):
+            with telemetry.span("solve.tiny-matmul"):  # a TraceAnnotation
                 x = jnp.ones((64, 64))
                 (x @ x).block_until_ready()
         found = []

@@ -275,6 +275,18 @@ class TestFusedGateTelemetry:
         assert len(gate_lines) == 1  # once per coordinate, not per call
         assert "pipelined block loop" in gate_lines[0].getMessage()
 
+    def test_fused_fit_counts_its_blocks(self, problem):
+        """A resident fit takes the fused one-dispatch update, which solves
+        every block of the coordinate: `game_re.blocks` counts them there
+        too (the benchmark's `fit_dispatches` reads it)."""
+        with telemetry.run("fused_blocks") as run:
+            _fit(problem, problem["dense"], "lbfgs")
+            counters = run.report_compact()["counters"]
+        n_re, n_sweeps = 1, 2
+        assert "game_re.fused_gate_offs" not in counters  # the fused path
+        assert counters["game_re.blocks"] >= n_re * n_sweeps
+        assert counters["game.coordinate_updates"] == 2 * n_sweeps
+
     def test_unbudgeted_coordinate_still_fuses(self, problem):
         from photon_tpu.game.dataset import RandomEffectDataset
         from photon_tpu.game.random_effect import RandomEffectCoordinate

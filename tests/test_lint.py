@@ -304,6 +304,39 @@ def f():
                          "telemetry_sync")
         assert "rogue_family" in f.message
 
+    @pytest.mark.parametrize("call,word", [
+        ('telemetry.count_device("rogue.device_counter", x)',
+         "rogue.device_counter"),
+        ('telemetry.device_scope("rogue.scope")', "rogue.scope"),
+        ('device_scope("xpass.fwd")', None),
+    ], ids=["count_device", "unregistered_scope", "registered_scope"])
+    def test_device_counters_and_scopes_are_held_too(self, tmp_path, call,
+                                                     word):
+        root = write_repo(tmp_path, replace={
+            "photon_tpu/telemetry/__init__.py": '''
+"""chunk_uploads latency_ solve; device scope xpass.fwd"""
+TELEMETRY_REGISTRY = {
+    "counters": ("stream.chunk_uploads",),
+    "gauges": ("serving.latency_*",),
+    "span_families": ("solve",),
+    "device_scopes": ("xpass.fwd",),
+}
+'''}, extra={"photon_tpu/bad.py": f'''
+from photon_tpu import telemetry
+from photon_tpu.telemetry import device_scope
+
+def f(x):
+    with device_scope("xpass.fwd"):
+        {call}
+'''})
+        found = findings_of(run_rules(root, ["telemetry_sync"]),
+                            "telemetry_sync")
+        if word is None:
+            assert not found
+        else:
+            f, = found
+            assert word in f.message
+
     def test_selftest_mains_are_exempt(self, tmp_path):
         root = write_repo(tmp_path, extra={
             "photon_tpu/demo/__init__.py": "",
