@@ -23,6 +23,7 @@ from photon_tpu.data.matrix import (
     ShardedHybridRows,
     ShardedPermutedHybridRows,
     SparseRows,
+    rows_from_caller,
     shard_blocked_ell,
     shard_hybrid,
 )
@@ -39,13 +40,28 @@ class GLMBatch(NamedTuple):
         return self.y.shape[0]
 
 
+def _stored_rows(X, v) -> jax.Array:
+    """Caller-ordered per-row ``v`` as an f32 device array in the order X
+    stores its rows; host data is permuted on the host."""
+    if not isinstance(v, jax.Array):
+        v = np.asarray(v, np.float32)
+    return jnp.asarray(rows_from_caller(X, v), jnp.float32)
+
+
 def make_batch(X, y, weights=None, offsets=None) -> GLMBatch:
-    y = jnp.asarray(y, jnp.float32)
+    """The batch of design matrix X and the CALLER-ordered per-row y /
+    weights / offsets (row i of each belongs to row i of the matrix X was
+    built from). A GLMBatch is in the order X STORES its rows throughout:
+    for a `to_blocked_ell` layout, which stores them in tail-bucket order,
+    the three are permuted by its `row_order` here, once, so no evaluation
+    gathers by row. For every other X that order is the caller's. Per-row
+    results leave a batch through `data.matrix.rows_to_caller`."""
+    y = _stored_rows(X, y)
     n = y.shape[0]
-    if weights is None:
-        weights = jnp.ones((n,), jnp.float32)
-    if offsets is None:
-        offsets = jnp.zeros((n,), jnp.float32)
+    weights = (jnp.ones((n,), jnp.float32) if weights is None
+               else _stored_rows(X, weights))
+    offsets = (jnp.zeros((n,), jnp.float32) if offsets is None
+               else _stored_rows(X, offsets))
     if not isinstance(X, (SparseRows, HybridRows, ShardedHybridRows,
                           PermutedHybridRows, ShardedPermutedHybridRows,
                           BlockedEllRows, ShardedBlockedEllRows)):
@@ -59,8 +75,7 @@ def make_batch(X, y, weights=None, offsets=None) -> GLMBatch:
         if not (isinstance(X, jax.Array)
                 and jnp.issubdtype(X.dtype, jnp.floating)):
             X = jnp.asarray(X, jnp.float32)
-    return GLMBatch(X, y, jnp.asarray(weights, jnp.float32),
-                    jnp.asarray(offsets, jnp.float32))
+    return GLMBatch(X, y, weights, offsets)
 
 
 def pad_batch(batch: GLMBatch, target_n: int) -> GLMBatch:
@@ -102,17 +117,24 @@ def pad_batch(batch: GLMBatch, target_n: int) -> GLMBatch:
     elif isinstance(X, BlockedEllRows):
         import dataclasses
 
-        # Padding rows have no tail nnz: the dense block grows and the
-        # new rows' row_pos point at the appended zero slot (index B).
-        B = sum(int(v.shape[0]) for v in X.ell_vals)
+        # Padding rows have no tail nnz: the dense block grows and the new
+        # rows read a zero of the bucket concatenation — in a stored-order
+        # layout their own (they land after the tail-free rows, which is
+        # still concatenation order), else the shared slot B.
+        if X.row_order is None:
+            new_pos = jnp.full((extra,), X.tail_rows, jnp.int32)
+            row_order = None
+        else:
+            new_pos = jnp.arange(n, target_n, dtype=jnp.int32)
+            row_order = jnp.concatenate(
+                [jnp.asarray(X.row_order), new_pos])
         X = dataclasses.replace(
             X,
             dense=jnp.concatenate(
                 [X.dense, jnp.zeros((extra, X.dense.shape[1]),
                                     X.dense.dtype)]),
-            row_pos=jnp.concatenate(
-                [jnp.asarray(X.row_pos),
-                 jnp.full((extra,), B, jnp.asarray(X.row_pos).dtype)]))
+            row_pos=jnp.concatenate([jnp.asarray(X.row_pos), new_pos]),
+            row_order=row_order)
     elif isinstance(X, SparseRows):
         X = SparseRows(
             jnp.concatenate([X.indices, jnp.zeros((extra, X.indices.shape[1]), jnp.int32)]),
@@ -184,7 +206,9 @@ def shard_blocked_ell_batch(batch: GLMBatch, n_shards: int,
 
 
 def with_offsets(batch: GLMBatch, offsets) -> GLMBatch:
-    return batch._replace(offsets=jnp.asarray(offsets, jnp.float32))
+    """``batch`` with the CALLER-ordered ``offsets`` (translated into the
+    batch's stored row order, as `make_batch` does)."""
+    return batch._replace(offsets=_stored_rows(batch.X, offsets))
 
 
 def cast_features(batch: GLMBatch, dtype=jnp.bfloat16) -> GLMBatch:

@@ -333,7 +333,8 @@ class ShardedPermutedHybridRows:
 @partial(
     jax.tree_util.register_dataclass,
     data_fields=("dense", "ell_pcols", "ell_vals", "row_pos",
-                 "bucket_rows", "bucket_vals", "perm_cols", "inv_perm"),
+                 "bucket_rows", "bucket_vals", "perm_cols", "inv_perm",
+                 "row_order"),
     meta_fields=("n_features", "n_prefix", "last_col_pos", "tail_nnz"),
 )
 @dataclasses.dataclass(frozen=True)
@@ -352,14 +353,32 @@ class BlockedEllRows:
     the tail matvec is per bucket ONE gather of w plus ONE
     `einsum("rw,rw->r")` — a dense contraction XLA maps straight onto the
     vector/matrix units, f32 accumulation pinned by
-    ``preferred_element_type``. Bucket outputs concatenate in sorted-row
-    order and ONE (n,)-gather (`row_pos`) reassembles original row order;
-    rows with no tail hit an appended zero slot. Zero combining scatters,
-    zero `.at[].set` scatters, zero cumsum — in BOTH X passes.
+    ``preferred_element_type``. Zero combining scatters, zero `.at[].set`
+    scatters, zero cumsum — in BOTH X passes.
+
+    ROW ORDER. A GLM objective is a sum over rows, so the order the rows
+    are STORED in is free, and `to_blocked_ell` stores them in the order
+    the bucket outputs concatenate in: width-1 rows, then width-2, ...,
+    then the rows with no tail (original row id within a bucket). The
+    forward tail is then `concatenate(bucket outputs + [zeros])` and
+    `hot + tail` — no per-row gather in any evaluation. `row_order` (the
+    original row id of each stored row; None = the caller's order) records
+    it; it is fixed by the layout's builder, never by a caller, and which
+    forward form runs follows from it alone. Everything that pairs with
+    the rows of such a layout — a GLMBatch's y / weights / offsets, a
+    cotangent handed to rmatvec — is in the STORED order
+    (`data.dataset.make_batch` and `with_offsets` translate on the way
+    in); `matvec` / `matvec_lanes` hand their result back in the CALLER's
+    order through one `row_pos` gather, which scoring pays once a call
+    and a solver (`layout_matvec`) never. The shard / chunk views
+    (`ShardedBlockedEllRows.local()`, `.chunk(i)`) pad their buckets to a
+    ladder shared across shards, so their rows stay in the caller's order
+    and their forward tail keeps the `row_pos` gather per evaluation
+    (rows with no tail hit an appended zero slot).
 
     rmatvec keeps the embedding-style PRE-SORTED gather of the permuted
     layouts: the distinct tail columns are grouped by occurrence-count
-    bucket at build time, each bucket's (c_b, k_b) ORIGINAL-row-id matrix
+    bucket at build time, each bucket's (c_b, k_b) STORED-row-id matrix
     gathers the cotangent and reduces over k_b, and the gradient is
     assembled by concatenation in prefix order (identical machinery to
     PermutedHybridRows — `bucket_rows`/`bucket_vals` are byte-compatible).
@@ -378,7 +397,7 @@ class BlockedEllRows:
     0·w[0]; `tail_pad_waste` reports the pow2 slot overhead.
     """
 
-    dense: jax.Array | np.ndarray       # (n, d_sel) hot block, original rows
+    dense: jax.Array | np.ndarray       # (n, d_sel) hot block, stored rows
     ell_pcols: tuple                    # per width bucket: (r_b, W_b) int32
     #                                     PREFIX-RELATIVE col ids (absolute
     #                                     permuted id − d_sel; padding 0 with
@@ -390,8 +409,15 @@ class BlockedEllRows:
     #                                     that is a ~2 MB gather table vs
     #                                     40 MB, cache-resident on TPU
     ell_vals: tuple                     # per width bucket: (r_b, W_b) values
-    row_pos: jax.Array | np.ndarray     # (n,) int32 position in the bucket
-    #                                     concatenation (B = zero slot)
+    row_pos: jax.Array | np.ndarray     # (n,) int32: where the CALLER's row
+    #                                     i sits in the bucket concatenation.
+    #                                     Caller-order layouts: slot B (after
+    #                                     the B bucket rows) is the one zero
+    #                                     every tail-free row reads. Stored-
+    #                                     order layouts: the concatenation is
+    #                                     padded with zeros to n rows and IS
+    #                                     the stored order, so row_pos is the
+    #                                     inverse of row_order
     bucket_rows: tuple                  # per occ bucket: (c_b, k_b) row ids
     bucket_vals: tuple                  # per occ bucket: (c_b, k_b) values
     perm_cols: jax.Array | np.ndarray   # (d,) original col id per position
@@ -400,6 +426,9 @@ class BlockedEllRows:
     n_prefix: int                       # P = d_sel + distinct tail columns
     last_col_pos: int                   # permuted position of original col d-1
     tail_nnz: int                       # real (unpadded) tail nnz
+    row_order: jax.Array | np.ndarray | None = None  # (n,) int32 caller row
+    #                                     id of each stored row when the rows
+    #                                     are stored in concatenation order
 
     @property
     def shape(self):
@@ -408,6 +437,12 @@ class BlockedEllRows:
     @property
     def d_sel(self) -> int:
         return self.dense.shape[1]
+
+    @property
+    def tail_rows(self) -> int:
+        """B: rows of the bucket concatenation (the rows with a tail, plus
+        a shared ladder's padding rows)."""
+        return sum(int(v.shape[0]) for v in self.ell_vals)
 
     @property
     def ell_slots(self) -> int:
@@ -603,10 +638,13 @@ def _dense_scatter_chunked(rows_h, pos_h, vals_h, n, d_sel, dtype):
         r1 = min(n, r0 + row_chunk)
         lo, hi = np.searchsorted(rows_h, [r0, r1])
         m = hi - lo
-        # pad the COO length to a power of two so the jitted scatter
-        # compiles a couple of shapes, not one per chunk (padding entries
-        # add 0.0 at local (0, 0) — a no-op for scatter-add)
-        m_pad = next_pow2(max(m, 1))
+        # pad the COO length — to a power of two, from 2^20 on to a
+        # multiple of 2^20 — so the jitted scatter compiles a couple of
+        # shapes, not one per chunk (padding entries add 0.0 at local
+        # (0, 0) — a no-op for scatter-add). Powers of two all the way
+        # held 200 MB of padding on the device at the build's peak when
+        # a chunk's COO sat just past 2^24 (the bench's tail-free rows).
+        m_pad = min(next_pow2(max(m, 1)), quantize_rows(m, 1 << 20))
         r = np.zeros(m_pad, np.int32)
         p = np.zeros(m_pad, np.int32)
         v = np.zeros(m_pad, np.float32)
@@ -620,25 +658,38 @@ def _dense_scatter_chunked(rows_h, pos_h, vals_h, n, d_sel, dtype):
     return out
 
 
+def _hot_positions(X: SparseRows, d_dense: int):
+    """Pick the `d_dense` most frequent columns as the hot block. Returns
+    (ind, val, sel, pos): the host COO, the selected column ids ascending,
+    and per entry its hot-block slot ((n, k) int32; -1 = stays sparse)."""
+    ind = np.asarray(X.indices)
+    val = np.asarray(X.values)
+    d = X.n_features
+    counts = np.bincount(ind[val != 0.0].ravel(), minlength=d)
+    d_sel = min(d_dense, d)
+    sel = np.sort(np.argpartition(-counts, d_sel - 1)[:d_sel])
+    col_to_pos = np.full(d, -1, np.int32)
+    col_to_pos[sel] = np.arange(d_sel, dtype=np.int32)
+    return ind, val, sel, col_to_pos[ind]
+
+
 def _hot_cold_split(X: SparseRows, d_dense: int, device_dense_dtype):
-    """Shared front half of both hybrid builders: pick the `d_dense` most
+    """Shared front half of the hybrid builders: pick the `d_dense` most
     frequent columns, build the (n, d_sel) hot block (on device when
     `device_dense_dtype` is set, else host chunked-bincount), and extract
     the cold nnz as flat row-major COO. Returns
     (dense, sel, t_rows, t_cols, t_vals) with t_* exact-size (possibly
     empty) int64/f32 host arrays."""
-    ind = np.asarray(X.indices)
-    val = np.asarray(X.values)
-    n, k = ind.shape
-    d = X.n_features
-    nnz_mask = val != 0.0
-    counts = np.bincount(ind[nnz_mask].ravel(), minlength=d)
-    d_sel = min(d_dense, d)
-    sel = np.sort(np.argpartition(-counts, d_sel - 1)[:d_sel])
-    col_to_pos = np.full(d, -1, np.int64)
-    col_to_pos[sel] = np.arange(d_sel)
+    return _split_at(*_hot_positions(X, d_dense), device_dense_dtype)
 
-    pos = col_to_pos[ind]  # (n, k); -1 = stays sparse
+
+def _split_at(ind, val, sel, pos, device_dense_dtype):
+    """`_hot_cold_split` from `_hot_positions`' output on: a builder that
+    stores its rows in another order permutes the four row-wise in
+    between."""
+    n, k = ind.shape
+    d_sel = sel.shape[0]
+    nnz_mask = val != 0.0
     hot = (pos >= 0) & nnz_mask
     rows = np.repeat(np.arange(n), k).reshape(n, k)
     if device_dense_dtype is not None:
@@ -890,22 +941,33 @@ def to_permuted_hybrid(X: SparseRows, d_dense: int = 1024,
 def to_blocked_ell(X: SparseRows, d_dense: int = 1024,
                    device_dense_dtype=None) -> BlockedEllRows:
     """Build the blocked-ELL hybrid (see BlockedEllRows) from padded COO
-    rows.
+    rows, its rows STORED in the order the ELL buckets concatenate in.
 
-    One vectorized host pass sharing `_hot_cold_split` and the permuted
-    column machinery with `to_permuted_hybrid`, plus the ELL side: rows
-    bucketed by tail-nnz into the pow2 width ladder (rows sorted by nnz so
-    each bucket is a contiguous id range), every bucket a dense
-    (r_b, W_b) pcols/vals pair filled row-major from the flat tail, and
-    `row_pos` mapping original rows back into the bucket concatenation.
-    `device_dense_dtype` builds the hot block on device from compact COO
-    triples as `to_hybrid` does.
+    One vectorized host pass sharing the hot/cold split and the permuted
+    column machinery with `to_permuted_hybrid`, plus the ELL side: once
+    the hot columns are chosen each row's tail nnz is counted and the rows
+    are put in the stable order (width exponent ascending, tail-free rows
+    last, original row id within a bucket) BEFORE anything is laid, so the
+    hot block, the ELL buckets (each a contiguous row range, filled
+    row-major from the flat tail) and the occurrence buckets' row ids all
+    share that one order. `row_order` records it and `row_pos` is its
+    inverse. `device_dense_dtype` builds the hot block on device from
+    compact COO triples as `to_hybrid` does.
     """
-    n = np.asarray(X.indices).shape[0]
     d = X.n_features
     d_sel = min(d_dense, d)
-    dense, sel, t_rows, t_cols, t_vals = _hot_cold_split(
-        X, d_dense, device_dense_dtype)
+    ind, val, sel, pos = _hot_positions(X, d_dense)
+    n = ind.shape[0]
+    counts = ((pos < 0) & (val != 0.0)).sum(axis=1)     # tail nnz per row
+    e_row = _row_exponents(counts)
+    # int8 keys: numpy's stable sort of them is a radix sort
+    row_order = np.argsort(np.where(e_row < 0, 127, e_row).astype(np.int8),
+                           kind="stable").astype(np.int32)
+    row_pos = np.empty(n, np.int32)
+    row_pos[row_order] = np.arange(n, dtype=np.int32)
+    dense, sel, t_rows, t_cols, t_vals = _split_at(
+        ind[row_order], val[row_order], sel, pos[row_order],
+        device_dense_dtype)
     t_vals = t_vals.astype(np.float32)
     m = t_rows.size
 
@@ -913,12 +975,12 @@ def to_blocked_ell(X: SparseRows, d_dense: int = 1024,
         perm_cols, inv_perm = _column_perm(
             sel, np.zeros(0, np.int64), np.zeros(0, np.int64), d)
         return BlockedEllRows(
-            dense=dense, ell_pcols=(), ell_vals=(),
-            row_pos=np.zeros(n, np.int32),
+            dense=dense, ell_pcols=(), ell_vals=(), row_pos=row_pos,
             bucket_rows=(), bucket_vals=(),
             perm_cols=perm_cols, inv_perm=inv_perm,
             n_features=d, n_prefix=d_sel,
-            last_col_pos=int(inv_perm[d - 1]), tail_nnz=0)
+            last_col_pos=int(inv_perm[d - 1]), tail_nnz=0,
+            row_order=row_order)
 
     u_cols, inv, u_counts = np.unique(t_cols, return_inverse=True,
                                       return_counts=True)
@@ -932,15 +994,13 @@ def to_blocked_ell(X: SparseRows, d_dense: int = 1024,
     bucket_rows, bucket_vals = _occurrence_buckets(
         t_rows, t_vals, pcol, d_sel, e, order, u_counts)
 
-    row_bounds = np.searchsorted(t_rows, np.arange(n + 1)).astype(np.int64)
-    counts = np.diff(row_bounds)
-    e_row = _row_exponents(counts)
+    counts, e_row = counts[row_order], e_row[row_order]
     widths = [(int(ev), int((e_row == ev).sum()))
               for ev in np.unique(e_row[e_row >= 0])]
     # prefix-RELATIVE ids: the device tail gather reads w[d_sel:n_prefix]
     pcol_rel = (pcol.astype(np.int64) - d_sel).astype(np.int32)
-    pcs, pvs, row_pos = _fill_ell(widths, counts, e_row, row_bounds[:-1],
-                                  pcol_rel, t_vals)
+    pcs, pvs, _ = _fill_ell(widths, counts, e_row,
+                            np.cumsum(counts) - counts, pcol_rel, t_vals)
 
     return BlockedEllRows(
         dense=dense, ell_pcols=tuple(pcs), ell_vals=tuple(pvs),
@@ -948,7 +1008,8 @@ def to_blocked_ell(X: SparseRows, d_dense: int = 1024,
         bucket_rows=bucket_rows, bucket_vals=bucket_vals,
         perm_cols=perm_cols, inv_perm=inv_perm,
         n_features=d, n_prefix=d_sel + U,
-        last_col_pos=int(inv_perm[d - 1]), tail_nnz=int(m))
+        last_col_pos=int(inv_perm[d - 1]), tail_nnz=int(m),
+        row_order=row_order)
 
 
 def blocked_ell_from_scipy_csr(csr, d_dense: int = 1024,
@@ -1376,25 +1437,25 @@ def _bell_compute(v, g):
 
 
 def _bell_tail(X, w):
-    """Blocked-ELL tail matvec: per width bucket one gather of the SMALL
+    """Blocked-ELL tail matvec, bucket by bucket: one gather of the SMALL
     contiguous tail-coefficient slice w[d_sel:n_prefix] (ell_pcols are
     prefix-relative — the gather table is the ~U distinct tail columns,
     cache-resident at 10M-feature scale) + one dense einsum (f32
-    accumulation), reassembled into original row order by the single
-    `row_pos` gather. w: (d,) or (d, G) permuted; works on the (S, ...)
-    sharded buckets unchanged (the einsum string carries the extra axis).
+    accumulation) per width bucket. Returns the bucket outputs in ladder
+    order; the caller lays them over the rows. w: (d,) or (d, G)
+    permuted; works on the (S, ...) sharded buckets unchanged (the einsum
+    string carries the extra axis).
     """
     lanes = w.ndim == 2
     sharded = isinstance(X, ShardedBlockedEllRows)
     parts = []
-    with device_scope("xpass.fwd.tail"):
-        wt = w[X.d_sel:X.n_prefix]
-        for pc, pv in zip(X.ell_pcols, X.ell_vals):
-            v, g = _bell_compute(pv, wt[pc])      # ([S,] r_b, W_b[, G])
-            eq = ("srw,srwg->srg" if lanes else "srw,srw->sr") if sharded \
-                else ("rw,rwg->rg" if lanes else "rw,rw->r")
-            parts.append(jnp.einsum(eq, v, g,
-                                    preferred_element_type=jnp.float32))
+    wt = w[X.d_sel:X.n_prefix]
+    for pc, pv in zip(X.ell_pcols, X.ell_vals):
+        v, g = _bell_compute(pv, wt[pc])      # ([S,] r_b, W_b[, G])
+        eq = ("srw,srwg->srg" if lanes else "srw,srw->sr") if sharded \
+            else ("rw,rwg->rg" if lanes else "rw,rw->r")
+        parts.append(jnp.einsum(eq, v, g,
+                                preferred_element_type=jnp.float32))
     return parts
 
 
@@ -1416,27 +1477,37 @@ def _kernel_route(X, vec):
 
 
 def _bell_matvec(X: BlockedEllRows, w):
-    """w: (d,) or (d, G) PERMUTED. Hot block against the contiguous prefix
-    slice, blocked-ELL tail — gathers and dense contractions only. The
+    """w: (d,) or (d, G) PERMUTED → (n,) / (n, G) in the layout's STORED
+    row order. Hot block against the contiguous prefix slice, blocked-ELL
+    tail — gathers of `w` and dense contractions only. A stored-order
+    layout (`X.row_order`) lays the bucket outputs over its rows by
+    CONCATENATION (zeros for the tail-free rows at the end); a
+    caller-order one (a shard or chunk view) by the `row_pos` gather. The
     tail term routes through the Pallas kernels when the kernels seam is
     active (`photon_tpu.kernels.tail_matvec`, grid-tiled past the VMEM
     budget; both bitwise-equal)."""
     with device_scope("xpass.fwd.hot"):
         hot = jnp.matmul(X.dense, w[:X.d_sel].astype(X.dense.dtype),
                          preferred_element_type=jnp.float32)
-    if X.ell_vals:
-        rt = _kernel_route(X, w)
-        if rt is not None:
-            from photon_tpu import kernels
+    if not X.ell_vals:
+        return hot
+    rt = _kernel_route(X, w)
+    if rt is not None:
+        from photon_tpu import kernels
 
-            with device_scope("xpass.fwd.tail"):
-                tail = (kernels.tail_matvec(X, w) if rt == "fused"
-                        else kernels.tail_matvec_tiled(X, w))
-            return hot + tail
-    lanes = w.ndim == 2
-    parts = _bell_tail(X, w)
+        with device_scope("xpass.fwd.tail"):
+            tail = (kernels.tail_matvec(X, w) if rt == "fused"
+                    else kernels.tail_matvec_tiled(X, w))
+        return hot + tail
+    with device_scope("xpass.fwd.tail"):
+        parts = _bell_tail(X, w)
+        if X.row_order is not None:
+            rest = X.dense.shape[0] - X.tail_rows
+            if rest:
+                parts.append(jnp.zeros((rest,) + w.shape[1:], jnp.float32))
+            return hot + jnp.concatenate(parts, axis=0)
     with device_scope("xpass.fwd.reassemble"):
-        zero = jnp.zeros((1, w.shape[1]) if lanes else (1,), jnp.float32)
+        zero = jnp.zeros((1,) + w.shape[1:], jnp.float32)
         tail = jnp.concatenate(parts + [zero], axis=0)[X.row_pos]
     return hot + tail
 
@@ -1492,7 +1563,8 @@ def _sbell_matvec(X: ShardedBlockedEllRows, w):
                          preferred_element_type=jnp.float32)
     lanes = w.ndim == 2
     S = X.n_shards
-    parts = _bell_tail(X, w)
+    with device_scope("xpass.fwd.tail"):
+        parts = _bell_tail(X, w)
     with device_scope("xpass.fwd.reassemble"):
         zero = jnp.zeros((S, 1, w.shape[1]) if lanes else (S, 1),
                          jnp.float32)
@@ -1531,9 +1603,43 @@ def _sbell_rmatvec(X: ShardedBlockedEllRows, r, square: bool = False):
     return jnp.concatenate(parts, axis=0)
 
 
+def rows_from_caller(X: Matrix, v):
+    """Per-row values ((n,) or (n, ...), host or device) in the CALLER's
+    row order → the order X stores its rows in. The identity for every
+    layout but a stored-order BlockedEllRows."""
+    if isinstance(X, BlockedEllRows) and X.row_order is not None:
+        return v[X.row_order]
+    return v
+
+
+def rows_to_caller(X: Matrix, v):
+    """The inverse of `rows_from_caller`: per-row values in X's stored
+    order → the caller's."""
+    if isinstance(X, BlockedEllRows) and X.row_order is not None:
+        with device_scope("xpass.fwd.reassemble"):
+            return v[X.row_pos]
+    return v
+
+
 @device_scope("xpass.fwd")
 def matvec(X: Matrix, w: jax.Array) -> jax.Array:
-    """X @ w -> (n,). The GLM margin hot path.
+    """X @ w -> (n,) in the CALLER's row order: row i of the result is row
+    i of the matrix the layout was built from (`layout_matvec` is the form
+    a solver evaluates). The scoring path."""
+    return rows_to_caller(X, _matvec(X, w))
+
+
+@device_scope("xpass.fwd")
+def layout_matvec(X: Matrix, w: jax.Array) -> jax.Array:
+    """X @ w -> (n,) in the order X STORES its rows — the order of the y /
+    weights / offsets of a GLMBatch over X, and of the cotangent `rmatvec`
+    takes. The GLM margin hot path: a `to_blocked_ell` layout's has no
+    per-row gather."""
+    return _matvec(X, w)
+
+
+def _matvec(X: Matrix, w: jax.Array) -> jax.Array:
+    """X @ w -> (n,), stored row order.
 
     Mixed precision: when X is stored in bfloat16 (see dataset.cast_features),
     w is cast to bf16 so the contraction's OPERANDS are bf16 (half the HBM
@@ -1581,7 +1687,9 @@ def matvec(X: Matrix, w: jax.Array) -> jax.Array:
 @device_scope("xpass.t")
 def rmatvec(X: Matrix, r: jax.Array) -> jax.Array:
     """X^T @ r -> (d,). The gradient aggregation hot path (f32 accumulation,
-    bf16-storage aware like matvec)."""
+    bf16-storage aware like matvec). ``r`` is in the order X STORES its
+    rows (a GLMBatch's order; `rows_from_caller` translates a caller-order
+    vector) — as for rmatvec_lanes, sq_rmatvec and weighted_gram."""
     if isinstance(X, BlockedEllRows):
         return _bell_rmatvec(X, r)
     if isinstance(X, ShardedBlockedEllRows):
@@ -1615,7 +1723,21 @@ def rmatvec(X: Matrix, r: jax.Array) -> jax.Array:
 
 @device_scope("xpass.fwd")
 def matvec_lanes(X: Matrix, W: jax.Array) -> jax.Array:
-    """X @ W -> (n, G) for LANE-MINOR stacked coefficients W: (d, G).
+    """X @ W -> (n, G), lane-minor W: (d, G), rows in the CALLER's order
+    (see `matvec`)."""
+    return rows_to_caller(X, _matvec_lanes(X, W))
+
+
+@device_scope("xpass.fwd")
+def layout_matvec_lanes(X: Matrix, W: jax.Array) -> jax.Array:
+    """X @ W -> (n, G), rows in X's STORED order (see `layout_matvec`):
+    the multi-lane (reg-weight grid) hot path."""
+    return _matvec_lanes(X, W)
+
+
+def _matvec_lanes(X: Matrix, W: jax.Array) -> jax.Array:
+    """X @ W -> (n, G) for LANE-MINOR stacked coefficients W: (d, G),
+    stored row order.
 
     The multi-lane (reg-weight grid) hot path. Lane-minor layout is the
     TPU-native form: the hot dense block becomes ONE true (n, d_sel) ×
@@ -1970,7 +2092,7 @@ def _contract_blocked_ell_x_passes():
     n, d = X.shape
 
     def both(Xb, w, r):
-        z = matvec(Xb, w)                 # X pass 1: the margin
+        z = layout_matvec(Xb, w)          # X pass 1: the margin
         return z, rmatvec(Xb, r * z)      # X pass 2: the gradient backprop
 
     return both, (X, jnp.zeros((d,), jnp.float32),
@@ -1990,7 +2112,7 @@ def _contract_blocked_ell_lane_x_passes():
     G = 4
 
     def both(Xb, W, R):
-        Z = matvec_lanes(Xb, W)
+        Z = layout_matvec_lanes(Xb, W)
         return Z, rmatvec_lanes(Xb, R * Z)
 
     return both, (X, jnp.zeros((d, G), jnp.float32),

@@ -28,7 +28,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
-from photon_tpu.data.dataset import (ChunkedMatrix, GLMBatch,
+from photon_tpu.data.dataset import (ChunkedMatrix, GLMBatch, make_batch,
                                      make_chunked_batch)
 from photon_tpu.data.matrix import (BlockedEllRows, HybridRows, Matrix,
                                     PermutedHybridRows, SparseRows)
@@ -184,7 +184,9 @@ class FixedEffectDataset:
             # feature stream the solve saves from HBM.
             return make_chunked_batch(self.X, self.y, self.weights,
                                       np.asarray(offsets, np.float32))
-        return GLMBatch(self.X, self.y, self.weights, jnp.asarray(offsets, jnp.float32))
+        # y / weights / offsets are held in the caller's row order;
+        # make_batch puts them in the order X stores its rows
+        return make_batch(self.X, self.y, self.weights, offsets)
 
 
 @dataclasses.dataclass(frozen=True)

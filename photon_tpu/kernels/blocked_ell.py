@@ -191,17 +191,29 @@ def _tail_call(n_buckets: int, lanes: bool, interp: bool, n: int, G: int):
     return call
 
 
+def _tail_slots(X):
+    """(n,) int32: each STORED row's slot in `concatenate(bucket outputs +
+    [one zero])` — what the reassembling gather reads. A caller-order
+    layout carries it (`row_pos`); in a stored-order layout
+    (`X.row_order`) row i is slot i, and every tail-free row reads the
+    zero."""
+    if X.row_order is None:
+        return jnp.asarray(X.row_pos)
+    return jnp.minimum(jnp.arange(X.shape[0], dtype=jnp.int32), X.tail_rows)
+
+
 def tail_matvec(X, w):
     """The fused blocked-ELL tail matvec: (n,)/(n, G) f32 tail
-    contributions in ORIGINAL row order (the caller adds the hot block's
-    MXU matmul). ``w`` is the full permuted (d,)/(d, G) vector; the
+    contributions in the layout's STORED row order (the caller adds the
+    hot block's MXU matmul; the in-kernel reassembly reads `_tail_slots`,
+    which for a stored-order layout is the concatenation itself). ``w`` is the full permuted (d,)/(d, G) vector; the
     kernel consumes only the contiguous ``w[d_sel:n_prefix]`` tail
     slice. Bitwise-equal to `data.matrix._bell_matvec`'s tail term."""
     from photon_tpu import kernels as K
 
     lanes = w.ndim == 2
     wt = w[X.d_sel:X.n_prefix]
-    row_pos = jnp.asarray(X.row_pos)
+    row_pos = _tail_slots(X)
     n = int(row_pos.shape[0])
     G = int(w.shape[1]) if lanes else 0
     args = (row_pos, wt) + tuple(
@@ -271,7 +283,7 @@ def tail_matvec_tiled(X, w):
 
     lanes = w.ndim == 2
     wt = w[X.d_sel:X.n_prefix]
-    row_pos = jnp.asarray(X.row_pos)
+    row_pos = _tail_slots(X)
     G = int(w.shape[1]) if lanes else 0
     U = int(X.n_prefix - X.d_sel)
     args = (row_pos, wt) + tuple(
@@ -487,7 +499,7 @@ def _contract_kernel_x_passes():
 
     def both(Xb, w, r):
         with K.scope("on"):
-            z = M.matvec(Xb, w)
+            z = M.layout_matvec(Xb, w)
             return z, M.rmatvec(Xb, r * z)
 
     return both, (X, jnp.zeros((d,), jnp.float32),
@@ -531,7 +543,7 @@ def _contract_kernel_no_retrace():
 
     def passes(Xb, wv, rv):
         with K.scope("on"):
-            return M.matvec(Xb, wv), M.rmatvec(Xb, rv)
+            return M.layout_matvec(Xb, wv), M.rmatvec(Xb, rv)
 
     return passes, (X, w, r)
 

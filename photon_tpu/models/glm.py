@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from photon_tpu.data.matrix import (BlockedEllRows, Matrix,
-                                    PermutedHybridRows, matvec,
+                                    PermutedHybridRows,
+                                    layout_matvec_lanes, matvec,
                                     matvec_lanes)
 from photon_tpu.ops.losses import TaskType, mean_fn
 
@@ -127,20 +128,38 @@ def chunked_margins(X, w, offsets=0.0) -> jax.Array:
     return z + offsets
 
 
-@jax.jit
-def _score_many(W, X, offsets):
+@partial(jax.jit, static_argnames=("stored_rows",))
+def _score_many(W, X, offsets, stored_rows=False):
+    """(G, n) margins of stacked (G, d) coefficients. Rows — and the rows
+    of ``offsets`` — are in the CALLER's order, or with ``stored_rows`` in
+    the order X stores them (a GLMBatch's; the two differ for a
+    `to_blocked_ell` layout only)."""
     if isinstance(X, (PermutedHybridRows, BlockedEllRows)):
-        return matvec_lanes(X, W[:, X.perm_cols].T).T + offsets
+        mv = layout_matvec_lanes if stored_rows else matvec_lanes
+        return mv(X, W[:, X.perm_cols].T).T + offsets
     return jax.vmap(lambda w: matvec(X, w))(W) + offsets
+
+
+def _stack_means(models):
+    return jnp.stack([jnp.asarray(m.coefficients.means) for m in models])
 
 
 def score_models(models, X: Matrix, offsets=0.0) -> jax.Array:
     """(G, n) raw margins of G same-shape models over one design matrix, as
     ONE device program — the scoring side of a `train_glm_grid` sweep (the
     dense case compiles to a single (n, d)×(d, G) matmul; per-model scoring
-    would pay a dispatch round-trip per model)."""
-    W = jnp.stack([jnp.asarray(m.coefficients.means) for m in models])
-    return _score_many(W, X, jnp.asarray(offsets, jnp.float32))
+    would pay a dispatch round-trip per model). Rows, and ``offsets``, in
+    the caller's order."""
+    return _score_many(_stack_means(models), X,
+                       jnp.asarray(offsets, jnp.float32))
+
+
+def score_models_on_batch(models, batch) -> jax.Array:
+    """`score_models` over a GLMBatch, its offsets included, rows in the
+    BATCH's order: what pairs with ``batch.y`` / ``batch.weights``."""
+    return _score_many(_stack_means(models), batch.X,
+                       jnp.asarray(batch.offsets, jnp.float32),
+                       stored_rows=True)
 
 
 def logistic_regression(coeffs, variances=None):

@@ -731,12 +731,11 @@ def evaluate_glm_grid(grid, batch: GLMBatch, evaluator=None):
     per lane. Returns ``(best_index, [score per lane])``.
     """
     from photon_tpu.evaluation.evaluator import default_evaluator
-    from photon_tpu.models.glm import score_models
+    from photon_tpu.models.glm import score_models_on_batch
 
     task = grid[0][0].task
     evaluator = evaluator if evaluator is not None else default_evaluator(task)
-    margins = np.asarray(score_models([m for m, _ in grid], batch.X,
-                                      batch.offsets))
+    margins = np.asarray(score_models_on_batch([m for m, _ in grid], batch))
     scores = [float(evaluator.evaluate(margins[i], batch.y, batch.weights))
               for i in range(len(grid))]
     best = 0

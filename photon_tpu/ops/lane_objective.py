@@ -28,7 +28,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from photon_tpu.data.dataset import GLMBatch
-from photon_tpu.data.matrix import matvec_lanes, rmatvec_lanes
+from photon_tpu.data.matrix import layout_matvec_lanes, rmatvec_lanes
 from photon_tpu.ops.losses import loss_fns
 from photon_tpu.ops.objective import Objective
 from photon_tpu.telemetry import device_scope
@@ -48,7 +48,7 @@ def _eff_w_lanes(obj: Objective, W):
 def margin_lanes(obj: Objective, W, batch: GLMBatch):
     """z(W): (n, G) per-row margins, LOCAL to this shard."""
     Wt = _eff_w_lanes(obj, W)
-    z = matvec_lanes(batch.X, Wt) + batch.offsets[:, None]
+    z = layout_matvec_lanes(batch.X, Wt) + batch.offsets[:, None]
     if obj.norm_shifts is not None:
         z = z - (obj.norm_shifts @ Wt)[None, :]
     return z
@@ -57,7 +57,7 @@ def margin_lanes(obj: Objective, W, batch: GLMBatch):
 def direction_margin_lanes(obj: Objective, P, batch: GLMBatch):
     """dz = ∂z/∂w · p per lane (offset-free), LOCAL: (n, G)."""
     Pt = _eff_w_lanes(obj, P)
-    dz = matvec_lanes(batch.X, Pt)
+    dz = layout_matvec_lanes(batch.X, Pt)
     if obj.norm_shifts is not None:
         dz = dz - (obj.norm_shifts @ Pt)[None, :]
     return dz

@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_tpu.data.dataset import GLMBatch
-from photon_tpu.data.matrix import matvec, rmatvec, sq_rmatvec, weighted_gram
+from photon_tpu.data.matrix import (layout_matvec, rmatvec, sq_rmatvec,
+                                    weighted_gram)
 from photon_tpu.ops.fused import can_fuse, fused_value_and_grad
 from photon_tpu.ops.losses import TaskType, loss_fns
 from photon_tpu.telemetry import device_scope
@@ -99,7 +100,7 @@ class Objective:
         return w if self.norm_factors is None else w * self.norm_factors
 
     def _margin_of_eff(self, wt, batch: GLMBatch):
-        z = matvec(batch.X, wt) + batch.offsets
+        z = layout_matvec(batch.X, wt) + batch.offsets
         if self.norm_shifts is not None:
             z = z - jnp.dot(self.norm_shifts, wt)
         return z

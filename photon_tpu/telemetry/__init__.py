@@ -103,7 +103,12 @@ trace can put device time to a phase INSIDE one jitted solve. Flat dotted
 names, grouped by prefix: the X pass — xpass.fwd / xpass.t (the public
 `data/matrix.py` dispatchers, forward and transposed), xpass.fwd.hot /
 xpass.t.hot (the hot-block matmuls), xpass.fwd.tail / xpass.t.tail (the
-blocked-ELL gathers), xpass.fwd.reassemble (the `row_pos` reassembly);
+blocked-ELL gathers; for a layout whose rows are stored in concatenation
+order, `to_blocked_ell`'s, also the concatenate-and-add that lays the
+forward tail over the rows), xpass.fwd.reassemble (the `row_pos` gather:
+per evaluation only over a shard / chunk view, whose rows stay in the
+caller's order; over a stored-order layout only where public `matvec`
+hands a result back in the caller's order, never inside a solve);
 objective.loss (loss value / derivative at cached margins); the L-BFGS
 phases lbfgs.two_loop, lbfgs.push, lbfgs.linesearch, lbfgs.direction
 (descent test and ``dphi0``), lbfgs.update (accepted step, convergence,

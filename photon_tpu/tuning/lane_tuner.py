@@ -230,7 +230,8 @@ def _lane_scores(W, val_batch, evaluator, n_real: int) -> np.ndarray:
     from photon_tpu.models.glm import _score_many
 
     margins = np.asarray(_score_many(
-        W, val_batch.X, jnp.asarray(val_batch.offsets, jnp.float32)))
+        W, val_batch.X, jnp.asarray(val_batch.offsets, jnp.float32),
+        stored_rows=True))
     ys = np.empty((n_real,), np.float64)
     for i in range(n_real):
         s = float(evaluator.evaluate(margins[i], val_batch.y,

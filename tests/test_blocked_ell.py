@@ -19,12 +19,15 @@ import scipy.sparse as sp
 
 from photon_tpu.data.dataset import (cast_features, chunk_batch,
                                      chunk_blocked_ell, make_batch,
-                                     pad_batch, shard_blocked_ell_batch)
+                                     pad_batch, shard_blocked_ell_batch,
+                                     with_offsets)
 from photon_tpu.data.matrix import (BlockedEllRows, ShardedBlockedEllRows,
                                     SparseRows, blocked_ell_from_scipy_csr,
                                     from_scipy_csr, last_column_is_intercept,
+                                    layout_matvec, layout_matvec_lanes,
                                     matvec, matvec_lanes, rmatvec,
-                                    rmatvec_lanes, shard_blocked_ell,
+                                    rmatvec_lanes, rows_from_caller,
+                                    rows_to_caller, shard_blocked_ell,
                                     sorted_segment_sum, sq_rmatvec,
                                     to_blocked_ell, weighted_gram)
 from photon_tpu.models.training import train_glm, train_glm_grid
@@ -65,6 +68,11 @@ def _labels(rng, X):
                        .astype(np.float32))
 
 
+def _weights_offsets(rng, n):
+    return (rng.uniform(0.25, 4.0, size=n).astype(np.float32),
+            rng.normal(size=n).astype(np.float32))
+
+
 # ------------------------------------------------------------ layout facts
 def test_bell_roundtrip_and_layout(rng):
     X, B = _power_law_sparse(rng)
@@ -92,42 +100,92 @@ def test_bell_roundtrip_and_layout(rng):
     assert laid == B.tail_nnz <= total
     assert B.ell_slots >= B.tail_nnz
     assert B.tail_pad_waste >= 0.0
-    # row_pos: every row maps into [0, B_total] (B_total = the zero slot)
-    B_total = sum(v.shape[0] for v in B.ell_vals)
-    rp = np.asarray(B.row_pos)
-    assert rp.min() >= 0 and rp.max() <= B_total
+    # rows are STORED in concatenation order: width exponent ascending,
+    # tail-free rows last, original row id within a bucket (stable)
+    n = X.shape[0]
+    ro, rp = np.asarray(B.row_order), np.asarray(B.row_pos)
+    assert sorted(ro.tolist()) == list(range(n))
+    np.testing.assert_array_equal(rp[ro], np.arange(n))
+    hot_cols = set(perm[:B.d_sel].tolist())
+    ind, val = np.asarray(X.indices), np.asarray(X.values)
+    tail_nnz = np.array([sum(1 for c, v in zip(ind[i], val[i])
+                             if v != 0.0 and int(c) not in hot_cols)
+                         for i in range(n)])
+    assert tail_nnz.min() == 0 and (tail_nnz == 1).any() \
+        and tail_nnz.max() > 4            # 0 / 1 / many tail nonzeros
+    width = np.where(tail_nnz > 0,
+                     2 ** np.ceil(np.log2(np.maximum(tail_nnz, 1))), 1e9)
+    stored = width[ro]
+    assert (np.diff(stored) >= 0).all()
+    assert all((np.diff(ro[stored == w]) > 0).all()
+               for w in np.unique(stored))
+    assert B.tail_rows == int((tail_nnz > 0).sum()) \
+        == sum(v.shape[0] for v in B.ell_vals)
+    assert [int((stored == w).sum()) for w in widths] \
+        == [v.shape[0] for v in B.ell_vals]
 
 
-def test_bell_matvec_rmatvec_parity(rng):
+def _storage(B, bf16):
+    """B with f32 or bf16 value storage, and the comparison tolerance."""
+    if not bf16:
+        return B, dict(rtol=2e-4, atol=2e-4)
+    return (cast_features(make_batch(B, np.zeros(B.shape[0]))).X,
+            dict(rtol=3e-2, atol=0.25))
+
+
+@pytest.mark.parametrize("bf16", [False, True], ids=["f32", "bf16"])
+def test_bell_matvec_rmatvec_parity(rng, bf16):
+    """`matvec` answers in the CALLER's row order; `layout_matvec` in the
+    stored order; the transposed ops take a cotangent in the stored
+    order."""
     X, B = _power_law_sparse(rng)
+    B, tol = _storage(B, bf16)
     n, d = X.shape
     w = jnp.asarray(rng.normal(size=d).astype(np.float32))
     r = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    z_ref = np.asarray(matvec(X, w))
     np.testing.assert_allclose(
-        np.asarray(matvec(B, B.from_model_space(w))),
-        np.asarray(matvec(X, w)), rtol=2e-4, atol=2e-4)
+        np.asarray(matvec(B, B.from_model_space(w))), z_ref, **tol)
     np.testing.assert_allclose(
-        np.asarray(B.to_model_space(rmatvec(B, r))),
-        np.asarray(rmatvec(X, r)), rtol=2e-4, atol=2e-4)
+        np.asarray(layout_matvec(B, B.from_model_space(w))),
+        z_ref[np.asarray(B.row_order)], **tol)
     np.testing.assert_allclose(
-        np.asarray(B.to_model_space(sq_rmatvec(B, r))),
-        np.asarray(sq_rmatvec(X, r)), rtol=2e-4, atol=2e-4)
+        np.asarray(rows_to_caller(
+            B, layout_matvec(B, B.from_model_space(w)))), z_ref, **tol)
+    r_st = rows_from_caller(B, r)
+    np.testing.assert_array_equal(np.asarray(rows_to_caller(B, r_st)),
+                                  np.asarray(r))
+    np.testing.assert_allclose(
+        np.asarray(B.to_model_space(rmatvec(B, r_st))),
+        np.asarray(rmatvec(X, r)), **tol)
+    np.testing.assert_allclose(
+        np.asarray(B.to_model_space(sq_rmatvec(B, r_st))),
+        np.asarray(sq_rmatvec(X, r)), **tol)
+    # the caller-order cotangent is NOT what the transposed pass takes:
+    # the order matters, and this test can see it
+    assert not np.allclose(np.asarray(B.to_model_space(rmatvec(B, r))),
+                           np.asarray(rmatvec(X, r)), atol=1e-2)
 
 
-def test_bell_lane_ops_parity(rng):
+@pytest.mark.parametrize("bf16", [False, True], ids=["f32", "bf16"])
+def test_bell_lane_ops_parity(rng, bf16):
     X, B = _power_law_sparse(rng)
+    B, tol = _storage(B, bf16)
     n, d = X.shape
     G = 4
     W = jnp.asarray(rng.normal(size=(d, G)).astype(np.float32))
     R = jnp.asarray(rng.normal(size=(n, G)).astype(np.float32))
     perm = jnp.asarray(B.perm_cols)
     inv = np.asarray(B.inv_perm)
+    Z_ref = np.asarray(matvec_lanes(X, W))
     np.testing.assert_allclose(
-        np.asarray(matvec_lanes(B, W[perm])),
-        np.asarray(matvec_lanes(X, W)), rtol=2e-4, atol=2e-4)
+        np.asarray(matvec_lanes(B, W[perm])), Z_ref, **tol)
     np.testing.assert_allclose(
-        np.asarray(rmatvec_lanes(B, R))[inv],
-        np.asarray(rmatvec_lanes(X, R)), rtol=2e-4, atol=2e-4)
+        np.asarray(layout_matvec_lanes(B, W[perm])),
+        Z_ref[np.asarray(B.row_order)], **tol)
+    np.testing.assert_allclose(
+        np.asarray(rmatvec_lanes(B, rows_from_caller(B, R)))[inv],
+        np.asarray(rmatvec_lanes(X, R)), **tol)
 
 
 def test_bell_weighted_gram_parity(rng):
@@ -135,35 +193,87 @@ def test_bell_weighted_gram_parity(rng):
     r = jnp.asarray(np.abs(rng.normal(size=200)).astype(np.float32))
     inv = np.asarray(B.inv_perm)
     g_ref = np.asarray(weighted_gram(X, r))
-    g = np.asarray(weighted_gram(B, r))[inv][:, inv]
+    g = np.asarray(weighted_gram(B, rows_from_caller(B, r)))[inv][:, inv]
     np.testing.assert_allclose(g, g_ref, rtol=1e-4, atol=1e-4)
 
 
 def test_bell_empty_tail(rng):
-    # d_dense >= d: everything is hot, no ELL buckets at all
+    # d_dense >= d: everything is hot, no ELL buckets at all — every row
+    # is tail-free, so the stored order is the caller's
     X, B = _power_law_sparse(rng, n=100, d=40, k=5, d_dense=64)
-    assert B.ell_vals == () and B.tail_nnz == 0
+    assert B.ell_vals == () and B.tail_nnz == 0 and B.tail_rows == 0
     assert B.tail_pad_waste == 0.0
+    np.testing.assert_array_equal(np.asarray(B.row_order), np.arange(100))
     w = jnp.asarray(rng.normal(size=40).astype(np.float32))
-    np.testing.assert_allclose(
-        np.asarray(matvec(B, B.from_model_space(w))),
-        np.asarray(matvec(X, w)), rtol=2e-4, atol=2e-4)
+    for mv in (matvec, layout_matvec):
+        np.testing.assert_allclose(
+            np.asarray(mv(B, B.from_model_space(w))),
+            np.asarray(matvec(X, w)), rtol=2e-4, atol=2e-4)
     r = jnp.asarray(rng.normal(size=100).astype(np.float32))
     np.testing.assert_allclose(
         np.asarray(B.to_model_space(rmatvec(B, r))),
         np.asarray(rmatvec(X, r)), rtol=2e-4, atol=2e-4)
 
 
+def test_bell_every_row_has_a_tail(rng):
+    # one cold column of its own per row on top of the zipf draw: no
+    # tail-free rows, so the concatenation carries no zero block
+    n, d, k = 120, 400, 4
+    col = (rng.zipf(1.5, size=(n, k)).astype(np.int64) - 1) % 100
+    own = 200 + np.arange(n)[:, None]
+    ind = np.concatenate([col, own], axis=1).astype(np.int32)
+    val = rng.normal(size=(n, k + 1)).astype(np.float32)
+    order = np.argsort(ind, axis=1, kind="stable")
+    si = np.take_along_axis(ind, order, axis=1)
+    dup = np.zeros_like(ind, bool)
+    np.put_along_axis(dup, order[:, 1:], si[:, 1:] == si[:, :-1], axis=1)
+    val[dup] = 0.0
+    X = SparseRows(jnp.asarray(ind), jnp.asarray(val), d)
+    B = to_blocked_ell(X, 8)
+    assert B.tail_rows == n and len(B.ell_vals) >= 2
+    w = jnp.asarray(rng.normal(size=d).astype(np.float32))
+    r = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    np.testing.assert_allclose(
+        np.asarray(matvec(B, B.from_model_space(w))),
+        np.asarray(matvec(X, w)), rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(
+        np.asarray(B.to_model_space(rmatvec(B, rows_from_caller(B, r)))),
+        np.asarray(rmatvec(X, r)), rtol=2e-4, atol=2e-4)
+    b = pad_batch(make_batch(B, np.asarray(r)), 128)
+    z = np.asarray(matvec(b.X, B.from_model_space(w)))
+    np.testing.assert_allclose(z[:n], np.asarray(matvec(X, w)), rtol=2e-4,
+                               atol=2e-4)
+    np.testing.assert_array_equal(z[n:], 0.0)
+
+
 def test_bell_pad_and_cast(rng):
     X, B = _power_law_sparse(rng, n=100, d=300, k=6)
     y = jnp.asarray(rng.normal(size=100).astype(np.float32))
-    b = pad_batch(make_batch(B, y), 128)
+    wts = rng.uniform(0.5, 2.0, size=100).astype(np.float32)
+    b = pad_batch(make_batch(B, y, wts), 128)
     assert b.n == 128
+    # the batch is in the layout's stored order throughout, padding last
+    ro = np.asarray(b.X.row_order)
+    np.testing.assert_array_equal(ro[:100], np.asarray(B.row_order))
+    np.testing.assert_array_equal(ro[100:], np.arange(100, 128))
+    np.testing.assert_array_equal(np.asarray(b.y)[:100], np.asarray(y)[ro[:100]])
+    np.testing.assert_array_equal(np.asarray(b.weights)[:100], wts[ro[:100]])
+    np.testing.assert_array_equal(np.asarray(b.weights)[100:], 0.0)
+    np.testing.assert_array_equal(
+        np.asarray(rows_to_caller(b.X, b.y))[:100], np.asarray(y))
     w = jnp.asarray(rng.normal(size=300).astype(np.float32))
     z = np.asarray(matvec(b.X, b.X.from_model_space(w)))
     np.testing.assert_allclose(z[:100], np.asarray(matvec(X, w)), rtol=2e-4,
                                atol=2e-4)
     np.testing.assert_allclose(z[100:], 0.0, atol=1e-6)
+    zl = np.asarray(layout_matvec(b.X, b.X.from_model_space(w)))
+    np.testing.assert_allclose(zl[:100], np.asarray(matvec(X, w))[ro[:100]],
+                               rtol=2e-4, atol=2e-4)
+    r = jnp.asarray(rng.normal(size=128).astype(np.float32))
+    np.testing.assert_allclose(
+        np.asarray(b.X.to_model_space(
+            rmatvec(b.X, rows_from_caller(b.X, r)))),
+        np.asarray(rmatvec(X, r[:100])), rtol=2e-4, atol=2e-4)
     bc = cast_features(b)
     assert bc.X.dense.dtype == jnp.bfloat16
     assert all(v.dtype == jnp.bfloat16 for v in bc.X.ell_vals)
@@ -198,7 +308,8 @@ def test_bell_from_scipy_csr(rng):
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
     r = rng.normal(size=n).astype(np.float32)
     np.testing.assert_allclose(
-        np.asarray(B.to_model_space(rmatvec(B, jnp.asarray(r)))),
+        np.asarray(B.to_model_space(
+            rmatvec(B, jnp.asarray(rows_from_caller(B, r))))),
         M.T @ r, rtol=2e-4, atol=2e-4)
 
 
@@ -248,8 +359,14 @@ def test_bell_train_glm_parity(rng, task):
     # compared two truncated paths, which differ by reduction-order noise
     cfg = OptimizerConfig(max_iters=400, tolerance=tol, reg=l2(),
                           reg_weight=lam, history=5)
-    m_b, r_b = train_glm(make_batch(B, y), task, cfg)
-    m_s, r_s = train_glm(make_batch(X, y), task, cfg)
+    # non-constant weights and offsets (logistic; the two regressions'
+    # random responses are order-sensitive as they are): a batch whose
+    # y / weights / offsets were left in the caller's order would solve
+    # another problem
+    wts, offs = (_weights_offsets(rng, 400)
+                 if task is TaskType.LOGISTIC_REGRESSION else (None, None))
+    m_b, r_b = train_glm(make_batch(B, y, wts, offs), task, cfg)
+    m_s, r_s = train_glm(make_batch(X, y, wts, offs), task, cfg)
     assert bool(r_b.converged) and bool(r_s.converged)
     # value parity is the tight pin
     np.testing.assert_allclose(float(r_b.value), float(r_s.value), rtol=rtol)
@@ -273,16 +390,120 @@ def test_bell_grid_lanes_parity(rng):
     cfg = OptimizerConfig(max_iters=60, tolerance=1e-6, reg=l2(),
                           reg_weight=0.0, history=5)
     weights = [1e-1, 1.0, 30.0]
-    grid_b = train_glm_grid(make_batch(B, y), TaskType.LOGISTIC_REGRESSION,
-                            cfg, weights)
-    grid_s = train_glm_grid(make_batch(X, y), TaskType.LOGISTIC_REGRESSION,
-                            cfg, weights)
+    wts, offs = _weights_offsets(rng, X.shape[0])
+    batch_b, batch_s = make_batch(B, y, wts, offs), make_batch(X, y, wts, offs)
+    grid_b = train_glm_grid(batch_b, TaskType.LOGISTIC_REGRESSION, cfg,
+                            weights)
+    grid_s = train_glm_grid(batch_s, TaskType.LOGISTIC_REGRESSION, cfg,
+                            weights)
     for (m_b, r_b), (m_s, r_s) in zip(grid_b, grid_s):
         np.testing.assert_allclose(float(r_b.value), float(r_s.value),
                                    rtol=1e-4)
         np.testing.assert_allclose(np.asarray(m_b.coefficients.means),
                                    np.asarray(m_s.coefficients.means),
                                    atol=3e-2)
+    # model selection pairs the batch's margins with the batch's labels:
+    # the SAME models must score the same on either representation
+    from photon_tpu.models.training import evaluate_glm_grid
+
+    best_b, scores_b = evaluate_glm_grid(grid_s, batch_b)
+    best_s, scores_s = evaluate_glm_grid(grid_s, batch_s)
+    assert best_b == best_s
+    np.testing.assert_allclose(scores_b, scores_s, rtol=1e-5)
+
+
+# ------------------------------------------- where per-row data crosses
+def test_bell_batch_is_in_stored_order_and_with_offsets_translates(rng):
+    X, B = _power_law_sparse(rng, n=300, d=200, k=6, d_dense=16)
+    y = np.asarray(_labels(rng, X))
+    wts, offs = _weights_offsets(rng, 300)
+    ro = np.asarray(B.row_order)
+    assert (ro != np.arange(300)).any()
+    b = make_batch(B, y, wts, offs)
+    for got, ref in ((b.y, y), (b.weights, wts), (b.offsets, offs)):
+        np.testing.assert_array_equal(np.asarray(got), ref[ro])
+    offs2 = rng.normal(size=300).astype(np.float32)
+    np.testing.assert_array_equal(
+        np.asarray(with_offsets(b, jnp.asarray(offs2)).offsets), offs2[ro])
+    # the objective sees one and the same problem on either representation
+    from photon_tpu.ops.objective import Objective
+
+    obj = Objective(TaskType.LOGISTIC_REGRESSION, l2=0.3)
+    w = jnp.asarray(rng.normal(size=200).astype(np.float32) * 0.3)
+    v_s, g_s = obj.value_and_grad(w, with_offsets(make_batch(X, y, wts),
+                                                  offs2))
+    v_b, g_b = obj.value_and_grad(B.from_model_space(w),
+                                  with_offsets(make_batch(B, y, wts), offs2))
+    np.testing.assert_allclose(float(v_b), float(v_s), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(B.to_model_space(g_b)),
+                               np.asarray(g_s), rtol=2e-4, atol=2e-4)
+    # make_batch on any other X does what it always did
+    d = make_batch(np.asarray(rng.normal(size=(5, 3)), np.float32),
+                   [0., 1., 0., 1., 1.], offsets=np.arange(5.))
+    np.testing.assert_array_equal(np.asarray(d.offsets), np.arange(5.))
+    np.testing.assert_array_equal(np.asarray(d.weights), 1.0)
+
+
+def test_bell_lane_tuner_validation_scores(rng):
+    """tuning.lane_tuner._lane_scores pairs the validation batch's margins
+    with ITS labels and weights."""
+    from photon_tpu.evaluation.evaluator import default_evaluator
+    from photon_tpu.tuning.lane_tuner import _lane_scores
+
+    X, B = _power_law_sparse(rng, n=300, d=200, k=6, d_dense=16)
+    y = np.asarray(_labels(rng, X))
+    wts, offs = _weights_offsets(rng, 300)
+    W = jnp.asarray(rng.normal(size=(4, 200)).astype(np.float32) * 0.3)
+    ev = default_evaluator(TaskType.LOGISTIC_REGRESSION)
+    got = _lane_scores(W, make_batch(B, y, wts, offs), ev, 3)
+    ref = _lane_scores(W, make_batch(X, y, wts, offs), ev, 3)
+    np.testing.assert_allclose(got, ref, rtol=1e-5)
+    assert len(set(np.round(ref, 6))) == 3
+
+
+def test_bell_game_fixed_effect_with_a_random_effect(rng):
+    """A GAME fit whose fixed shard is a BlockedEllRows: the random
+    effect's scores reach the fixed effect as caller-ordered offsets, the
+    fixed effect's margins go back caller-ordered — the fit and the
+    scores match the SparseRows fit."""
+    from photon_tpu.game.dataset import GameData
+    from photon_tpu.game.estimator import (FixedEffectConfig, GameEstimator,
+                                           RandomEffectConfig)
+    from photon_tpu.game.scoring import score_game
+
+    n, n_ent = 360, 12
+    X, B = _power_law_sparse(rng, n=n, d=150, k=6, d_dense=16)
+    ent = rng.integers(0, n_ent, size=n)
+    Xr = rng.normal(size=(n, 2)).astype(np.float32)
+    w_re = rng.normal(size=(n_ent, 2)) * 1.5
+    wf = rng.normal(size=150).astype(np.float32) * 0.5
+    logit = np.asarray(matvec(X, jnp.asarray(wf))) \
+        + np.einsum("nd,nd->n", Xr, w_re[ent])
+    y = (rng.random(n) < 1 / (1 + np.exp(-logit))).astype(np.float32)
+    wts, offs = _weights_offsets(rng, n)
+    cfg = OptimizerConfig(max_iters=60, tolerance=1e-7, reg=l2(),
+                          reg_weight=1.0)
+
+    def fit(shard):
+        data = GameData.build(y, {"f": shard, "r": Xr}, {"e": ent},
+                              weights=wts, offsets=offs)
+        est = GameEstimator(
+            task=TaskType.LOGISTIC_REGRESSION,
+            coordinate_configs={
+                "fixed": FixedEffectConfig("f", cfg),
+                "per_e": RandomEffectConfig("e", "r", cfg)},
+            n_sweeps=2, warm_start=False)
+        model = est.fit(data)[0].model
+        return model, np.asarray(score_game(model, data))
+
+    (m_b, s_b), (m_s, s_s) = fit(B), fit(X)
+    np.testing.assert_allclose(
+        np.asarray(m_b["fixed"].model.coefficients.means),
+        np.asarray(m_s["fixed"].model.coefficients.means), atol=5e-3)
+    np.testing.assert_allclose(np.asarray(m_b["per_e"].coefficients),
+                               np.asarray(m_s["per_e"].coefficients),
+                               atol=5e-3)
+    np.testing.assert_allclose(s_b, s_s, atol=2e-2)
 
 
 def test_bell_streamed_parity(rng):

@@ -238,6 +238,63 @@ def test_serving_int8_kernel_refused(one_chip, compiled_mode, B):
                  re_cs)
 
 
+# --------------------------- gathers per evaluation (stored-order layout)
+def _gather_outputs(jaxpr):
+    """Output shapes of every `gather` equation, sub-jaxprs included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            out += [tuple(v.aval.shape) for v in eqn.outvars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _gather_outputs(sub)
+    return out
+
+
+@pytest.mark.parametrize("G", [0, 8], ids=["scalar", "G8"])
+def test_blocked_ell_evaluation_gathers_only_by_bucket(G):
+    """One value-and-gradient over a `to_blocked_ell` batch gathers once
+    per ELL width bucket (of `w`) and once per occurrence bucket (of the
+    cotangent) and NOWHERE else: the forward tail is laid over the rows
+    by concatenation, so no gather produces an (n,) / (n, G) vector."""
+    from photon_tpu.data.dataset import cast_features, make_batch
+    from photon_tpu.data.matrix import _contract_blocked_ell
+    from photon_tpu.ops import lane_objective
+    from photon_tpu.ops.losses import TaskType
+    from photon_tpu.ops.objective import Objective
+
+    X = _contract_blocked_ell()
+    n, d = X.shape
+    rng = np.random.default_rng(0)
+    batch = cast_features(make_batch(
+        X, (rng.uniform(size=n) < 0.5).astype(np.float32),
+        rng.uniform(0.5, 2.0, size=n), rng.normal(size=n)))
+    obj = Objective(TaskType.LOGISTIC_REGRESSION, l2=0.5)
+    if G:
+        l2s = jnp.full((G,), 0.5, jnp.float32)
+
+        def fn(W):
+            z = lane_objective.margin_lanes(obj, W, batch)
+            return lane_objective.value_and_grad_at_margin_lanes(
+                obj, l2s, W, z, batch)
+
+        w = jnp.zeros((d, G), jnp.float32)
+    else:
+        fn = lambda w: obj.value_and_grad(w, batch)  # noqa: E731
+        w = jnp.zeros((d,), jnp.float32)
+    shapes = _gather_outputs(jax.make_jaxpr(fn)(w).jaxpr)
+    assert len(X.ell_vals) >= 3 and len(X.bucket_vals) >= 3
+    assert len(shapes) == len(X.ell_vals) + len(X.bucket_vals), shapes
+    expected = [tuple(v.shape) + ((G,) if G else ())
+                for v in X.ell_vals + X.bucket_vals]
+    assert sorted(shapes) == sorted(expected)
+    assert (n, G) not in shapes and (n,) not in shapes
+    # and so does the lowered program: the jitted evaluation has that many
+    import re
+
+    assert len(re.findall(r'stablehlo\.gather"?\(',
+                          jax.jit(fn).lower(w).as_text())) == len(shapes)
+
+
 # ------------------------------------- one all-reduce per evaluation (HLO)
 def _all_reduces(compiled) -> int:
     from photon_tpu.analysis import hlo_all_reduce_count
