@@ -1679,9 +1679,25 @@ def _matvec(X: Matrix, w: jax.Array) -> jax.Array:
         # Sparse runs on the VPU (gather + multiply + reduce), never the MXU:
         # bf16 is a STORAGE format only — upcast in registers, full-precision
         # products, f32 accumulation. w/r vectors are small; never downcast.
-        return jnp.einsum("nk,nk->n", X.values.astype(jnp.float32),
-                          w[X.indices])
-    return jnp.matmul(X, w.astype(X.dtype), preferred_element_type=jnp.float32)
+        # (a multiply and a sum, not an einsum: a batched dot may run on
+        # the MXU at its default precision)
+        return jnp.sum(X.values.astype(jnp.float32) * w[X.indices], axis=-1)
+    return jnp.matmul(X, w.astype(X.dtype), precision=_dense_precision(X),
+                      preferred_element_type=jnp.float32)
+
+
+def _dense_precision(X: jax.Array):
+    """Matmul precision of a plain dense X against one vector. f32 storage
+    MEANS f32 products: the TPU's default would round both operands to bf16
+    (2^-9 a product), which a solve that is meant to reach f32 resolution
+    cannot see past. bf16 storage is exact in one pass already. One vector
+    a pass is bound by reading X, not by the multiplier, so the extra MXU
+    passes cost little. Where it matters on the v5e: the per-entity solves'
+    transposed pass under `vmap` is an MXU convolution (off 2.4e-3 of the
+    result's scale at the default, 7e-8 at HIGHEST); the forward pass and
+    an unbatched (n, d) shard are f32 multiply-and-reduce either way
+    (PERF.md section 6, PR 27)."""
+    return (jax.lax.Precision.HIGHEST if X.dtype == jnp.float32 else None)
 
 
 @device_scope("xpass.t")
@@ -1718,7 +1734,8 @@ def rmatvec(X: Matrix, r: jax.Array) -> jax.Array:
         return jax.ops.segment_sum(
             contrib, X.indices.reshape(-1), num_segments=X.n_features,
         )
-    return jnp.matmul(X.T, r.astype(X.dtype), preferred_element_type=jnp.float32)
+    return jnp.matmul(X.T, r.astype(X.dtype), precision=_dense_precision(X),
+                      preferred_element_type=jnp.float32)
 
 
 @device_scope("xpass.fwd")

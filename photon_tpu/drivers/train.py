@@ -65,6 +65,28 @@ class CoordinateSpec:
     reg_alpha: float = 0.5  # elastic-net mixing
     regularize_intercept: bool = True
     active_cap: Optional[int] = None  # random-effect active-data bound
+    # random-effect feature-space projection (reference: projectorType on
+    # the random-effect data configuration): "index_map" — each entity's
+    # solve runs over the features its own active rows touch — or
+    # {"random": dim} — one shared Gaussian projection to `dim` columns
+    projection: Optional[object] = None
+
+    def projection_config(self):
+        from photon_tpu.game.projector import ProjectionConfig, ProjectorType
+
+        spec = self.projection
+        if spec is None:
+            return None
+        if self.entity_name is None:
+            raise ValueError("projection applies to random-effect "
+                             "coordinates only (set entity_name)")
+        if isinstance(spec, str) and spec.lower() == "index_map":
+            return ProjectionConfig(ProjectorType.INDEX_MAP)
+        if isinstance(spec, dict) and set(spec) == {"random"}:
+            return ProjectionConfig(ProjectorType.RANDOM,
+                                    projected_dim=int(spec["random"]))
+        raise ValueError(f"unknown projection {spec!r}: \"index_map\" or "
+                         "{\"random\": dim}")
 
     def reg_context(self) -> reg.RegularizationContext:
         t = self.reg_type.lower()
@@ -93,7 +115,8 @@ class CoordinateSpec:
         if self.entity_name is None:
             return FixedEffectConfig(self.feature_shard, opt)
         return RandomEffectConfig(
-            self.entity_name, self.feature_shard, opt, active_cap=self.active_cap
+            self.entity_name, self.feature_shard, opt,
+            active_cap=self.active_cap, projection=self.projection_config(),
         )
 
 

@@ -220,12 +220,15 @@ def _fused_fixed_update(batch, base, scores, w0, obj, l1, y, weights,
     from photon_tpu.models.variance import compute_variances
 
     loss, _, _ = loss_fns(task)
-    offs = _sum_scores(base, scores)
+    with telemetry.device_scope("game.objective"):
+        offs = _sum_scores(base, scores)
     b = batch._replace(offsets=offs)
-    res = solve(obj, b, w0, config, l1_weight=l1)
-    var = compute_variances(obj, res.w, b, variance)
-    margin = matvec(batch.X, res.w)
-    objective = jnp.sum(weights * loss(offs + margin, y))
+    with telemetry.device_scope("game_fixed.solve"):
+        res = solve(obj, b, w0, config, l1_weight=l1)
+        var = compute_variances(obj, res.w, b, variance)
+        margin = matvec(batch.X, res.w)
+    with telemetry.device_scope("game.objective"):
+        objective = jnp.sum(weights * loss(offs + margin, y))
     return res, var, margin, objective
 
 
@@ -314,6 +317,7 @@ def coordinate_descent(
         y = jnp.asarray(y, jnp.float32)
         weights = jnp.asarray(weights, jnp.float32)
         base = jnp.asarray(base_offsets, jnp.float32)
+        no_score = jnp.zeros_like(base)
 
     # Scores of any pre-existing models participate as offsets from the start
     # (reference: CoordinateDescent seeds offsets from the initial GameModel).
@@ -352,6 +356,7 @@ def coordinate_descent(
         cd_scope = ck.scope(f"game-{fp}-{ck.invocation(fp)}")
 
     deferred_re: list = []  # (stats-list index slot fillers for fused REs)
+    own_tables: set = set()  # coordinates whose (E, d) table this call made
     update_log: list = []  # (sweep, coordinate) per objective_history entry
     done_updates = 0
     stats_entries: list = []
@@ -389,6 +394,12 @@ def coordinate_descent(
                 warm = models.get(name)
                 prior = priors.get(name)
                 others = tuple(s for o, s in scores.items() if o != name)
+                if not streamed:
+                    # always as many as the other coordinates: a fused
+                    # update's program is then the same in the first sweep,
+                    # when some have no score yet, as in every later one
+                    others += (no_score,) * (len(coordinates) - 1
+                                             - len(others))
                 # per-update sub-scope: a live random-effect update's
                 # bucket-level state lands under u<k>/re and is dropped
                 # the moment the update completes
@@ -422,6 +433,10 @@ def coordinate_descent(
                         scores[name] = margin
                         coordinate_stats[name].append(res)
                         objective_history.append(objective)
+                        if telemetry.enabled():  # two tiny dispatches
+                            telemetry.count_device(
+                                "game_fixed.row_iterations",
+                                res.iterations.astype(jnp.float32) * ds.n)
                         if ck is not None:
                             stat_entry = {
                                 "name": name, "kind": "fixed",
@@ -442,15 +457,25 @@ def coordinate_descent(
                                  and prior is None and not streamed
                                  else None)
                         if fused is not None:
-                            fn, blocks_args, obj, lam = fused
+                            fn, blocks_args, objs, lam = fused
                             ds = coord.dataset
                             E, d = ds.n_entities, ds.dim
-                            coeffs0 = (jnp.asarray(warm.coefficients)
-                                       if warm is not None
-                                       and warm.coefficients.shape == (E, d)
-                                       else jnp.zeros((E, d), jnp.float32))
-                            coeffs, variances, margin, objective, st = fn(
-                                coeffs0, base, others, obj, lam,
+                            # the update writes the table in place (the
+                            # program donates it): a table this descent
+                            # made is handed over as it is, a caller's
+                            # model is copied first
+                            if name in own_tables:
+                                coeffs0 = warm.coefficients
+                            elif (warm is not None
+                                    and warm.coefficients.shape == (E, d)):
+                                coeffs0 = jnp.array(warm.coefficients,
+                                                    jnp.float32)
+                            else:
+                                coeffs0 = jnp.zeros((E, d), jnp.float32)
+                            own_tables.add(name)
+                            (coeffs, variances, margin, objective, st,
+                             values) = fn(
+                                coeffs0, base, others, objs, lam,
                                 blocks_args, ds.X,
                                 jnp.asarray(ds.entity_dense), y, weights)
                             # the ONE dispatch solved every block of the
@@ -458,6 +483,16 @@ def coordinate_descent(
                             # RandomEffectCoordinate.train counts its own)
                             telemetry.count("game_re.blocks",
                                             len(blocks_args))
+                            telemetry.count_device(
+                                "game_re.row_iterations", st[3])
+                            telemetry.count_device(
+                                "game_re.block_steps", st[4])
+                            telemetry.count_device(
+                                "game_re.moved_row_iterations", st[5])
+                            telemetry.count_device(
+                                "game_re.iterations", st[2])
+                            telemetry.count_device(
+                                "game_re.linesearch_trials", st[6])
                             models[name] = RandomEffectModel(
                                 entity_name=ds.entity_name,
                                 feature_shard=ds.shard_name,
@@ -473,14 +508,17 @@ def coordinate_descent(
                                 # RETrainStats below
                                 slot = len(coordinate_stats[name])
                                 coordinate_stats[name].append(None)
-                                deferred_re.append((name, slot, E, st))
+                                deferred_re.append(
+                                    (name, slot, E, values, st))
                             else:
                                 # checkpointing forces the stats now —
                                 # the progress payload needs host values
-                                c_, f_, it_ = (int(v) for v in
-                                               jax.device_get(st))
+                                c_, f_, it_, ri_ = jax.device_get(st[:4])
+                                c_, f_, it_ = int(c_), int(f_), int(it_)
                                 coordinate_stats[name].append(
-                                    RETrainStats(E, c_, f_, it_))
+                                    RETrainStats(E, c_, f_, it_,
+                                                 row_iterations=float(ri_),
+                                                 entity_values=values))
                                 stat_entry = {"name": name, "kind": "re",
                                               "E": E, "c": c_, "f": f_,
                                               "it": it_}
@@ -569,9 +607,11 @@ def coordinate_descent(
                                 coordinate=name, sweep=sweep)
     from photon_tpu.game.random_effect import RETrainStats
 
-    for (name, slot, E, _), (c, f, it) in zip(deferred_re, re_stats):
-        coordinate_stats[name][slot] = RETrainStats(E, int(c), int(f),
-                                                    int(it))
+    for (name, slot, E, values, _), (c, f, it, ri, *_) in zip(
+            deferred_re, re_stats):
+        coordinate_stats[name][slot] = RETrainStats(
+            E, int(c), int(f), int(it), row_iterations=float(ri),
+            entity_values=values)
     ordered = {name: models[name] for name in update_sequence}
     for name in coordinates:  # score-only coordinates outside the sequence
         if name in models and name not in ordered:

@@ -5,9 +5,13 @@ RandomEffectDataset, GameDatum}. The reference partitions random-effect data
 by entity id across Spark executors and trains one Breeze solver per entity.
 On TPU the same structure becomes dense batched tensors:
 
-- entities are bucketed by row count into power-of-two block shapes
-  (bucket m = smallest power of two ≥ the entity's active rows), so a handful
-  of distinct XLA programs covers every entity size;
+- entities are bucketed into a handful of block shapes (m rows × w solve
+  width) planned by BYTES (`plan_buckets`): each entity starts in the cell
+  of its power-of-two row count and, under an INDEX_MAP projection, the
+  width class of the features its own active rows touch; cells are merged,
+  cheapest added padding first, while a shape saves less memory than it is
+  worth, so a handful of distinct XLA programs covers every entity size
+  and no bucket is padded to a width only its widest entity needs;
 - within a bucket, entities are stacked into (E, m, …) arrays — the per-entity
   solver is `vmap`'d over the leading axis, and that axis is shardable across
   the mesh's ``data`` axis, which is how per-entity training scales across
@@ -23,11 +27,14 @@ alongside the blocks.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from photon_tpu import telemetry
 from photon_tpu.data.dataset import (ChunkedMatrix, GLMBatch, make_batch,
                                      make_chunked_batch)
 from photon_tpu.data.matrix import (BlockedEllRows, HybridRows, Matrix,
@@ -113,8 +120,9 @@ def _shard_dim(X: Matrix) -> int:
     return X.n_features if isinstance(X, SparseRows) else X.shape[1]
 
 
-def _gather_rows(X: Matrix, idx: np.ndarray):
-    """Host-side row gather; returns numpy (dense) or numpy-backed SparseRows."""
+def _host_rows(X: Matrix):
+    """One host copy of a random-effect shard for entity bucketing: numpy
+    (dense) or an (indices, values) pair (SparseRows)."""
     if isinstance(X, (HybridRows, PermutedHybridRows, BlockedEllRows)):
         raise TypeError(
             f"{type(X).__name__} shards are not supported for GAME entity bucketing "
@@ -127,10 +135,8 @@ def _gather_rows(X: Matrix, idx: np.ndarray):
             "shards used exclusively by fixed effects — keep this shard "
             "out of the streamed-objective set")
     if isinstance(X, SparseRows):
-        ind = np.asarray(X.indices)[idx]
-        val = np.asarray(X.values)[idx]
-        return ind, val
-    return np.asarray(X)[idx]
+        return np.asarray(X.indices), np.asarray(X.values)
+    return np.asarray(X)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -210,15 +216,9 @@ class REBlock:
         return int(self.entity_index.shape[0])
 
 
-def _next_pow2(x: int, floor: int = 4) -> int:
-    from photon_tpu.data.matrix import next_pow2
-
-    return next_pow2(x, floor)
-
-
-def _project_dense(Xd: np.ndarray, icpt) -> tuple:
+def _project_dense(Xd: np.ndarray, icpt, width: int) -> tuple:
     """INDEX_MAP-project a dense (E, m, d) bucket: per-entity active columns
-    only, intercept pinned last."""
+    only, intercept pinned last, `width` projected columns."""
     from photon_tpu.game.projector import (
         build_index_map_projection,
         project_dense_block,
@@ -228,27 +228,154 @@ def _project_dense(Xd: np.ndarray, icpt) -> tuple:
     if icpt is not None:
         active[:, icpt] = False
     sets = [np.nonzero(a)[0] for a in active]
-    bp = build_index_map_projection(sets, icpt)
+    bp = build_index_map_projection(sets, icpt, width=width)
     return jnp.asarray(project_dense_block(Xd, bp)), bp
 
 
-def _project_sparse(ind3: np.ndarray, val3: np.ndarray, icpt) -> tuple:
-    """INDEX_MAP-project a padded-COO (E, m, k) bucket to per-entity dense
-    (E, m, p) blocks."""
-    from photon_tpu.game.projector import (
-        build_index_map_projection,
-        project_sparse_block,
-    )
+@partial(jax.jit, static_argnames=("width",))
+def _densify(local, val, width: int):
+    """(..., k) projected columns and values → the dense (..., width) rows: every
+    slot compared against every column and summed over the slots, so a
+    feature a row names twice accumulates (SparseRows matvec semantics)
+    and nothing is scattered."""
+    hit = local[..., None] == jnp.arange(width, dtype=local.dtype)
+    return jnp.sum(jnp.where(hit, val[..., None], 0.0), axis=-2)
 
-    E = ind3.shape[0]
-    sets = []
-    for e in range(E):
-        feats = np.unique(ind3[e][val3[e] != 0.0])
+
+def _project_sparse(ind3: np.ndarray, val3: np.ndarray, icpt,
+                    width: int) -> tuple:
+    """INDEX_MAP-project a padded-COO (E, m, k) bucket to per-entity dense
+    (E, m, width) blocks. The index map is built on the host; the block is
+    laid out on the device from the (E, m, k) columns and values, k slots a
+    row across the link instead of `width`."""
+    from photon_tpu.game.projector import sparse_index_map
+
+    bp, local = sparse_index_map(ind3, val3, icpt, width=width)
+    return _densify(local, val3, bp.dim), bp
+
+
+def _active_widths(Xg, mask: np.ndarray, icpt) -> np.ndarray:
+    """(E,) INDEX_MAP solve width of each entity of a gathered (E, m, ...)
+    group: the features its masked rows touch, plus the intercept's column."""
+    extra = 1 if icpt is not None else 0
+    if isinstance(Xg, tuple):
+        ind, val = Xg
+        live = (val != 0.0) & mask[..., None]
         if icpt is not None:
-            feats = feats[feats != icpt]
-        sets.append(feats)
-    bp = build_index_map_projection(sets, icpt)
-    return jnp.asarray(project_sparse_block(ind3, val3, bp)), bp
+            live &= ind != icpt
+        sentinel = np.iinfo(np.int32).max
+        ids = np.sort(np.where(live, ind, sentinel).astype(np.int32)
+                      .reshape(ind.shape[0], -1), axis=1)
+        first = ids != sentinel
+        first[:, 1:] &= ids[:, 1:] != ids[:, :-1]
+        return first.sum(axis=1) + extra
+    active = np.any((Xg != 0.0) & mask[..., None], axis=1)
+    if icpt is not None:
+        active[:, icpt] = False
+    return active.sum(axis=1) + extra
+
+
+# ------------------------------------------------------------- bucket plan
+# A distinct block shape costs one solver compile and one launch per update;
+# padding costs bytes on every pass of every solve. A merge of two shapes is
+# taken while it adds fewer padded bytes than 1/_SHAPE_WORTH of the device's
+# memory, down to _DEFAULT_SHAPES shapes (small problems: the compile is
+# dearer than any padding). One coordinate's blocks may hold at most
+# 1/_BLOCK_SHARE of the device: the other coordinates' blocks, the
+# coefficient tables, the flat shards and the solver state live there too.
+_SHAPE_WORTH = 128
+_BLOCK_SHARE = 2
+_DEFAULT_SHAPES = 3
+_NOMINAL_DEVICE_BYTES = 16 << 30  # where the backend reports no limit (CPU)
+
+
+def _device_memory_bytes() -> Optional[int]:
+    """The default device's memory limit; None where the backend reports
+    none (the CPU), and then no plan is refused for its size."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats["bytes_limit"]) if stats.get("bytes_limit") else None
+
+
+def _pow2_ceil(x: np.ndarray, floor: int) -> np.ndarray:
+    """`data.matrix.next_pow2`, elementwise: the smallest floor·2^j ≥ x."""
+    x = np.maximum(np.asarray(x, np.int64), floor)
+    return floor << np.ceil(np.log2(x / floor)).astype(np.int64)
+
+
+def _width_class(width: np.ndarray) -> np.ndarray:
+    """Projected-width classes: a power of two up to one 128-lane tile,
+    whole tiles above it (a power of two would pad 679 columns to 1024)."""
+    width = np.asarray(width, np.int64)
+    return np.where(width <= 128, _pow2_ceil(width, 2),
+                    -(-width // 128) * 128)
+
+
+@dataclasses.dataclass(frozen=True)
+class BucketPlan:
+    """Block shapes for one random-effect coordinate: ``buckets`` is a list
+    of (m rows, w solve width, entity ids) in (m, w) order; the two byte
+    counts are of the blocks' feature values (rows × width × 4, or a sparse
+    block's k slots × 8)."""
+
+    buckets: list
+    bytes_real: int     # Σ entities: active rows × own width
+    bytes_padded: int   # Σ buckets: entities × m × w
+
+
+def plan_buckets(rows: np.ndarray, widths: np.ndarray, slot_bytes: int,
+                 width_classes: bool = False, min_block_rows: int = 4,
+                 max_blocks: Optional[int] = None,
+                 device_bytes: Optional[int] = None,
+                 name: str = "") -> BucketPlan:
+    """Plan block shapes by bytes. ``rows`` / ``widths``: each entity's
+    active rows and solve width; ``width_classes``: widths differ by entity
+    (an INDEX_MAP projection) and a bucket's is a `_width_class`;
+    ``slot_bytes``: bytes of one (row, column) slot. The merge cost is the
+    padded bytes a merge adds; merging stops at `_DEFAULT_SHAPES` shapes or
+    when the cheapest merge costs more than a shape is worth on this device.
+    ``max_blocks``, where a caller passes it, is an upper limit that is met
+    whatever it costs. A plan over the device's share raises here, before
+    anything is allocated, naming its largest bucket."""
+    rows = np.asarray(rows, np.int64)
+    widths = np.asarray(widths, np.int64)
+    m_of = _pow2_ceil(rows, min_block_rows)
+    w_of = _width_class(widths) if width_classes else widths
+    cells, cell_of = np.unique(np.stack([m_of, w_of], axis=1), axis=0,
+                               return_inverse=True)
+    cell_of = cell_of.reshape(-1)
+    M = cells[:, 0].astype(np.float64)
+    W = cells[:, 1].astype(np.float64)
+    groups = [np.nonzero(cell_of == c)[0] for c in range(len(cells))]
+    E = np.asarray([len(g) for g in groups], np.float64)
+    forced = max_blocks is not None
+    limit = max_blocks if forced else _DEFAULT_SHAPES
+    worth = (device_bytes or _NOMINAL_DEVICE_BYTES) // _SHAPE_WORTH
+    while len(groups) > limit:
+        held = M * W * E
+        cost = (np.maximum.outer(M, M) * np.maximum.outer(W, W)
+                * np.add.outer(E, E) - np.add.outer(held, held)) * slot_bytes
+        cost[np.tril_indices(len(groups))] = np.inf
+        i, j = np.unravel_index(int(np.argmin(cost)), cost.shape)
+        if not forced and cost[i, j] >= worth:
+            break
+        M[i], W[i], E[i] = max(M[i], M[j]), max(W[i], W[j]), E[i] + E[j]
+        groups[i] = np.concatenate([groups[i], groups[j]])
+        M, W, E = (np.delete(a, j) for a in (M, W, E))
+        del groups[j]
+    order = np.lexsort((W, M))
+    buckets = [(int(M[b]), int(W[b]), groups[b]) for b in order]
+    sizes = [m * w * len(g) * slot_bytes for m, w, g in buckets]
+    padded = int(sum(sizes))
+    if device_bytes is not None and padded > device_bytes // _BLOCK_SHARE:
+        m, w, g = buckets[int(np.argmax(sizes))]
+        raise ValueError(
+            f"random-effect coordinate {name!r}: its blocks would hold "
+            f"{padded:,} bytes, over 1/{_BLOCK_SHARE} of the device's "
+            f"{device_bytes:,}; the largest bucket is {len(g):,} entities x "
+            f"{m} rows x {w} columns ({max(sizes):,} bytes). Lower "
+            "active_cap, or shard the coordinate over a mesh")
+    real = int((rows * widths).sum()) * slot_bytes
+    return BucketPlan(buckets, real, padded)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -274,6 +401,11 @@ class RandomEffectDataset:
     # blocks themselves (REBlock.proj).
     projection: Optional[object] = None  # projector.ProjectionConfig
     projector: Optional[object] = None  # projector.RandomProjector
+    # The bucket plan's byte counts (`plan_buckets`): the feature values the
+    # entities' active rows hold at their own solve widths, and the padded
+    # blocks allocated for them.
+    block_bytes_real: int = 0
+    block_bytes_padded: int = 0
 
     @property
     def n_entities(self) -> int:
@@ -292,7 +424,7 @@ class RandomEffectDataset:
         min_block_rows: int = 4,
         seed: int = 0,
         projection=None,
-        max_blocks: int = 3,
+        max_blocks: Optional[int] = None,
     ) -> "RandomEffectDataset":
         X = data.shards[shard_name]
         raw = np.asarray(data.entity_ids[entity_name])
@@ -329,51 +461,26 @@ class RandomEffectDataset:
         if active_cap is not None:
             # Down-sample each oversized entity's active rows uniformly
             # (reference: random-effect data config numActiveDataPointsUpperBound).
-            rng = np.random.default_rng(seed)
             if (counts > active_cap).any():
-                parts = []
-                for e in range(E):
-                    seg = starts[e] + rng.permutation(counts[e])
-                    # Weight-0 rows (streamed down-sampling) must never
-                    # displace weight-carrying rows from the capped active
-                    # set — stable-sort so carrying rows come first,
-                    # uniformly sampled among themselves.
-                    zero = w_np[order[seg]] == 0.0
-                    if zero.any():
-                        seg = seg[np.argsort(zero, kind="stable")]
-                    parts.append(seg)
-                perm = np.concatenate(parts)
-            else:
-                perm = np.arange(n)
-            order = order[perm]
+                # One pass for every entity: within an entity (the rows of
+                # `order` are grouped by it) rows sort by a uniform draw.
+                # Weight-0 rows (streamed down-sampling) must never
+                # displace weight-carrying rows from the capped active
+                # set, so carrying rows come first, uniformly sampled
+                # among themselves.
+                rng = np.random.default_rng(seed)
+                order = order[np.lexsort((rng.random(n),
+                                          w_np[order] == 0.0,
+                                          entity_dense[order]))]
             active_counts = np.minimum(counts, active_cap)
         else:
             active_counts = counts
-
-        buckets: dict[int, list[int]] = {}
-        for e in range(E):
-            m = _next_pow2(max(int(active_counts[e]), 1), min_block_rows)
-            buckets.setdefault(m, []).append(e)
-
-        # Each distinct block shape costs one solver compile (~tens of
-        # seconds on TPU via the remote compiler) while padded-row compute in
-        # the vmapped solves is nearly free — so greedily merge adjacent
-        # power-of-two buckets (padding the smaller one up) until at most
-        # ``max_blocks`` shapes remain. Merge the pair that adds the fewest
-        # padded row-slots.
-        if max_blocks < 1:
-            raise ValueError(f"max_blocks must be >= 1, got {max_blocks}")
-        while len(buckets) > max_blocks:
-            sizes = sorted(buckets)
-            costs = [len(buckets[sizes[i]]) * (sizes[i + 1] - sizes[i])
-                     for i in range(len(sizes) - 1)]
-            i = int(np.argmin(costs))
-            buckets[sizes[i + 1]] = buckets.pop(sizes[i]) + buckets[sizes[i + 1]]
 
         # Optional feature-space projection (reference:
         # projector.* / RandomEffectDatasetInProjectedSpace).
         projector_obj = None
         icpt = None
+        index_map = False
         if projection is not None:
             from photon_tpu.data.matrix import last_column_is_intercept
             from photon_tpu.game.projector import ProjectorType, RandomProjector
@@ -386,11 +493,57 @@ class RandomEffectDataset:
                     keep_intercept=icpt is not None,
                     seed=projection.seed,
                 )
+            else:
+                index_map = True
 
+        sparse = isinstance(X, SparseRows)
+        # one host copy of the shard for every gather below
+        X_host = _host_rows(X)
         y, w = data.y, data.weights
+
+        def gather(ents, m):
+            """(row positions (E_b, m), real-row mask, gathered features) of
+            entities `ents` padded to m rows; padding slots are clamped to
+            the entity's first row and silenced by the mask."""
+            pos = np.arange(m)
+            mask = pos[None, :] < active_counts[ents][:, None]
+            row_idx = order[starts[ents][:, None]
+                            + np.where(mask, pos[None, :], 0)]
+            if sparse:
+                return row_idx, mask, (X_host[0][row_idx], X_host[1][row_idx])
+            return row_idx, mask, X_host[row_idx]
+
+        # Block shapes, planned by bytes (`plan_buckets`): rows from the
+        # active counts; the solve width is the shard's (or the RANDOM
+        # projection's) for every entity, and under INDEX_MAP each
+        # entity's own — the features its active rows touch.
+        if index_map:
+            widths = np.zeros(E, np.int64)
+            m_of = _pow2_ceil(active_counts, min_block_rows)
+            for m in np.unique(m_of):
+                ents = np.nonzero(m_of == m)[0]
+                _, mask, Xg = gather(ents, int(m))
+                widths[ents] = _active_widths(Xg, mask, icpt)
+            slot_bytes = 4
+        elif projector_obj is not None:
+            widths, slot_bytes = np.full(E, projector_obj.dim_out), 4
+        elif sparse:  # (index, value) pairs, k slots a row
+            widths, slot_bytes = np.full(E, X_host[0].shape[-1]), 8
+        else:
+            widths, slot_bytes = np.full(E, _shard_dim(X)), 4
+        if max_blocks is not None and max_blocks < 1:
+            raise ValueError(f"max_blocks must be >= 1, got {max_blocks}")
+        plan = plan_buckets(active_counts, widths, slot_bytes,
+                            width_classes=index_map,
+                            min_block_rows=min_block_rows,
+                            max_blocks=max_blocks,
+                            device_bytes=_device_memory_bytes(),
+                            name=entity_name)
+        telemetry.count("game_re.block_bytes_real", plan.bytes_real)
+        telemetry.count("game_re.block_bytes_padded", plan.bytes_padded)
+
         blocks = []
-        for m in sorted(buckets):
-            ents = np.asarray(buckets[m], np.int64)
+        for m, width, ents in plan.buckets:
             # Difficulty-sorted chunk packing: lanes that share a vmapped
             # lax.while_loop chunk all run until the SLOWEST lane converges
             # (random_effect dispatches buckets in fixed-size lane chunks),
@@ -401,39 +554,29 @@ class RandomEffectDataset:
             # row_index / INDEX_MAP projection below are built in the same
             # (sorted) order, so scatter-back and projection are unchanged.
             ents = ents[np.argsort(active_counts[ents], kind="stable")]
-            st, ct = starts[ents], active_counts[ents]
-            pos = np.arange(m)
-            mask = pos[None, :] < ct[:, None]  # (E_b, m)
-            # Clamp padding slots to the entity's first row; weight 0 silences them.
-            idx2d = st[:, None] + np.where(mask, pos[None, :], 0)
-            row_idx = order[idx2d]  # (E_b, m) original row positions
+            row_idx, mask, Xg = gather(ents, m)
             wb = np.where(mask, w[row_idx], 0.0).astype(np.float32)
             yb = y[row_idx].astype(np.float32)
-            Xg = _gather_rows(X, row_idx.reshape(-1))
-            E_b = len(ents)
             block_dim = None
             block_proj = None
-            if isinstance(X, SparseRows):
-                ind, val = Xg
-                k = ind.shape[-1]
-                ind3 = ind.reshape(E_b, m, k)
-                val3 = (val.reshape(E_b, m, k) * mask[..., None]).astype(np.float32)
+            if sparse:
+                ind3 = Xg[0]
+                val3 = (Xg[1] * mask[..., None]).astype(np.float32)
                 if projector_obj is not None:
                     Xb = jnp.asarray(projector_obj.project_sparse_rows(ind3, val3))
                     block_dim = projector_obj.dim_out
-                elif projection is not None:
-                    Xb, block_proj = _project_sparse(ind3, val3, icpt)
+                elif index_map:
+                    Xb, block_proj = _project_sparse(ind3, val3, icpt, width)
                     block_dim = block_proj.dim
                 else:
                     Xb = (jnp.asarray(ind3), jnp.asarray(val3))
             else:
-                d = Xg.shape[-1]
-                Xd = (Xg.reshape(E_b, m, d) * mask[..., None]).astype(np.float32)
+                Xd = (Xg * mask[..., None]).astype(np.float32)
                 if projector_obj is not None:
                     Xb = jnp.asarray(projector_obj.project_rows(Xd))
                     block_dim = projector_obj.dim_out
-                elif projection is not None:
-                    Xb, block_proj = _project_dense(Xd, icpt)
+                elif index_map:
+                    Xb, block_proj = _project_dense(Xd, icpt, width)
                     block_dim = block_proj.dim
                 else:
                     Xb = jnp.asarray(Xd)
@@ -451,8 +594,11 @@ class RandomEffectDataset:
             )
 
         n_active = int(active_counts.sum())
-        if not isinstance(X, SparseRows):
-            X = jnp.asarray(X, jnp.float32)
+        # the flat scoring shard lives on the device: a host shard would
+        # cross to it again on every coordinate update
+        X = (SparseRows(jnp.asarray(X.indices), jnp.asarray(X.values),
+                        X.n_features) if sparse
+             else jnp.asarray(X, jnp.float32))
         return RandomEffectDataset(
             entity_name=entity_name,
             shard_name=shard_name,
@@ -465,17 +611,19 @@ class RandomEffectDataset:
             n_passive=n - n_active,
             projection=projection,
             projector=projector_obj,
+            block_bytes_real=plan.bytes_real,
+            block_bytes_padded=plan.bytes_padded,
         )
 
-    def block_batch(self, block: REBlock, offsets_full) -> GLMBatch:
+    def block_batch(self, block: REBlock, offsets_full=None) -> GLMBatch:
         """Batched (E, m, ...) GLMBatch for one bucket, offsets gathered from
-        the full per-row offset vector (other coordinates' scores)."""
-        offs = jnp.asarray(offsets_full, jnp.float32)[block.row_index]
-        if block.dim is not None:  # projected buckets are always dense
-            Xb = block.X
-        elif isinstance(self.X, SparseRows):
-            ind, val = block.X
-            Xb = SparseRows(ind, val, self.X.n_features)
-        else:
+        the full per-row offset vector (other coordinates' scores); with
+        none given the batch carries no offsets (the one-dispatch update
+        lays them in inside its program)."""
+        offs = (None if offsets_full is None else
+                jnp.asarray(offsets_full, jnp.float32)[block.row_index])
+        if block.dim is None and isinstance(self.X, SparseRows):
+            Xb = SparseRows(*block.X, self.X.n_features)
+        else:  # dense, or projected (always dense)
             Xb = block.X
         return GLMBatch(Xb, block.y, block.weights, offs)

@@ -119,6 +119,16 @@ class GameEstimator:
             entry = (data, {}, {})
             self._caches[id(data)] = entry
         return entry[1], entry[2]
+    def datasets(self, data) -> dict:
+        """{coordinate name: the dataset `fit(data)` built for it} — the
+        bucketed blocks a fit trained on (which rows an active-row cap
+        kept, each bucket's index map), for whoever checks a fit against
+        the data; a coordinate no fit has built yet is absent."""
+        cache, _ = self._caches_for(data)
+        return {name: cache[self._dataset_key(cfg)]
+                for name, cfg in self.coordinate_configs.items()
+                if self._dataset_key(cfg) in cache}
+
     # entity-id column for sharded (per-entity) validation evaluators;
     # defaults to the first random-effect coordinate's entity type.
     evaluator_entity: Optional[str] = None
@@ -146,10 +156,11 @@ class GameEstimator:
     def _build_dataset(data: GameData, cfg: CoordinateConfig):
         if isinstance(cfg, FixedEffectConfig):
             return FixedEffectDataset.build(data, cfg.feature_shard)
-        return RandomEffectDataset.build(
-            data, cfg.entity_name, cfg.feature_shard, active_cap=cfg.active_cap,
-            projection=cfg.projection,
-        )
+        with telemetry.span("game_re.build", entity=cfg.entity_name):
+            return RandomEffectDataset.build(
+                data, cfg.entity_name, cfg.feature_shard,
+                active_cap=cfg.active_cap, projection=cfg.projection,
+            )
 
     def _build_coordinates(self, datasets: dict, configs: dict,
                            cache: Optional[dict] = None) -> dict:

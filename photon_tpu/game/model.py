@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from photon_tpu import telemetry
 from photon_tpu.data.matrix import Matrix, SparseRows
 from photon_tpu.models.glm import Coefficients, GeneralizedLinearModel
 from photon_tpu.ops.losses import TaskType, mean_fn
@@ -47,7 +48,35 @@ def _padded_coeffs(coefficients, dense_ids):
 
 @jax.jit
 def _re_score_jit(coefficients, X, dense_ids):
-    return score_rows(X, _padded_coeffs(coefficients, dense_ids))
+    return score_entities(X, coefficients, dense_ids)
+
+
+def score_entities(X: Matrix, coefficients: jax.Array,
+                   dense_ids: jax.Array, exact: bool = False) -> jax.Array:
+    """Rowwise margin x_i · coefficients[id_i]; id == E (unseen) scores 0.
+
+    A SparseRows shard reads its k coefficients a row straight from the
+    (E, d) table — the (n, d) per-row copy `_padded_coeffs` makes is never
+    built (at d in the thousands it is the table many times over); the
+    out-of-range id E gathers the fill value, which is the zero row.
+
+    ``exact``: the k products a row as an f32 multiply and a sum. The
+    default is the einsum the serving rungs mirror bit for bit — a batched
+    dot, which a TPU compiler MAY run on the MXU at its default precision
+    (both factors rounded to bf16, 2^-9 a product). A coordinate-descent
+    update asks for exact margins, because the other coordinates train
+    against them as offsets. A pin, not a repair: on the v5e the installed
+    compiler runs the einsum as the same f32 multiply-and-sum, to the bit
+    (PERF.md section 6, PR 27)."""
+    with telemetry.device_scope("game_re.score"):
+        if isinstance(X, SparseRows):
+            gathered = coefficients.at[dense_ids[:, None], X.indices].get(
+                mode="fill", fill_value=0)
+            if exact:
+                return jnp.sum(X.values.astype(jnp.float32) * gathered,
+                               axis=-1)
+            return jnp.einsum("nk,nk->n", X.values, gathered)
+        return score_rows(X, _padded_coeffs(coefficients, dense_ids))
 
 
 def score_rows(X: Matrix, coeff_rows: jax.Array) -> jax.Array:

@@ -79,20 +79,26 @@ def build_index_map_projection(
     active_sets: list,
     intercept_index: Optional[int],
     floor: int = 2,
+    width: Optional[int] = None,
 ) -> BlockProjection:
     """Build a bucket's projection from per-entity active feature sets.
 
     ``active_sets``: one sorted 1-D int array per entity (global feature ids,
     excluding the intercept). When ``intercept_index`` is given it is pinned
     to the LAST projected column of every entity, preserving the
-    intercept-last convention that ``make_objective`` relies on.
+    intercept-last convention that ``make_objective`` relies on. ``width``
+    fixes the bucket's projected width (a bucket plan's); by default it is
+    the widest entity's, padded to a power of two.
     """
     from photon_tpu.data.matrix import next_pow2
 
     E = len(active_sets)
     extra = 1 if intercept_index is not None else 0
-    width = max((len(s) for s in active_sets), default=0) + extra
-    p = next_pow2(max(width, 1), floor)
+    need = max((len(s) for s in active_sets), default=0) + extra
+    p = next_pow2(max(need, 1), floor) if width is None else int(width)
+    if p < need:
+        raise ValueError(f"projected width {p} is under the bucket's widest "
+                         f"entity ({need} columns)")
     proj_idx = np.zeros((E, p), np.int64)
     proj_mask = np.zeros((E, p), np.float32)
     for e, s in enumerate(active_sets):
@@ -112,47 +118,58 @@ def project_dense_block(Xb: np.ndarray, proj: BlockProjection) -> np.ndarray:
     return (out * proj.proj_mask[:, None, :]).astype(np.float32)
 
 
-def project_sparse_block(
-    ind: np.ndarray, val: np.ndarray, proj: BlockProjection
-) -> np.ndarray:
-    """Padded-COO (E, m, k) → dense (E, m, p) in each entity's projected space.
+def sparse_index_map(
+    ind: np.ndarray,
+    val: np.ndarray,
+    intercept_index: Optional[int],
+    width: Optional[int] = None,
+    floor: int = 2,
+) -> tuple:
+    """A padded-COO (E, m, k) bucket's `BlockProjection` and each nonzero's
+    projected column ((E, m, k) int32), in ONE sorted-unique pass over the
+    bucket's (entity, feature) pairs: an axis-wise argsort of each entity's
+    m·k feature ids gives its sorted active features (the first of every
+    run), each feature's projected column (the run's rank) and, scattered
+    back through the sort, every nonzero's column. A slot whose value is 0
+    gets column 0, where it adds nothing.
 
-    Scatter-add each nonzero into its projected column (duplicate feature
-    slots within a row accumulate, matching SparseRows matvec semantics).
-    """
+    ``width`` fixes p (the bucket plan's width); by default it is the
+    widest entity's, padded to a power of two as
+    `build_index_map_projection` pads. The same layout as
+    `build_index_map_projection` gives for the same active sets."""
+    from photon_tpu.data.matrix import next_pow2
+
     E, m, k = ind.shape
-    p = proj.dim
-    icpt = proj.intercept_index
-    # local position of each nonzero's global feature in its entity's layout:
-    # sorted non-intercept actives first, intercept (if any) pinned at p-1
-    local = np.empty((E, m, k), np.int64)
-    keep = np.empty((E, m, k), bool)
-    for e in range(E):
-        nact = int(proj.proj_mask[e].sum()) - (1 if icpt is not None else 0)
-        row = proj.proj_idx[e, :nact]  # sorted ascending by construction
-        flat = ind[e].reshape(-1)
-        if nact:
-            loc = np.clip(np.searchsorted(row, flat), 0, nact - 1)
-            hit = row[loc] == flat
-        else:
-            loc = np.zeros(m * k, np.int64)
-            hit = np.zeros(m * k, bool)
-        is_icpt = (flat == icpt) if icpt is not None else np.zeros(m * k, bool)
-        local[e] = np.where(is_icpt, p - 1, np.where(hit, loc, 0)).reshape(m, k)
-        keep[e] = (hit | is_icpt).reshape(m, k)
-    out = np.zeros((E, m, p), np.float32)
-    np.add.at(
-        out,
-        (
-            np.arange(E)[:, None, None],
-            np.arange(m)[None, :, None],
-            local,
-        ),
-        # nonzeros outside the active set exist only as zero-valued padding
-        # slots; ``keep`` zeroes them so they cannot pollute column 0
-        val * keep,
-    )
-    return out * proj.proj_mask[:, None, :]
+    sentinel = np.iinfo(np.int32).max  # sorts last; never a feature id
+    live = val != 0.0
+    is_icpt = (live & (ind == intercept_index) if intercept_index is not None
+               else np.zeros(ind.shape, bool))
+    is_feat = live & ~is_icpt
+    ids = np.where(is_feat, ind, sentinel).astype(np.int32).reshape(E, m * k)
+    perm = np.argsort(ids, axis=1)
+    sorted_ids = np.take_along_axis(ids, perm, axis=1)
+    first = sorted_ids != sentinel
+    first[:, 1:] &= sorted_ids[:, 1:] != sorted_ids[:, :-1]
+    rank = np.cumsum(first, axis=1, dtype=np.int32) - 1
+    extra = 1 if intercept_index is not None else 0
+    need = int(first.sum(axis=1).max(initial=0)) + extra
+    p = next_pow2(max(need, 1), floor) if width is None else int(width)
+    if p < need:
+        raise ValueError(f"projected width {p} is under the bucket's widest "
+                         f"entity ({need} columns)")
+    proj_idx = np.zeros((E, p), np.int64)
+    proj_mask = np.zeros((E, p), np.float32)
+    ent, slot = np.nonzero(first)
+    proj_idx[ent, rank[ent, slot]] = sorted_ids[ent, slot]
+    proj_mask[ent, rank[ent, slot]] = 1.0
+    if intercept_index is not None:
+        proj_idx[:, -1] = intercept_index
+        proj_mask[:, -1] = 1.0
+    local = np.empty_like(rank)
+    np.put_along_axis(local, perm, rank, axis=1)
+    local = np.where(is_icpt, np.int32(p - 1),
+                     np.where(is_feat, local.reshape(E, m, k), np.int32(0)))
+    return BlockProjection(proj_idx, proj_mask, intercept_index), local
 
 
 def gather_rows(full: np.ndarray, proj: BlockProjection) -> np.ndarray:

@@ -72,9 +72,12 @@ def _pad_axis0(tree, target: int):
 
 # XLA-TPU compile time grows superlinearly in the vmapped lane count (~3s at
 # 512 lanes, ~100s at 39k), so big entity blocks are solved in fixed-size
-# lane chunks: one compile per block SHAPE, many cheap dispatches.
+# lane chunks: one compile per block SHAPE, many cheap dispatches. The
+# lanes of a chunk also step together until its slowest stops: on GLMix's
+# widths 4096 beat both 512 (every op of a step nearer its launch cost:
+# +13 % a fit) and 65,536 (every lane waits for the slowest of a whole
+# bucket: +30 %) — my chip runs, PR 27.
 _MAX_SOLVE_LANES = 4096
-
 # Module-level solver cache keyed on (with_prior, weight-normalized config,
 # variance type); the Objective and the L1 weight are runtime ARGUMENTS, so
 # reg-weight grids and repeated estimator fits all share compilations.
@@ -222,6 +225,16 @@ class RETrainStats:
     # the totals. None on the fused one-dispatch path, which keeps only
     # device-scalar totals.
     iterations_per_entity: Optional[np.ndarray] = dataclasses.field(
+        default=None, compare=False, repr=False)
+    # Σ over entities of weight-carrying active rows × solver iterations
+    # taken: the update's work in rows·iterations (the fixed effect's is
+    # n × iterations). None where a resumed run restored only the totals.
+    row_iterations: Optional[float] = dataclasses.field(
+        default=None, compare=False)
+    # (E,) each entity's final objective (its loss over its active rows at
+    # the update's offsets + its penalty) as its own solve computed it, on
+    # the device; the one-dispatch update only.
+    entity_values: Optional[jax.Array] = dataclasses.field(
         default=None, compare=False, repr=False)
 
 
@@ -594,8 +607,21 @@ class RandomEffectCoordinate:
             key_to_index=ds.key_to_index,
             variances=None if variances is None else jnp.asarray(variances),
         )
-        return model, RETrainStats(E, n_conv, n_fail, total_iters,
-                                   iters_per_entity)
+        return model, RETrainStats(
+            E, n_conv, n_fail, total_iters, iters_per_entity,
+            row_iterations=float(self._rows_per_entity() @ iters_per_entity))
+
+    def _rows_per_entity(self) -> np.ndarray:
+        """(E,) weight-carrying active rows of each entity, read from the
+        blocks once a coordinate."""
+        rows = getattr(self, "_rows_cache", None)
+        if rows is None:
+            rows = np.zeros((self.dataset.n_entities,), np.int64)
+            for block in self.dataset.blocks:
+                rows[block.entity_index] = np.count_nonzero(
+                    np.asarray(block.weights), axis=1)
+            self._rows_cache = rows
+        return rows
 
     def score(self, model: RandomEffectModel) -> jax.Array:
         """Per-row margin for ALL rows — active and passive — via one gather
@@ -604,18 +630,30 @@ class RandomEffectCoordinate:
         return model.score(self.dataset.X, self.dataset.entity_dense)
 
     def fused_update_program(self):
-        """ONE-dispatch whole-coordinate update for the no-projection /
-        no-prior / no-normalization / single-device case: offsets sum, every
-        bucket's (chunk-scanned) solves, the coefficient/variance scatter,
-        the full-row margins, and the objective — one jitted program, where
-        the unfused train()+score()+objective route pays ~4+ device
-        dispatches.
+        """ONE-dispatch whole-coordinate update for the no-prior /
+        no-normalization / single-device case, unprojected or INDEX_MAP:
+        offsets sum, every bucket's warm starts read from the (E, d) table
+        (through the bucket's index map where it has one), its
+        (chunk-scanned) solves, the coefficient/variance write-back, the
+        full-row margins, and the objective — one jitted program, where the
+        unfused train()+score()+objective route pays ~4+ device dispatches
+        and, projected, carries the (E, d) table through the host. The path
+        is chosen by what the dataset carries: a RANDOM projection (its
+        dense matrix lives on the host) keeps the block loop.
 
-        Returns (fn, blocks_args, obj, lam) — call
-        ``fn(coeffs, base, scores_tuple, obj, lam, blocks_args, X,
-        dense_ids, y, weights)`` → (coeffs', variances', margins,
-        objective, (n_conv, n_fail, n_iters)) — or None when this
-        coordinate needs the general train() path.
+        Returns (fn, blocks_args, objs, lam) — call
+        ``fn(coeffs, base, scores_tuple, objs, lam, blocks_args, X,
+        dense_ids, y, weights)``, which DONATES ``coeffs`` (the table is
+        updated in place: pass a buffer nothing else reads) →
+        (coeffs', variances', margins, objective, (n_conv, n_fail,
+        n_iters, row_iters, block_steps, moved_row_iters, ls_trials),
+        values) — `row_iters` / `block_steps` the update's work: Σ
+        weight-carrying rows × iterations over the entities, and Σ
+        lock-step iterations over the blocks; `moved_row_iters` the part of
+        `row_iters` whose iteration lowered its lane's loss; `ls_trials` Σ
+        line-search evaluations over the entities; `values` (E,) each
+        entity's own final objective, as its solve computed it — or None
+        when this coordinate needs the general train() path.
         """
         cached = getattr(self, "_fused_cache", None)
         if cached is not None:
@@ -639,25 +677,31 @@ class RandomEffectCoordinate:
                     "passes); training on the pipelined block loop",
                     ds.entity_name, self.straggler_budget)
             return None
-        if (ds.projection is not None or self.mesh is not None
+        if (ds.projector is not None or self.mesh is not None
                 or (self.normalization is not None
                     and not self.normalization.is_identity)):
             return None
         fns = self._solver_for(False)
-        meta = []       # (chunk, e_pad, e_real) per block — static
-        blocks_args = []  # (row_index, ents, batch_base) per block — arrays
-        n = int(ds.entity_dense.shape[0])
+        meta = []       # (lane chunk, entities) per block — static
+        blocks_args = []  # (row_index, ents, cols, batch_base) — arrays
+        objs = []
+        d = ds.dim
         for block in ds.blocks:
-            chunk = _lane_chunk(block.n_entities)
-            e_pad = pad_to_multiple(block.n_entities, chunk)
-            meta.append((chunk, e_pad, block.n_entities))
-            base_batch = ds.block_batch(block, np.zeros((n,), np.float32))
+            e_real = block.n_entities
+            base_batch = ds.block_batch(block)
+            meta.append((min(e_real, _MAX_SOLVE_LANES), e_real))
+            # the bucket's index map as table columns; a padding column
+            # points past the table, where a read fills 0 and a write drops
+            cols = (None if block.proj is None else jnp.asarray(
+                np.where(block.proj.proj_mask > 0, block.proj.proj_idx, d)
+                .astype(np.int32)))
             blocks_args.append((block.row_index,
-                                jnp.asarray(block.entity_index),
+                                jnp.asarray(block.entity_index), cols,
                                 base_batch))
+            objs.append(self._block_objective(
+                block.dim if block.dim is not None else d))
         out = (_fused_re_fn(fns, tuple(meta), self.task, self.variance),
-               tuple(blocks_args),
-               self._block_objective(ds.dim), _l1_lam(self.config))
+               tuple(blocks_args), tuple(objs), _l1_lam(self.config))
         self._fused_cache = out
         return out
 
@@ -669,44 +713,91 @@ class RandomEffectCoordinate:
 _FUSED_RE: dict = {}
 
 
+def _solve_lanes(raw_fn, head: tuple, args: tuple, chunk: int, e_real: int):
+    """A bucket's vmapped solves over its RESIDENT arrays, `chunk` lanes at
+    a time: one call where the bucket is one chunk; else `lax.scan` over
+    slices of the arrays as they lie (no padded copy of the block). The
+    last slice is laid back over the end of the bucket, so its first lanes
+    solve entities the slice before it has solved (the same problem, the
+    same answer) and are cut from the result."""
+    if e_real <= chunk:
+        return raw_fn(*head, *args)
+    k = -(-e_real // chunk)
+    starts = np.minimum(np.arange(k) * chunk, e_real - chunk)
+
+    def body(_, start):
+        part = jax.tree_util.tree_map(
+            lambda x: jax.lax.dynamic_slice_in_dim(x, start, chunk), args)
+        return None, raw_fn(*head, *part)
+
+    _, outs = jax.lax.scan(body, None, jnp.asarray(starts, jnp.int32))
+    overlap = k * chunk - e_real
+    return jax.tree_util.tree_map(
+        lambda x: jnp.concatenate(
+            [x[:-1].reshape((-1,) + x.shape[2:]), x[-1, overlap:]]), outs)
+
+
 def _fused_re_fn(solver_fns, meta: tuple, task, variance):
     key = (solver_fns[1], meta, task, variance)
     fn = _FUSED_RE.get(key)
     if fn is not None:
         return fn
+    raw_fn = solver_fns[1]
 
-    def run(coeffs, base, scores, obj, lam, blocks_args, X, dense_ids,
+    def run(coeffs, base, scores, objs, lam, blocks_args, X, dense_ids,
             y, weights):
-        from photon_tpu.game.model import _padded_coeffs, score_rows
+        from photon_tpu.game.model import score_entities
         from photon_tpu.game.scoring import _sum_scores
         from photon_tpu.ops.losses import loss_fns
 
         loss, _, _ = loss_fns(task)
-        offs = _sum_scores(base, scores)
+        with telemetry.device_scope("game.objective"):
+            offs = _sum_scores(base, scores)
+        # The table is updated IN PLACE (its buffer is donated): the
+        # buckets partition the entities, so a bucket reads its own rows'
+        # warm starts before it, and nothing after it, writes them.
         variances = (jnp.zeros_like(coeffs)
                      if variance is not VarianceComputationType.NONE
                      else None)
-        conv = fail = iters = None
-        for (row_index, ents, batch_base), (chunk, e_pad, e_real) in \
-                zip(blocks_args, meta):
-            batch = batch_base._replace(offsets=offs[row_index])
-            args = _pad_axis0((batch, coeffs[ents]), e_pad)
-            res, var = dispatch_chunked(solver_fns, (obj, lam), args,
-                                        chunk, e_pad, mesh=None)
-            coeffs = coeffs.at[ents].set(res.w[:e_real])
-            if var is not None and variances is not None:
-                variances = variances.at[ents].set(var[:e_real])
-            c = jnp.sum(res.converged[:e_real])
-            f = jnp.sum(res.failed[:e_real])
-            it = jnp.sum(res.iterations[:e_real])
-            conv = c if conv is None else conv + c
-            fail = f if fail is None else fail + f
-            iters = it if iters is None else iters + it
-        margins = score_rows(X, _padded_coeffs(coeffs, dense_ids))
-        objective = jnp.sum(weights * loss(offs + margins, y))
-        return coeffs, variances, margins, objective, (conv, fail, iters)
+        conv = fail = iters = row_iters = steps = moved = trials = 0
+        values = jnp.zeros((coeffs.shape[0],), jnp.float32)
+        for (row_index, ents, cols, batch_base), (chunk, e_real), obj in \
+                zip(blocks_args, meta, objs):
+            at = (ents,) if cols is None else (ents[:, None], cols)
+            with telemetry.device_scope("game_re.gather"):
+                batch = batch_base._replace(offsets=offs[row_index])
+                w0 = coeffs.at[at].get(mode="fill", fill_value=0)
+            with telemetry.device_scope("game_re.solve"):
+                res, var = _solve_lanes(raw_fn, (obj, lam), (batch, w0),
+                                        chunk, e_real)
+            with telemetry.device_scope("game_re.scatter"):
+                if cols is not None:  # columns outside the map go to 0
+                    coeffs = coeffs.at[ents].set(0.0)
+                coeffs = coeffs.at[at].set(res.w, mode="drop")
+                if var is not None and variances is not None:
+                    variances = variances.at[at].set(var, mode="drop")
+            conv += jnp.sum(res.converged)
+            fail += jnp.sum(res.failed)
+            iters += jnp.sum(res.iterations)
+            rows = jnp.sum(batch_base.weights != 0.0, axis=1)
+            row_iters += jnp.sum(rows.astype(jnp.float32)
+                                 * res.iterations.astype(jnp.float32))
+            steps += jnp.max(res.iterations)
+            # iterations that lowered the lane's loss: the rest repeated
+            # a point (a fixed-depth solve past its stall)
+            lower = res.loss_history[:, 1:] < res.loss_history[:, :-1]
+            moved += jnp.sum(rows.astype(jnp.float32)
+                             * jnp.sum(lower, axis=1).astype(jnp.float32))
+            if res.evaluations is not None:
+                trials += jnp.sum(res.evaluations)
+            values = values.at[ents].set(res.value)
+        margins = score_entities(X, coeffs, dense_ids, exact=True)
+        with telemetry.device_scope("game.objective"):
+            objective = jnp.sum(weights * loss(offs + margins, y))
+        return coeffs, variances, margins, objective, (
+            conv, fail, iters, row_iters, steps, moved, trials), values
 
-    fn = jax.jit(run)
+    fn = jax.jit(run, donate_argnums=(0,))
     _FUSED_RE[key] = fn
     return fn
 

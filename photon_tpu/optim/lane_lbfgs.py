@@ -34,6 +34,7 @@ from jax import lax
 from photon_tpu.ops import lane_objective as lo
 from photon_tpu.optim.lbfgs import _convergence
 from photon_tpu.optim.linesearch import C1, C2, _cubic_min
+from photon_tpu.optim.config import stop_state
 from photon_tpu.optim.tracker import OptResult
 from photon_tpu.telemetry import device_scope
 
@@ -351,15 +352,17 @@ def minimize_lbfgs_margin_lanes(
             gnorm = jnp.sqrt(jnp.sum(g_new * g_new, axis=0))
             converged = _convergence(ok, s.f, f_new, gnorm, g0norm, dphi0,
                                      tolerance, dtype)
+            done, converged, failed = stop_state(
+                tolerance, (s.done, s.converged, s.failed),
+                jnp.where(active, converged, s.converged),
+                active & (converged | ~ok), active & ~ok & ~converged)
             it = s.it + 1
             its = jnp.where(active, s.its + 1, s.its)
             return _LaneState(
                 W=W_new, z=z_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho,
                 sy=sy, yy=yy, valid=valid, idx=idx, it=it,
                 evals=s.evals + ls_evals, its=its,
-                done=s.done | (active & (converged | ~ok)),
-                converged=jnp.where(active, converged, s.converged),
-                failed=s.failed | (active & ~ok & ~converged),
+                done=done, converged=converged, failed=failed,
                 hist=s.hist.at[it].set(
                     jnp.where(active, f_new, s.hist[it])),
                 ghist=s.ghist.at[it].set(

@@ -37,6 +37,7 @@ from jax import lax
 
 from photon_tpu.ops import lane_objective as lo
 from photon_tpu.optim.lane_lbfgs import _push_lanes, two_loop_lanes
+from photon_tpu.optim.config import stop_state
 from photon_tpu.optim.tracker import OptResult
 
 
@@ -207,15 +208,17 @@ def minimize_owlqn_lanes(
         precision_limited = (~ls.succ) & (jnp.abs(dphi0) <= noise)
         converged = grad_conv | f_conv | precision_limited
 
+        done, converged, failed = stop_state(
+            tolerance, (s.done, s.converged, s.failed),
+            jnp.where(active, converged, s.converged),
+            active & (converged | ~ls.succ), active & ~ls.succ & ~converged)
         it = s.it + 1
         its = jnp.where(active, s.its + 1, s.its)
         return _LaneState(
             W=W_new, z=z_new, f=f_new, F=F_new, g=g_new, S=S, Y=Y, rho=rho,
             sy=sy, yy=yy, valid=valid, idx=idx, it=it,
             evals=s.evals + ls.i, its=its,
-            done=s.done | (active & (converged | ~ls.succ)),
-            converged=jnp.where(active, converged, s.converged),
-            failed=s.failed | (active & ~ls.succ & ~converged),
+            done=done, converged=converged, failed=failed,
             hist=s.hist.at[it].set(jnp.where(active, F_new, s.hist[it])),
             ghist=s.ghist.at[it].set(jnp.where(active, pgnorm, s.ghist[it])),
         )

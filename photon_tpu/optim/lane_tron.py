@@ -35,6 +35,7 @@ from jax import lax
 
 from photon_tpu.ops import lane_objective as lo
 from photon_tpu.optim.tron import _tr_stops, _tr_update
+from photon_tpu.optim.config import stop_state
 from photon_tpu.optim.tracker import OptResult
 
 _Z_REFRESH = 64  # as optim.tron: accept-chained margin re-derivation period
@@ -202,14 +203,16 @@ def minimize_tron_margin_lanes(
         converged, stuck = _tr_stops(accept, actual, pred, s.f, f_new,
                                      gnorm, g0norm, delta_new, tolerance,
                                      dtype)
+        done, converged, failed = stop_state(
+            tolerance, (s.done, s.converged, s.failed),
+            jnp.where(active, converged, s.converged),
+            active & (converged | stuck), active & stuck & ~converged)
         it = s.it + 1
         its = jnp.where(active, s.its + 1, s.its)
         return _LaneState(
             W=W_new, z=z_new, f=f_new, g=g_new,
             delta=jnp.where(active, delta_new, s.delta), it=it, its=its,
-            done=s.done | (active & (converged | stuck)),
-            converged=jnp.where(active, converged, s.converged),
-            failed=s.failed | (active & stuck & ~converged),
+            done=done, converged=converged, failed=failed,
             hist=s.hist.at[it].set(jnp.where(active, f_new, s.hist[it])),
             ghist=s.ghist.at[it].set(jnp.where(active, gnorm, s.ghist[it])),
         )

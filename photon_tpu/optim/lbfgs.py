@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from photon_tpu.optim.config import stop_state
 from photon_tpu.optim.linesearch import wolfe_line_search
 from photon_tpu.optim.tracker import OptResult
 from photon_tpu.parallel.mesh import vary_like
@@ -185,14 +186,16 @@ def minimize_lbfgs(
             gnorm = jnp.linalg.norm(g_new)
             converged = _convergence(ok, s.f, f_new, gnorm, g0norm, dphi0,
                                      tolerance, dtype)
+            done, converged, failed = stop_state(
+                tolerance, (s.done, s.converged, s.failed), converged,
+                converged | ~ok, ~ok & ~converged)
             it = s.it + 1
             solver_tap("lbfgs", it, f_new, gnorm, jnp.where(ok, alpha, 0.0))
             snapshot_tap("lbfgs", it, w_new, f_new, gnorm)
             return _State(
                 w=w_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho, sy=sy, yy=yy,
                 idx=idx, count=count, it=it, evals=s.evals + ls_evals,
-                done=converged | ~ok,
-                converged=converged, failed=s.failed | (~ok & ~converged),
+                done=done, converged=converged, failed=failed,
                 hist=s.hist.at[it].set(f_new),
                 ghist=s.ghist.at[it].set(gnorm),
             )
@@ -344,6 +347,9 @@ def minimize_lbfgs_margin(
             gnorm = jnp.linalg.norm(g_new)
             converged = _convergence(ok, s.f, f_new, gnorm, g0norm, dphi0,
                                      tolerance, dtype)
+            done, converged, failed = stop_state(
+                tolerance, (s.done, s.converged, s.failed), converged,
+                converged | ~ok, ~ok & ~converged)
             it = s.it + 1
             solver_tap("lbfgs_margin", it, f_new, gnorm,
                        jnp.where(ok, alpha, 0.0))
@@ -352,8 +358,7 @@ def minimize_lbfgs_margin(
                 w=w_new, z=z_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho,
                 sy=sy, yy=yy, idx=idx,
                 count=count, it=it, evals=s.evals + ls_evals,
-                done=converged | ~ok,
-                converged=converged, failed=s.failed | (~ok & ~converged),
+                done=done, converged=converged, failed=failed,
                 hist=s.hist.at[it].set(f_new),
                 ghist=s.ghist.at[it].set(gnorm),
             )

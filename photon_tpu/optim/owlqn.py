@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_tpu.optim.lbfgs import two_loop, _push
+from photon_tpu.optim.config import stop_state
 from photon_tpu.optim.tracker import OptResult
 from photon_tpu.parallel.mesh import vary_like
 # Opt-in in-loop iteration telemetry; compiled out by default (see
@@ -168,6 +169,9 @@ def minimize_owlqn(
         noise = 4.0 * jnp.finfo(dtype).eps * jnp.maximum(jnp.abs(s.F), 1.0)
         precision_limited = (~ok) & (jnp.abs(dphi0) <= noise)
         converged = grad_conv | f_conv | precision_limited
+        done, converged, failed = stop_state(
+            tolerance, (s.done, s.converged, s.failed), converged,
+            converged | ~ok, ~ok & ~converged)
         it = s.it + 1
         solver_tap("owlqn", it, F_new, pgnorm, jnp.where(ok, ls.a, 0.0))
         snapshot_tap("owlqn", it, w_new, F_new, pgnorm)
@@ -175,8 +179,7 @@ def minimize_owlqn(
             w=w_new, f=f_new, F=F_new, g=g_new, S=S, Y=Y, rho=rho,
             sy=sy, yy=yy, idx=idx,
             count=count, it=it, evals=s.evals + ls.i,
-            done=converged | ~ok, converged=converged,
-            failed=s.failed | (~ok & ~converged),
+            done=done, converged=converged, failed=failed,
             hist=s.hist.at[it].set(F_new),
             ghist=s.ghist.at[it].set(pgnorm),
         )

@@ -72,6 +72,7 @@ from photon_tpu import profiling
 from photon_tpu import telemetry
 from photon_tpu.data.dataset import GLMBatch
 from photon_tpu.data.matrix import ShardedBlockedEllRows, SparseRows
+from photon_tpu.optim.config import stop_state
 from photon_tpu.optim.lbfgs import _Z_REFRESH, two_loop
 from photon_tpu.optim.linesearch import C1, C2
 from photon_tpu.optim.owlqn import pseudo_gradient
@@ -721,6 +722,13 @@ def _host_wolfe(phi, f0: float, dphi0: float, a_init: float,
     return a_star, f_star, done or a_star > 0.0, i
 
 
+def _stop_host(tolerance, before, converged: bool, ok: bool) -> tuple:
+    """`optim.config.stop_state` over the host loops' Python booleans."""
+    c, ok = np.bool_(converged), np.bool_(ok)
+    return tuple(bool(v) for v in stop_state(
+        tolerance, tuple(np.bool_(v) for v in before), c, c | ~ok, ~ok & ~c))
+
+
 def _convergence_host(ok, f_old, f_new, gnorm, g0norm, dphi0,
                       tolerance) -> bool:
     """Host mirror of optim.lbfgs._convergence (f32 noise floor)."""
@@ -1042,16 +1050,16 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
             w_new, g_new, f_new = w, g, f
 
         gnorm = float(jnp.linalg.norm(g_new))
-        converged = _convergence_host(ok, f, f_new, gnorm, g0norm, dphi0,
-                                      tolerance)
-        failed = failed or (not ok and not converged)
+        now = _convergence_host(ok, f, f_new, gnorm, g0norm, dphi0,
+                                tolerance)
+        done, converged, failed = _stop_host(
+            tolerance, (done, converged, failed), now, ok)
         it += 1
         hist[it], ghist[it] = f_new, gnorm
         telemetry.count("solver.iterations")
         telemetry.iteration("lbfgs_streamed", it, f_new, grad_norm=gnorm,
                             step=(alpha if ok else 0.0), trials=n_trials)
         w, g, f = w_new, g_new, f_new
-        done = converged or not ok
         if ck is not None:
             # iteration boundary = the crash-consistency cut
             ck.update("lbfgs_streamed", _pack_lbfgs_state(
@@ -1238,15 +1246,15 @@ def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
             max(abs(F), abs(F_new)), 1e-12)
         noise = 4.0 * float(np.finfo(np.float32).eps) * max(abs(F), 1.0)
         precision_limited = (not ok) and abs(dphi0) <= noise
-        converged = grad_conv or f_conv or precision_limited
-        failed = failed or (not ok and not converged)
+        done, converged, failed = _stop_host(
+            tolerance, (done, converged, failed),
+            grad_conv or f_conv or precision_limited, ok)
         it += 1
         hist[it], ghist[it] = F_new, pgnorm
         telemetry.count("solver.iterations")
         telemetry.iteration("owlqn_streamed", it, F_new, grad_norm=pgnorm,
                             trials=evals)
         w, g, f, F = w_new, g_new, f_new, F_new
-        done = converged or not ok
         if ck is not None:
             ck.update("owlqn_streamed", _pack_owlqn_state(
                 d, n_chunks, data, max_iters, it, f, F, pg0norm, hist,

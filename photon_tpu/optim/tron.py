@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from photon_tpu.optim.config import stop_state
 from photon_tpu.optim.tracker import OptResult
 from photon_tpu.parallel.mesh import vary_like
 # Opt-in in-loop iteration telemetry; compiled out by default (see
@@ -176,13 +177,15 @@ def minimize_tron(
         gnorm = jnp.linalg.norm(g_new)
         converged, stuck = _tr_stops(accept, actual, pred, s.f, f_new, gnorm,
                                      g0norm, delta, tolerance, dtype)
+        done, converged, failed = stop_state(
+            tolerance, (s.done, s.converged, s.failed), converged,
+            converged | stuck, stuck & ~converged)
         it = s.it + 1
         solver_tap("tron", it, f_new, gnorm, delta)
         snapshot_tap("tron", it, w_new, f_new, gnorm, aux=delta)
         return _State(
             w=w_new, f=f_new, g=g_new, delta=delta, it=it,
-            done=converged | stuck, converged=converged,
-            failed=s.failed | (stuck & ~converged),
+            done=done, converged=converged, failed=failed,
             hist=s.hist.at[it].set(f_new),
             ghist=s.ghist.at[it].set(gnorm),
         )
@@ -339,13 +342,15 @@ def minimize_tron_margin(
         gnorm = jnp.linalg.norm(g_new)
         converged, stuck = _tr_stops(accept, actual, pred, s.f, f_new, gnorm,
                                      g0norm, delta, tolerance, dtype)
+        done, converged, failed = stop_state(
+            tolerance, (s.done, s.converged, s.failed), converged,
+            converged | stuck, stuck & ~converged)
         it = s.it + 1
         solver_tap("tron_margin", it, f_new, gnorm, delta)
         snapshot_tap("tron_margin", it, w_new, f_new, gnorm, aux=delta)
         return _MarginState(
             w=w_new, z=z_new, f=f_new, g=g_new, delta=delta, it=it,
-            done=converged | stuck, converged=converged,
-            failed=s.failed | (stuck & ~converged),
+            done=done, converged=converged, failed=failed,
             hist=s.hist.at[it].set(f_new),
             ghist=s.ghist.at[it].set(gnorm),
         )

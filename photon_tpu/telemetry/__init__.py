@@ -33,6 +33,16 @@ the random-effect block pipeline's `game_re.*` family —
 blocks/blocks_in_flight/readback_wait_ns plus the straggler compaction's
 straggler_entities/tail_resolves/iters_saved and the fused-update gate's
 fused_gate_offs, with per-block upload/solve/readback/tail_solve spans;
+block_bytes_real/block_bytes_padded (a dataset build's bucket plan: the
+bytes its entities' capped rows × solve widths hold, and the bytes of the
+padded blocks allocated for them), and from the one-dispatch update, as
+`count_device` values, row_iterations (Σ over entities of weight-carrying
+rows × solver iterations taken), moved_row_iterations (the part of it
+whose iteration lowered its lane's loss: the rest repeated a point),
+iterations / linesearch_trials (Σ over entities of solver iterations and
+of line-search evaluations) and block_steps (Σ over blocks of the
+block's lock-step iterations: the largest over its lanes), beside the
+fixed effect's game_fixed.row_iterations (rows × iterations taken);
 the pod-scale GAME composition's `game_e2e.*` family —
 streamed_fixed_updates/host_offset_sums/objective_chunks counters from
 the descent loop's host-margin-cache exchange,
@@ -113,7 +123,14 @@ objective.loss (loss value / derivative at cached margins); the L-BFGS
 phases lbfgs.two_loop, lbfgs.push, lbfgs.linesearch, lbfgs.direction
 (descent test and ``dphi0``), lbfgs.update (accepted step, convergence,
 history write); solve.prologue / solve.epilogue (before and after the
-`while_loop`, and the lane-minor → lane-major transpose). The resident
+`while_loop`, and the lane-minor → lane-major transpose); the phases of a
+coordinate-descent update — game_re.gather (offsets laid into a bucket's
+rows, warm starts read from the (E, d) table through the bucket's index
+map), game_re.solve (the bucket's vmapped per-entity solves: the L-BFGS
+and X-pass scopes nest under it), game_re.scatter (results written back
+to the table), game_re.score (per-row margins from the table),
+game_fixed.solve (the fixed effect's solve, same nesting) and
+game.objective (offsets sum and the tracking objective). The resident
 solves report the `solver.*` pair iterations / linesearch_trials through
 `count_device` — a counter whose value is still a device array: the
 attached `Run` keeps the reference and resolves every pending array in
@@ -398,6 +415,11 @@ TELEMETRY_REGISTRY = {
         "game_re.blocks", "game_re.readback_wait_ns",
         "game_re.straggler_entities", "game_re.tail_resolves",
         "game_re.iters_saved", "game_re.fused_gate_offs",
+        "game_re.block_bytes_real", "game_re.block_bytes_padded",
+        "game_re.row_iterations", "game_re.block_steps",
+        "game_re.moved_row_iterations", "game_re.iterations",
+        "game_re.linesearch_trials",
+        "game_fixed.row_iterations",
         "game_e2e.pod_scale_runs", "game_e2e.streamed_fixed_updates",
         "game_e2e.objective_chunks",
         "game_e2e.host_offset_sums", "game_e2e.score_stream_chunks",
@@ -429,6 +451,8 @@ TELEMETRY_REGISTRY = {
         "lbfgs.two_loop", "lbfgs.push", "lbfgs.linesearch",
         "lbfgs.direction", "lbfgs.update",
         "solve.prologue", "solve.epilogue",
+        "game_re.gather", "game_re.solve", "game_re.scatter",
+        "game_re.score", "game_fixed.solve", "game.objective",
     ),
 }
 DEVICE_SCOPES = TELEMETRY_REGISTRY["device_scopes"]

@@ -338,3 +338,29 @@ def test_lane_grid_device_results_layout(rng):
     assert res.w.shape == (3, 100)
     assert res.value.shape == (3,)
     assert var is None
+
+
+@pytest.mark.parametrize("optimizer,l1", [(OptimizerType.LBFGS, False),
+                                          (OptimizerType.LBFGS, True),
+                                          (OptimizerType.TRON, False)],
+                         ids=["lbfgs", "owlqn", "tron"])
+def test_lane_grid_tolerance_zero_is_a_fixed_depth(rng, optimizer, l1):
+    """The lane solvers follow `optim.config.stop_state` as the scalar ones
+    do: at ``tolerance`` 0 every lane takes ``max_iters`` iterations, ends
+    converged and not failed, at the objective the early-stopping solve
+    reaches."""
+    X, y = _sparse_problem(rng, n=200, d=12, k=4)
+    batch = make_batch(X, y)
+    weights = [2.0, 8.0]
+    reg_ctx = elastic_net(0.5) if l1 else l2()
+    cfg = OptimizerConfig(optimizer=optimizer, max_iters=40, tolerance=0.0,
+                          reg=reg_ctx)
+    fixed = train_glm_grid(batch, TaskType.LOGISTIC_REGRESSION, cfg, weights)
+    early = train_glm_grid(
+        batch, TaskType.LOGISTIC_REGRESSION,
+        dataclasses.replace(cfg, tolerance=1e-7), weights)
+    for (_, res), (_, res_early) in zip(fixed, early):
+        assert int(res.iterations) == 40 > int(res_early.iterations)
+        assert bool(res.converged) and not bool(res.failed)
+        np.testing.assert_allclose(float(res.value), float(res_early.value),
+                                   rtol=1e-5)

@@ -196,3 +196,73 @@ def test_tron_nan_region_shrinks_not_grows():
     # Must make real progress into the interior (true min has f < -5).
     assert np.isfinite(float(res.value)) and float(res.value) < -5.0
     assert not bool(res.failed)
+
+
+# ---------------------------------------------------- tolerance 0: fixed depth
+def _stalling_problem(rng):
+    """A small, well-conditioned L2 logistic problem: every solver is at
+    f32 resolution long before 60 iterations."""
+    X, y, vg0, hvp0 = _logistic_problem(rng, n=64, d=6)
+
+    def vg(w):
+        f, g = vg0(w)
+        return f + 0.5 * 5.0 * w @ w, g + 5.0 * w
+
+    return vg, lambda w, v: hvp0(w, v) + 5.0 * v
+
+
+def _solve_with(solver: str, vg, hvp, max_iters: int, tolerance: float):
+    w0 = jnp.zeros(6, jnp.float32)
+    if solver == "lbfgs":
+        return minimize_lbfgs(vg, w0, max_iters=max_iters,
+                              tolerance=tolerance)
+    if solver == "owlqn":
+        return minimize_owlqn(vg, w0, 0.01, max_iters=max_iters,
+                              tolerance=tolerance)
+    return minimize_tron(vg, hvp, w0, max_iters=max_iters,
+                         tolerance=tolerance)
+
+
+@pytest.mark.parametrize("solver", ["lbfgs", "owlqn", "tron"])
+def test_tolerance_zero_is_a_fixed_depth(rng, solver):
+    """`optim.config.stop_state`, every solver alike: at ``tolerance`` 0 a
+    solve that stalls at f32 resolution runs its whole ``max_iters``,
+    repeats its last point (the same objective a deeper budget ends at,
+    nothing non-finite), counts the repeats in ``iterations``, and ends
+    converged and not failed; a positive tolerance stops at the first
+    stop."""
+    vg, hvp = _stalling_problem(rng)
+    early = _solve_with(solver, vg, hvp, 60, 1e-7)
+    assert int(early.iterations) < 60 and bool(early.converged)
+    fixed = _solve_with(solver, vg, hvp, 60, 0.0)
+    deeper = _solve_with(solver, vg, hvp, 90, 0.0)
+    assert int(fixed.iterations) == 60 and int(deeper.iterations) == 90
+    for res in (fixed, deeper):
+        assert bool(res.converged) and not bool(res.failed)
+        assert np.all(np.isfinite(np.asarray(res.w)))
+        assert np.all(np.isfinite(np.asarray(res.loss_history)))
+    hist = np.asarray(fixed.loss_history)
+    assert np.all(np.diff(hist) <= 0.0)           # never a worse point
+    assert np.all(hist[40:] == hist[-1])          # the stall: repeats
+    np.testing.assert_allclose(float(fixed.value), float(deeper.value),
+                               rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(fixed.w), np.asarray(deeper.w),
+                               atol=1e-5)
+    np.testing.assert_allclose(float(fixed.value), float(early.value),
+                               rtol=1e-5)
+
+
+def test_tolerance_zero_lanes_step_together_under_vmap(rng):
+    """Under `vmap` every lane of a fixed-depth solve takes ``max_iters``
+    steps, whichever lane stalls first."""
+    vg, _ = _stalling_problem(rng)
+    scales = jnp.asarray([0.5, 1.0, 4.0], jnp.float32)
+
+    def one(scale):
+        return minimize_lbfgs(lambda w: vg(w * scale), jnp.zeros(6),
+                              max_iters=30, tolerance=0.0)
+
+    res = jax.vmap(one)(scales)
+    assert np.asarray(res.iterations).tolist() == [30, 30, 30]
+    assert np.asarray(res.converged).all() and not np.asarray(
+        res.failed).any()

@@ -89,16 +89,16 @@ class TestBlockProjection:
     def test_sparse_block_varying_active_sizes(self):
         """Regression: entities whose active count + 1 < padded width p must
         still route intercept values to the intercept column, not feature 0."""
-        from photon_tpu.game.projector import project_sparse_block
+        from photon_tpu.game.dataset import _project_sparse
 
         # entity 0: features {2, 5} + intercept 9 (p=4 -> nact+1 < p)
-        bp = build_index_map_projection(
-            [np.array([2, 5]), np.array([1, 3, 7])], intercept_index=9)
-        assert bp.dim == 4
         ind = np.array([[[2, 5, 9, 0]], [[1, 3, 9, 0]]])  # (E=2, m=1, k=4)
         val = np.array([[[1.5, -2.0, 1.0, 0.0]], [[4.0, 5.0, 1.0, 0.0]]],
                        np.float32)
-        out = project_sparse_block(ind, val, bp)
+        out, bp = _project_sparse(ind, val, 9, width=4)
+        assert bp.dim == 4
+        np.testing.assert_array_equal(bp.proj_idx[1], [1, 3, 0, 9])
+        np.testing.assert_array_equal(bp.proj_mask[1], [1, 1, 0, 1])
         np.testing.assert_allclose(out[0, 0], [1.5, -2.0, 0.0, 1.0])
         np.testing.assert_allclose(out[1, 0], [4.0, 5.0, 0.0, 1.0])
 
