@@ -14,9 +14,13 @@ vector lanes. See ops.lane_objective for the layout argument.
 
 Differences from the scalar solver (optim/lbfgs.py), all masked per lane:
 - the Wolfe search runs lock-step with sticky per-lane `done` freezing,
-- the (s, y) history uses a globally rotating slot + per-slot per-lane
-  validity masks instead of per-lane idx/count (a lane that skips a push —
-  failed line search or failed curvature — just leaves its slot invalid),
+- the (s, y) history (`LaneHistory`) uses a globally rotating slot +
+  per-slot per-lane validity masks instead of per-lane idx/count (a lane
+  that skips a push — failed line search or failed curvature — just leaves
+  its slot invalid); like the scalar `History` it carries its inner
+  products, so an iteration reads the (m, d, G) buffers twice (one fused
+  reduction pass in `_push_lanes`, one combination pass in
+  `two_loop_lanes`) and writes one slot of each,
 - converged/failed lanes freeze: their state stops updating while the
   remaining lanes run to their own convergence.
 
@@ -32,7 +36,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_tpu.ops import lane_objective as lo
-from photon_tpu.optim.lbfgs import _convergence
+from photon_tpu.optim.lbfgs import (_coefficients, _combine, _convergence,
+                                     _pushed, _pushed_block, _recency,
+                                     _to_recency, _to_slots)
 from photon_tpu.optim.linesearch import C1, C2, _cubic_min
 from photon_tpu.optim.config import stop_state
 from photon_tpu.optim.tracker import OptResult
@@ -160,82 +166,109 @@ def wolfe_line_search_lanes(
     return out.a_star, out.f_star, ok, out.i
 
 
+class LaneHistory(NamedTuple):
+    """The rotating (s, y) history of G lanes and the inner products the
+    recursion needs (optim.lbfgs.History with a trailing lane axis: S, Y
+    in slot order, the small blocks flat and in recency order, index 0 the
+    newest slot).
+
+    ``sy`` / ``yy`` are products of the STORED vectors — with a narrower
+    ``history_dtype``, of the rounded ones, so the coefficient recursion
+    is the vector-space recursion over what the slots really hold. What
+    STEERS (``rho``, ``gamma``, curvature acceptance) is f32 from the
+    UNROUNDED pair: with f32 storage that is the blocks' diagonals to the
+    bit, with bf16 storage it is deliberately more accurate than they are
+    (the guarantee the bf16 quality test pins)."""
+    S: jax.Array      # (m, d, G) history dtype
+    Y: jax.Array      # (m, d, G)
+    sy: jax.Array     # (m·m, G) S[i]·Y[k] at i·m + k, per lane, f32
+    yy: jax.Array     # (m·m, G) Y[i]·Y[k]
+    sv: jax.Array     # (m, G) S[i]·v for the vector the next direction is of
+    yv: jax.Array     # (m, G) Y[i]·v
+    rho: jax.Array    # (m, G) 1 / sᵀy of the unrounded pair
+    gamma: jax.Array  # (m, G) sᵀy / yᵀy of the unrounded pair
+    valid: jax.Array  # (m, G)
+    idx: jax.Array    # () rotating write slot
+
+
+def empty_lane_history(m: int, d: int, G: int, history_dtype) -> LaneHistory:
+    f32 = jnp.float32
+    return LaneHistory(
+        S=jnp.zeros((m, d, G), history_dtype),
+        Y=jnp.zeros((m, d, G), history_dtype),
+        sy=jnp.zeros((m * m, G), f32), yy=jnp.zeros((m * m, G), f32),
+        sv=jnp.zeros((m, G), f32), yv=jnp.zeros((m, G), f32),
+        rho=jnp.zeros((m, G), f32), gamma=jnp.zeros((m, G), f32),
+        valid=jnp.zeros((m, G), bool), idx=jnp.zeros((), jnp.int32))
+
+
 @device_scope("lbfgs.two_loop")
-def two_loop_lanes(g, S, Y, rho, valid, idx, sy, yy):
-    """H·g per lane over the rotating history. g: (d, G); S/Y: (m, d, G);
-    rho/valid/sy/yy: (m, G); idx: () next write slot. Invalid (slot, lane)
-    pairs are masked out, so a lane's effective history is its valid slots
-    in recency order — same recursion as optim.lbfgs.two_loop per lane.
+def two_loop_lanes(h: LaneHistory, v):
+    """H·v per lane over the rotating history, for the ``v`` (d, G) the
+    last `_push_lanes` was given — the same recursion as
+    optim.lbfgs.two_loop per lane. Invalid (slot, lane) pairs are masked
+    out, so a lane's effective history is its valid slots in recency
+    order; gamma comes from each lane's newest VALID pair (holes shift it
+    to the next older valid one).
 
-    ``sy``/``yy`` are the sᵀy / yᵀy inner products CACHED at push time,
-    computed f32 from the UNROUNDED pair (with f32 storage that is bitwise
-    what a recompute from the stored slots gives; with a narrower
-    ``history_dtype`` it is deliberately MORE accurate than one — the f32
-    steering guarantee the bf16 quality test pins). Deriving gamma from
-    the cache also keeps per-iteration history traffic to the two reads
-    the recursion itself needs — recomputing cost a third full (m, d, G)
-    pass over S and Y, ~1/3 of the history HBM traffic that bounds lane
-    scaling past G=8 (docs/PERF.md lane table)."""
-    m = S.shape[0]
-
-    def bwd(i, carry):
-        q, alphas = carry
-        slot = jnp.mod(idx - 1 - i, m)
-        v = valid[slot]
-        # bf16-storage histories upcast in registers here (bf16 × f32
-        # promotes to f32); the reduction is f32 either way.
-        alpha = jnp.where(v, rho[slot] * jnp.sum(S[slot] * q, axis=0), 0.0)
-        q = q - alpha[None, :] * Y[slot]
-        return q, alphas.at[slot].set(alpha)
-
-    G = g.shape[1]
-    q, alphas = lax.fori_loop(
-        0, m, bwd, (g, jnp.zeros((m, G), g.dtype)))
-
-    # Per-lane gamma from each lane's newest VALID pair (the scalar solver's
-    # newest pair; holes shift it to the next older valid one).
-    def newest(i, carry):
-        gamma, found = carry
-        slot = jnp.mod(idx - 1 - i, m)
-        v = valid[slot] & ~found
-        gamma = jnp.where(v, sy[slot] / jnp.maximum(yy[slot], 1e-20), gamma)
-        return gamma, found | valid[slot]
-
-    gamma, _ = lax.fori_loop(
-        0, m, newest,
-        (jnp.ones((G,), g.dtype), jnp.zeros((G,), bool)))
-    r = gamma[None, :] * q
-
-    def fwd(j, r):
-        slot = jnp.mod(idx - 1 - (m - 1 - j), m)
-        v = valid[slot]
-        beta = jnp.where(v, rho[slot] * jnp.sum(Y[slot] * r, axis=0), 0.0)
-        return r + jnp.where(v, alphas[slot] - beta, 0.0)[None, :] * S[slot]
-
-    return lax.fori_loop(0, m, fwd, r)
+    Reads: the coefficients come from the carried (m, m, G) products
+    (no d-sized operand), then ONE pass combines gamma·v + cy·Y + cs·S:
+    S, Y (upcast in registers when stored narrower) and v read once, the
+    result written once. The vector-space recursion this replaces read a
+    slot and re-read and re-wrote the (d, G) working array in each of 2m
+    dependent steps (PERF.md §6, PR 28)."""
+    m = h.sv.shape[0]
+    gamma = jnp.ones_like(h.gamma[0])
+    for i in reversed(range(m)):  # the newest valid pair wins
+        gamma = jnp.where(h.valid[i], h.gamma[i], gamma)
+    cy, cs = _coefficients(h.sy, h.yy, h.sv, h.yv, h.rho, gamma, h.valid)
+    slots = _recency(h.idx, m)
+    return _combine(gamma * v, _to_slots(slots, cy), h.Y,
+                    _to_slots(slots, cs), h.S)
 
 
 @device_scope("lbfgs.push")
-def _push_lanes(S, Y, rho, valid, idx, s, y, accept, SY, YY):
-    """Write (s, y) into the rotating slot for lanes where ``accept`` holds
-    AND the curvature condition passes; other lanes' slot goes invalid. The
-    slot index rotates globally (one dynamic-update-slice per array instead
-    of per-lane scatters). ``SY``/``YY`` (m, G) cache the accepted pairs'
-    sᵀy / yᵀy so the two-loop never re-reads S, Y to recompute gamma."""
-    m = S.shape[0]
+def _push_lanes(h: LaneHistory, s, y, accept, v) -> LaneHistory:
+    """Write (s, y) into the rotating slot for lanes where ``accept``
+    holds AND the curvature condition passes; other lanes' slot goes
+    invalid and is zeroed. The slot index rotates globally (one dynamic-
+    update-slice per array instead of per-lane scatters). ``v`` (d, G) is
+    what the NEXT `two_loop_lanes` will be applied to.
+
+    Reads: ONE fused pass over S and Y for their products with the
+    written pair and with ``v`` (the new pair's row and column of the
+    Gram blocks, the next direction's sv / yv); writes one slot of each,
+    cast to the storage dtype at the write. Acceptance, rho and gamma are
+    f32 from the unrounded pair."""
+    m = h.sv.shape[0]
+    f32 = jnp.float32
     sy = jnp.sum(s * y, axis=0)
     yy = jnp.sum(y * y, axis=0)
     acc = accept & (sy > 1e-10 * jnp.maximum(yy, 1e-20))
-    # Storage may be narrower than the solve (history_dtype): cast at the
-    # write; every steering inner product above is already f32.
-    S = S.at[idx].set(jnp.where(acc[None, :], s.astype(S.dtype), S[idx]))
-    Y = Y.at[idx].set(jnp.where(acc[None, :], y.astype(Y.dtype), Y[idx]))
-    rho = rho.at[idx].set(
-        jnp.where(acc, 1.0 / jnp.maximum(sy, 1e-20), rho[idx]))
-    SY = SY.at[idx].set(jnp.where(acc, sy, SY[idx]))
-    YY = YY.at[idx].set(jnp.where(acc, yy, YY[idx]))
-    valid = valid.at[idx].set(acc)
-    return S, Y, rho, valid, jnp.mod(idx + 1, m), SY, YY
+    s_w = jnp.where(acc[None, :], s, 0.0).astype(h.S.dtype)
+    y_w = jnp.where(acc[None, :], y, 0.0).astype(h.Y.dtype)
+    s_r, y_r = s_w.astype(f32), y_w.astype(f32)  # what the slot will hold
+
+    slots = _recency(h.idx, m)
+
+    def dots(A, x):  # (m, d, G) · (d, G) -> (m, G), f32, slot order;
+        # spelled per slot, the form the compiler fuses into one pass here
+        return jnp.stack([jnp.sum(A[a] * x, axis=0) for a in range(m)])
+
+    S_y, S_v, Y_s, Y_y, Y_v = _to_recency(slots, jnp.stack([
+        dots(h.S, y_r), dots(h.S, v),
+        dots(h.Y, s_r), dots(h.Y, y_r), dots(h.Y, v)], axis=1)
+    ).swapaxes(0, 1)
+    return LaneHistory(
+        S=h.S.at[h.idx].set(s_w), Y=h.Y.at[h.idx].set(y_w),
+        sy=_pushed_block(h.sy, m, Y_s, S_y, jnp.sum(s_r * y_r, axis=0)),
+        yy=_pushed_block(h.yy, m, Y_y, Y_y, jnp.sum(y_r * y_r, axis=0)),
+        sv=_pushed(S_v, jnp.sum(s_r * v, axis=0)),
+        yv=_pushed(Y_v, jnp.sum(y_r * v, axis=0)),
+        rho=_pushed(h.rho, 1.0 / jnp.maximum(sy, 1e-20)),
+        gamma=_pushed(h.gamma, sy / jnp.maximum(yy, 1e-20)),
+        valid=_pushed(h.valid, acc),
+        idx=jnp.mod(h.idx + 1, m))
 
 
 class _LaneState(NamedTuple):
@@ -243,13 +276,7 @@ class _LaneState(NamedTuple):
     z: jax.Array       # (n, G) cached margins, shard-local
     f: jax.Array       # (G,)
     g: jax.Array       # (d, G)
-    S: jax.Array       # (m, d, G)
-    Y: jax.Array       # (m, d, G)
-    rho: jax.Array     # (m, G)
-    sy: jax.Array      # (m, G) cached sᵀy per accepted pair
-    yy: jax.Array      # (m, G) cached yᵀy per accepted pair
-    valid: jax.Array   # (m, G)
-    idx: jax.Array     # () rotating write slot
+    h: LaneHistory     # its sv / yv are against g
     it: jax.Array      # () global iteration counter
     evals: jax.Array   # () lock-step line-search evaluations so far
     its: jax.Array     # (G,) per-lane iterations taken
@@ -304,15 +331,14 @@ def minimize_lbfgs_margin_lanes(
 
     def body(s: _LaneState):
         active = ~s.done
-        hg = two_loop_lanes(s.g, s.S, s.Y, s.rho, s.valid, s.idx,
-                            s.sy, s.yy)
+        hg = two_loop_lanes(s.h, s.g)
         with device_scope("lbfgs.direction"):
             D = -hg
             dphi0 = jnp.sum(D * s.g, axis=0)
             bad_dir = dphi0 >= 0.0
             D = jnp.where(bad_dir[None, :], -s.g, D)
             dphi0 = jnp.where(bad_dir, -jnp.sum(s.g * s.g, axis=0), dphi0)
-            has_hist = jnp.any(s.valid, axis=0)
+            has_hist = jnp.any(s.h.valid, axis=0)
             dnorm = jnp.sqrt(jnp.sum(D * D, axis=0))
             a_init = jnp.where(has_hist, 1.0, 1.0 / jnp.maximum(dnorm, 1.0))
             ray = lo.ray_reg_coeffs_lanes(obj, l2s, s.W, D)
@@ -344,9 +370,7 @@ def minimize_lbfgs_margin_lanes(
             f_new = jnp.where(step, f_star, s.f)
             g_new = jnp.where(step[None, :], g_new, s.g)
 
-        S, Y, rho, valid, idx, sy, yy = _push_lanes(
-            s.S, s.Y, s.rho, s.valid, s.idx, W_new - s.W, g_new - s.g, step,
-            s.sy, s.yy)
+        h = _push_lanes(s.h, W_new - s.W, g_new - s.g, step, g_new)
 
         with device_scope("lbfgs.update"):
             gnorm = jnp.sqrt(jnp.sum(g_new * g_new, axis=0))
@@ -359,8 +383,7 @@ def minimize_lbfgs_margin_lanes(
             it = s.it + 1
             its = jnp.where(active, s.its + 1, s.its)
             return _LaneState(
-                W=W_new, z=z_new, f=f_new, g=g_new, S=S, Y=Y, rho=rho,
-                sy=sy, yy=yy, valid=valid, idx=idx, it=it,
+                W=W_new, z=z_new, f=f_new, g=g_new, h=h, it=it,
                 evals=s.evals + ls_evals, its=its,
                 done=done, converged=converged, failed=failed,
                 hist=s.hist.at[it].set(
@@ -372,10 +395,8 @@ def minimize_lbfgs_margin_lanes(
     with device_scope("solve.prologue"):
         init = _LaneState(
             W=W0, z=z0, f=f0, g=g0,
-            S=jnp.zeros((m, d, G), hdtype), Y=jnp.zeros((m, d, G), hdtype),
-            rho=jnp.zeros((m, G), dtype), sy=jnp.zeros((m, G), dtype),
-            yy=jnp.zeros((m, G), dtype), valid=jnp.zeros((m, G), bool),
-            idx=jnp.zeros((), jnp.int32), it=jnp.zeros((), jnp.int32),
+            h=empty_lane_history(m, d, G, hdtype),
+            it=jnp.zeros((), jnp.int32),
             evals=jnp.zeros((), jnp.int32),
             its=jnp.zeros((G,), jnp.int32),
             done=g0norm <= 1e-14, converged=g0norm <= 1e-14,

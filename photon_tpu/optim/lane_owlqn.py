@@ -19,10 +19,11 @@ Differences from the scalar solver (optim/owlqn.py), all masked per lane:
   margin-cached L-BFGS there is no z + a·dz shortcut — each trial pays
   one SHARED X pass; the accepted lane's trial margin is carried out of
   the search, so the outer step adds only the gradient's Xᵀ pass;
-- the (s, y) history uses the same globally rotating slot + per-(slot,
-  lane) validity masks and cached f32 sᵀy/yᵀy steering products as the
-  lane L-BFGS (optim/lane_lbfgs._push_lanes), including optional bf16
-  history storage.
+- the (s, y) history is the lane L-BFGS's (optim/lane_lbfgs.LaneHistory:
+  globally rotating slot, per-(slot, lane) validity masks, carried inner
+  products, f32 steering scalars from the unrounded pair, optional bf16
+  storage); its products are taken with the PSEUDO-gradient at the new
+  point, inside the push's one reduction pass.
 
 Numerics per lane match the scalar OWL-QN to f32 reduction noise (pinned
 by tests/test_lane_solver.py).
@@ -36,7 +37,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from photon_tpu.ops import lane_objective as lo
-from photon_tpu.optim.lane_lbfgs import _push_lanes, two_loop_lanes
+from photon_tpu.optim.lane_lbfgs import (LaneHistory, _push_lanes,
+                                          empty_lane_history,
+                                          two_loop_lanes)
 from photon_tpu.optim.config import stop_state
 from photon_tpu.optim.tracker import OptResult
 
@@ -62,13 +65,7 @@ class _LaneState(NamedTuple):
     f: jax.Array       # (G,) smooth part (data loss + L2)
     F: jax.Array       # (G,) f + L1
     g: jax.Array       # (d, G) smooth gradient
-    S: jax.Array       # (m, d, G)
-    Y: jax.Array
-    rho: jax.Array     # (m, G)
-    sy: jax.Array      # (m, G) cached f32 steering products
-    yy: jax.Array
-    valid: jax.Array   # (m, G)
-    idx: jax.Array     # () rotating write slot
+    h: LaneHistory     # its sv / yv are against the pseudo-gradient at W
     it: jax.Array
     evals: jax.Array   # () lock-step line-search evaluations so far
     its: jax.Array     # (G,)
@@ -128,7 +125,7 @@ def minimize_owlqn_lanes(
     def body(s: _LaneState):
         active = ~s.done
         pg = pseudo_gradient_lanes(s.W, s.g, l1s, mask)
-        D = -two_loop_lanes(pg, s.S, s.Y, s.rho, s.valid, s.idx, s.sy, s.yy)
+        D = -two_loop_lanes(s.h, pg)
         # Orthant constraint on the direction (Andrew & Gao p_k).
         D = jnp.where(D * pg < 0.0, D, 0.0)
         dphi0 = jnp.sum(D * pg, axis=0)
@@ -152,7 +149,7 @@ def minimize_owlqn_lanes(
             dec = jnp.sum(pg * (W_try - s.W), axis=0)
             return f_try + l1_term(W_try), dec, z_try
 
-        has_hist = jnp.any(s.valid, axis=0)
+        has_hist = jnp.any(s.h.valid, axis=0)
         dnorm = jnp.sqrt(jnp.sum(D * D, axis=0))
         a0 = jnp.where(has_hist, 1.0, 1.0 / jnp.maximum(dnorm, 1.0))
 
@@ -193,11 +190,10 @@ def minimize_owlqn_lanes(
         g_new = jnp.where(step[None, :], g_new, s.g)
         F_new = jnp.where(step, ls.F, s.F)
 
-        S, Y, rho, valid, idx, sy, yy = _push_lanes(
-            s.S, s.Y, s.rho, s.valid, s.idx, W_new - s.W, g_new - s.g, step,
-            s.sy, s.yy)
-
+        # the push takes the history's products with the NEXT direction's
+        # vector — the pseudo-gradient at W_new (as optim.owlqn)
         pg_new = pseudo_gradient_lanes(W_new, g_new, l1s, mask)
+        h = _push_lanes(s.h, W_new - s.W, g_new - s.g, step, pg_new)
         pgnorm = jnp.sqrt(jnp.sum(pg_new * pg_new, axis=0))
         grad_conv = pgnorm <= tolerance * jnp.maximum(1.0, pg0norm)
         f_conv = ls.succ & (
@@ -215,8 +211,7 @@ def minimize_owlqn_lanes(
         it = s.it + 1
         its = jnp.where(active, s.its + 1, s.its)
         return _LaneState(
-            W=W_new, z=z_new, f=f_new, F=F_new, g=g_new, S=S, Y=Y, rho=rho,
-            sy=sy, yy=yy, valid=valid, idx=idx, it=it,
+            W=W_new, z=z_new, f=f_new, F=F_new, g=g_new, h=h, it=it,
             evals=s.evals + ls.i, its=its,
             done=done, converged=converged, failed=failed,
             hist=s.hist.at[it].set(jnp.where(active, F_new, s.hist[it])),
@@ -225,10 +220,8 @@ def minimize_owlqn_lanes(
 
     init = _LaneState(
         W=W0, z=z0, f=f0, F=F0, g=g0,
-        S=jnp.zeros((m, d, G), hdtype), Y=jnp.zeros((m, d, G), hdtype),
-        rho=jnp.zeros((m, G), dtype), sy=jnp.zeros((m, G), dtype),
-        yy=jnp.zeros((m, G), dtype), valid=jnp.zeros((m, G), bool),
-        idx=jnp.zeros((), jnp.int32), it=jnp.zeros((), jnp.int32),
+        h=empty_lane_history(m, d, G, hdtype),
+        it=jnp.zeros((), jnp.int32),
         evals=jnp.zeros((), jnp.int32),
         its=jnp.zeros((G,), jnp.int32),
         done=pg0norm <= 1e-14, converged=pg0norm <= 1e-14,

@@ -9,8 +9,12 @@ owns it in the reference.
 
 Pieces:
 - pseudo-gradient of F = f + λ|w|₁  (subgradient choice per Andrew & Gao)
-- two-loop L-BFGS direction on the pseudo-gradient, projected to agree in
-  sign with the steepest-descent direction
+- L-BFGS direction of the pseudo-gradient (optim.lbfgs.two_loop: the
+  coefficient recursion over the history's carried inner products, then
+  one combination pass), projected to agree in sign with the
+  steepest-descent direction; the history's products with the pseudo-
+  gradient come out of the push's one reduction pass, which is handed the
+  pseudo-gradient at the NEW point
 - backtracking line search with orthant projection π(·; ξ)
 """
 from __future__ import annotations
@@ -21,7 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from photon_tpu.optim.lbfgs import two_loop, _push
+from photon_tpu.optim.lbfgs import (History, _push, empty_history,
+                                     two_loop)
 from photon_tpu.optim.config import stop_state
 from photon_tpu.optim.tracker import OptResult
 from photon_tpu.parallel.mesh import vary_like
@@ -45,13 +50,7 @@ class _State(NamedTuple):
     f: jax.Array  # smooth part
     F: jax.Array  # f + L1
     g: jax.Array  # smooth gradient
-    S: jax.Array
-    Y: jax.Array
-    rho: jax.Array
-    sy: jax.Array
-    yy: jax.Array
-    idx: jax.Array
-    count: jax.Array
+    h: History  # its sv / yv are against the PSEUDO-gradient at w
     it: jax.Array
     evals: jax.Array  # line-search evaluations taken so far
     done: jax.Array
@@ -94,8 +93,7 @@ def minimize_owlqn(
 
     def body(s: _State):
         pg = pseudo_gradient(s.w, s.g, l1_weight, mask)
-        direction = -two_loop(pg, s.S, s.Y, s.rho, s.idx, s.count,
-                              s.sy, s.yy)
+        direction = -two_loop(s.h, pg)
         # Constrain direction to the quasi-Newton orthant: any component that
         # disagrees in sign with -pg is zeroed (Andrew & Gao eq. for p_k).
         direction = jnp.where(direction * pg < 0.0, direction, 0.0)
@@ -110,7 +108,7 @@ def minimize_owlqn(
         def project(w):
             return jnp.where(w * xi > 0.0, w, 0.0)
 
-        a0 = jnp.where(s.count > 0, 1.0,
+        a0 = jnp.where(s.h.count > 0, 1.0,
                        1.0 / jnp.maximum(jnp.linalg.norm(direction), 1.0))
 
         class LS(NamedTuple):
@@ -148,13 +146,12 @@ def minimize_owlqn(
         F_new = jnp.where(ok, F_new, s.F)
         g_new = jnp.where(ok, g_new, s.g)
 
-        # History uses smooth gradients (Andrew & Gao): y = Δg, s = Δw.
-        S, Y, rho, idx, count, sy, yy = _push(
-            s.S, s.Y, s.rho, s.idx, s.count, w_new - s.w, g_new - s.g,
-            s.sy, s.yy
-        )
-
+        # History uses smooth gradients (Andrew & Gao): y = Δg, s = Δw —
+        # but the next direction is of the PSEUDO-gradient at w_new, so
+        # that is the vector the push takes the history's products with
+        # (the same reduction pass; the direction needs none of its own).
         pg_new = pseudo_gradient(w_new, g_new, l1_weight, mask)
+        h = _push(s.h, w_new - s.w, g_new - s.g, pg_new)
         pgnorm = jnp.linalg.norm(pg_new)
         grad_conv = pgnorm <= tolerance * jnp.maximum(1.0, pg0norm)
         # Gate f_conv on an accepted step: a rejected step leaves F unchanged
@@ -176,9 +173,8 @@ def minimize_owlqn(
         solver_tap("owlqn", it, F_new, pgnorm, jnp.where(ok, ls.a, 0.0))
         snapshot_tap("owlqn", it, w_new, F_new, pgnorm)
         return _State(
-            w=w_new, f=f_new, F=F_new, g=g_new, S=S, Y=Y, rho=rho,
-            sy=sy, yy=yy, idx=idx,
-            count=count, it=it, evals=s.evals + ls.i,
+            w=w_new, f=f_new, F=F_new, g=g_new, h=h, it=it,
+            evals=s.evals + ls.i,
             done=done, converged=converged, failed=failed,
             hist=s.hist.at[it].set(F_new),
             ghist=s.ghist.at[it].set(pgnorm),
@@ -187,10 +183,7 @@ def minimize_owlqn(
     solver_tap("owlqn", 0, F0, pg0norm)
     init = vary_like(_State(
         w=w0, f=f0, F=F0, g=g0,
-        S=jnp.zeros((m, d), dtype), Y=jnp.zeros((m, d), dtype),
-        rho=jnp.zeros((m,), dtype),
-        sy=jnp.zeros((), dtype), yy=jnp.zeros((), dtype),
-        idx=jnp.zeros((), jnp.int32), count=jnp.zeros((), jnp.int32),
+        h=empty_history(m, d, dtype),
         it=jnp.zeros((), jnp.int32), evals=jnp.zeros((), jnp.int32),
         done=pg0norm <= 1e-14, converged=pg0norm <= 1e-14,
         failed=jnp.zeros((), bool), hist=hist0, ghist=ghist0,

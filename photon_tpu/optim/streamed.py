@@ -31,10 +31,11 @@ not:
 - The outer loop runs on HOST (it must re-stream chunks per evaluation, so a
   `lax.while_loop` cannot express it), but every numeric step — two-loop
   direction, history push, chunk partials, margin updates — is the SAME
-  device code the resident solvers run (`two_loop` is imported, not
-  reimplemented), and convergence criteria mirror `optim.lbfgs._convergence`
-  / `optim.owlqn` term for term. The parity tests pin streamed == resident
-  to f32 accumulation noise (tests/test_streamed.py).
+  device code the resident solvers run (`two_loop` and `_push` are
+  imported, not reimplemented), and convergence criteria mirror
+  `optim.lbfgs._convergence` / `optim.owlqn` term for term. The parity
+  tests pin streamed == resident to f32 accumulation noise
+  (tests/test_streamed.py).
 - L-BFGS line search rides CACHED PER-CHUNK MARGINS: z chains on host as
   z += α·dz (refreshed from w every `_Z_REFRESH` iterations, like the
   resident margin solver), so a Wolfe trial uploads 16 bytes/row of (z, dz)
@@ -73,7 +74,9 @@ from photon_tpu import telemetry
 from photon_tpu.data.dataset import GLMBatch
 from photon_tpu.data.matrix import ShardedBlockedEllRows, SparseRows
 from photon_tpu.optim.config import stop_state
-from photon_tpu.optim.lbfgs import _Z_REFRESH, two_loop
+from photon_tpu.optim.lbfgs import (_Z_REFRESH, History, _push,
+                                     empty_history, history_from_slots,
+                                     two_loop)
 from photon_tpu.optim.linesearch import C1, C2
 from photon_tpu.optim.owlqn import pseudo_gradient
 from photon_tpu.optim.tracker import OptResult
@@ -176,8 +179,8 @@ def _axpy(w, a, p):
 
 
 @jax.jit
-def _lbfgs_direction(g, S, Y, rho, idx, count, sy, yy):
-    p = -two_loop(g, S, Y, rho, idx, count, sy, yy)
+def _lbfgs_direction(g, h):
+    p = -two_loop(h, g)
     dphi0 = jnp.dot(p, g)
     bad = dphi0 >= 0.0
     p = jnp.where(bad, -g, p)
@@ -186,9 +189,9 @@ def _lbfgs_direction(g, S, Y, rho, idx, count, sy, yy):
 
 
 @jax.jit
-def _owlqn_direction(w, g, l1, mask, S, Y, rho, idx, count, sy, yy):
+def _owlqn_direction(w, g, l1, mask, h):
     pg = pseudo_gradient(w, g, l1, mask)
-    p = -two_loop(pg, S, Y, rho, idx, count, sy, yy)
+    p = -two_loop(h, pg)
     p = jnp.where(p * pg < 0.0, p, 0.0)
     dphi0 = jnp.dot(p, pg)
     bad = dphi0 >= 0.0
@@ -221,15 +224,10 @@ def _l1_term(w, l1, mask):
     return l1 * jnp.sum(mask * jnp.abs(w))
 
 
-@jax.jit
-def _pair_stats(s, y):
-    return jnp.dot(s, y), jnp.dot(y, y)
-
-
-@jax.jit
-def _write_slot(S, Y, rho, idx, s, y, sy):
-    return (S.at[idx].set(s), Y.at[idx].set(y),
-            rho.at[idx].set(1.0 / jnp.maximum(sy, 1e-20)))
+_pseudo_gradient = jax.jit(pseudo_gradient)
+# NOT donated: a checkpoint session may still hold the previous history's
+# buffers for a snapshot it has not written yet
+_push_history = jax.jit(_push)
 
 
 # ------------------------------------------------------------- mesh backend
@@ -615,31 +613,19 @@ def _check_streamable(obj, mesh) -> None:
 
 
 class _History:
-    """Host-orchestrated circular (s, y) history — device buffers, host
-    bookkeeping. push() applies optim.lbfgs._push's exact curvature gate."""
+    """Host-orchestrated circular (s, y) history: the resident solvers'
+    `History` on the device, pushed by their `_push` (curvature gate, slot
+    write and inner products in one program). The host mirrors ``count``
+    only, for the first step's length."""
 
     def __init__(self, m: int, d: int, dtype=jnp.float32):
-        self.S = jnp.zeros((m, d), dtype)
-        self.Y = jnp.zeros((m, d), dtype)
-        self.rho = jnp.zeros((m,), dtype)
-        self.m, self.idx, self.count = m, 0, 0
-        self.sy, self.yy = 0.0, 0.0
+        self.h = empty_history(m, d, dtype)
+        self.count = 0
 
-    def push(self, s, y) -> None:
-        sy, yy = (float(v) for v in _pair_stats(s, y))
-        if not sy > 1e-10 * max(yy, 1e-20):
-            return  # curvature condition failed: skip, keep newest stats
-        self.S, self.Y, self.rho = _write_slot(
-            self.S, self.Y, self.rho, np.int32(self.idx), s, y,
-            np.float32(sy))
-        self.idx = (self.idx + 1) % self.m
-        self.count = min(self.count + 1, self.m)
-        self.sy, self.yy = sy, yy
-
-    def args(self) -> tuple:
-        return (self.S, self.Y, self.rho, np.int32(self.idx),
-                np.int32(self.count), np.float32(self.sy),
-                np.float32(self.yy))
+    def push(self, s, y, v) -> None:
+        """``v``: the vector the next direction is of (optim.lbfgs._push)."""
+        self.h = _push_history(self.h, s, y, v)
+        self.count = int(self.h.count)
 
 
 # ---------------------------------------------------------- host line search
@@ -767,9 +753,10 @@ def _pack_stream_state(kind, d, n_chunks, chunk_rows, max_iters, it, f,
         "hist": np.asarray(hist), "ghist": np.asarray(ghist),
         "converged": bool(converged), "failed": bool(failed),
         "done": bool(done), "w": w, "g": g,
-        "S": hist_st.S, "Y": hist_st.Y, "rho": hist_st.rho,
-        "h_idx": int(hist_st.idx), "h_count": int(hist_st.count),
-        "h_sy": float(hist_st.sy), "h_yy": float(hist_st.yy),
+        "S": hist_st.h.S, "Y": hist_st.h.Y,
+        "h_sy": hist_st.h.sy, "h_yy": hist_st.h.yy,
+        "h_sv": hist_st.h.sv, "h_yv": hist_st.h.yv,
+        "h_idx": int(hist_st.h.idx), "h_count": int(hist_st.h.count),
     }
     if extra:
         st.update(extra)
@@ -792,19 +779,33 @@ def _validate_stream_state(st: dict, kind: str, d: int, n_chunks: int,
             "differ; margin caches re-shard).")
 
 
-def _restore_history(st: dict, history: int, d: int) -> _History:
-    hs = _History(history, d)
-    S, Y, rho = (np.asarray(st["S"]), np.asarray(st["Y"]),
-                 np.asarray(st["rho"]))
-    if S.shape != (history, d):
-        from photon_tpu.checkpoint import SnapshotStateError
+def _restore_history(st: dict, history: int, d: int, v) -> _History:
+    """``v``: the vector the resumed solve's next direction is of (the
+    snapshot's gradient; OWL-QN's pseudo-gradient at its iterate)."""
+    from photon_tpu.checkpoint import SnapshotStateError
 
+    hs = _History(history, d)
+    S, Y = np.asarray(st["S"]), np.asarray(st["Y"])
+    idx, count = int(st["h_idx"]), int(st["h_count"])
+    if "h_sv" not in st and S.shape == (history, d):
+        # written before the history carried its products: bare (m, d)
+        # rings, from which every product is recomputed — resumes the
+        # same solve, to f32 reduction noise rather than to the bit
+        hs.h = history_from_slots(jnp.asarray(S), jnp.asarray(Y), idx,
+                                  count, v)
+    elif S.shape != hs.h.S.shape:
         raise SnapshotStateError(
             f"curvature history shape {S.shape} in snapshot vs "
-            f"({history}, {d}) in the resuming solve")
-    hs.S, hs.Y, hs.rho = jnp.asarray(S), jnp.asarray(Y), jnp.asarray(rho)
-    hs.idx, hs.count = int(st["h_idx"]), int(st["h_count"])
-    hs.sy, hs.yy = float(st["h_sy"]), float(st["h_yy"])
+            f"{hs.h.S.shape} in the resuming solve (history {history}, "
+            f"{d} features)")
+    else:
+        hs.h = History(
+            S=jnp.asarray(S), Y=jnp.asarray(Y),
+            **{k: jnp.asarray(np.asarray(st["h_" + k]))
+               for k in ("sy", "yy", "sv", "yv")},
+            idx=jnp.asarray(idx, jnp.int32),
+            count=jnp.asarray(count, jnp.int32))
+    hs.count = count
     return hs
 
 
@@ -913,7 +914,7 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
 
             w = jax.device_put(w, replicated(mesh))
             g = jax.device_put(g, replicated(mesh))
-        hist_st = _restore_history(st, history, d)
+        hist_st = _restore_history(st, history, d, g)
         z_cache = _restore_z_cache(st, data, mesh)
         f, g0norm = float(st["f"]), float(st["g0norm"])
         hist = np.array(st["hist"], np.float32)
@@ -967,7 +968,7 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
             ck.maybe_snapshot()
     dz_cache: list = [None] * n_chunks
     while not done and it < max_iters:
-        p, dphi0_dev, pnorm = _lbfgs_direction(g, *hist_st.args())
+        p, dphi0_dev, pnorm = _lbfgs_direction(g, hist_st.h)
         dphi0 = float(dphi0_dev)
         a_init = (1.0 if hist_st.count > 0
                   else 1.0 / max(float(pnorm), 1.0))
@@ -1045,7 +1046,7 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
             _eval_tick(ck)
             f_new = f_star  # the accepted trial's value, as the resident
             # margin solver uses it
-            hist_st.push(w_new - w, g_new - g)
+            hist_st.push(w_new - w, g_new - g, g_new)
         else:
             w_new, g_new, f_new = w, g, f
 
@@ -1161,7 +1162,8 @@ def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
 
             w = jax.device_put(w, replicated(mesh))
             g = jax.device_put(g, replicated(mesh))
-        hist_st = _restore_history(st, history, d)
+        hist_st = _restore_history(st, history, d,
+                                   _pseudo_gradient(w, g, l1, mask))
         f, F = float(st["f"]), float(st["F"])
         pg0norm = float(st["g0norm"])
         hist = np.array(st["hist"], np.float32)
@@ -1196,7 +1198,7 @@ def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
             ck.maybe_snapshot()
     while not done and it < max_iters:
         p, dphi0_dev, xi, pg, pnorm = _owlqn_direction(
-            w, g, l1, mask, *hist_st.args())
+            w, g, l1, mask, hist_st.h)
         dphi0 = float(dphi0_dev)
         a0 = 1.0 if hist_st.count > 0 else 1.0 / max(float(pnorm), 1.0)
 
@@ -1236,7 +1238,10 @@ def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
         if ok:
             f_new, g_new = value_grad_pass(w_new)  # gradient stream
             F_new = f_new + float(_l1_term(w_new, l1, mask))
-            hist_st.push(w_new - w, g_new - g)  # smooth-gradient history
+            # smooth-gradient history; its products are with the next
+            # direction's vector, the pseudo-gradient at w_new
+            hist_st.push(w_new - w, g_new - g,
+                         _pseudo_gradient(w_new, g_new, l1, mask))
         else:
             w_new, g_new, f_new, F_new = w, g, f, F
 

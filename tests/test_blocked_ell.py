@@ -397,11 +397,17 @@ def test_bell_grid_lanes_parity(rng):
     grid_s = train_glm_grid(batch_s, TaskType.LOGISTIC_REGRESSION, cfg,
                             weights)
     for (m_b, r_b), (m_s, r_s) in zip(grid_b, grid_s):
+        # 3e-4 and 8e-2, not 1e-4 and 3e-2: the cap cuts the 1e-1 lane
+        # ~0.45 above its optimum of 385 on both layouts, and two
+        # truncated paths agree as far as their reduction orders do — the
+        # PARENT's two-loop with its dots summed in another order reads
+        # 1.3e-4 and 0.046 here, the carried-products form 1.4e-4 and
+        # 0.041 (PERF.md §6, PR 28)
         np.testing.assert_allclose(float(r_b.value), float(r_s.value),
-                                   rtol=1e-4)
+                                   rtol=3e-4)
         np.testing.assert_allclose(np.asarray(m_b.coefficients.means),
                                    np.asarray(m_s.coefficients.means),
-                                   atol=3e-2)
+                                   atol=8e-2)
     # model selection pairs the batch's margins with the batch's labels:
     # the SAME models must score the same on either representation
     from photon_tpu.models.training import evaluate_glm_grid

@@ -147,6 +147,60 @@ class TestStreamedBitParity:
         np.testing.assert_array_equal(w_ref, w)
 
 
+    @pytest.mark.parametrize("kind", ["lbfgs", "owlqn"])
+    def test_snapshot_without_carried_products_resumes(self, cb, tmp_path,
+                                                       monkeypatch, kind):
+        """A snapshot written before the history carried its inner
+        products — bare (m, d) rings, `rho`, the newest pair's sᵀy / yᵀy
+        as two floats — still resumes: the products are recomputed from
+        the slots (optim.lbfgs.history_from_slots), so the resumed solve
+        is the same solve to f32 reduction noise, not to the bit."""
+        from photon_tpu.optim import streamed
+
+        cfg = CFG if kind == "lbfgs" else OptimizerConfig(
+            max_iters=8, tolerance=0.0, reg=reg.l1(), reg_weight=1e-3,
+            history=4)
+        w_ref = _solve(cb, cfg=cfg)
+        pack = streamed._pack_stream_state
+
+        def pack_as_before(*args, **kwargs):
+            st = pack(*args, **kwargs)
+            m, d = cfg.history, st["d"]
+            for k in ("S", "Y"):
+                st[k] = np.asarray(st[k])[:m].reshape(m, -1)[:, :d]
+            sy, yy = (np.asarray(st.pop(k)).reshape(m, m)
+                      for k in ("h_sy", "h_yy"))
+            order = (st["h_idx"] - 1 - np.arange(m)) % m  # recency -> slot
+            rho = np.zeros(m, np.float32)
+            rho[order] = 1.0 / np.maximum(np.diag(sy), 1e-20)
+            st.update(rho=rho, h_sy=float(sy[0, 0]), h_yy=float(yy[0, 0]))
+            for k in ("h_sv", "h_yv"):
+                del st[k]
+            return st
+
+        ckdir = tmp_path / "before"
+        try:
+            with monkeypatch.context() as mp:
+                mp.setattr(streamed, "_pack_stream_state", pack_as_before)
+                with checkpoint.session(str(ckdir), every_evals=1,
+                                        every_s=None, async_writer=False):
+                    with checkpoint.fault_plan(
+                            checkpoint.FaultPlan.kill_at("evaluation", 6)):
+                        _solve(cb, cfg=cfg)
+        except checkpoint.InjectedFault:
+            pass
+        restores = []
+        monkeypatch.setattr(
+            streamed, "history_from_slots",
+            lambda *a, f=streamed.history_from_slots: (
+                restores.append(int(a[3])), f(*a))[1])
+        with checkpoint.session(str(ckdir), every_evals=1, every_s=None,
+                                async_writer=False):
+            w = _solve(cb, cfg=cfg)
+        assert restores and restores[0] > 0  # resumed with pairs in it
+        np.testing.assert_allclose(w, w_ref, rtol=0, atol=2e-5)
+
+
 # ------------------------------------------------------------ streamed mesh
 class TestStreamedMeshBitParity:
     def test_mesh_kill_every_site_resume_bit_identical(self, cb, tmp_path,

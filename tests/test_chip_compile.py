@@ -22,6 +22,7 @@ persistent compilation cache is off around the compiles (an executable for
 a described chip can be written to the cache but not read back).
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -289,8 +290,6 @@ def test_blocked_ell_evaluation_gathers_only_by_bucket(G):
     assert sorted(shapes) == sorted(expected)
     assert (n, G) not in shapes and (n,) not in shapes
     # and so does the lowered program: the jitted evaluation has that many
-    import re
-
     assert len(re.findall(r'stablehlo\.gather"?\(',
                           jax.jit(fn).lower(w).as_text())) == len(shapes)
 
@@ -375,3 +374,119 @@ def test_all_reduce_combiner_threshold(topo, gshape, expected):
         fn, _shape((64, 8), "float32", NamedSharding(mesh, P("data"))),
         _shape(gshape, "float32", NamedSharding(mesh, P())))
     assert _all_reduces(compiled) == expected
+
+
+# ----------------------------------------- the L-BFGS history's byte budget
+def _while_bodies(hlo_text):
+    """The text of every computation a `while` of the module runs as its
+    body."""
+    bodies = []
+    for name in re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", hlo_text):
+        start = hlo_text.index(f"%{name} ")
+        bodies.append(hlo_text[start:hlo_text.index("\n}", start)])
+    return bodies
+
+
+def _readers_of(hlo_text, shape_prefix):
+    """{parameter: [(name, op), ...]} — for each entry parameter of the
+    given shape, the instructions that take it, or a buffer updated in
+    place from it, as an operand (tuple plumbing aside)."""
+    entry = hlo_text[hlo_text.index("ENTRY"):]
+    shapes, origin, readers = {}, {}, {}
+    for line in entry.splitlines():
+        mt = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?[\}\)\]]) "
+                      r"([a-z][\w\-]*)\((.*)", line)
+        if not mt:
+            continue
+        name, shape, op, rest = mt.groups()
+        shapes[name] = shape
+        if op == "parameter" and shape.startswith(shape_prefix):
+            origin[name] = name
+            readers[name] = []
+        plumbing = op in ("tuple", "get-tuple-element", "bitcast",
+                          "parameter")
+        for o in re.findall(r"%([\w.\-]+)", rest.split("), ")[0]):
+            if o in origin:
+                if not plumbing:
+                    readers[origin[o]].append((name, op))
+                if shape_prefix in shape and op != "tuple":
+                    origin[name] = origin[o]  # updated in place, or a
+                    # multi-output fusion that also returns the update
+    return readers
+
+
+@pytest.mark.parametrize("form", ["scalar", "lanes-bf16"])
+def test_history_step_is_two_passes(one_chip, form):
+    """One iteration's push + direction at the cells' own shapes (history
+    5, 10M features; 8 bf16 lanes) reads the (S, Y) history twice — one
+    fused reduction pass, one combination pass — writes one slot of each
+    and makes O(1) passes over d-vectors, and no loop of the program
+    touches a d-sized operand. The recursion this replaced fetched a slot
+    and re-read and re-wrote the working vector in each of 2m dependent
+    `while` steps.
+
+    Pinned by structure: each of S and Y (the buffer and its in-place
+    update) is an operand of exactly three instructions of the compiled
+    step, all fusions — the reduction pass, the slot update, the
+    combination pass. A later edit that un-fuses a pass adds readers.
+
+    The byte count is the second fence, set at what `cost_analysis()`
+    reads today plus 4 %: 1.681e9 bytes for the scalar form (3.50 × the
+    480 MB of S and Y) and 7.201e9 for the lane form (4.50 × its 1.6 GB).
+    Both are above the two reads they make because a slot update is
+    charged its whole in-place operand (as read in the scalar form, as
+    read and written in the lane form) — 1.0 and 2.0 histories that no
+    pass moves — and the rest is the d-vectors: s, y, v in, the written
+    pair, the direction. A third read of the history would add 1.0. (At
+    d = 2^20 the compiler stages the history through its near memory and
+    the copies swamp both counts, so this compiles the real width.)"""
+    from photon_tpu.optim import lane_lbfgs, lbfgs
+
+    m, d, G = 5, GLM_FEATURES, 8
+
+    def shapes_of(make):
+        return jax.tree_util.tree_map(
+            lambda x: _shape(x.shape, x.dtype, one_chip),
+            jax.eval_shape(make))
+
+    if form == "scalar":
+        h = shapes_of(lambda: lbfgs.empty_history(m, d, jnp.float32))
+        vec = _shape((d,), "float32", one_chip)
+
+        def step(h, s, y, v):
+            h = lbfgs._push(h, s, y, v)
+            return h, lbfgs.two_loop(h, v)
+
+        args = (h, vec, vec, vec)
+    else:
+        h = shapes_of(lambda: lane_lbfgs.empty_lane_history(
+            m, d, G, jnp.bfloat16))
+        vec = _shape((d, G), "float32", one_chip)
+
+        def step(h, s, y, accept, v):
+            h = lane_lbfgs._push_lanes(h, s, y, accept, v)
+            return h, lane_lbfgs.two_loop_lanes(h, v)
+
+        args = (h, vec, vec, _shape((G,), "bool", one_chip), vec)
+    compiled = jax.jit(step, donate_argnums=0).lower(*args).compile()
+    text = compiled.as_text()
+
+    dims = ",".join(str(n) for n in h.S.shape)
+    prefix = f"{'bf16' if form == 'lanes-bf16' else 'f32'}[{dims}]"
+    readers = _readers_of(text, prefix)
+    assert len(readers) == 2, readers  # S and Y
+    for took in readers.values():
+        assert len(took) == 3 and {op for _, op in took} == {"fusion"}, \
+            readers
+
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    history = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in (h.S, h.Y))
+    measured = {"scalar": 1.681e9, "lanes-bf16": 7.201e9}[form]
+    assert cost["bytes accessed"] <= 1.04 * measured, (
+        cost["bytes accessed"], cost["bytes accessed"] / history)
+
+    for body in _while_bodies(text):
+        assert str(d) not in body and f"[{h.S.shape[1]}," not in body, \
+            body[:400]

@@ -249,9 +249,14 @@ def test_lane_grid_owlqn_sharded_hybrid(rng, mesh8):
                                 optimizer=OptimizerType.OWLQN))
         np.testing.assert_allclose(float(res.value), float(r_seq.value),
                                    rtol=1e-4)
+        # 6e-2, not 2e-2: the stop at 1e-6 pins F to 1.4e-4 and leaves
+        # coefficients of size 1-3 free by a few hundredths in this flat
+        # valley (L2 part 0.05). The PARENT's two-loop with its dots
+        # summed in any other order reads 0.022-0.037 here, the
+        # carried-products form 0.030 (PERF.md §6, PR 28)
         np.testing.assert_allclose(np.asarray(model.coefficients.means),
                                    np.asarray(m_seq.coefficients.means),
-                                   atol=2e-2)
+                                   atol=6e-2)
 
 
 def test_lane_grid_sharded_hybrid(rng, mesh8):

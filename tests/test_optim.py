@@ -266,3 +266,305 @@ def test_tolerance_zero_lanes_step_together_under_vmap(rng):
     assert np.asarray(res.iterations).tolist() == [30, 30, 30]
     assert np.asarray(res.converged).all() and not np.asarray(
         res.failed).any()
+
+
+# ------------------------------------------------- the carried-Gram history
+# The direction is computed on coefficients over carried inner products
+# (optim.lbfgs.History / optim.lane_lbfgs.LaneHistory). The plain two-loop
+# recursion over the STORED vectors, in float64 numpy, stays here as the
+# reference for every form of it.
+
+_M, _D, _LANES = 5, 48, 3
+
+
+def _two_loop_reference(pairs, v):
+    """pairs: oldest → newest (s, y, sᵀy, yᵀy), the vectors as stored and
+    the steering products of the pair as given."""
+    q = np.asarray(v, np.float64).copy()
+    alphas = []
+    for s, y, sy, _ in reversed(pairs):
+        a = (s @ q) / max(sy, 1e-20)
+        q -= a * y
+        alphas.append(a)
+    r = q * (pairs[-1][2] / max(pairs[-1][3], 1e-20) if pairs else 1.0)
+    for (s, y, sy, _), a in zip(pairs, reversed(alphas)):
+        r += (a - (y @ r) / max(sy, 1e-20)) * s
+    return r
+
+
+def _history_events(scenario):
+    """Per push: (s, y, accept, v) with s, y, v (LANES, D) f32 and accept
+    (LANES,) bool — the lane solvers' own veto; a pair may also fail
+    curvature. ``v`` is the vector the next direction is of."""
+    rng = np.random.default_rng(7)
+    if scenario == "illcond":
+        return _illconditioned_events(rng), rng
+    B = rng.normal(size=(_D, _D))
+    A = B @ B.T / _D + 0.5 * np.eye(_D)  # condition number ~ 10
+    n = {"empty": 0, "partial": 3, "skipped": 8, "holes": 9,
+         "stalled": 26}.get(scenario)
+    if n is None:  # "rot<r>": the write slot ends at r
+        n = _M + int(scenario[3:])
+    events = []
+    for k in range(n):
+        s = rng.normal(size=(_LANES, _D)) * 0.3
+        y = s @ A
+        accept = np.ones(_LANES, bool)
+        if scenario == "skipped" and k in (3, 5):
+            y = -y  # negative curvature in every lane: the push is skipped
+        if scenario == "holes":
+            if k in (2, 6):
+                y[1] = -y[1]
+            if k in (0, 4, 5):
+                accept[2] = False
+                s[2], y[2] = 0.0, 0.0  # a lane that did not step
+        if scenario == "stalled" and k >= 6:
+            s[0], y[0] = 0.0, 0.0  # GLMix's stalled lane: s = 0, 20 times
+        v = rng.normal(size=(_LANES, _D))
+        events.append((s.astype(np.float32), y.astype(np.float32), accept,
+                       v.astype(np.float32)))
+    return events, rng
+
+
+def _illconditioned_events(rng, cond=1e4, n=40):
+    """The pairs and gradients of a real L-BFGS run (float64, exact line
+    search) on a quadratic of condition number 1e4, scaled so |s| is about
+    1e-4: every stored vector nearly parallel to the next gradient, where
+    the coefficient recursion's differences of carried products cancel
+    most — the case that would show an error growing with conditioning."""
+    Q, _ = np.linalg.qr(rng.normal(size=(_D, _D)))
+    A = (Q * np.logspace(0.0, np.log10(cond), _D)) @ Q.T
+    per_lane = []
+    for _ in range(_LANES):
+        b = 2e-3 * rng.normal(size=_D)
+        w, g, pairs, run = np.zeros(_D), -b, [], []
+        for _ in range(n):
+            d = -_two_loop_reference(
+                [(s, y, s @ y, y @ y) for s, y in pairs], g)
+            a = -(g @ d) / (d @ A @ d)
+            s, y = a * d, a * (A @ d)
+            w, g = w + s, g + y
+            pairs = (pairs + [(s, y)])[-_M:]
+            run.append((s, y, g))
+        per_lane.append(run)
+    f32 = np.float32
+    return [tuple(np.stack([lane[k][j] for lane in per_lane]).astype(f32)
+                  for j in (0, 1)) + (np.ones(_LANES, bool),
+            np.stack([lane[k][2] for lane in per_lane]).astype(f32))
+            for k in range(n)]
+
+
+def _stored(x, dtype):
+    return np.asarray(jnp.asarray(x).astype(dtype).astype(jnp.float32),
+                      np.float64)
+
+
+def _accepted(s, y, accept):
+    sy, yy = float(s.astype(np.float64) @ y), float(y.astype(np.float64) @ y)
+    return bool(accept) and sy > 1e-10 * max(yy, 1e-20), sy, yy
+
+
+@pytest.fixture(scope="module")
+def history_forms():
+    from photon_tpu.optim import lane_lbfgs, lbfgs
+
+    return {
+        "scalar": (jax.jit(lbfgs._push), jax.jit(lbfgs.two_loop)),
+        "vmap": (jax.jit(jax.vmap(lbfgs._push)),
+                 jax.jit(jax.vmap(lbfgs.two_loop))),
+        "lanes": (jax.jit(lane_lbfgs._push_lanes),
+                  jax.jit(lane_lbfgs.two_loop_lanes)),
+    }
+
+
+@pytest.mark.parametrize("scenario", [
+    "empty", "partial", "rot0", "rot1", "rot2", "rot3", "rot4", "skipped",
+    "holes", "stalled", "illcond"])
+@pytest.mark.parametrize("form", ["scalar", "scalar-tiled", "vmap",
+                                  "lanes-f32", "lanes-bf16"])
+def test_direction_matches_plain_two_loop(history_forms, form, scenario,
+                                          monkeypatch):
+    """After every push the new direction equals the float64 two-loop over
+    the stored vectors, and the carried products equal the products
+    recomputed from the stored slots.
+
+    atol 2e-5 of the direction's largest entry. Measured against the
+    float64 reference, the coefficient recursion and the SAME recursion on
+    f32 vectors err alike and both grow with conditioning — 2e-7 / 1e-7
+    of the direction's norm at condition number 10, 1.5e-6 / 1.5e-6 at
+    1e4 ("illcond": a real run's pairs and gradients, |s| ~ 1e-4), 1e-5 /
+    9e-6 at 1e6 — so 2e-5 holds every case here with room for the
+    reduction order, and a wrong slot order, mask, gamma or Gram entry is
+    off by percents."""
+    from photon_tpu.optim import lane_lbfgs, lbfgs
+
+    events, rng = _history_events(scenario)
+    lanes = form.startswith("lanes")
+    hdtype = jnp.bfloat16 if form == "lanes-bf16" else jnp.float32
+    if form == "scalar-tiled":  # the large-d layout, at a width not of 128
+        monkeypatch.setattr(lbfgs, "_TILED_FROM", _D)
+        form = "scalar"
+    push, direction = history_forms["lanes" if lanes else form]
+    L = 1 if form == "scalar" else _LANES
+    v = rng.normal(size=(_LANES, _D)).astype(np.float32)
+    if form == "scalar":
+        h = lbfgs.empty_history(_M, _D, jnp.float32)
+        assert h.S.ndim == (3 if lbfgs._TILED_FROM == _D else 2)
+    elif form == "vmap":
+        h = jax.vmap(lambda _: lbfgs.empty_history(_M, _D, jnp.float32))(
+            jnp.arange(L))
+    else:
+        h = lane_lbfgs.empty_lane_history(_M, _D, L, hdtype)
+    # the reference's own bookkeeping, per lane: the scalar form keeps the
+    # last m ACCEPTED pairs; the lane form rotates one global slot and a
+    # lane that does not take it leaves a hole
+    kept = [[] for _ in range(L)]
+
+    def check():
+        if form == "scalar":
+            got = np.asarray(direction(h, jnp.asarray(v[0])))[None]
+        elif form == "vmap":
+            got = np.asarray(direction(h, jnp.asarray(v)))
+        else:
+            got = np.asarray(direction(h, jnp.asarray(v.T))).T
+        for lane in range(L):
+            pairs = [p for p in kept[lane] if p is not None]
+            want = _two_loop_reference(pairs, v[lane])
+            np.testing.assert_allclose(
+                got[lane], want, rtol=0, atol=2e-5 * np.abs(want).max(),
+                err_msg=f"lane {lane} after {len(kept[lane])} pushes")
+        # carried products (flat, recency order: entry [i, k] at i·m + k,
+        # index i the pair i pushes back, slot idx − 1 − i) against the
+        # slots as stored; row m of the scalar form's S, Y is the unread one
+        S, Y = np.asarray(h.S, np.float64), np.asarray(h.Y, np.float64)
+        if form == "scalar":  # flat or tiled
+            S, Y = (A.reshape(_M + 1, -1)[None, :_M, :_D] for A in (S, Y))
+        elif form == "vmap":
+            S, Y = S[:, :_M], Y[:, :_M]
+        else:
+            S, Y = np.moveaxis(S, 2, 0), np.moveaxis(Y, 2, 0)
+        S, Y = S.copy(), Y.copy()
+        idx = np.broadcast_to(np.asarray(h.idx), (L,))
+        for lane in range(L):
+            order = (idx[lane] - 1 - np.arange(_M)) % _M
+            S[lane], Y[lane] = S[lane][order], Y[lane][order]
+
+        def lane_first(x):
+            x = np.asarray(x)
+            return (np.moveaxis(x, -1, 0) if lanes
+                    else x[None] if form == "scalar" else x)
+
+        v64 = v[:L].astype(np.float64)[:, None]
+        for name, got_blk, A, B in (("sy", h.sy, S, Y), ("yy", h.yy, Y, Y),
+                                    ("sv", h.sv, S, v64), ("yv", h.yv, Y, v64)):
+            # to f32 rounding of the factors' sizes, not of the product's:
+            # a real run's newest step is orthogonal to its new gradient
+            size = (np.linalg.norm(A, axis=2).max()
+                    * np.linalg.norm(B, axis=2).max())
+            np.testing.assert_allclose(
+                lane_first(got_blk).reshape(L, -1),
+                np.einsum("lad,lbd->lab", A, B).reshape(L, -1),
+                rtol=0, atol=2e-5 * max(size, 1e-30), err_msg=name)
+
+    check()
+    for s, y, accept, v in events:
+        for lane in range(L):
+            ok, sy, yy = _accepted(s[lane], y[lane], accept[lane])
+            pair = (_stored(s[lane], hdtype), _stored(y[lane], hdtype),
+                    sy, yy) if ok else None
+            if lanes or ok:  # a lane-form veto leaves a hole (None)
+                kept[lane] = (kept[lane] + [pair])[-_M:]
+        if form == "scalar":
+            h = push(h, jnp.asarray(s[0]), jnp.asarray(y[0]),
+                     jnp.asarray(v[0]))
+        elif form == "vmap":
+            h = push(h, jnp.asarray(s), jnp.asarray(y), jnp.asarray(v))
+        else:
+            h = push(h, jnp.asarray(s.T), jnp.asarray(y.T),
+                     jnp.asarray(accept), jnp.asarray(v.T))
+        check()
+    if scenario.startswith("rot") and form != "vmap":
+        assert int(h.idx) == int(scenario[3:]) % _M
+    if scenario == "holes" and form == "vmap":
+        assert len(set(np.asarray(h.idx).tolist())) > 1  # per-lane idx
+
+
+_COUPLED_SOLVERS = ["lbfgs", "lbfgs_margin", "owlqn", "lanes", "lanes_owlqn",
+                    "streamed", "streamed_owlqn"]
+
+
+@pytest.mark.parametrize("solver", _COUPLED_SOLVERS)
+def test_direction_is_of_the_vector_the_push_was_given(solver, rng,
+                                                       monkeypatch):
+    """`two_loop(h, v)` / `two_loop_lanes(h, v)` are only right for the
+    ``v`` the history's last push was given (``h.sv`` / ``h.yv`` are
+    products with THAT vector) and nothing in their signature can enforce
+    it. So every solver is run eagerly with the four functions recording:
+    each direction call must be handed the vector of the push that made
+    the history it is handed."""
+    import dataclasses
+
+    from photon_tpu.data.dataset import chunk_batch, make_batch
+    from photon_tpu.models.training import train_glm, train_glm_grid
+    from photon_tpu.ops.losses import TaskType
+    from photon_tpu.optim import (lane_lbfgs, lane_owlqn, lbfgs, owlqn,
+                                  regularization as reg, streamed)
+    from photon_tpu.optim.config import OptimizerConfig
+
+    pushed = {}  # id of a history's sv buffer -> the v it was pushed with
+    calls = {"push": 0, "direction": 0}
+
+    def recording_push(fn, v_at):
+        def push(*args):
+            h = fn(*args)
+            pushed[id(h.sv)] = (h.sv, np.asarray(args[v_at]))
+            calls["push"] += 1
+            return h
+        return push
+
+    def recording_direction(fn, has_pairs):
+        def direction(h, v):
+            if bool(np.any(has_pairs(h))):
+                kept, v_pushed = pushed[id(h.sv)]
+                assert kept is h.sv
+                np.testing.assert_array_equal(np.asarray(v), v_pushed)
+                calls["direction"] += 1
+            return fn(h, v)
+        return direction
+
+    scalar = lambda h: h.count > 0
+    lane = lambda h: h.valid
+    push, push_lanes = (recording_push(lbfgs._push, 3),
+                        recording_push(lane_lbfgs._push_lanes, 4))
+    two, two_lanes = (recording_direction(lbfgs.two_loop, scalar),
+                      recording_direction(lane_lbfgs.two_loop_lanes, lane))
+    for mod, name, fn in (
+            (lbfgs, "_push", push), (owlqn, "_push", push),
+            (streamed, "_push_history", push),
+            (lbfgs, "two_loop", two), (owlqn, "two_loop", two),
+            (streamed, "two_loop", two),
+            (lane_lbfgs, "_push_lanes", push_lanes),
+            (lane_owlqn, "_push_lanes", push_lanes),
+            (lane_lbfgs, "two_loop_lanes", two_lanes),
+            (lane_owlqn, "two_loop_lanes", two_lanes)):
+        monkeypatch.setattr(mod, name, fn)
+
+    X, y, vg, _ = _logistic_problem(rng, n=60, d=5)
+    task = TaskType.LOGISTIC_REGRESSION
+    l1 = solver.endswith("owlqn")
+    cfg = OptimizerConfig(max_iters=4, tolerance=0.0, history=2,
+                          reg=reg.l1() if l1 else reg.l2(),
+                          reg_weight=0.05 if l1 else 0.5, kernels="off")
+    with jax.disable_jit():
+        if solver == "lbfgs":
+            minimize_lbfgs(vg, jnp.zeros(5), max_iters=4, tolerance=0.0,
+                           history=2)
+        elif solver in ("lbfgs_margin", "owlqn"):
+            train_glm(make_batch(X, y), task, cfg)
+        elif solver.startswith("lanes"):
+            train_glm_grid(make_batch(X, y), task,
+                           dataclasses.replace(cfg, reg_weight=0.0),
+                           [0.05, 0.5])
+        else:
+            train_glm(chunk_batch(make_batch(X, y), 20), task, cfg)
+    assert calls["push"] == 4 and calls["direction"] == 3, calls

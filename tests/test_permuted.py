@@ -133,8 +133,14 @@ def test_perm_train_glm_parity(rng):
     m_p, r_p = train_glm(make_batch(P, y), TaskType.LOGISTIC_REGRESSION, cfg)
     m_s, r_s = train_glm(make_batch(X, y), TaskType.LOGISTIC_REGRESSION, cfg)
     np.testing.assert_allclose(float(r_p.value), float(r_s.value), rtol=1e-5)
+    # 3e-2, not 5e-3: the two layouts reduce in different orders and a
+    # relative-decrease stop at 1e-6 leaves each solve anywhere in a flat
+    # neighbourhood of the optimum. The old pin held by both solves
+    # sharing the two-loop's arithmetic: the PARENT's vector-space
+    # recursion with its dots summed in another order reads 0.0054-0.0135
+    # here, the carried-products form 0.0077-0.016 (PERF.md §6, PR 28)
     np.testing.assert_allclose(np.asarray(m_p.coefficients.means),
-                               np.asarray(m_s.coefficients.means), atol=5e-3)
+                               np.asarray(m_s.coefficients.means), atol=3e-2)
     # model scoring translates to permuted space internally
     np.testing.assert_allclose(np.asarray(m_p.score(P)),
                                np.asarray(m_p.score(X)), rtol=2e-4, atol=2e-4)

@@ -200,8 +200,12 @@ class TestTrainGlmGrid:
             c = dataclasses.replace(cfg, reg_weight=wt)
             obj = make_objective(TaskType.LOGISTIC_REGRESSION, c, d)
             r_s = solve(obj, batch, w0, c)
+            # 4e-5, not 1e-5: the lane and scalar histories sum their
+            # inner products in different orders; the PARENT's two-loop
+            # with its dots summed in another order reads 1.5e-5 here,
+            # the carried-products form 1.9e-5 (PERF.md §6, PR 28)
             np.testing.assert_allclose(np.asarray(m_g.coefficients.means),
-                                       np.asarray(r_s.w), atol=1e-5)
+                                       np.asarray(r_s.w), atol=4e-5)
         # stronger L1 → sparser lane
         nnz = [int((np.abs(np.asarray(m.coefficients.means)) > 1e-6).sum())
                for m, _ in grid]
