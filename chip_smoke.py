@@ -5,9 +5,9 @@ One process, one TPU v5e chip, the entry points a user would call, at the
 widths the repo advertises, random data made from ``--seed``:
 
 - ``glm``   — `train_glm` / `train_glm_grid` take five iterations on
-  bench.py's 10M-feature sparse problem (and its dense sibling), default
-  kernel route and ``kernels="off"``, checked against a plain float64 numpy
-  objective computed on the host from the same COO.
+  bench.py's 10M-feature sparse problem (and its dense sibling), checked
+  against a plain float64 numpy objective computed on the host from the
+  same COO.
 - ``game``  — the flagship GAME data written to Avro, then
   `drivers.train.main` (fixed + per-user + per-item, both ingest modes) and
   `drivers.score.main`; the AUC is recomputed from the scorer's file.
@@ -171,32 +171,13 @@ def layout_summary(X) -> dict:
             "dense_dtype": str(X.dense.dtype)}
 
 
-def x_pass_route(X, lanes: int) -> str:
-    """The trace-time verdict of `kernels.route` for this layout's two X
-    passes under the CURRENT mode (what the solve that follows traces)."""
-    import jax
-
-    from photon_tpu import kernels
-
-    d, n = int(X.shape[1]), int(X.shape[0])
-    vec = (lambda r: (r, lanes)) if lanes else (lambda r: (r,))
-    w = jax.ShapeDtypeStruct(vec(d), "float32")
-    r = jax.ShapeDtypeStruct(vec(n), "float32")
-    names = {"fused": "pallas-fused", "tiled": "pallas-tiled", None: "xla"}
-    return (f"matvec={names[kernels.route(X, w)]},"
-            f"rmatvec={names[kernels.route(X, r)]}")
-
-
 # ------------------------------------------------------------- phase: glm
 def run_glm(seed: int, sz: dict, clock: CompileClock) -> None:
-    import dataclasses
-
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     import bench
-    from photon_tpu import kernels
     from photon_tpu.data.dataset import make_batch
     from photon_tpu.models.training import train_glm, train_glm_grid
     from photon_tpu.ops.losses import TaskType
@@ -231,67 +212,34 @@ def run_glm(seed: int, sz: dict, clock: CompileClock) -> None:
                                reg_weight=0.0, history=5,
                                lane_history_dtype="bfloat16")
         lams = [float(v) for v in bench.S_GRID]
-        routes, finals, solve_s = {}, {}, {}
-        for label, mode in (("default", None), ("kernels_off", "off")):
-            if label == "kernels_off":
-                # drop the in-process executables: whatever route (b)
-                # shares with (a) must now come back from the PERSISTENT
-                # cache — the second compile of the same run, cold vs warm
-                jax.clear_caches()
-            h0, m0 = clock.hits, clock.misses
-            with kernels.scope(mode):
-                routes[label] = {
-                    "mode": kernels.mode(),
-                    "single": x_pass_route(batch.X, 0),
-                    "grid": x_pass_route(batch.X, len(lams))}
-            c0 = clock.seconds
-            t0 = time.perf_counter()
-            _, res = train_glm(batch, task,
-                               dataclasses.replace(single, kernels=mode))
-            w1 = np.asarray(res.w)
-            gres, _ = train_glm_grid(
-                batch, task, dataclasses.replace(grid, kernels=mode), lams,
-                device_results=True)
-            W = np.asarray(gres.w)                      # (G, d)
-            histG = np.asarray(gres.loss_history)       # (G, iters + 1)
-            solve_s[label] = {
-                "wall_s": round(time.perf_counter() - t0, 2),
-                "compile_s": round(clock.seconds - c0, 2),
-                "cache_hits": clock.hits - h0,
-                "cache_misses": clock.misses - m0}
-            ref1 = reference(w1, single.reg_weight)
-            check_lane("glm single", res.loss_history, res.value, w1, ref1,
-                       n_log2)
-            worst = max(
-                check_lane(f"glm grid lane {g}", histG[g], gres.value[g],
-                           W[g], reference(W[g], lam), n_log2)
-                for g, lam in enumerate(lams))
-            finals[label] = {
+        h0, m0, c0 = clock.hits, clock.misses, clock.seconds
+        t0 = time.perf_counter()
+        _, res = train_glm(batch, task, single)
+        w1 = np.asarray(res.w)
+        gres, _ = train_glm_grid(batch, task, grid, lams,
+                                 device_results=True)
+        W = np.asarray(gres.w)                      # (G, d)
+        histG = np.asarray(gres.loss_history)       # (G, iters + 1)
+        report["solve"] = {
+            "wall_s": round(time.perf_counter() - t0, 2),
+            "compile_s": round(clock.seconds - c0, 2),
+            "cache_hits": clock.hits - h0,
+            "cache_misses": clock.misses - m0}
+        ref1 = reference(w1, single.reg_weight)
+        check_lane("glm single", res.loss_history, res.value, w1, ref1,
+                   n_log2)
+        worst = max(
+            check_lane(f"glm grid lane {g}", histG[g], gres.value[g],
+                       W[g], reference(W[g], lam), n_log2)
+            for g, lam in enumerate(lams))
+        report["checks"] = {
+            "n_log2": n_log2, "loss_rtol": LOSS_RTOL,
+            "single": {
                 "single_loss": float(res.value), "single_numpy": ref1,
                 "single_iters": int(res.iterations),
                 "grid_losses": [float(v) for v in gres.value],
-                "grid_worst_rel_vs_numpy": worst, "w1": w1}
-        # route (a) against route (b): the same five iterations twice
-        a, b = finals["default"], finals["kernels_off"]
-        rel = abs(a["single_loss"] - b["single_loss"]) / b["single_loss"]
-        relg = max(abs(p - q) / q for p, q in zip(a["grid_losses"],
-                                                  b["grid_losses"]))
-        dw = float(np.max(np.abs(a["w1"] - b["w1"])))
-        check(rel <= LOSS_RTOL and relg <= LOSS_RTOL,
-              "glm: default route and kernels=off disagree",
-              single_rel=rel, grid_rel=relg)
-        report["routes"] = routes
-        report["solves"] = solve_s
-        report["second_compile"] = {
-            "first_s": solve_s["default"]["compile_s"],
-            "second_s": solve_s["kernels_off"]["compile_s"],
-            "second_cache_hits": solve_s["kernels_off"]["cache_hits"]}
-        report["checks"] = {
-            "n_log2": n_log2, "loss_rtol": LOSS_RTOL,
-            "single": {k: v for k, v in a.items() if k != "w1"},
-            "route_a_vs_b": {"single_loss_rel": rel, "grid_loss_rel": relg,
-                             "single_w_max_abs": dw}}
-        del batch, finals, a, b
+                "grid_worst_rel_vs_numpy": worst}}
+        del batch
 
         # the dense sibling, cheaply: bench.py's 524,288 x 256 f32 problem
         drows = sz["dense_rows"]
@@ -606,7 +554,7 @@ def run_game(seed: int, sz: dict, out_dir: str, clock: CompileClock) -> dict:
 def run_serve(seed: int, sz: dict, game: dict, clock: CompileClock) -> None:
     import numpy as np
 
-    from photon_tpu import kernels, serving
+    from photon_tpu import serving
     from photon_tpu.data.matrix import quantize_blocks
 
     model, vdata, offline = (game["model"], game["vdata"],
@@ -651,13 +599,6 @@ def run_serve(seed: int, sz: dict, game: dict, clock: CompileClock) -> None:
             warm_s = time.perf_counter() - t0
             warm_compile_s = clock.seconds - c0
             traced = ladder._jit._cache_size()
-            route = "xla"
-            if quant == "int8" and kernels.active():
-                from photon_tpu.kernels import serving as KS
-
-                if all(KS.fused_feasible(*ladder.example_args(b))
-                       for b in ladder.ladder):
-                    route = "pallas-fused"
             disp = serving.MicroBatchDispatcher(ladder)
             got = np.full(len(pool), np.nan)
             errors: list = []
@@ -710,7 +651,7 @@ def run_serve(seed: int, sz: dict, game: dict, clock: CompileClock) -> None:
                   ladder=label, max_abs_diff=float(diff.max()),
                   worst_tol=float(tol[np.argmax(diff - tol)]))
             report[label] = {
-                "rungs": list(ladder.ladder), "route": route,
+                "rungs": list(ladder.ladder),
                 "warmup_s": round(warm_s, 2),
                 "warmup_compile_s": round(warm_compile_s, 2),
                 "drive_s": round(drive_s, 2), "requests": len(pool),

@@ -4,12 +4,12 @@
     python -m photon_tpu --selfcheck --json     # machine report
     python -m photon_tpu --selfcheck --only telemetry profiling
 
-Runs the thirteen per-package selftests as subprocesses (each CLI
+Runs the twelve per-package selftests as subprocesses (each CLI
 self-provisions its 8-device CPU platform, so results match CI exactly
 and one crashed subsystem cannot take the others down). A SELF-TEST, NOT
 CHIP EVIDENCE: the children default to the CPU backend (a chip belongs to
-one process at a time, and Pallas kernels run interpreted in the kernels
-suite). Whether the program runs on the chip is `chip_smoke.py`'s job;
+one process at a time). Whether the program runs on the chip is
+`chip_smoke.py`'s job;
 whether a kernel compiles for it is tests/test_chip_compile.py's.
 
 - ``analysis``   — `python -m photon_tpu.analysis --json` (the full
@@ -46,13 +46,6 @@ whether a kernel compiles for it is tests/test_chip_compile.py's.
                    parity-probed atomic hot-swap with kill-mid-swap
                    falling back to the old model, and both continual
                    contracts
-- ``kernels``    — `--selftest`: the roofline-closure round — Pallas
-                   interpret-mode kernel-vs-XLA bitwise parity (matvec/
-                   rmatvec/lanes/sq across storage dtypes), the streamed
-                   chunk path kernels-on == kernels-off bit for bit, the
-                   dispatch seam's fallback + signature invariance, the
-                   donated upload ring's rotation, and the four
-                   roofline-closure contracts
 - ``ingest``     — `--selftest`: the round-14 ingest data plane —
                    one-pass scan, worker-pool decode parity (incl.
                    worker-kill degrade), decode-once chunk cache
@@ -98,7 +91,6 @@ SUITES: tuple = (
     ("game", ("photon_tpu.game", "--selftest", "--json")),
     ("continual", ("photon_tpu.continual", "--selftest", "--json")),
     ("ingest", ("photon_tpu.ingest", "--selftest", "--json")),
-    ("kernels", ("photon_tpu.kernels", "--selftest", "--json")),
     ("tuning", ("photon_tpu.tuning", "--selftest", "--json")),
     ("parallel", ("photon_tpu.parallel", "--selftest", "--json")),
 )

@@ -15,8 +15,6 @@ import importlib
 # the registry itself is a flat name -> spec mapping.
 HOT_PATH_MODULES = (
     "photon_tpu.data.matrix",         # blocked-ELL scatter-free X passes
-    "photon_tpu.kernels.blocked_ell",  # Pallas kernel X passes + seam
-    "photon_tpu.kernels.serving",     # fused int8 serving-rung kernel
     "photon_tpu.data.ingest_plane",   # ingest plane: chunk-program invariance
     "photon_tpu.ops.objective",       # resident evaluation + trial programs
     "photon_tpu.parallel.mesh",       # shard_map value_and_grad (1-D, hybrid)

@@ -1459,46 +1459,18 @@ def _bell_tail(X, w):
     return parts
 
 
-def _kernel_route(X, vec):
-    """The backend-dispatch seam (photon_tpu/kernels), now a LADDER:
-    ``"fused"`` when the knob is active (PHOTON_TPU_KERNELS /
-    OptimizerConfig.kernels), X is a plain BlockedEllRows with a tail
-    (the sharded global views keep XLA; inside shard_map `local()` is a
-    plain BlockedEllRows, so the mesh hot loop still routes here), and
-    the single-fused form fits the VMEM budget; ``"tiled"`` past the
-    budget while the grid-tiled form still fits; ``None`` → the XLA
-    path below, the always-available — and bitwise-identical —
-    fallback."""
-    if not isinstance(X, BlockedEllRows):
-        return None
-    from photon_tpu import kernels
-
-    return kernels.route(X, vec)
-
-
 def _bell_matvec(X: BlockedEllRows, w):
     """w: (d,) or (d, G) PERMUTED → (n,) / (n, G) in the layout's STORED
     row order. Hot block against the contiguous prefix slice, blocked-ELL
     tail — gathers of `w` and dense contractions only. A stored-order
     layout (`X.row_order`) lays the bucket outputs over its rows by
     CONCATENATION (zeros for the tail-free rows at the end); a
-    caller-order one (a shard or chunk view) by the `row_pos` gather. The
-    tail term routes through the Pallas kernels when the kernels seam is
-    active (`photon_tpu.kernels.tail_matvec`, grid-tiled past the VMEM
-    budget; both bitwise-equal)."""
+    caller-order one (a shard or chunk view) by the `row_pos` gather."""
     with device_scope("xpass.fwd.hot"):
         hot = jnp.matmul(X.dense, w[:X.d_sel].astype(X.dense.dtype),
                          preferred_element_type=jnp.float32)
     if not X.ell_vals:
         return hot
-    rt = _kernel_route(X, w)
-    if rt is not None:
-        from photon_tpu import kernels
-
-        with device_scope("xpass.fwd.tail"):
-            tail = (kernels.tail_matvec(X, w) if rt == "fused"
-                    else kernels.tail_matvec_tiled(X, w))
-        return hot + tail
     with device_scope("xpass.fwd.tail"):
         parts = _bell_tail(X, w)
         if X.row_order is not None:
@@ -1515,29 +1487,13 @@ def _bell_matvec(X: BlockedEllRows, w):
 def _bell_rmatvec(X: BlockedEllRows, r, square: bool = False):
     """Xᵀr (or (X∘X)ᵀr): hot matmul + per-occurrence-bucket pre-sorted
     gather/reduce, assembled by concatenation — no scatter. r: (n,) or
-    (n, G). The bucket block routes through the Pallas kernels when the
-    kernels seam is active (`photon_tpu.kernels.bucket_rmatvec`,
-    grid-tiled past the VMEM budget; both bitwise-equal)."""
+    (n, G)."""
     f32 = jnp.float32
     lanes = r.ndim == 2
     with device_scope("xpass.t.hot"):
         dense = X.dense * X.dense if square else X.dense
         parts = [jnp.matmul(dense.T, r.astype(X.dense.dtype),
                             preferred_element_type=f32)]
-    rt = _kernel_route(X, r) if X.bucket_vals else None
-    if rt is not None:
-        from photon_tpu import kernels
-
-        with device_scope("xpass.t.tail"):
-            parts.append(
-                kernels.bucket_rmatvec(X, r, square=square)
-                if rt == "fused"
-                else kernels.bucket_rmatvec_tiled(X, r, square=square))
-        pad = X.n_features - X.n_prefix
-        if pad:
-            parts.append(jnp.zeros(
-                (pad, r.shape[1]) if lanes else (pad,), f32))
-        return jnp.concatenate(parts, axis=0)
     with device_scope("xpass.t.tail"):
         for br, bv in zip(X.bucket_rows, X.bucket_vals):
             if square:

@@ -566,21 +566,6 @@ def train_glm_grid(
             "streamed mode has no lane-minor grid (every lane would "
             "multiply the per-pass host→device stream); run the sweep "
             "sequentially — each point is a train_glm(ChunkedBatch) solve")
-    if config.kernels is not None:
-        # Pallas-kernel knob threaded per solve (photon_tpu/kernels):
-        # scope the whole grid dispatch, then recurse with the field
-        # cleared so the jit-cache key stays mode-independent.
-        import dataclasses as _dc
-
-        from photon_tpu import kernels as _kernels
-
-        with _kernels.scope(config.kernels):
-            return train_glm_grid(
-                batch, task, _dc.replace(config, kernels=None), reg_weights,
-                mesh=mesh, w0=w0, variance=variance,
-                normalization=normalization, device_results=device_results,
-                prior_mean=prior_mean, prior_precision=prior_precision,
-                prior=prior)
     d = _matrix_dim(batch.X)
     sharded_hybrid = mesh is not None and isinstance(batch.X,
                                                      _SHARDED_TYPES)
@@ -844,13 +829,11 @@ def train_glm_streamed(
         res = minimize_owlqn_streamed(
             obj, data, w0, config.reg.l1_weight(config.reg_weight),
             max_iters=config.max_iters, tolerance=config.tolerance,
-            history=config.history, reg_mask=obj.reg_mask, mesh=mesh,
-            kernels=config.kernels)
+            history=config.history, reg_mask=obj.reg_mask, mesh=mesh)
     else:
         res = minimize_lbfgs_streamed(
             obj, data, w0, max_iters=config.max_iters,
-            tolerance=config.tolerance, history=config.history, mesh=mesh,
-            kernels=config.kernels)
+            tolerance=config.tolerance, history=config.history, mesh=mesh)
     if permuted:
         # Back to original column order (one gather) BEFORE the
         # normalization unfold, as at every permuted boundary.
@@ -917,20 +900,6 @@ def train_glm(
             batch, task, config, w0=w0, prior_mean=prior_mean,
             prior_precision=prior_precision, normalization=normalization,
             mesh=mesh)
-    if config.kernels is not None:
-        # Pallas-kernel knob threaded per solve (photon_tpu/kernels):
-        # scope the whole resident dispatch, then recurse with the field
-        # cleared so the jit-cache key stays mode-independent.
-        import dataclasses as _dc
-
-        from photon_tpu import kernels as _kernels
-
-        with _kernels.scope(config.kernels):
-            return train_glm(
-                batch, task, _dc.replace(config, kernels=None), mesh=mesh,
-                w0=w0, variance=variance, prior_mean=prior_mean,
-                prior_precision=prior_precision, prior=prior,
-                normalization=normalization)
     d = _matrix_dim(batch.X)
     norm = _active_norm(normalization)
     permuted = isinstance(batch.X, _PERMUTED_TYPES)

@@ -28,7 +28,7 @@ Two lowerings of the same math:
   obvious alternative — a 1-D grid over row tiles with auto-pipelining —
   lowers to Mosaic in O(grid²) Python time in this JAX version, minutes for
   billion-row shapes; the manual-DMA kernel lowers in O(1).)
-- interpreter path (tests only, `kernels.interpreted`): small
+- interpreter path (tests only, `interpreted`): small
   auto-pipelined grid, no manual DMA.
 
 Used automatically by Objective(fused=True) for dense, unnormalized batches;
@@ -36,6 +36,7 @@ everything else falls back to the jnp path.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -187,17 +188,39 @@ def _fused_call(task, X, w, y, weights, offsets, interpret):
     return loss[0, 0], grad[0, :]
 
 
+# Whether the kernel runs via Pallas ``interpret=True``: only inside a test
+# harness's `interpreted` block. Everywhere else it compiles for the
+# attached device — or the run fails with the compiler's message; no
+# backend check turns interpretation on.
+_INTERPRETED = False
+
+
+@contextlib.contextmanager
+def interpreted():
+    """TEST HARNESS ONLY (tests/conftest.py): run the kernel in interpret
+    mode for the duration, so CPU tests can pin its arithmetic against
+    the jnp objective. Clears jit caches on entry and exit (the flag is a
+    trace-time fact, not part of jit's cache key)."""
+    global _INTERPRETED
+    before, _INTERPRETED = _INTERPRETED, True
+    if not before:
+        jax.clear_caches()
+    try:
+        yield
+    finally:
+        _INTERPRETED = before
+        if not before:
+            jax.clear_caches()
+
+
 def lowering_available(d: int) -> bool:
     """Whether a lowering of the fused kernel exists here for feature
     width ``d``: the compiled DMA path is a TPU kernel and needs the
     feature dim lane-aligned (Mosaic memref row-slices require the minor
     dim to be a multiple of the 128-lane tile); the interpreter lowering
-    runs only inside a test harness's `kernels.interpreted` block. Any
-    other backend or width takes the jnp objective."""
-    from photon_tpu import kernels
-
-    return kernels.interpret() or (jax.default_backend() == "tpu"
-                                   and d % 128 == 0)
+    runs only inside a test harness's `interpreted` block. Any other
+    backend or width takes the jnp objective."""
+    return _INTERPRETED or (jax.default_backend() == "tpu" and d % 128 == 0)
 
 
 def can_fuse(X) -> bool:
@@ -214,9 +237,6 @@ def fused_value_and_grad(task: TaskType, X, w, y, weights, offsets):
     """(Σᵢ wᵢ·loss(zᵢ, yᵢ), Xᵀ(w∘d1)) — LOCAL sums (caller psums).
 
     Compiled manual-DMA pallas (callers gate on `can_fuse`); the
-    interpreter lowering only inside `kernels.interpreted` (tests).
+    interpreter lowering only inside `interpreted` (tests).
     """
-    from photon_tpu import kernels
-
-    return _fused_call(task, X, w, y, weights, offsets,
-                       kernels.interpret())
+    return _fused_call(task, X, w, y, weights, offsets, _INTERPRETED)

@@ -41,16 +41,6 @@ class OptimizerConfig:
     # Wolfe search vets it as usual (quality pinned by
     # tests/test_lane_solver.py::test_lane_grid_bf16_history_quality).
     lane_history_dtype: str | None = None
-    # Pallas-kernel dispatch for the blocked-ELL X passes
-    # (photon_tpu/kernels): "on" dispatches the kernels (they compile for
-    # the attached device or the solve fails with the compiler's
-    # message), "off" forces the XLA path, "auto" is the XLA path too
-    # while the v5e's compiler refuses them. None (default) inherits
-    # the process-wide PHOTON_TPU_KERNELS env knob. A per-solve value
-    # that FLIPS the effective mode clears jit caches on entry/exit (the
-    # dispatch branch is a trace-time fact) — set the env knob for
-    # steady-state use and this field for explicit A/B.
-    kernels: str | None = None
 
     def effective_optimizer(self) -> OptimizerType:
         """The reference forces OWLQN whenever an L1 term is present."""

@@ -847,7 +847,6 @@ def minimize_lbfgs_streamed(
     max_ls_evals: int = 12,
     mesh=None,
     prefetch=2,
-    kernels=None,
 ) -> OptResult:
     """L-BFGS whose value+gradient accumulate over streamed device chunks —
     the treeAggregate-per-iteration execution regime, same math and same
@@ -864,17 +863,9 @@ def minimize_lbfgs_streamed(
     per solver iteration (loss/grad_norm/step/trials — the live face of
     `OptResult.loss_history`), plus feature-stream / evaluation /
     line-search / margin-cache counters (photon_tpu.telemetry; no-ops
-    without an attached Run).
-
-    ``kernels``: the Pallas-kernel three-state knob ("on"/"off"/"auto";
-    None inherits the PHOTON_TPU_KERNELS env default) scoped over every
-    chunk program of this solve — blocked-ELL chunk ladders then run
-    their X passes through photon_tpu/kernels inside each jitted chunk
-    program."""
-    from photon_tpu import kernels as _kernels
-
+    without an attached Run)."""
     with telemetry.span("solve.lbfgs_streamed", mesh=mesh is not None,
-                        n_chunks=data.n_chunks), _kernels.scope(kernels):
+                        n_chunks=data.n_chunks):
         return _lbfgs_streamed(obj, data, w0, max_iters, tolerance,
                                history, max_ls_evals, mesh, prefetch)
 
@@ -1087,7 +1078,6 @@ def minimize_owlqn_streamed(
     ladder_lanes: int = 8,
     mesh=None,
     prefetch=2,
-    kernels=None,
 ) -> OptResult:
     """OWL-QN over streamed chunks (``prefetch``: int window or an
     `data.ingest_plane.AdaptivePrefetch` controller, as in the streamed
@@ -1101,12 +1091,9 @@ def minimize_owlqn_streamed(
 
     Telemetry mirrors the streamed L-BFGS: live `iteration` events plus
     feature-stream / evaluation / ladder-trial counters from the host
-    driver loop (no-ops without an attached Run). ``kernels`` scopes the
-    Pallas-kernel knob over the solve as in `minimize_lbfgs_streamed`."""
-    from photon_tpu import kernels as _kernels
-
+    driver loop (no-ops without an attached Run)."""
     with telemetry.span("solve.owlqn_streamed", mesh=mesh is not None,
-                        n_chunks=data.n_chunks), _kernels.scope(kernels):
+                        n_chunks=data.n_chunks):
         return _owlqn_streamed(obj, data, w0, l1_weight, max_iters,
                                tolerance, history, max_ls_evals, reg_mask,
                                ladder_lanes, mesh, prefetch)

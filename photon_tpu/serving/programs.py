@@ -94,14 +94,6 @@ def _build_score_fn(coords: tuple, task, output_mean: bool,
     never materialize in HBM; bf16 blocks upcast in registers the same
     way. The cold-miss row dequantizes to exact zeros by construction
     (all-zero rows quantize at scale 1.0).
-
-    An int8 rung routes through the FUSED Pallas serving kernel
-    (`kernels/serving.py`) when the kernels seam is active and the
-    rung's operands fit the VMEM budget — one kernel for the whole
-    margin, bitwise-equal to this body (the branch is trace-time; mode
-    flips clear jit caches via `kernels.scope`, and the AOT key carries
-    the route so a stored export never replays the wrong path). The XLA
-    body below stays the always-available fallback.
     """
     import jax.numpy as jnp
 
@@ -110,15 +102,6 @@ def _build_score_fn(coords: tuple, task, output_mean: bool,
     mean = mean_fn(task)
 
     def score(offsets, shards, ids, fixed_ws, re_cs):
-        if quantize == "int8":
-            from photon_tpu import kernels as K
-            from photon_tpu.kernels import serving as KS
-
-            if K.active() and KS.fused_feasible(offsets, shards, ids,
-                                                fixed_ws, re_cs):
-                margin = KS.fused_int8_margin(coords, offsets, shards,
-                                              ids, fixed_ws, re_cs)
-                return mean(margin) if output_mean else margin
         margin = offsets
         for name, kind, shard in coords:
             if kind == "fixed":
@@ -182,7 +165,6 @@ class ProgramLadder:
         self.quant_report: Optional[dict] = None
         self._qdev = None  # (f32-generation token, quantized device blocks)
         self._qlock = threading.Lock()
-        self._kmark: dict = {}  # (bucket, vmem budget) -> AOT route suffix
         self.store = store
         self.output_mean = bool(output_mean)
         self.model_tag = model_tag
@@ -244,37 +226,10 @@ class ProgramLadder:
         raise AssertionError  # unreachable: checked above
 
     # ------------------------------------------------------------- programs
-    def _kernel_marker(self, bucket: int) -> str:
-        """AOT-key suffix carrying an int8 rung's trace-time kernel
-        route: a stored export replays WITHOUT tracing, so the fused-
-        kernel verdict must be part of the file identity — otherwise a
-        kernels-on export would keep serving after the knob flips off
-        (or vice versa). Feasibility is memoized per (bucket, budget)."""
-        if self.quantize != "int8":
-            return ""
-        from photon_tpu import kernels as K
-
-        if not K.active():
-            return ""
-        from photon_tpu.kernels import serving as KS
-
-        mkey = (int(bucket), K.vmem_budget())
-        with self._qlock:
-            mark = self._kmark.get(mkey)
-        if mark is None:
-            # compute OUTSIDE the lock: example_args re-enters
-            # _quant_blocks, which takes _qlock itself (a duplicate
-            # feasibility probe is cheap; the verdict is deterministic)
-            mark = (":pk" if KS.fused_feasible(*self.example_args(bucket))
-                    else "")
-            with self._qlock:
-                self._kmark[mkey] = mark
-        return mark
-
     def _key(self, bucket: int) -> str:
         tag = (self.model_tag if self.quantize is None
                else f"{self.model_tag}:{self.quantize}")
-        return f"serving/{tag}@B{bucket}{self._kernel_marker(bucket)}"
+        return f"serving/{tag}@B{bucket}"
 
     def _quant_blocks(self) -> tuple:
         """(fixed_ws, re_cs) in this ladder's quantized form, computed
@@ -468,7 +423,7 @@ def _contract_serving_request():
                 "re-quantization) and raises if the rung's dispatch "
                 "signature moves, so a model push never retraces a "
                 "quantized ladder",
-    collectives={}, tags=("serving", "kernels"))
+    collectives={}, tags=("serving",))
 def _contract_serving_quantized_rung():
     ladder = ProgramLadder(_tiny_store(), ladder=(8,),
                            sparse_k={"member": 3}, output_mean=True,

@@ -91,10 +91,7 @@ rounds/configs/survivor_resolves counters and the
 round_model_flops gauge (the modeled FLOPs `profiling.model.estimate_fn`
 priced the round's lane dispatch at, published BEFORE dispatch so a
 budget breach is attributable), with one `tuning.round` span per
-GP-propose/screen/halve/re-solve round — the round-20 tile autotuner's
-`kernels.*` pair — kernels.tile_measures (one per live candidate-tile
-wall-clock) and kernels.tile_cache_hits (one per winner reused from the
-on-disk tile cache without re-measuring; `tuning/tile_tuner.py`) — with the stall-driven prefetch's
+GP-propose/screen/halve/re-solve round — with the stall-driven prefetch's
 stream.prefetch_widened/stream.prefetch_narrowed counters and one
 `prefetch_decision` event per depth verdict beside the existing
 stream.prefetch_depth gauge — and HBM
@@ -426,7 +423,6 @@ TELEMETRY_REGISTRY = {
         "game_e2e.score_stream_rows", "game_e2e.chunked_fit_points",
         "eval.scatter_elems_saved",
         "tuning.rounds", "tuning.configs", "tuning.survivor_resolves",
-        "kernels.tile_measures", "kernels.tile_cache_hits",
     ),
     "gauges": (
         "stream.prefetch_depth", "ingest.workers",

@@ -6,9 +6,6 @@ from photon_tpu.tuning.tuner import TuningResult, tune, tune_glm_reg
 from photon_tpu.tuning.lane_tuner import (
     LaneBudget, LaneTuningResult, RoundBudgetError, tune_glm_reg_lanes,
 )
-from photon_tpu.tuning.tile_tuner import (
-    CANDIDATE_TILES, DEFAULT_TILE, autotune_tiles, tile_for,
-)
 
 __all__ = [
     "GaussianProcess", "fit_gp", "expected_improvement",
@@ -16,5 +13,4 @@ __all__ = [
     "TuningResult", "tune", "tune_glm_reg",
     "LaneBudget", "LaneTuningResult", "RoundBudgetError",
     "tune_glm_reg_lanes",
-    "CANDIDATE_TILES", "DEFAULT_TILE", "autotune_tiles", "tile_for",
 ]

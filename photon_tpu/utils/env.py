@@ -20,22 +20,6 @@ from typing import Optional
 __all__ = ["KNOB_DOCS", "get_raw", "declared"]
 
 KNOB_DOCS = {
-    "PHOTON_TPU_KERNELS": (
-        "Pallas-kernel dispatch for the blocked-ELL X passes: on | off | "
-        "auto (the default: the XLA path while the v5e's compiler refuses "
-        "the kernels). Owner: photon_tpu.kernels "
-        "(mode(); OptimizerConfig.kernels overrides per solve)."),
-    "PHOTON_TPU_KERNELS_VMEM": (
-        "Per-call VMEM byte budget for the single-fused-kernel form; a "
-        "layout whose operands exceed it routes to the grid-tiled forms "
-        "(and past even those, the XLA path). Default 12 MiB, "
-        "unbounded in the tests' interpret mode. Owner: photon_tpu.kernels "
-        "(vmem_budget())."),
-    "PHOTON_TPU_KERNELS_TILE": (
-        "Row-tile override for the grid-tiled kernel forms: a positive "
-        "pow2 multiple of 8 that beats the autotuned/cached per-backend "
-        "choice (tuning/tile_tuner.py). Unset (default) = tuner winner, "
-        "else DEFAULT_TILE. Owner: photon_tpu.kernels (tile_override())."),
     "PHOTON_TPU_PEAK_FLOPS": (
         "Modeled per-chip FLOP/s ceiling for roofline-utilization "
         "denominators (overrides the device_kind table's row). Owner: "

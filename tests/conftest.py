@@ -98,11 +98,11 @@ def _interpret_pallas_kernels():
     """Pallas interpret mode is reachable from tests only: the product
     never interprets a kernel (a dispatched kernel compiles for the
     attached device or fails), so the suite turns the interpreter on for
-    itself. tests/test_chip_compile.py compiles the same kernels for the
+    itself. tests/test_chip_compile.py compiles the same kernel for the
     described v5e with ``interpret=False``."""
-    from photon_tpu import kernels
+    from photon_tpu.ops import fused
 
-    with kernels.interpreted():
+    with fused.interpreted():
         yield
 
 

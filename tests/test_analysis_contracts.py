@@ -24,15 +24,16 @@ _REGISTRY = load_registry()
 
 
 def test_registry_is_broad_enough():
-    """≥ 48 specs (round 19 added the request-tracing off-state pin:
+    """≥ 46 specs (round 19 added the request-tracing off-state pin:
     `serving_trace_off_is_free` — zero extra primitives + zero rung
-    signature drift armed vs disarmed) spanning every workload family."""
-    assert len(_REGISTRY) >= 51
+    signature drift armed vs disarmed; PR 29 took the five Pallas-kernel
+    specs out with the kernels) spanning every workload family."""
+    assert len(_REGISTRY) >= 46
     tags = {t for spec in _REGISTRY.values() for t in spec.tags}
     for family in ("resident", "streamed", "mesh-streamed", "lane", "game",
                    "serving", "checkpoint", "profiling", "sparse",
-                   "evaluation", "continual", "ingest", "kernels",
-                   "tuning", "multihost"):
+                   "evaluation", "continual", "ingest", "tuning",
+                   "multihost"):
         assert family in tags, f"no contract covers the {family} family"
 
 
@@ -67,23 +68,10 @@ def test_serving_trace_off_is_free_spec_is_registered():
 
 
 def test_roofline_closure_specs_are_registered():
-    """The round-15 acceptance pins, strict: the kernel-dispatched X
-    passes forbid the FULL scatter family and require f32 accumulation
-    (the walker descends into the pallas_call body, so the law holds
-    INSIDE the kernel); the two no-retrace invariances (kernel seam,
-    donated ring) and the quantized rung budget ZERO collectives with no
-    transfer/f64 escape hatch."""
-    from photon_tpu.analysis.walker import (SCATTER_ADD_PRIMITIVES,
-                                            SCATTER_PRIMITIVES)
-
-    spec = _REGISTRY["blocked_ell_kernel_x_passes"]
-    assert SCATTER_PRIMITIVES <= spec.forbid
-    assert SCATTER_ADD_PRIMITIVES <= spec.forbid
-    assert spec.require_f32_accum
-    assert not spec.allow_transfers and not spec.allow_f64
-    assert "kernels" in spec.tags
-    for name in ("blocked_ell_kernel_no_retrace",
-                 "mesh_stream_donated_no_retrace",
+    """The round-15 acceptance pins, strict: the donated ring's
+    no-retrace invariance and the quantized rung budget ZERO collectives
+    with no transfer/f64 escape hatch."""
+    for name in ("mesh_stream_donated_no_retrace",
                  "serving_quantized_rung_invariance"):
         spec = _REGISTRY[name]
         assert dict(spec.collectives or {}) == {}, name

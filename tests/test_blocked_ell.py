@@ -700,3 +700,107 @@ def test_bell_chunked_margins_permuted(rng):
     got = np.asarray(chunked_margins(cb.X, w))
     ref = np.asarray(matvec(X, jnp.asarray(w)))
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
+
+
+# --------------------------------- the wide-bucket matrix against float64
+def _wide_bucket_problem(n=51, d=160, d_dense=8, seed=0, bf16=False):
+    """A blocked-ELL layout exercising MANY width buckets: row i carries
+    (i % 18) + 1 tail nnz on top of 2 hot columns, so the pow2 width
+    ladder spans 1/2/4/8/16/32 and n=51 divides nothing. Returns the
+    layout and the dense float64 matrix of the values it STORES (the COO's
+    own for f32 storage, each rounded to bf16 for bf16 storage), rows in
+    the caller's order, columns in model space."""
+    rng = np.random.default_rng(seed)
+    rows_ind, rows_val = [], []
+    kmax = 21
+    for i in range(n):
+        tail = (i % 18) + 1
+        cols = rng.permutation(np.arange(2, d - 1))[:tail]  # distinct
+        ind = np.concatenate([[0, 1], cols, np.zeros(kmax - 2 - tail,
+                                                     np.int64)])
+        val = np.concatenate([rng.normal(size=2 + tail),
+                              np.zeros(kmax - 2 - tail)])
+        rows_ind.append(ind)
+        rows_val.append(val)
+    ind = np.asarray(rows_ind, np.int32)
+    val = np.asarray(rows_val, np.float32)
+    X = to_blocked_ell(SparseRows(ind, val, d), d_dense)
+    stored = val
+    if bf16:
+        bf = jnp.bfloat16
+        X = dataclasses.replace(
+            X, dense=jnp.asarray(X.dense).astype(bf),
+            ell_vals=tuple(jnp.asarray(v).astype(bf) for v in X.ell_vals),
+            bucket_vals=tuple(jnp.asarray(v).astype(bf)
+                              for v in X.bucket_vals))
+        stored = np.asarray(jnp.asarray(val).astype(bf))
+    D = np.zeros((n, d), np.float64)
+    np.add.at(D, (np.repeat(np.arange(n), kmax), ind.ravel()),
+              stored.astype(np.float64).ravel())
+    return X, D
+
+
+@pytest.fixture(scope="module", params=[False, True], ids=["f32", "bf16"])
+def wide_bucket(request):
+    X, D = _wide_bucket_problem(bf16=request.param)
+    assert len(X.ell_vals) >= 4  # widths 1/2/4/8/16…: real coverage
+    assert X.row_order is not None
+    return X, D, request.param
+
+
+@pytest.mark.parametrize("fn", [matvec, layout_matvec, matvec_lanes,
+                                layout_matvec_lanes, rmatvec, rmatvec_lanes,
+                                sq_rmatvec], ids=lambda f: f.__name__)
+def test_wide_bucket_matrix_against_float64(wide_bucket, fn):
+    """Every public X pass of the blocked-ELL layout, over every width
+    bucket the pow2 ladder makes, against a dense float64 numpy product
+    of the SAME stored values built from the COO (no layout code): the
+    forward passes in the caller's order (`matvec`) and in the stored one
+    (`layout_matvec`, through `row_order`), the transposed ones from a
+    stored-order cotangent, scalar and lane-minor, f32 and bf16 storage.
+
+    Tolerance, per output element, c·Σ|x||v| over the terms of its sum.
+    f32 storage: one rounding a product, one an add, ≤ 51 terms (a
+    column's rows) — c = 64·2^-24. bf16 storage multiplies bf16 OPERANDS
+    with f32 accumulation (`_matvec`'s recipe), so the reference rounds
+    the vector to bf16 as the pass does; a bf16 × bf16 product is exact
+    in f32, which leaves the same f32 accumulation and the same c.
+    `sq_rmatvec` over bf16 storage is the exception, in its HOT columns
+    only: the hot block squares in bf16 and takes the cotangent in bf16
+    (two roundings of 2^-8 a term: c = 2^-7 there); its tail squares in
+    f32 and keeps the first c."""
+    X, D, bf16 = wide_bucket
+    n, d = X.shape
+    rng = np.random.default_rng(1)
+    perm = np.asarray(X.perm_cols)
+    order = np.asarray(X.row_order)
+    G = 3
+    op = fn.__name__
+    lanes = op.endswith("_lanes")
+    forward = not op.startswith(("rmatvec", "sq_"))
+    shape = ((d, G) if forward else (n, G)) if lanes \
+        else ((d,) if forward else (n,))
+    v = rng.normal(size=shape).astype(np.float32)   # model / caller space
+
+    def seen(a):
+        """The vector as the pass's products see it."""
+        if bf16 and op != "sq_rmatvec":
+            a = np.asarray(jnp.asarray(a).astype(jnp.bfloat16))
+        return a.astype(np.float64)
+
+    if forward:
+        got = np.asarray(fn(X, jnp.asarray(v[perm])), np.float64)
+        want, mass = D @ seen(v), np.abs(D) @ np.abs(v)
+        if op.startswith("layout_"):
+            want, mass = want[order], mass[order]
+    else:
+        got = np.asarray(fn(X, jnp.asarray(v[order])), np.float64)
+        M = D * D if op == "sq_rmatvec" else D
+        want, mass = (M.T @ seen(v))[perm], (np.abs(M).T @ np.abs(v))[perm]
+    c = np.full(got.shape[:1], 64 * 2.0 ** -24)
+    if bf16 and op == "sq_rmatvec":
+        c[:X.d_sel] = 2.0 ** -7
+    tol = (c * mass.T).T + 1e-30
+    assert got.shape == want.shape
+    err = np.abs(got - want)
+    assert np.all(err <= tol), float(np.max(err / tol))

@@ -1,17 +1,22 @@
-"""Compile the repo's Pallas kernels — and count the mesh evaluation's
-all-reduces — for a DESCRIBED TPU v5e (`v5e:2x2`), with no chip attached.
+"""Compile what the repo runs on the chip — its one Pallas kernel, the
+serving rungs, the blocked-ELL X-pass forms no cell runs — and count the
+mesh evaluation's all-reduces, for a DESCRIBED TPU v5e (`v5e:2x2`), with
+no chip attached.
 
-Every other kernel test runs Pallas interpret mode, which accepts programs
-the chip's compiler refuses. Here each kernel of `photon_tpu/kernels/` and
-`photon_tpu/ops/fused.py` goes through the real TPU compiler at the shapes
-`chip_smoke.py`'s `glm` and `serve` phases really have, without
-``interpret``:
+`photon_tpu/ops/fused.py` (the one Pallas kernel: its other tests run
+Pallas interpret mode, which accepts programs the chip's compiler
+refuses), the serving rung bodies and the second-order X passes go through
+the real TPU compiler at the shapes `chip_smoke.py`'s `glm` and `serve`
+phases really have, without ``interpret``. What compiles is pinned as
+compiling; what the compiler refuses is pinned as refused, message and
+all.
 
-- the ones that compile are pinned as compiling;
-- the ones the compiler refuses are pinned as refused, message and all, and
-  `kernels.active()` keeps ``auto`` off them (PERF.md records the list).
-  When a later PR repairs one, its case here flips from "refused" to
-  "compiles" in the same diff that puts it back into ``auto``.
+A NEW KERNEL STARTS HERE (ROADMAP Reach A2): Mosaic lowers only same-shape
+2-D gathers (an in-vreg `take_along_axis`), so a kernel that gathers from
+a table with arbitrary indices — what every blocked-ELL tail form and the
+fused int8 rung this repo once carried did — is refused ("Only 2D gather
+is supported"). Prove in this file that a form compiles at the `GLM_*`
+shapes BEFORE any dispatch code exists.
 
 A compile that passes is not a chip run: nothing here says anything about
 results or times.
@@ -53,11 +58,6 @@ SERVE_D_FIXED, SERVE_D_RE = 33, 4
 SERVE_USERS, SERVE_ITEMS = 100_000, 50_000
 SERVE_RUNGS = (8, 256)  # smallest and largest rung of the default ladder
 
-# Mosaic's gather rule takes a 2-D operand whose shape equals the indices'
-# and the output's (an in-vreg `take_along_axis`); a table gather is neither
-_GATHER_1D = "Only 2D gather is supported"
-_GATHER_LANES = "Shape mismatch in input, indices and output"
-
 
 @pytest.fixture(scope="module")
 def topo():
@@ -81,15 +81,6 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture
-def compiled_mode(monkeypatch):
-    """The product's mode: conftest turns Pallas interpret mode on for the
-    suite; these tests compile, so turn it back off."""
-    from photon_tpu import kernels as K
-
-    monkeypatch.setattr(K, "_INTERPRETED", False)
 
 
 def _shape(shape, dtype, sharding):
@@ -145,74 +136,16 @@ def test_fused_objective_highest_precision_refused(one_chip):
                        precision="highest")
 
 
-# ------------------------------------------------- kernels/blocked_ell.py
-def _tail_fused(G, s):
-    from photon_tpu.kernels.blocked_ell import _tail_call
-
-    args = [_shape((GLM_N,), "int32", s), _shape(_vec(GLM_U, G), "float32", s)]
-    for shape in GLM_ELL:
-        args += [_shape(shape, "int32", s), _shape(shape, "bfloat16", s)]
-    return _tail_call(len(GLM_ELL), bool(G), False, GLM_N, G), args
-
-
-def _tail_tiled(G, s):
-    from photon_tpu.kernels.blocked_ell import _tiled_tail_call
-
-    r_b, W = GLM_ELL[2]
-    T = 256
-    R = -(-r_b // T) * T
-    return (_tiled_tail_call(W, T, R // T, bool(G), False, GLM_U, G),
-            [_shape(_vec(GLM_U, G), "float32", s),
-             _shape((R, W), "int32", s), _shape((R, W), "bfloat16", s)])
-
-
-def _rmatvec_fused(G, s):
-    from photon_tpu.kernels.blocked_ell import _rmatvec_call
-
-    args = [_shape(_vec(GLM_N, G), "float32", s)]
-    for shape in GLM_BUCKETS:
-        args += [_shape(shape, "int32", s), _shape(shape, "bfloat16", s)]
-    return (_rmatvec_call(len(GLM_BUCKETS), bool(G), False, False, GLM_U, G),
-            args)
-
-
-def _rmatvec_tiled(G, s):
-    from photon_tpu.kernels.blocked_ell import _tiled_rmatvec_call
-
-    c_b, kk = GLM_BUCKETS[7]
-    T = 256
-    C = -(-c_b // T) * T
-    return (_tiled_rmatvec_call(kk, T, C // T, bool(G), False, False,
-                                GLM_N, G),
-            [_shape(_vec(GLM_N, G), "float32", s),
-             _shape((C, kk), "int32", s), _shape((C, kk), "bfloat16", s)])
-
-
-@pytest.mark.parametrize("G", [0, 8], ids=["scalar", "G8"])
-@pytest.mark.parametrize("build", [_tail_fused, _tail_tiled,
-                                   _rmatvec_fused, _rmatvec_tiled],
-                         ids=lambda f: f.__name__.lstrip("_"))
-def test_blocked_ell_kernel_refused(one_chip, build, G):
-    """REFUSED by the v5e's compiler — recorded, not hidden: all four
-    blocked-ELL kernel forms gather from a VMEM-resident table with
-    arbitrary (rows, W) indices (`wt[pc]`, `r[br]`), and Mosaic lowers
-    only same-shape 2-D gathers. A repair needs a different algorithm
-    (DMA gather or one-hot matmul), so `kernels.active()` keeps ``auto``
-    on the XLA path and mode ``on`` surfaces this error."""
-    call, args = build(G, one_chip)
-    exc, msg = ((ValueError, _GATHER_LANES) if G
-                else (NotImplementedError, _GATHER_1D))
-    with pytest.raises(exc, match=msg):
-        _compile(call, *args)
-
-
-# ------------------------------------------------------ kernels/serving.py
+# ---------------------------------------------------- serving/programs.py
+@pytest.mark.parametrize("quantize", [None, "int8", "bf16"],
+                         ids=["f32", "int8", "bf16"])
 @pytest.mark.parametrize("B", SERVE_RUNGS)
-def test_serving_int8_kernel_refused(one_chip, compiled_mode, B):
-    """REFUSED: the fused int8 rung gathers per-entity rows inside the
-    kernel (`q[eids]`), the same unsupported table gather — so an int8
-    ladder's default route on the chip is the XLA rung."""
-    from photon_tpu.kernels.serving import fused_int8_margin
+def test_serving_rung_compiles(one_chip, B, quantize):
+    """The body that actually serves — one XLA program a rung, the int8 /
+    bf16 dequantization inside it — compiles at the serve phase's store,
+    smallest and largest rung of the default ladder."""
+    from photon_tpu.ops.losses import TaskType
+    from photon_tpu.serving.programs import _build_score_fn
 
     s = one_chip
     coords = (("fixed", "fixed", "fixed"), ("per_user", "random", "u_re"),
@@ -222,21 +155,78 @@ def test_serving_int8_kernel_refused(one_chip, compiled_mode, B):
               "i_re": _shape((B, SERVE_D_RE), "float32", s)}
     ids = {"per_user": _shape((B,), "int32", s),
            "per_item": _shape((B,), "int32", s)}
-    fixed_ws = {"fixed": (_shape((SERVE_D_FIXED,), "int8", s),
-                          _shape((), "float32", s))}
-    re_cs = {
-        "per_user": (_shape((SERVE_USERS + 1, SERVE_D_RE), "int8", s),
-                     _shape((SERVE_USERS + 1,), "float32", s)),
-        "per_item": (_shape((SERVE_ITEMS + 1, SERVE_D_RE), "int8", s),
-                     _shape((SERVE_ITEMS + 1,), "float32", s))}
 
-    def fn(offsets, shards, ids, fixed_ws, re_cs):
-        return fused_int8_margin(coords, offsets, shards, ids, fixed_ws,
-                                 re_cs)
+    def block(shape):
+        if quantize == "int8":
+            return (_shape(shape, "int8", s),
+                    _shape(shape[:-1], "float32", s))
+        return _shape(shape, "bfloat16" if quantize else "float32", s)
 
-    with pytest.raises(ValueError, match=_GATHER_LANES):
-        _compile(fn, _shape((B,), "float32", s), shards, ids, fixed_ws,
-                 re_cs)
+    fixed_ws = {"fixed": block((SERVE_D_FIXED,))}
+    re_cs = {"per_user": block((SERVE_USERS + 1, SERVE_D_RE)),
+             "per_item": block((SERVE_ITEMS + 1, SERVE_D_RE))}
+    fn = _build_score_fn(coords, TaskType.LOGISTIC_REGRESSION, True,
+                         quantize=quantize)
+    compiled = _compile(fn, _shape((B,), "float32", s), shards, ids,
+                        fixed_ws, re_cs)
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+# ----------------------- second-order X passes at the glm phase's shapes
+def _glm_batch(s):
+    """The glm phase's batch as shapes: the stored-order blocked-ELL
+    layout `to_blocked_ell` built (module constants), bf16 values."""
+    from photon_tpu.data.dataset import GLMBatch
+    from photon_tpu.data.matrix import BlockedEllRows
+
+    d_sel = 1024
+    rows = _shape((GLM_N,), "float32", s)
+    X = BlockedEllRows(
+        dense=_shape((GLM_N, d_sel), "bfloat16", s),
+        ell_pcols=tuple(_shape(b, "int32", s) for b in GLM_ELL),
+        ell_vals=tuple(_shape(b, "bfloat16", s) for b in GLM_ELL),
+        row_pos=_shape((GLM_N,), "int32", s),
+        bucket_rows=tuple(_shape(b, "int32", s) for b in GLM_BUCKETS),
+        bucket_vals=tuple(_shape(b, "bfloat16", s) for b in GLM_BUCKETS),
+        perm_cols=_shape((GLM_FEATURES,), "int32", s),
+        inv_perm=_shape((GLM_FEATURES,), "int32", s),
+        n_features=GLM_FEATURES, n_prefix=d_sel + GLM_U,
+        last_col_pos=0, tail_nnz=sum(r * w for r, w in GLM_ELL),
+        row_order=_shape((GLM_N,), "int32", s))
+    return GLMBatch(X, rows, rows, rows)
+
+
+@pytest.mark.parametrize("form,G", [("sq_rmatvec", 0), ("sq_rmatvec", 8),
+                                    ("hvp", 8)],
+                         ids=["sq_rmatvec-scalar", "sq_rmatvec-G8", "hvp-G8"])
+def test_second_order_x_pass_compiles(one_chip, form, G):
+    """Forms no benchmark cell runs (PERF.md §7 row 4): the squared
+    transposed pass under the Hessian diagonal, scalar and eight lanes, and
+    lane TRON's Hessian-vector product (`matvec` ∘ curvature weights ∘
+    `rmatvec`, the margin cached) — single passes at 2,097,152 × 10M — fit
+    the chip and compile. The SCALAR Hessian-vector product compiles too
+    (PR 29, by hand) but takes 58 s of a worker alone and 87 s beside five
+    others, so it is not kept here."""
+    from photon_tpu.data.matrix import sq_rmatvec
+    from photon_tpu.ops import lane_objective
+    from photon_tpu.ops.losses import TaskType
+    from photon_tpu.ops.objective import Objective
+
+    s = one_chip
+    batch = _glm_batch(s)
+    obj = Objective(TaskType.LOGISTIC_REGRESSION, l2=0.5)
+    z = _shape(_vec(GLM_N, G), "float32", s)
+    if form == "sq_rmatvec":
+        compiled = _compile(lambda b, r: sq_rmatvec(b.X, r), batch, z)
+    else:
+        l2s = jnp.full((G,), 0.5, jnp.float32)
+        compiled = _compile(
+            lambda b, z, V: lane_objective.hvp_at_margin_lanes(
+                obj, l2s, z, b, V),
+            batch, z, _shape((GLM_FEATURES, G), "float32", s))
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16 * 2 ** 30
 
 
 # --------------------------- gathers per evaluation (stored-order layout)

@@ -834,5 +834,6 @@ def save(path):
         from photon_tpu.__main__ import SUITES
 
         names = [n for n, _ in SUITES]
-        # round 18: + the whole-program concurrency auditor (threads)
-        assert "lint" in names and "threads" in names and len(names) == 13
+        # round 18: + the whole-program concurrency auditor (threads);
+        # PR 29: - the kernels suite, deleted with the kernels
+        assert "lint" in names and "threads" in names and len(names) == 12

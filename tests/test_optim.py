@@ -554,7 +554,7 @@ def test_direction_is_of_the_vector_the_push_was_given(solver, rng,
     l1 = solver.endswith("owlqn")
     cfg = OptimizerConfig(max_iters=4, tolerance=0.0, history=2,
                           reg=reg.l1() if l1 else reg.l2(),
-                          reg_weight=0.05 if l1 else 0.5, kernels="off")
+                          reg_weight=0.05 if l1 else 0.5)
     with jax.disable_jit():
         if solver == "lbfgs":
             minimize_lbfgs(vg, jnp.zeros(5), max_iters=4, tolerance=0.0,

@@ -8,6 +8,8 @@ fixed-effect-only fallback.
 Marked `release_programs`: the ladder compiles one program per rung per
 configuration; teardown drops them (tests/conftest.py).
 """
+import copy
+import dataclasses
 import json
 import os
 import subprocess
@@ -793,6 +795,226 @@ class TestHotSwapConcurrency:
         with pytest.raises(ValueError, match="identically-shaped"):
             store.reload_coefficients(
                 serving.CoefficientStore.from_game_model(small))
+
+
+# ----------------------------------------------------------- quantized rungs
+class TestQuantizedRungs:
+    """The quantized rungs (int8 + row-wise scales, bf16): the warmup
+    accuracy gate REFUSES a breach (`QuantizationRefused`, counted), the
+    cold-miss row dequantizes to exact zeros, mixed-size dispatch never
+    retraces, a hot-swap re-quantizes — and every rung of the default
+    ladder equals a float64 numpy margin over the dequantized blocks."""
+
+    def _ladder(self, quantize=None, eps=0.5, E=32, df=12, dr=6, k=3,
+                **ladder_kw):
+        rng = np.random.default_rng(8)
+        task = TaskType.LOGISTIC_REGRESSION
+        keys = np.asarray(sorted(str(i) for i in range(E)))
+        model = GameModel({
+            "fixed": FixedEffectModel(GeneralizedLinearModel(
+                Coefficients(jnp.asarray(
+                    rng.normal(size=df).astype(np.float32))), task),
+                "global"),
+            "perMember": RandomEffectModel(
+                entity_name="memberId", feature_shard="member", task=task,
+                coefficients=jnp.asarray(
+                    rng.normal(size=(E, dr)).astype(np.float32)),
+                entity_keys=keys,
+                key_to_index={kk: i for i, kk in enumerate(keys.tolist())}),
+        }, task)
+        store = serving.CoefficientStore.from_game_model(model)
+        ladder_kw = {"floor": 8, "max_batch": 16, **ladder_kw}
+        return serving.ProgramLadder(
+            store, sparse_k={"member": k}, quantize=quantize,
+            quant_epsilon=eps, **ladder_kw), (df, dr, k, E)
+
+    def test_epsilon_refusal_and_counter(self):
+        from photon_tpu.serving.programs import QuantizationRefused
+
+        ladder, _ = self._ladder(quantize="int8", eps=1e-9)
+        run = telemetry.start_run("quant_refusal_test")
+        try:
+            with pytest.raises(QuantizationRefused, match="exceeds"):
+                ladder.warmup()
+            assert run.counters.get("serving.quant_refusals", 0) == 1
+        finally:
+            telemetry.finish_run()
+        assert ladder.quant_report["max_abs_diff"] > 0.0
+
+    def test_gate_passes_and_reports(self):
+        ladder, _ = self._ladder(quantize="int8", eps=0.5)
+        assert ladder.warmup() >= 1
+        rep = ladder.quant_report
+        assert rep["mode"] == "int8"
+        assert 0.0 < rep["max_abs_diff"] <= 0.5
+
+    def test_cold_miss_row_bitwise(self):
+        """An unseen entity's quantized score equals the f32 ladder's bit
+        for bit: the all-zero cold-miss row quantizes at scale 1.0 and
+        dequantizes to exact zeros."""
+        ladder, (df, dr, k, E) = self._ladder(quantize="int8")
+        f32, _ = self._ladder(quantize=None)
+        ladder.warmup()
+        f32.warmup()
+        rng = np.random.default_rng(9)
+        off = np.zeros(8, np.float32)
+        shards = {"global": np.zeros((8, df), np.float32),
+                  "member": SparseRows(
+                      rng.integers(0, dr, size=(8, k)).astype(np.int32),
+                      rng.normal(size=(8, k)).astype(np.float32), dr)}
+        ids = {"perMember": np.full(8, E, np.int32)}  # the cold row
+        np.testing.assert_array_equal(
+            np.asarray(f32.score_padded(off, shards, ids)),
+            np.asarray(ladder.score_padded(off, shards, ids)))
+
+    @pytest.mark.parametrize("mode", ["int8", "bf16"])
+    def test_mixed_sizes_never_retrace(self, mode):
+        ladder, (df, dr, k, _E) = self._ladder(quantize=mode)
+        ladder.warmup()
+        rng = np.random.default_rng(10)
+        for B in (8, 16, 8, 16, 8):
+            shards = {"global": rng.normal(size=(B, df)).astype(np.float32),
+                      "member": SparseRows(
+                          rng.integers(0, dr, size=(B, k)).astype(np.int32),
+                          rng.normal(size=(B, k)).astype(np.float32), dr)}
+            ids = {"perMember": np.zeros(B, np.int32)}
+            ladder.score_padded(np.zeros(B, np.float32), shards, ids)
+        assert ladder.assert_no_retrace() <= len(ladder.ladder)
+
+    def test_hot_swap_requantizes(self):
+        """A reload_coefficients swap invalidates the quantized-block
+        cache: the next dispatch scores the NEW model (tracked via a
+        margin that flips sign when every coefficient is negated)."""
+        ladder, (df, dr, k, _E) = self._ladder(quantize="int8")
+        ladder.warmup()
+        rng = np.random.default_rng(11)
+        shards = {"global": rng.normal(size=(8, df)).astype(np.float32),
+                  "member": SparseRows(
+                      np.zeros((8, k), np.int32),
+                      np.zeros((8, k), np.float32), dr)}
+        ids = {"perMember": np.zeros(8, np.int32)}
+        before = np.asarray(ladder.score_padded(
+            np.zeros(8, np.float32), shards, ids))
+        other = copy.copy(ladder.store)
+        neg_fixed = {n: dataclasses.replace(
+            b, weights=-np.asarray(b.weights)) for n, b in
+            ladder.store.fixed.items()}
+        neg_rand = {n: dataclasses.replace(
+            b, coefficients=-np.asarray(b.coefficients)) for n, b in
+            ladder.store.random.items()}
+        other.fixed, other.random = neg_fixed, neg_rand
+        other._device = None
+        ladder.store.reload_coefficients(other)
+        after = np.asarray(ladder.score_padded(
+            np.zeros(8, np.float32), shards, ids))
+        # logistic mean head: negated margins mirror around 0.5
+        np.testing.assert_allclose(np.asarray(before) + np.asarray(after),
+                                   1.0, atol=1e-6)
+
+    def test_hot_swap_invalidates_qdev(self):
+        """A `continual.hot_swap` swings `device_blocks()` to a new
+        generation, which invalidates the ladder's `_qdev` quantized-block
+        cache — the next dispatch re-quantizes and scores the new model
+        (negated coefficients mirror the logistic mean around 0.5),
+        through the same executables (no retrace)."""
+        from photon_tpu.continual import hot_swap
+
+        ladder, (df, dr, k, _E) = self._ladder(quantize="int8")
+        ladder.warmup()
+        rng = np.random.default_rng(31)
+        off = np.zeros(8, np.float32)
+        shards = {"global": rng.normal(size=(8, df)).astype(np.float32),
+                  "member": SparseRows(
+                      rng.integers(0, dr, size=(8, k)).astype(np.int32),
+                      rng.normal(size=(8, k)).astype(np.float32), dr)}
+        ids = {"perMember": np.zeros(8, np.int32)}
+        before = np.asarray(ladder.score_padded(off, shards, ids))
+        token_before = ladder._qdev[0]
+        other = copy.copy(ladder.store)
+        other.fixed = {n: dataclasses.replace(
+            b, weights=-np.asarray(b.weights))
+            for n, b in ladder.store.fixed.items()}
+        other.random = {n: dataclasses.replace(
+            b, coefficients=-np.asarray(b.coefficients))
+            for n, b in ladder.store.random.items()}
+        other._device = None
+        hot_swap(ladder.store, other, probe=None, root=None)
+        after = np.asarray(ladder.score_padded(off, shards, ids))
+        assert ladder._qdev[0] is not token_before  # cache turned over
+        np.testing.assert_allclose(before + after, 1.0, atol=1e-6)
+        assert ladder.assert_no_retrace() <= len(ladder.ladder)
+
+    @pytest.mark.parametrize("B", [8, 16, 32, 64, 128, 256])
+    @pytest.mark.parametrize("mode", ["int8", "bf16"])
+    def test_rung_matches_float64_reference(self, mode, B):
+        """Rung by rung over the default ladder 8…256: the margin a
+        quantized rung returns is the float64 numpy margin over the
+        DEQUANTIZED blocks (q·scale, or bf16 → f64), half of the batch
+        scoring unseen entities (the zero row E).
+
+        Tolerance: the reference reads the very blocks the rung reads, so
+        what separates them is f32 arithmetic alone — one rounding for
+        the dequantizing product, one per multiply, one per add of a
+        ≤ (df + k)-term sum: per row (df + k + 2)·2^-23·Σ|x||w|. And the
+        blocks themselves sit where quantization says: within half a
+        step (scale / 2) of the f32 store, within 2^-8 relative for
+        bf16."""
+        ladder, (df, dr, k, E) = self._ladder(
+            quantize=mode, max_batch=256, output_mean=False)
+        assert ladder.ladder == (8, 16, 32, 64, 128, 256)
+        rng = np.random.default_rng(100 + B)
+        off = rng.normal(size=B).astype(np.float32)
+        xg = rng.normal(size=(B, df)).astype(np.float32)
+        ind = rng.integers(0, dr, size=(B, k)).astype(np.int32)
+        val = rng.normal(size=(B, k)).astype(np.float32)
+        eid = rng.integers(0, E, size=B).astype(np.int32)
+        eid[::2] = E  # unseen: the cold-miss row
+        got = np.asarray(ladder.score_padded(
+            off, {"global": xg, "member": SparseRows(ind, val, dr)},
+            {"perMember": eid}), np.float64)
+
+        fixed_q, re_q = ladder._quant_blocks()
+        w32 = np.asarray(ladder.store.fixed["fixed"].weights, np.float64)
+        c32 = np.asarray(ladder.store.random["perMember"].coefficients,
+                         np.float64)
+        if mode == "int8":
+            q, s = fixed_q["fixed"]
+            w = np.asarray(q, np.float64) * float(s)
+            q, s = re_q["perMember"]
+            s = np.asarray(s, np.float64)
+            C = np.asarray(q, np.float64) * s[:, None]
+            assert np.all(np.abs(w - w32) <= 0.5 * float(fixed_q["fixed"][1])
+                          * (1 + 1e-6))
+            assert np.all(np.abs(C - c32) <= 0.5 * s[:, None] * (1 + 1e-6))
+        else:
+            w = np.asarray(fixed_q["fixed"]).astype(np.float64)
+            C = np.asarray(re_q["perMember"]).astype(np.float64)
+            assert np.all(np.abs(w - w32) <= 2.0 ** -8 * np.abs(w32))
+            assert np.all(np.abs(C - c32) <= 2.0 ** -8 * np.abs(c32))
+        assert not C[E].any()  # the zero row survives quantization
+        rows = C[eid]                                        # (B, dr)
+        picked = np.take_along_axis(rows, ind.astype(np.int64), axis=1)
+        want = (off.astype(np.float64) + xg.astype(np.float64) @ w
+                + np.sum(val.astype(np.float64) * picked, axis=1))
+        mass = (np.abs(off) + np.abs(xg).astype(np.float64) @ np.abs(w)
+                + np.sum(np.abs(val) * np.abs(picked), axis=1))
+        tol = (df + k + 2) * 2.0 ** -23 * mass
+        assert np.all(np.abs(got - want) <= tol), \
+            float(np.max(np.abs(got - want) / tol))
+
+    @pytest.mark.parametrize("quantize,keys", [
+        (None, ["serving/m@B8", "serving/m@B256"]),
+        ("int8", ["serving/m:int8@B8", "serving/m:int8@B256"]),
+        ("bf16", ["serving/m:bf16@B8", "serving/m:bf16@B256"])],
+        ids=["f32", "int8", "bf16"])
+    def test_aot_key_is_stable(self, quantize, keys):
+        """The AOT store's file identity, as LITERAL strings: an export
+        stored by an earlier checkout is found by this one (the key never
+        carried anything but the model tag, the quantization and the
+        rung)."""
+        ladder, _ = self._ladder(quantize=quantize, max_batch=256,
+                                 model_tag="m")
+        assert [ladder._key(b) for b in (8, 256)] == keys
 
 
 def test_selftest_cli_end_to_end():
