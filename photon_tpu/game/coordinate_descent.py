@@ -457,7 +457,7 @@ def coordinate_descent(
                                  and prior is None and not streamed
                                  else None)
                         if fused is not None:
-                            fn, blocks_args, objs, lam = fused
+                            fn, blocks_args, plan, objs, lam = fused
                             ds = coord.dataset
                             E, d = ds.n_entities, ds.dim
                             # the update writes the table in place (the
@@ -476,13 +476,17 @@ def coordinate_descent(
                             (coeffs, variances, margin, objective, st,
                              values) = fn(
                                 coeffs0, base, others, objs, lam,
-                                blocks_args, ds.X,
-                                jnp.asarray(ds.entity_dense), y, weights)
+                                blocks_args, plan, y, weights)
                             # the ONE dispatch solved every block of the
                             # coordinate (the pipelined loop in
                             # RandomEffectCoordinate.train counts its own)
                             telemetry.count("game_re.blocks",
                                             len(blocks_args))
+                            # where the update read its margins from
+                            telemetry.count("game_re.block_scored_rows",
+                                            plan.n_block_rows)
+                            telemetry.count("game_re.table_scored_rows",
+                                            plan.n_table_rows)
                             telemetry.count_device(
                                 "game_re.row_iterations", st[3])
                             telemetry.count_device(

@@ -20,15 +20,22 @@ On TPU the same structure becomes dense batched tensors:
 
 The reference's active/passive split (`numActiveDataPointsUpperBound`,
 RandomEffectDataset.activeData/passiveData) maps to `active_cap`: each
-entity's first `active_cap` rows (after an optional shuffle) are trained on;
-all rows — active and passive — are scored via the flat per-row layout kept
-alongside the blocks.
+entity's first `active_cap` rows (after an optional shuffle) are trained on
+and live in the blocks; the flat per-row layout kept alongside covers every
+row. Who scores what: the one-dispatch update
+(`random_effect.fused_update_program`) scores an ACTIVE row from its bucket
+— the block times the bucket's fresh solution — and reads the (E, d) table
+only for the PASSIVE rows (`RandomEffectDataset.scoring_plan`); every other
+scorer (the block loop's `RandomEffectCoordinate.score`, validation,
+`RandomEffectModel.score`, serving) scores all its rows, active and
+passive, from the table via the flat layout.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -201,6 +208,10 @@ class REBlock:
 
     m: int  # rows per entity (power of two)
     entity_index: np.ndarray  # (E,) dense entity ids (host)
+    # (E,) rows each entity holds here (host): slots j < active_rows[e] of
+    # `row_index` / `X` are its active rows — whatever their weight — and
+    # the rest padding
+    active_rows: np.ndarray
     row_index: jnp.ndarray  # (E, m) int32 original row positions (clamped for padding)
     y: jnp.ndarray  # (E, m)
     weights: jnp.ndarray  # (E, m); 0 marks padding
@@ -214,6 +225,11 @@ class REBlock:
     @property
     def n_entities(self) -> int:
         return int(self.entity_index.shape[0])
+
+    @property
+    def held(self) -> np.ndarray:
+        """(E, m) bool, host: the slots that hold a row (the rest pad)."""
+        return np.arange(self.m)[None, :] < self.active_rows[:, None]
 
 
 def _project_dense(Xd: np.ndarray, icpt, width: int) -> tuple:
@@ -378,12 +394,38 @@ def plan_buckets(rows: np.ndarray, widths: np.ndarray, slot_bytes: int,
     return BucketPlan(buckets, real, padded)
 
 
+class ScoringPlan(NamedTuple):
+    """Where the one-dispatch update reads each training row's margin.
+
+    The update lays its buckets' block margins `X_b · w_b`, flattened
+    (E_b · m,) and concatenated in block order, in front of the table
+    margins of the passive sub-shard; ``slot_of_row[i]`` is row i's place in
+    that vector. A row a block holds (slot j < its entity's `active_rows`)
+    points at its slot, never at a padding slot; every other row — beyond
+    the cap, or of an entity dropped for carrying no weight (dense id E) —
+    points at Σ_b E_b·m + its rank among such rows."""
+
+    slot_of_row: jax.Array   # (n,) int32
+    X_passive: Matrix        # the passive rows of the flat shard, in rank order
+    ids_passive: jax.Array   # (n_passive,) int32 their dense entity ids
+
+    @property
+    def n_table_rows(self) -> int:
+        return int(self.ids_passive.shape[0])
+
+    @property
+    def n_block_rows(self) -> int:
+        return int(self.slot_of_row.shape[0]) - self.n_table_rows
+
+
 @dataclasses.dataclass(frozen=True)
 class RandomEffectDataset:
     """Entity-bucketed random-effect data (reference: RandomEffectDataset).
 
     `blocks` hold the active training rows; `entity_dense` + the shard give
-    the flat per-row view used for scoring (covers passive rows too).
+    the flat per-row view every table scorer reads (all rows, passive too).
+    The one-dispatch update scores the active rows from the blocks and only
+    the rest from the table: `scoring_plan`.
     """
 
     entity_name: str
@@ -414,6 +456,26 @@ class RandomEffectDataset:
     @property
     def dim(self) -> int:
         return _shard_dim(self.X)
+
+    @functools.cached_property
+    def scoring_plan(self) -> ScoringPlan:
+        """The `ScoringPlan` of these blocks, built and placed on the device
+        once a dataset (every coordinate over it shares the one copy of the
+        passive sub-shard)."""
+        slot = np.full(self.entity_dense.shape[0], -1, np.int64)
+        base = 0
+        for block in self.blocks:
+            at = np.nonzero(block.held.reshape(-1))[0]
+            slot[np.asarray(block.row_index).reshape(-1)[at]] = base + at
+            base += block.n_entities * block.m
+        passive = np.nonzero(slot < 0)[0]
+        slot[passive] = base + np.arange(passive.shape[0])
+        rows = jnp.asarray(passive.astype(np.int32))
+        X = self.X
+        X_passive = (SparseRows(X.indices[rows], X.values[rows], X.n_features)
+                     if isinstance(X, SparseRows) else X[rows])
+        return ScoringPlan(jnp.asarray(slot.astype(np.int32)), X_passive,
+                           jnp.asarray(self.entity_dense[passive]))
 
     @staticmethod
     def build(
@@ -584,6 +646,7 @@ class RandomEffectDataset:
                 REBlock(
                     m=m,
                     entity_index=ents.astype(np.int32),
+                    active_rows=active_counts[ents].astype(np.int32),
                     row_index=jnp.asarray(row_idx.astype(np.int32)),
                     y=jnp.asarray(yb),
                     weights=jnp.asarray(wb),

@@ -641,10 +641,17 @@ class RandomEffectCoordinate:
         is chosen by what the dataset carries: a RANDOM projection (its
         dense matrix lives on the host) keeps the block loop.
 
-        Returns (fn, blocks_args, objs, lam) — call
-        ``fn(coeffs, base, scores_tuple, objs, lam, blocks_args, X,
-        dense_ids, y, weights)``, which DONATES ``coeffs`` (the table is
-        updated in place: pass a buffer nothing else reads) →
+        The margins come from where a row's features already lie: a row a
+        bucket holds is one element of `X_b · w_b`, the bucket's block
+        times the solution its solve has just returned (the same forward
+        pass, the same space: what this method's gates guarantee); only the
+        rows no block holds read the updated table, and one (n,) gather
+        lays both over the rows (`dataset.ScoringPlan`).
+
+        Returns (fn, blocks_args, plan, objs, lam) — call
+        ``fn(coeffs, base, scores_tuple, objs, lam, blocks_args, plan, y,
+        weights)``, which DONATES ``coeffs`` (the table is updated in
+        place: pass a buffer nothing else reads) →
         (coeffs', variances', margins, objective, (n_conv, n_fail,
         n_iters, row_iters, block_steps, moved_row_iters, ls_trials),
         values) — `row_iters` / `block_steps` the update's work: Σ
@@ -701,7 +708,8 @@ class RandomEffectCoordinate:
             objs.append(self._block_objective(
                 block.dim if block.dim is not None else d))
         out = (_fused_re_fn(fns, tuple(meta), self.task, self.variance),
-               tuple(blocks_args), tuple(objs), _l1_lam(self.config))
+               tuple(blocks_args), ds.scoring_plan, tuple(objs),
+               _l1_lam(self.config))
         self._fused_cache = out
         return out
 
@@ -744,8 +752,8 @@ def _fused_re_fn(solver_fns, meta: tuple, task, variance):
         return fn
     raw_fn = solver_fns[1]
 
-    def run(coeffs, base, scores, objs, lam, blocks_args, X, dense_ids,
-            y, weights):
+    def run(coeffs, base, scores, objs, lam, blocks_args, plan, y, weights):
+        from photon_tpu.data.matrix import layout_matvec
         from photon_tpu.game.model import score_entities
         from photon_tpu.game.scoring import _sum_scores
         from photon_tpu.ops.losses import loss_fns
@@ -761,6 +769,7 @@ def _fused_re_fn(solver_fns, meta: tuple, task, variance):
                      else None)
         conv = fail = iters = row_iters = steps = moved = trials = 0
         values = jnp.zeros((coeffs.shape[0],), jnp.float32)
+        scored = []  # the buckets' (E_b · m,) block margins, then the table's
         for (row_index, ents, cols, batch_base), (chunk, e_real), obj in \
                 zip(blocks_args, meta, objs):
             at = (ents,) if cols is None else (ents[:, None], cols)
@@ -770,6 +779,11 @@ def _fused_re_fn(solver_fns, meta: tuple, task, variance):
             with telemetry.device_scope("game_re.solve"):
                 res, var = _solve_lanes(raw_fn, (obj, lam), (batch, w0),
                                         chunk, e_real)
+            with telemetry.device_scope("game_re.score"):
+                # the rows this bucket holds, by the forward pass its
+                # objective runs, at the solution in the block's own space
+                scored.append(jax.vmap(layout_matvec)(
+                    batch_base.X, res.w).reshape(-1))
             with telemetry.device_scope("game_re.scatter"):
                 if cols is not None:  # columns outside the map go to 0
                     coeffs = coeffs.at[ents].set(0.0)
@@ -791,7 +805,12 @@ def _fused_re_fn(solver_fns, meta: tuple, task, variance):
             if res.evaluations is not None:
                 trials += jnp.sum(res.evaluations)
             values = values.at[ents].set(res.value)
-        margins = score_entities(X, coeffs, dense_ids, exact=True)
+        slot_of_row, X_passive, ids_passive = plan
+        if ids_passive.shape[0]:  # rows no block holds: the updated table
+            scored.append(score_entities(X_passive, coeffs, ids_passive,
+                                         exact=True))
+        with telemetry.device_scope("game_re.score"):
+            margins = jnp.concatenate(scored)[slot_of_row]
         with telemetry.device_scope("game.objective"):
             objective = jnp.sum(weights * loss(offs + margins, y))
         return coeffs, variances, margins, objective, (

@@ -41,7 +41,10 @@ rows × solver iterations taken), moved_row_iterations (the part of it
 whose iteration lowered its lane's loss: the rest repeated a point),
 iterations / linesearch_trials (Σ over entities of solver iterations and
 of line-search evaluations) and block_steps (Σ over blocks of the
-block's lock-step iterations: the largest over its lanes), beside the
+block's lock-step iterations: the largest over its lanes), and per
+update on the host block_scored_rows / table_scored_rows (the training
+rows whose margin came from a bucket's block pass, and from the table
+gather: together the rows × the updates), beside the
 fixed effect's game_fixed.row_iterations (rows × iterations taken);
 the pod-scale GAME composition's `game_e2e.*` family —
 streamed_fixed_updates/host_offset_sums/objective_chunks counters from
@@ -125,7 +128,8 @@ coordinate-descent update — game_re.gather (offsets laid into a bucket's
 rows, warm starts read from the (E, d) table through the bucket's index
 map), game_re.solve (the bucket's vmapped per-entity solves: the L-BFGS
 and X-pass scopes nest under it), game_re.scatter (results written back
-to the table), game_re.score (per-row margins from the table),
+to the table), game_re.score (per-row margins: block passes for the rows
+the buckets hold, the table gather for the rest, one reassembly gather),
 game_fixed.solve (the fixed effect's solve, same nesting) and
 game.objective (offsets sum and the tracking objective). The resident
 solves report the `solver.*` pair iterations / linesearch_trials through
@@ -416,6 +420,7 @@ TELEMETRY_REGISTRY = {
         "game_re.row_iterations", "game_re.block_steps",
         "game_re.moved_row_iterations", "game_re.iterations",
         "game_re.linesearch_trials",
+        "game_re.block_scored_rows", "game_re.table_scored_rows",
         "game_fixed.row_iterations",
         "game_e2e.pod_scale_runs", "game_e2e.streamed_fixed_updates",
         "game_e2e.objective_chunks",

@@ -367,12 +367,11 @@ def test_descent_scopes_reach_the_compiled_updates():
                                    projection=INDEX_MAP)
     coord = RandomEffectCoordinate(ds, TASK, OptimizerConfig(
         max_iters=3, tolerance=0.0, reg=reg.l2(), reg_weight=L2))
-    fn, blocks_args, objs, lam = coord.fused_update_program()
+    fn, blocks_args, plan, objs, lam = coord.fused_update_program()
     n = data.n
     zeros = jnp.zeros((n,), jnp.float32)
     text = fn.lower(jnp.zeros((ds.n_entities, ds.dim), jnp.float32), zeros,
-                    (zeros,), objs, lam, blocks_args, ds.X,
-                    jnp.asarray(ds.entity_dense), zeros,
+                    (zeros,), objs, lam, blocks_args, plan, zeros,
                     zeros).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', text)
     for scope in ("game_re.gather", "game_re.solve", "game_re.scatter",
