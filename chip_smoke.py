@@ -791,7 +791,8 @@ def mesh_glm(seed: int, sz: dict, ctx: MeshCtx, clock: CompileClock) -> None:
         del one
         sharded = cast_features(shard_blocked_ell_batch(
             make_batch(SparseRows(ind, va, bench.S_FEATURES), y), ctx.n_dev,
-            d_dense=bench.S_DENSE, device_dense_dtype=jnp.bfloat16))
+            d_dense=bench.S_DENSE, device_dense_dtype=jnp.bfloat16,
+            mesh=ctx.mesh))
         placed, _, _ = _sharded_prep(
             sharded, jnp.zeros((bench.S_FEATURES,), jnp.float32), ctx.mesh)
         jax.block_until_ready(placed)

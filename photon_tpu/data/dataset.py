@@ -188,21 +188,25 @@ def shard_permuted_batch(batch: GLMBatch, n_shards: int,
 
 def shard_blocked_ell_batch(batch: GLMBatch, n_shards: int,
                             d_dense: int = 1024,
-                            device_dense_dtype=None) -> GLMBatch:
+                            device_dense_dtype=None,
+                            mesh=None) -> GLMBatch:
     """Pad a sparse batch to the mesh and re-lay its X as
     ShardedBlockedEllRows (data.matrix.shard_blocked_ell): the mesh-ready
     form of the blocked-ELL layout — each device gets its own ELL row
     buckets + occurrence buckets under one global column permutation, so
     the sharded solve compiles to one all-reduce and zero scatters of any
     kind (models/training's `sharded_blocked_ell_value_and_grad`
-    contract)."""
+    contract). A device-built hot block (`device_dense_dtype`) needs the
+    ``mesh`` the solve will run on: it is built shard by shard, each on
+    the device that keeps it (no device ever holds the whole block)."""
     from photon_tpu.parallel.mesh import pad_to_multiple
 
     if not isinstance(batch.X, SparseRows):
         raise TypeError("shard_blocked_ell_batch expects SparseRows")
     batch = pad_batch(batch, pad_to_multiple(batch.n, n_shards))
     return batch._replace(X=shard_blocked_ell(
-        batch.X, n_shards, d_dense, device_dense_dtype=device_dense_dtype))
+        batch.X, n_shards, d_dense, device_dense_dtype=device_dense_dtype,
+        mesh=mesh))
 
 
 def with_offsets(batch: GLMBatch, offsets) -> GLMBatch:

@@ -23,6 +23,7 @@ import numpy as np
 
 # named device scopes (op metadata only): a profiler trace reads the X
 # pass's share of a compiled solve by these names
+from photon_tpu import telemetry
 from photon_tpu.telemetry import device_scope
 
 
@@ -673,29 +674,69 @@ def _hot_positions(X: SparseRows, d_dense: int):
     return ind, val, sel, col_to_pos[ind]
 
 
-def _hot_cold_split(X: SparseRows, d_dense: int, device_dense_dtype):
+def _dense_on_devices(hot, pos, val, d_sel, dtype, mesh):
+    """The (n, d_sel) hot block built on the device(s) that KEEP it: one
+    `_dense_scatter_chunked` per keeping device over that device's own
+    row range, from the compact hot COO of that range alone. With no mesh
+    (the one-device builders) the default device keeps every row and the
+    result is a plain device array; with a mesh, slot j of
+    `flat_mesh_devices` keeps rows [j·n/D, (j+1)·n/D), THIS process builds
+    the slots its own devices own (`local_row_slots`: on several hosts
+    each builds its own rows and touches no other's) and the pieces are
+    assembled, without a copy, into ONE array already sharded as
+    `models.training._hybrid_specs` shards a hot block — so no device ever
+    holds more than its own shard plus one scatter chunk, and a later
+    `device_put` to that sharding moves nothing."""
+    n, k = hot.shape
+    from photon_tpu.parallel.mesh import (data_sharding, flat_mesh_devices,
+                                          local_row_slots)
+
+    if mesh is None:
+        devices, slots = [None], [0]
+    else:
+        devices, slots = flat_mesh_devices(mesh), local_row_slots(mesh)
+    n_loc = n // len(devices)
+    local_rows = np.repeat(np.arange(n_loc, dtype=np.int32), k).reshape(
+        n_loc, k)
+    parts = []
+    for j in slots:
+        rows_j = slice(j * n_loc, (j + 1) * n_loc)
+        h = hot[rows_j]
+        with jax.default_device(devices[j]):
+            parts.append(_dense_scatter_chunked(
+                local_rows[h], pos[rows_j][h].astype(np.int32),
+                val[rows_j][h].astype(np.float32), n_loc, d_sel, dtype))
+    if mesh is None:
+        return parts[0]
+    return jax.make_array_from_single_device_arrays(
+        (n, d_sel), data_sharding(mesh), parts)
+
+
+def _hot_cold_split(X: SparseRows, d_dense: int, device_dense_dtype,
+                    mesh=None):
     """Shared front half of the hybrid builders: pick the `d_dense` most
     frequent columns, build the (n, d_sel) hot block (on device when
-    `device_dense_dtype` is set, else host chunked-bincount), and extract
+    `device_dense_dtype` is set — on the devices of ``mesh`` that keep its
+    rows when one is handed in — else host chunked-bincount), and extract
     the cold nnz as flat row-major COO. Returns
     (dense, sel, t_rows, t_cols, t_vals) with t_* exact-size (possibly
     empty) int64/f32 host arrays."""
-    return _split_at(*_hot_positions(X, d_dense), device_dense_dtype)
+    return _split_at(*_hot_positions(X, d_dense), device_dense_dtype, mesh)
 
 
-def _split_at(ind, val, sel, pos, device_dense_dtype):
+def _split_at(ind, val, sel, pos, device_dense_dtype, mesh=None):
     """`_hot_cold_split` from `_hot_positions`' output on: a builder that
     stores its rows in another order permutes the four row-wise in
-    between."""
+    between. No (n, k) row-id array is made: the hot COO's row ids are
+    local to each keeping device's (or each host chunk's) row range, and
+    the tail's follow from the flat positions."""
     n, k = ind.shape
     d_sel = sel.shape[0]
     nnz_mask = val != 0.0
     hot = (pos >= 0) & nnz_mask
-    rows = np.repeat(np.arange(n), k).reshape(n, k)
     if device_dense_dtype is not None:
-        dense = _dense_scatter_chunked(
-            rows[hot].astype(np.int32), pos[hot].astype(np.int32),
-            val[hot].astype(np.float32), n, d_sel, device_dense_dtype)
+        dense = _dense_on_devices(hot, pos, val, d_sel, device_dense_dtype,
+                                  mesh)
     else:
         # bincount over flat (row, pos) ids: C-speed accumulation —
         # np.add.at is an order of magnitude slower at the 10M-feature
@@ -706,15 +747,15 @@ def _split_at(ind, val, sel, pos, device_dense_dtype):
         for r0 in range(0, n, row_chunk):
             r1 = min(n, r0 + row_chunk)
             h = hot[r0:r1]
-            flat_ids = ((rows[r0:r1][h] - r0) * np.int64(d_sel)
+            flat_ids = (np.nonzero(h)[0] * np.int64(d_sel)
                         + pos[r0:r1][h])
             dense[r0:r1] = np.bincount(
                 flat_ids, weights=val[r0:r1][h].astype(np.float64),
                 minlength=(r1 - r0) * d_sel,
             ).astype(np.float32).reshape(r1 - r0, d_sel)
     cold = (~hot) & nnz_mask
-    flat = cold.reshape(-1)           # row-major → tail rows ascending
-    t_rows = rows.reshape(-1)[flat]
+    flat = np.flatnonzero(cold)       # row-major → tail rows ascending
+    t_rows = flat // k
     t_cols = ind.reshape(-1)[flat]
     t_vals = val.reshape(-1)[flat].astype(np.float32)
     return dense, sel, t_rows, t_cols, t_vals
@@ -1025,11 +1066,22 @@ def blocked_ell_from_scipy_csr(csr, d_dense: int = 1024,
 
 
 def shard_blocked_ell(X: SparseRows, n_shards: int, d_dense: int = 1024,
-                      device_dense_dtype=None) -> ShardedBlockedEllRows:
+                      device_dense_dtype=None,
+                      mesh=None) -> ShardedBlockedEllRows:
     """Build the SHARDED blocked-ELL hybrid (see ShardedBlockedEllRows)
     from padded COO rows. Rows must already divide ``n_shards``
     (`data.dataset.shard_blocked_ell_batch` pads + builds; the streamed
     chunk ladder rides the same builder with S = n_chunks).
+
+    ``mesh`` says WHERE a device-built hot block (`device_dense_dtype`)
+    lives, and a device build does not go without it: each device of the
+    mesh builds the rows it keeps (`_dense_on_devices`) and ``dense``
+    comes back row-sharded over it, one addressable shard a device — at
+    8.4M × 1024 in bf16 the whole block is 17 GB, more than a chip holds,
+    and there is no second route that assembles it on one. A host-built
+    block (`device_dense_dtype=None`: the streamed chunk ladder's, with
+    S = n_chunks × D) takes no mesh. Every other leaf is host numpy
+    either way, and no leaf's value depends on the mesh.
 
     One vectorized host pass mirroring `shard_permuted_hybrid`: a GLOBAL
     column permutation (hot prefix from global frequencies, tail ranks by
@@ -1044,10 +1096,49 @@ def shard_blocked_ell(X: SparseRows, n_shards: int, d_dense: int = 1024,
         raise ValueError(
             f"{n} rows do not divide {n_shards} shards; pad the batch first "
             "(data.dataset.shard_blocked_ell_batch)")
+    if device_dense_dtype is not None and mesh is None:
+        raise ValueError(
+            "a device-built hot block of a sharded layout is built on the "
+            "devices that keep its shards: hand in the mesh "
+            "(shard_blocked_ell_batch(..., mesh=mesh)), or build it on the "
+            "host (device_dense_dtype=None)")
+    if mesh is not None and int(mesh.devices.size) != n_shards:
+        raise ValueError(
+            f"{n_shards} shards cannot live on a mesh of "
+            f"{int(mesh.devices.size)} devices: a mesh keeps one shard a "
+            "device")
+    with telemetry.span("layout.shard_build", shards=n_shards, rows=n):
+        return _shard_blocked_ell(X, n_shards, d_dense, device_dense_dtype,
+                                  mesh)
+
+
+def _count_shard_bytes(S, ell_rows_own, ladder, cs_counts, bucket_rows):
+    """Counters ``layout.shard_bytes_real`` / ``layout.shard_bytes_padded``:
+    the bytes (an int32 id and an f32 value a slot) of the shards' ELL and
+    occurrence buckets laid out each to its OWN shapes — its own rows a
+    width, its own power-of-two count a column — and padded to the common
+    shapes every shard shares (r_b = the most rows any shard has at a
+    width, a column's bucket from its MAX-LOCAL count)."""
+    slot = 4 + 4
+    own_occ = np.where(cs_counts > 0,
+                       1 << _bucket_exponents(cs_counts), 0).sum()
+    real = sum(int(r) << int(ev) for own in ell_rows_own
+               for ev, r in own.items()) + int(own_occ)
+    padded = S * (sum(r_b << ev for ev, r_b in ladder)
+                  + sum(int(b.shape[1]) * int(b.shape[2])
+                        for b in bucket_rows))
+    telemetry.count("layout.shard_bytes_real", real * slot)
+    telemetry.count("layout.shard_bytes_padded", padded * slot)
+
+
+def _shard_blocked_ell(X, n_shards, d_dense, device_dense_dtype, mesh):
+    """`shard_blocked_ell` after its checks, inside its span."""
+    n = np.asarray(X.indices).shape[0]
+    d = X.n_features
     n_local = n // n_shards
     d_sel = min(d_dense, d)
     dense, sel, t_rows, t_cols, t_vals = _hot_cold_split(
-        X, d_dense, device_dense_dtype)
+        X, d_dense, device_dense_dtype, mesh)
     t_vals = t_vals.astype(np.float32)
     m_tot = t_rows.size
     S = n_shards
@@ -1091,12 +1182,16 @@ def shard_blocked_ell(X: SparseRows, n_shards: int, d_dense: int = 1024,
         counts_s = np.diff(rbs)
         shard_layouts.append((counts_s, _row_exponents(counts_s),
                               rbs[:-1].astype(np.int64)))
+    ell_rows_own = [
+        {int(ev): int((e_row_s == ev).sum())
+         for ev in np.unique(e_row_s[e_row_s >= 0])}
+        for _, e_row_s, _ in shard_layouts]
     widths: dict[int, int] = {}
-    for counts_s, e_row_s, _ in shard_layouts:
-        for ev in np.unique(e_row_s[e_row_s >= 0]):
-            r_b = int((e_row_s == ev).sum())
-            widths[int(ev)] = max(widths.get(int(ev), 0), r_b)
+    for own in ell_rows_own:
+        for ev, r_b in own.items():
+            widths[ev] = max(widths.get(ev, 0), r_b)
     ladder = sorted(widths.items())
+    _count_shard_bytes(S, ell_rows_own, ladder, cs_counts, bucket_rows)
     pcol_rel = (pcol.astype(np.int64) - d_sel).astype(np.int32)
     per_shard = [_fill_ell(ladder, counts_s, e_row_s, starts_s, pcol_rel,
                            t_vals)

@@ -340,19 +340,30 @@ def _init_w0(d, w0, norm, allow_lanes=False):
     return jnp.asarray(w0)
 
 
-def _sharded_prep(batch: GLMBatch, w0, mesh: Mesh):
-    """Shard-count check + device placement + psum axis name for a
-    ShardedHybridRows solve (shared by train_glm and train_glm_grid)."""
+def place_sharded_batch(batch: GLMBatch, mesh: Mesh) -> GLMBatch:
+    """A sharded-layout batch (`data.dataset.shard_*_batch`) placed on
+    ``mesh`` as the sharded solves read it: every per-shard leaf's axis 0
+    over the mesh, the column permutation replicated. A caller that
+    solves the same batch again and again places it once; the solves'
+    own placement then finds every leaf where it belongs and moves
+    nothing — as it finds a hot block that `shard_blocked_ell` built on
+    the mesh's devices."""
     if batch.X.n_shards != mesh.devices.size:
         raise ValueError(
             f"ShardedHybridRows has {batch.X.n_shards} shards but the mesh "
             f"has {mesh.devices.size} devices; rebuild with "
             "data.dataset.shard_hybrid_batch(batch, mesh.devices.size)")
-    axes = tuple(mesh.axis_names)
-    batch = jax.device_put(
-        batch, _hybrid_specs(batch.X, axes,
+    return jax.device_put(
+        batch, _hybrid_specs(batch.X, tuple(mesh.axis_names),
                              wrap=lambda s: NamedSharding(mesh, s)))
+
+
+def _sharded_prep(batch: GLMBatch, w0, mesh: Mesh):
+    """Shard-count check + device placement + psum axis name for a
+    ShardedHybridRows solve (shared by train_glm and train_glm_grid)."""
+    batch = place_sharded_batch(batch, mesh)
     w0 = jax.device_put(w0, replicated(mesh))
+    axes = tuple(mesh.axis_names)
     return batch, w0, (axes[0] if len(axes) == 1 else axes)
 
 
@@ -391,6 +402,19 @@ def _count_solve(res: OptResult) -> None:
     if res.evaluations is not None:
         telemetry.count_device("solver.linesearch_trials", res.evaluations,
                                reduce="max")
+
+
+def _count_mesh_psum(res: OptResult, d: int) -> None:
+    """`mesh.psum_bytes` of one sharded L-BFGS solve: the f32 gradient's
+    bytes (static from its shape) × its gradient all-reduces, one at the
+    start and one an iteration. The iteration count stays on the device,
+    as `_count_solve`'s do, and is multiplied on the host when the report
+    is read (``scale``): a product made on the device would be one more
+    dispatch a solve, and a program built inside a traced window."""
+    gradient = 4.0 * d
+    telemetry.count("mesh.psum_bytes", gradient)
+    telemetry.count_device("mesh.psum_bytes", res.iterations, reduce="max",
+                           scale=gradient)
 
 
 def _lane_solve(obj, batch, w0, l2s, l1s, config):
@@ -975,6 +999,8 @@ def train_glm(
             res, var = _train_run_sharded(batch, w0, obj, _l1_lam(config),
                                           _static_config(config), variance,
                                           mesh)
+        if config.effective_optimizer() is OptimizerType.LBFGS:
+            _count_mesh_psum(res, d)
     elif mesh is not None:
         batch, w0 = _mesh_prep(batch, w0, mesh)
     elif (obj.fused

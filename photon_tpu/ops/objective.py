@@ -79,7 +79,8 @@ class Objective:
     def _psum(self, x):
         if self.axis_name is None:
             return x
-        return lax.psum(x, self.axis_name)
+        with device_scope("mesh.psum"):
+            return lax.psum(x, self.axis_name)
 
     def _psum_many(self, *xs):
         """One all-reduce for several partial sums (skipping Nones).
@@ -90,8 +91,9 @@ class Objective:
         compiled all-reduce count)."""
         if self.axis_name is None:
             return xs
-        present = lax.psum(tuple(x for x in xs if x is not None),
-                           self.axis_name)
+        with device_scope("mesh.psum"):
+            present = lax.psum(tuple(x for x in xs if x is not None),
+                               self.axis_name)
         it = iter(present)
         return tuple(None if x is None else next(it) for x in xs)
 

@@ -46,6 +46,16 @@ update on the host block_scored_rows / table_scored_rows (the training
 rows whose margin came from a bucket's block pass, and from the table
 gather: together the rows × the updates), beside the
 fixed effect's game_fixed.row_iterations (rows × iterations taken);
+the sharded layout build's `layout.*` pair — shard_bytes_real /
+shard_bytes_padded (`data.matrix.shard_blocked_ell`: the bytes of the
+shards' ELL and occurrence buckets each laid out to its own shapes, and
+padded to the common shapes every shard shares), with one
+`layout.shard_build` span a build — and the mesh solve's mesh.psum_bytes
+(`models.training.train_glm(mesh=)`: the payload bytes of the gradient
+all-reduces of one sharded L-BFGS solve — the f32 gradient's bytes,
+static from its shape, × (iterations + 1), the iterations read from the
+result through `count_device`; the lane sweep, OWL-QN and TRON on a mesh
+are not counted: no cell or test reads them yet);
 the pod-scale GAME composition's `game_e2e.*` family —
 streamed_fixed_updates/host_offset_sums/objective_chunks counters from
 the descent loop's host-margin-cache exchange,
@@ -131,7 +141,10 @@ and X-pass scopes nest under it), game_re.scatter (results written back
 to the table), game_re.score (per-row margins: block passes for the rows
 the buckets hold, the table gather for the rest, one reassembly gather),
 game_fixed.solve (the fixed effect's solve, same nesting) and
-game.objective (offsets sum and the tracking objective). The resident
+game.objective (offsets sum and the tracking objective); and mesh.psum
+(the objective's all-reduces over the mesh axis — `Objective._psum` /
+`_psum_many`, so the scalar and the lane objective alike — entered only
+where an axis name is set: a one-device solve traces none). The resident
 solves report the `solver.*` pair iterations / linesearch_trials through
 `count_device` — a counter whose value is still a device array: the
 attached `Run` keeps the reference and resolves every pending array in
@@ -295,15 +308,17 @@ def count(name: str, value: float = 1.0) -> None:
         r.count(name, value)
 
 
-def count_device(name: str, value, reduce: str = "sum") -> None:
+def count_device(name: str, value, reduce: str = "sum",
+                 scale: float = 1.0) -> None:
     """`count` for a value that is still a DEVICE array, reduced over its
-    elements by ``reduce`` ("sum" or "max") on the host. The run keeps the
-    reference and reads every pending array back in one `device_get` when
-    a report is asked for — never here, so the dispatch that produced
-    ``value`` stays asynchronous."""
+    elements by ``reduce`` ("sum" or "max") on the host and multiplied
+    there by ``scale``. The run keeps the reference and reads every
+    pending array back in one `device_get` when a report is asked for —
+    never here, so the dispatch that produced ``value`` stays
+    asynchronous."""
     r = _CURRENT
     if r is not None:
-        r.count_device(name, value, reduce)
+        r.count_device(name, value, reduce, scale)
 
 
 def gauge(name: str, value) -> None:
@@ -422,6 +437,8 @@ TELEMETRY_REGISTRY = {
         "game_re.linesearch_trials",
         "game_re.block_scored_rows", "game_re.table_scored_rows",
         "game_fixed.row_iterations",
+        "layout.shard_bytes_real", "layout.shard_bytes_padded",
+        "mesh.psum_bytes",
         "game_e2e.pod_scale_runs", "game_e2e.streamed_fixed_updates",
         "game_e2e.objective_chunks",
         "game_e2e.host_offset_sums", "game_e2e.score_stream_chunks",
@@ -442,7 +459,7 @@ TELEMETRY_REGISTRY = {
     "span_families": (
         "train", "score", "ingest", "solve",
         "game", "game_re", "serving", "checkpoint", "continual",
-        "tuning", "parallel",
+        "tuning", "parallel", "layout",
     ),
     "device_scopes": (
         "xpass.fwd", "xpass.fwd.hot", "xpass.fwd.tail",
@@ -454,6 +471,7 @@ TELEMETRY_REGISTRY = {
         "solve.prologue", "solve.epilogue",
         "game_re.gather", "game_re.solve", "game_re.scatter",
         "game_re.score", "game_fixed.solve", "game.objective",
+        "mesh.psum",
     ),
 }
 DEVICE_SCOPES = TELEMETRY_REGISTRY["device_scopes"]

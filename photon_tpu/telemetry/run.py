@@ -147,7 +147,7 @@ class Run:
         self.spans: list[Span] = []
         self.counters: dict[str, float] = {}
         # (name, device array, reduce) of count_device, not yet read back
-        self._pending_device: list[tuple[str, Any, str]] = []
+        self._pending_device: list[tuple[str, Any, str, float]] = []
         self.gauges: dict[str, Any] = {}
         self.iterations: list[dict] = []
         self._iter_cap = int(keep_iterations)
@@ -211,16 +211,18 @@ class Run:
         with self._lock:
             self.counters[name] = self.counters.get(name, 0.0) + value
 
-    def count_device(self, name: str, value, reduce: str = "sum") -> None:
+    def count_device(self, name: str, value, reduce: str = "sum",
+                     scale: float = 1.0) -> None:
         """A counter bump whose value is still on the device: kept by
-        reference, read back and reduced over its elements ("sum" or
-        "max") by `_resolve_device_counts` when a report is asked for. No
-        transfer and no dispatch happens here."""
+        reference, read back, reduced over its elements ("sum" or "max")
+        and multiplied by the host number ``scale`` by
+        `_resolve_device_counts` when a report is asked for. No transfer
+        and no dispatch happens here."""
         if reduce not in ("sum", "max"):
             raise ValueError(f"count_device: reduce is 'sum' or 'max', "
                              f"not {reduce!r}")
         with self._lock:
-            self._pending_device.append((name, value, reduce))
+            self._pending_device.append((name, value, reduce, scale))
 
     def _resolve_device_counts(self) -> None:
         """ONE `device_get` for every pending `count_device` array."""
@@ -231,10 +233,10 @@ class Run:
         import jax
         import numpy as np
 
-        values = jax.device_get([v for _, v, _ in pending])
-        for (name, _, reduce), v in zip(pending, values):
-            self.count(name, float(np.max(v) if reduce == "max"
-                                   else np.sum(v)))
+        values = jax.device_get([p[1] for p in pending])
+        for (name, _, reduce, scale), v in zip(pending, values):
+            self.count(name, scale * float(np.max(v) if reduce == "max"
+                                           else np.sum(v)))
 
     def gauge(self, name: str, value) -> None:
         with self._lock:

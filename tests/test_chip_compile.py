@@ -53,6 +53,19 @@ GLM_BUCKETS = ((380559, 1), (58680, 2), (37970, 4), (23819, 8),
                (15253, 16), (9252, 32), (5701, 64), (3441, 128),
                (2104, 256), (1290, 512), (781, 1024), (208, 2048))
 GLM_FEATURES = 10_000_000
+# The SHARDED blocked-ELL layout of the benchmark's four-chip cell
+# (`glm-sparse10m-mesh4.single`: 4 shards of 2^21 rows) as
+# `shard_blocked_ell` laid it in this sandbox from `gen/sparse_mesh.py`'s
+# draw (the shapes follow from the fixed pattern, so every seed has them):
+# per-shard common shapes, (r_b, W) ELL buckets and (c_b, k) occurrence
+# buckets. Against the one-chip layout above the count-1 occurrence bucket
+# is three times as long: a column's bucket comes from its MAX-LOCAL count.
+MESH4_SHARDS, MESH4_N = 4, 1 << 23
+MESH4_PREFIX = 1421599
+MESH4_ELL = ((683602, 1), (560351, 2), (410696, 4), (43782, 8), (44, 16))
+MESH4_BUCKETS = ((1130732, 1), (131102, 2), (71318, 4), (38804, 8),
+                 (21190, 16), (11900, 32), (6811, 64), (3966, 128),
+                 (2320, 256), (1373, 512), (828, 1024), (231, 2048))
 # the serve phase's store: the flagship GAME model (benches/_flagship_data)
 SERVE_D_FIXED, SERVE_D_RE = 33, 4
 SERVE_USERS, SERVE_ITEMS = 100_000, 50_000
@@ -337,6 +350,66 @@ def test_mesh_value_and_grad_is_one_all_reduce(topo):
         obj)
     compiled = _compile(vg, obj_shapes, shapes, _shape((d,), "float32", rep))
     assert _all_reduces(compiled) == 1
+
+
+def test_mesh4_sharded_solve_compiles(topo):
+    """The whole sharded solve of `glm-sparse10m-mesh4.single` —
+    `_train_run_sharded`, 40 L-BFGS iterations over 4 × 2,097,152 rows ×
+    10M features in bf16 — compiles for the four described chips and fits
+    one: three all-reduces in the program (the first evaluation's pair,
+    the gradient an iteration, the line search's two scalars), each under
+    `mesh.psum`."""
+    from photon_tpu.data.dataset import GLMBatch
+    from photon_tpu.data.matrix import ShardedBlockedEllRows
+    from photon_tpu.models.training import (_static_config,
+                                            _train_run_sharded,
+                                            make_objective)
+    from photon_tpu.models.variance import VarianceComputationType
+    from photon_tpu.ops.losses import TaskType
+    from photon_tpu.optim.config import OptimizerConfig
+    from photon_tpu.optim.regularization import l2
+
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    dat, rep = NamedSharding(mesh, P(("data",))), NamedSharding(mesh, P())
+    S, n, d = MESH4_SHARDS, MESH4_N, GLM_FEATURES
+
+    def per_shard(buckets, dtype):
+        return tuple(_shape((S, r, w), dtype, dat) for r, w in buckets)
+
+    X = ShardedBlockedEllRows(
+        dense=_shape((n, 1024), "bfloat16", dat),
+        ell_pcols=per_shard(MESH4_ELL, "int32"),
+        ell_vals=per_shard(MESH4_ELL, "bfloat16"),
+        row_pos=_shape((S, n // S), "int32", dat),
+        bucket_rows=per_shard(MESH4_BUCKETS, "int32"),
+        bucket_vals=per_shard(MESH4_BUCKETS, "bfloat16"),
+        perm_cols=_shape((d,), "int32", rep),
+        inv_perm=_shape((d,), "int32", rep),
+        n_features=d, n_prefix=MESH4_PREFIX, last_col_pos=1023,
+        tail_nnz=13509184)
+    rows = _shape((n,), "float32", dat)
+    cfg = OptimizerConfig(max_iters=40, tolerance=0.0, reg=l2(),
+                          reg_weight=1e-3, history=5)
+    obj = make_objective(TaskType.LOGISTIC_REGRESSION, cfg, d,
+                         axis_name="data", intercept_index=1023)
+    obj_shapes = jax.tree_util.tree_map(
+        lambda leaf: _shape(np.shape(leaf), jnp.asarray(leaf).dtype, rep),
+        obj)
+    with jax.default_matmul_precision("default"):
+        compiled = _train_run_sharded.lower(
+            GLMBatch(X, rows, rows, rows), _shape((d,), "float32", rep),
+            obj_shapes, None, _static_config(cfg),
+            VarianceComputationType.NONE, mesh).compile()
+    reduces = [ln for ln in compiled.as_text().splitlines()
+               if " all-reduce(" in ln or " all-reduce-start(" in ln]
+    assert len(reduces) == 3
+    for ln in reduces:
+        op_name = re.search(r'op_name="([^"]*)"', ln).group(1)
+        assert "mesh.psum" in op_name.split("/"), op_name
+    mem = compiled.memory_analysis()
+    on_device = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                 + mem.output_size_in_bytes)
+    assert 4.3e9 < on_device < 0.6 * 16 * 2 ** 30, on_device
 
 
 @pytest.mark.parametrize("gshape,expected", [

@@ -32,7 +32,11 @@ LOGISTIC = TaskType.LOGISTIC_REGRESSION
 XPASS = {s for s in telemetry.DEVICE_SCOPES if s.startswith("xpass.")}
 # the scopes of a GLM solve; the coordinate-descent phases (`game*`) wrap
 # whole updates and are pinned by tests/test_glmix_wide.py
-EVERY = {s for s in telemetry.DEVICE_SCOPES if not s.startswith("game")}
+# — and `mesh.psum` is entered only where an objective has an axis name:
+# no one-device program carries it (tests/test_mesh_cell.py pins where it is)
+MESH = {"mesh.psum"}
+EVERY = {s for s in telemetry.DEVICE_SCOPES
+         if not s.startswith("game")} - MESH
 
 
 def _cfg(**kw):
@@ -121,7 +125,7 @@ def test_scopes_reach_the_compiled_program(build, expected, absent):
             if any(s in part for name in op_names
                    for part in name.split("/"))}
     assert expected <= seen, sorted(expected - seen)
-    assert not (absent & seen), sorted(absent & seen)
+    assert not ((absent | MESH) & seen), sorted((absent | MESH) & seen)
     if not {"lbfgs.push"} <= expected:  # a bare evaluation: no solver phase
         assert not {s for s in seen if s.startswith(("lbfgs.", "solve."))}
 
