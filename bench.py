@@ -165,7 +165,7 @@ def sparse_batch(ind, va, y):
     # triples (12 B/hot nnz) instead of the materialized 4.3 GB bf16 block
     # (~5x fewer bytes). Tail/scalars still cast bf16 on host first
     # (cast_features), then one device_put. BlockedEllRows keeps both X
-    # passes scatter-free AND scan-free: the tail matvec is pow2-width ELL
+    # passes scatter-free AND scan-free: the tail matvec is ladder-width ELL
     # row buckets (gather + dense einsum, bf16 multiply / f32 accumulate)
     # instead of a full-tail cumsum — the layout exists to avoid TPU
     # scatter-adds and scans (not measured on the current chip — PERF.md).
@@ -174,8 +174,8 @@ def sparse_batch(ind, va, y):
                        device_dense_dtype=jnp.bfloat16)
     total_nnz = n * (k + 1)
     stats = {
-        # hot/tail split + pow2 pad waste of the blocked-ELL tail: layout
-        # facts (not wall-clocks) that make the sparse legs' cost model
+        # hot/tail split + the width ladder's pad waste in the blocked-ELL
+        # tail: layout facts (not wall-clocks) that make the sparse legs' cost model
         # auditable from the JSON line alone.
         "sparse10m_tail_pad_waste": round(float(H.tail_pad_waste), 4),
         "sparse10m_tail_nnz_frac": round(H.tail_nnz / total_nnz, 4),

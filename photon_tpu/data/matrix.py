@@ -265,9 +265,10 @@ class ShardedPermutedHybridRows:
     absent shards carry zero-padded slots). Per-device bucket work is
     therefore ~the single-device cost, not 1/S of it; the layout wins
     where the hot block + lane-stacked scatters dominate (the measured
-    regime for reg sweeps) and the bucket exponent uses MAX-LOCAL
-    occurrence counts, so per-shard padding stays ≤2× per present
-    column + one slot per absent shard.
+    regime for reg sweeps) and a column's bucket comes from its
+    MAX-LOCAL occurrence count, so per-shard padding stays under a third
+    of the slots of the column's fullest shard + one slot per absent
+    shard.
 
     Works in two views like ShardedHybridRows: global (plain jit; ops
     vmap the shard axis) and local (inside shard_map via `local()`).
@@ -349,9 +350,11 @@ class BlockedEllRows:
     a `row_bounds` boundary pass — a log-depth scan over every tail nnz,
     per X pass, per line-search direction. This layout replaces it with
     the classic blocked-ELL form: rows are bucketed by tail-nnz into a
-    small set of power-of-two widths (`next_pow2` ladder), each bucket is
-    a dense (r_b, W_b) pair of permuted-column-id / value matrices, and
-    the tail matvec is per bucket ONE gather of w plus ONE
+    small set of widths — the rungs 1, 2, 3, 4, 6, 8, 12, 16, 24, … of
+    the one width ladder (`_width_rungs`: every power of two and 3·2^(j−1)
+    between 2^j and 2^(j+1)), a row in the smallest rung that holds it —
+    each bucket is a dense (r_b, W_b) pair of permuted-column-id / value
+    matrices, and the tail matvec is per bucket ONE gather of w plus ONE
     `einsum("rw,rw->r")` — a dense contraction XLA maps straight onto the
     vector/matrix units, f32 accumulation pinned by
     ``preferred_element_type``. Zero combining scatters, zero `.at[].set`
@@ -395,7 +398,8 @@ class BlockedEllRows:
     boundary (models/training, models/glm).
 
     Padding slots carry (column 0, value 0) so they contribute exactly
-    0·w[0]; `tail_pad_waste` reports the pow2 slot overhead.
+    0·w[0]; `tail_pad_waste` reports the ladder's slot overhead (under a
+    third of a bucket; the occurrence buckets ride the same ladder).
     """
 
     dense: jax.Array | np.ndarray       # (n, d_sel) hot block, stored rows
@@ -452,7 +456,9 @@ class BlockedEllRows:
 
     @property
     def tail_pad_waste(self) -> float:
-        """Fraction of ELL slots that are pow2 padding (0.0 = none)."""
+        """ELL padding slots per real tail nonzero, slots ÷ nnz − 1 (0.0 =
+        none): the width ladder's rounding and, in a shard or chunk view,
+        the shared ladder's padding rows."""
         slots = self.ell_slots
         return (slots / self.tail_nnz - 1.0) if self.tail_nnz else 0.0
 
@@ -479,7 +485,7 @@ class ShardedBlockedEllRows:
 
     Every per-shard structure is padded to a COMMON shape across shards
     (shard axis leading): the ELL width ladder is the union of per-shard
-    exponents with r_b = the max per-shard row count, occurrence buckets
+    rungs with r_b = the max per-shard row count, occurrence buckets
     use MAX-LOCAL counts exactly as ShardedPermutedHybridRows, and
     `row_pos` is (S, n_local) with LOCAL concat positions. Sharding every
     data leaf's axis 0 over the mesh gives each device a complete
@@ -802,13 +808,31 @@ def to_hybrid(X: SparseRows, d_dense: int = 1024,
     )
 
 
-def _bucket_exponents(counts: np.ndarray) -> np.ndarray:
-    """pow2 bucket exponent per count (0 for counts ≤ 1; f64 log2 is exact
-    at powers of two well past any realistic count)."""
-    e = np.zeros(counts.shape, np.int64)
-    big = counts > 1
-    e[big] = np.ceil(np.log2(counts[big].astype(np.float64))).astype(np.int64)
-    return e
+def _width_rungs(counts: np.ndarray) -> np.ndarray:
+    """Rung of the ONE width ladder both tail structures are bucketed by —
+    widths 1, 2, 3, 4, 6, 8, 12, 16, 24, …: every power of two and 3·2^(j−1)
+    between 2^j and 2^(j+1) — per count: the smallest rung whose width
+    (`_rung_width`) is ≥ the count (rung 0 for counts ≤ 1). Monotone in the
+    count, so a sort by rung is a sort by width, and a bucket is under a
+    third padding. Integer arithmetic (the count's bit length via `frexp`,
+    exact below 2^53)."""
+    c = np.asarray(counts).astype(np.int64)
+    rung = np.zeros(c.shape, np.int64)
+    big = c > 1
+    cb = c[big]
+    j = np.frexp((cb - 1).astype(np.float64))[1].astype(np.int64)
+    # 2^(j-1) < c ≤ 2^j: the rung of 2^j is 2j − 1; from j = 2 on the rung
+    # below it, 2j − 2, is 3·2^(j−2)
+    between = (j >= 2) & (cb <= 3 * (np.int64(1) << np.maximum(j - 2, 0)))
+    rung[big] = 2 * j - 1 - between
+    return rung
+
+
+def _rung_width(rung):
+    """Width of a rung of `_width_rungs`' ladder — 1, 2, 3, 4, 6, 8, 12, … —
+    elementwise (int64)."""
+    above = np.maximum(np.asarray(rung, np.int64) - 1, 0)
+    return np.where(np.asarray(rung) == 0, 1, (2 + above % 2) << (above // 2))
 
 
 def _column_perm(sel, u_cols, order, d):
@@ -833,11 +857,11 @@ def _occurrence_buckets(t_rows, t_vals, pcol, d_sel, e, order, u_counts):
     counts_by_rank = u_counts[order]
     col_offsets = np.concatenate([[0], np.cumsum(counts_by_rank)])
     pos_within = np.arange(m) - col_offsets[rank_per]
-    es = e[order]                      # exponent per rank, ascending
+    es = e[order]                      # rung per rank, ascending
     bucket_rows, bucket_vals = [], []
     for e_v in np.unique(es):
         r0, r1 = np.searchsorted(es, [e_v, e_v + 1])
-        c_b, k_b = int(r1 - r0), 1 << int(e_v)
+        c_b, k_b = int(r1 - r0), int(_rung_width(e_v))
         lo, hi = int(col_offsets[r0]), int(col_offsets[r1])
         br = np.zeros((c_b, k_b), np.int32)
         bv = np.zeros((c_b, k_b), np.float32)
@@ -863,11 +887,11 @@ def _sharded_occurrence_buckets(loc_rows, t_vals, rank_nnz, s_ids, S, e,
     offsets_rs = np.concatenate([[0], np.cumsum(counts_rs)])
     pos_within = np.arange(m_tot) - offsets_rs[rs_key]
     rank_sorted = rank_nnz[nnz_order]
-    es = e[order]                      # exponent per rank, ascending
+    es = e[order]                      # rung per rank, ascending
     bucket_rows, bucket_vals = [], []
     for e_v in np.unique(es):
         r0, r1 = np.searchsorted(es, [e_v, e_v + 1])
-        c_b, k_b = int(r1 - r0), 1 << int(e_v)
+        c_b, k_b = int(r1 - r0), int(_rung_width(e_v))
         lo, hi = np.searchsorted(rank_sorted, [r0, r1])
         br = np.zeros((S, c_b, k_b), np.int32)
         bv = np.zeros((S, c_b, k_b), np.float32)
@@ -882,15 +906,27 @@ def _sharded_occurrence_buckets(loc_rows, t_vals, rank_nnz, s_ids, S, e,
     return tuple(bucket_rows), tuple(bucket_vals)
 
 
-def _row_exponents(counts: np.ndarray) -> np.ndarray:
-    """ELL width-bucket exponent per row tail-nnz count (-1 = no tail)."""
-    e = np.where(counts > 0, _bucket_exponents(counts), -1)
-    return e.astype(np.int64)
+def _row_rungs(counts: np.ndarray) -> np.ndarray:
+    """ELL width-bucket rung per row tail-nnz count (-1 = no tail)."""
+    return np.where(counts > 0, _width_rungs(counts), -1)
+
+
+def _count_tail_slots(tail_nnz, ell_vals, bucket_vals):
+    """Counters ``layout.tail_nnz`` / ``layout.ell_slots`` /
+    ``layout.occ_slots`` of one blocked-ELL build: the tail's real
+    nonzeros, and the slots the ELL row buckets and the occurrence buckets
+    hold for them — what the forward and the transposed tail gather read
+    an evaluation, summed over the shards of a sharded build. Padding is
+    slots ÷ nnz − 1. A layout with no tail counts nothing."""
+    telemetry.count("layout.tail_nnz", tail_nnz)
+    telemetry.count("layout.ell_slots", sum(int(v.size) for v in ell_vals))
+    telemetry.count("layout.occ_slots",
+                    sum(int(v.size) for v in bucket_vals))
 
 
 def _fill_ell(widths, counts, e_row, starts, pcol, vals):
     """One shard's ELL row buckets over a shared ``widths`` ladder of
-    (exponent, r_b) pairs. ``starts``: per-row offset of the row's slice
+    (rung, r_b) pairs. ``starts``: per-row offset of the row's slice
     in the (global) flat row-major tail arrays. Returns
     ([(r_b, W_b) pcols], [(r_b, W_b) vals], row_pos) where row_pos maps
     each row to its position in the bucket concatenation (rows with no
@@ -901,7 +937,7 @@ def _fill_ell(widths, counts, e_row, starts, pcol, vals):
     out_c, out_v = [], []
     base = 0
     for e_v, r_b in widths:
-        w_b = 1 << e_v
+        w_b = int(_rung_width(e_v))
         rows_b = np.flatnonzero(e_row == e_v)
         pc = np.zeros((r_b, w_b), np.int32)
         pv = np.zeros((r_b, w_b), np.float32)
@@ -926,12 +962,14 @@ def to_permuted_hybrid(X: SparseRows, d_dense: int = 1024,
 
     One vectorized host pass: pick the `d_dense` most frequent columns as
     the hot block (relabeled to prefix positions [0, d_sel)), group the
-    distinct tail columns by power-of-two occurrence bucket (relabeled to
-    [d_sel, P) in bucket order — the order rmatvec's concatenation
-    produces), and lay the tail twice: row-major flat (matvec's cumsum
-    reduction) and column-major padded per bucket (rmatvec's gather+reduce;
-    pow-2 padding wastes ≤2× on multi-occurrence columns, none on the
-    count-1 majority). `device_dense_dtype` builds the dense block on
+    distinct tail columns by occurrence bucket — a column's count rounded
+    up to a rung of the width ladder 1, 2, 3, 4, 6, 8, 12, …
+    (`_width_rungs`); relabeled to [d_sel, P) in bucket order, the order
+    rmatvec's concatenation produces — and lay the tail twice:
+    row-major flat (matvec's cumsum reduction) and column-major padded per
+    bucket (rmatvec's gather+reduce; the ladder's padding is under a third
+    of a bucket on multi-occurrence columns, none on the count-1
+    majority). `device_dense_dtype` builds the dense block on
     device from compact COO triples as `to_hybrid` does.
     """
     n = np.asarray(X.indices).shape[0]
@@ -960,7 +998,7 @@ def to_permuted_hybrid(X: SparseRows, d_dense: int = 1024,
     u_cols, inv, u_counts = np.unique(t_cols, return_inverse=True,
                                       return_counts=True)
     U = u_cols.size
-    e = _bucket_exponents(u_counts)
+    e = _width_rungs(u_counts)
     order = np.lexsort((u_cols, e))   # bucket-major, col-id within bucket
     rank = np.empty(U, np.int64)
     rank[order] = np.arange(U)
@@ -987,7 +1025,7 @@ def to_blocked_ell(X: SparseRows, d_dense: int = 1024,
     One vectorized host pass sharing the hot/cold split and the permuted
     column machinery with `to_permuted_hybrid`, plus the ELL side: once
     the hot columns are chosen each row's tail nnz is counted and the rows
-    are put in the stable order (width exponent ascending, tail-free rows
+    are put in the stable order (width rung ascending, tail-free rows
     last, original row id within a bucket) BEFORE anything is laid, so the
     hot block, the ELL buckets (each a contiguous row range, filled
     row-major from the flat tail) and the occurrence buckets' row ids all
@@ -1000,7 +1038,7 @@ def to_blocked_ell(X: SparseRows, d_dense: int = 1024,
     ind, val, sel, pos = _hot_positions(X, d_dense)
     n = ind.shape[0]
     counts = ((pos < 0) & (val != 0.0)).sum(axis=1)     # tail nnz per row
-    e_row = _row_exponents(counts)
+    e_row = _row_rungs(counts)
     # int8 keys: numpy's stable sort of them is a radix sort
     row_order = np.argsort(np.where(e_row < 0, 127, e_row).astype(np.int8),
                            kind="stable").astype(np.int32)
@@ -1026,7 +1064,7 @@ def to_blocked_ell(X: SparseRows, d_dense: int = 1024,
     u_cols, inv, u_counts = np.unique(t_cols, return_inverse=True,
                                       return_counts=True)
     U = u_cols.size
-    e = _bucket_exponents(u_counts)
+    e = _width_rungs(u_counts)
     order = np.lexsort((u_cols, e))
     rank = np.empty(U, np.int64)
     rank[order] = np.arange(U)
@@ -1042,6 +1080,7 @@ def to_blocked_ell(X: SparseRows, d_dense: int = 1024,
     pcol_rel = (pcol.astype(np.int64) - d_sel).astype(np.int32)
     pcs, pvs, _ = _fill_ell(widths, counts, e_row,
                             np.cumsum(counts) - counts, pcol_rel, t_vals)
+    _count_tail_slots(m, pvs, bucket_vals)
 
     return BlockedEllRows(
         dense=dense, ell_pcols=tuple(pcs), ell_vals=tuple(pvs),
@@ -1087,8 +1126,9 @@ def shard_blocked_ell(X: SparseRows, n_shards: int, d_dense: int = 1024,
     column permutation (hot prefix from global frequencies, tail ranks by
     MAX-LOCAL occurrence bucket) and PER-SHARD structures padded to
     common shapes — the ELL width ladder is the union of per-shard row
-    exponents with r_b = max over shards (absent (shard, width) pairs
-    carry all-zero rows that contribute nothing and are never gathered).
+    rungs (`_width_rungs`: widths 1, 2, 3, 4, 6, 8, 12, …) with r_b = max
+    over shards (absent (shard, width) pairs carry all-zero rows that
+    contribute nothing and are never gathered).
     """
     n = np.asarray(X.indices).shape[0]
     d = X.n_features
@@ -1116,15 +1156,16 @@ def _count_shard_bytes(S, ell_rows_own, ladder, cs_counts, bucket_rows):
     """Counters ``layout.shard_bytes_real`` / ``layout.shard_bytes_padded``:
     the bytes (an int32 id and an f32 value a slot) of the shards' ELL and
     occurrence buckets laid out each to its OWN shapes — its own rows a
-    width, its own power-of-two count a column — and padded to the common
-    shapes every shard shares (r_b = the most rows any shard has at a
-    width, a column's bucket from its MAX-LOCAL count)."""
+    width, its own count's rung of the width ladder (`_width_rungs`) a
+    column — and padded to the common shapes every shard shares (r_b = the
+    most rows any shard has at a width, a column's bucket from its
+    MAX-LOCAL count)."""
     slot = 4 + 4
     own_occ = np.where(cs_counts > 0,
-                       1 << _bucket_exponents(cs_counts), 0).sum()
-    real = sum(int(r) << int(ev) for own in ell_rows_own
+                       _rung_width(_width_rungs(cs_counts)), 0).sum()
+    real = sum(int(r) * int(_rung_width(ev)) for own in ell_rows_own
                for ev, r in own.items()) + int(own_occ)
-    padded = S * (sum(r_b << ev for ev, r_b in ladder)
+    padded = S * (sum(r_b * int(_rung_width(ev)) for ev, r_b in ladder)
                   + sum(int(b.shape[1]) * int(b.shape[2])
                         for b in bucket_rows))
     telemetry.count("layout.shard_bytes_real", real * slot)
@@ -1162,7 +1203,7 @@ def _shard_blocked_ell(X, n_shards, d_dense, device_dense_dtype, mesh):
     U = u_cols.size
     # per-(column, shard) occurrence counts -> MAX-LOCAL count per column
     cs_counts = np.bincount(inv * S + s_ids, minlength=U * S).reshape(U, S)
-    e = _bucket_exponents(cs_counts.max(axis=1))
+    e = _width_rungs(cs_counts.max(axis=1))
     order = np.lexsort((u_cols, e))   # bucket-major, col-id within bucket
     rank = np.empty(U, np.int64)
     rank[order] = np.arange(U)
@@ -1180,7 +1221,7 @@ def _shard_blocked_ell(X, n_shards, d_dense, device_dense_dtype, mesh):
         lo, hi = int(sb[s]), int(sb[s + 1])
         rbs = lo + np.searchsorted(loc_rows[lo:hi], np.arange(n_local + 1))
         counts_s = np.diff(rbs)
-        shard_layouts.append((counts_s, _row_exponents(counts_s),
+        shard_layouts.append((counts_s, _row_rungs(counts_s),
                               rbs[:-1].astype(np.int64)))
     ell_rows_own = [
         {int(ev): int((e_row_s == ev).sum())
@@ -1201,6 +1242,7 @@ def _shard_blocked_ell(X, n_shards, d_dense, device_dense_dtype, mesh):
     ell_vals = tuple(np.stack([p[1][b] for p in per_shard])
                      for b in range(len(ladder)))
     row_pos = np.stack([p[2] for p in per_shard])
+    _count_tail_slots(m_tot, ell_vals, bucket_vals)
 
     return ShardedBlockedEllRows(
         dense=dense, ell_pcols=ell_pcols, ell_vals=ell_vals,
@@ -1270,9 +1312,10 @@ def shard_permuted_hybrid(X: SparseRows, n_shards: int,
     ranks by occurrence bucket) and PER-SHARD structures: each shard's
     row-major flat tail slice (padded to the max shard length) and its
     occurrence-bucket matrices holding the shard's LOCAL occurrences of
-    every bucket column (absent shards carry zero slots). The bucket
-    exponent uses the MAX-LOCAL count across shards — not the global
-    count — so per-shard padding stays ≤2× per present column.
+    every bucket column (absent shards carry zero slots). A column's
+    bucket comes from its MAX-LOCAL count across shards — not the global
+    count — so per-shard padding stays under a third of the slots of the
+    column's fullest shard.
     """
     n = np.asarray(X.indices).shape[0]
     d = X.n_features
@@ -1310,7 +1353,7 @@ def shard_permuted_hybrid(X: SparseRows, n_shards: int,
     U = u_cols.size
     # per-(column, shard) occurrence counts -> MAX-LOCAL count per column
     cs_counts = np.bincount(inv * S + s_ids, minlength=U * S).reshape(U, S)
-    e = _bucket_exponents(cs_counts.max(axis=1))
+    e = _width_rungs(cs_counts.max(axis=1))
     order = np.lexsort((u_cols, e))   # bucket-major, col-id within bucket
     rank = np.empty(U, np.int64)
     rank[order] = np.arange(U)

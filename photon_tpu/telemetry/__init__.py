@@ -46,10 +46,13 @@ update on the host block_scored_rows / table_scored_rows (the training
 rows whose margin came from a bucket's block pass, and from the table
 gather: together the rows × the updates), beside the
 fixed effect's game_fixed.row_iterations (rows × iterations taken);
-the sharded layout build's `layout.*` pair — shard_bytes_real /
-shard_bytes_padded (`data.matrix.shard_blocked_ell`: the bytes of the
-shards' ELL and occurrence buckets each laid out to its own shapes, and
-padded to the common shapes every shard shares), with one
+the blocked-ELL builds' `layout.*` family — tail_nnz / ell_slots /
+occ_slots (`data.matrix.to_blocked_ell` and `shard_blocked_ell`: the
+tail's real nonzeros and the slots the ELL row buckets and the occurrence
+buckets hold for them under the width ladder, summed over shards), and of
+the sharded build alone shard_bytes_real / shard_bytes_padded (the bytes
+of the shards' ELL and occurrence buckets each laid out to its own shapes,
+and padded to the common shapes every shard shares), with one
 `layout.shard_build` span a build — and the mesh solve's mesh.psum_bytes
 (`models.training.train_glm(mesh=)`: the payload bytes of the gradient
 all-reduces of one sharded L-BFGS solve — the f32 gradient's bytes,
@@ -438,6 +441,7 @@ TELEMETRY_REGISTRY = {
         "game_re.block_scored_rows", "game_re.table_scored_rows",
         "game_fixed.row_iterations",
         "layout.shard_bytes_real", "layout.shard_bytes_padded",
+        "layout.tail_nnz", "layout.ell_slots", "layout.occ_slots",
         "mesh.psum_bytes",
         "game_e2e.pod_scale_runs", "game_e2e.streamed_fixed_updates",
         "game_e2e.objective_chunks",

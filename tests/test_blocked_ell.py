@@ -17,12 +17,14 @@ import numpy as np
 import pytest
 import scipy.sparse as sp
 
+from photon_tpu import telemetry
 from photon_tpu.data.dataset import (cast_features, chunk_batch,
                                      chunk_blocked_ell, make_batch,
                                      pad_batch, shard_blocked_ell_batch,
                                      with_offsets)
 from photon_tpu.data.matrix import (BlockedEllRows, ShardedBlockedEllRows,
-                                    SparseRows, blocked_ell_from_scipy_csr,
+                                    SparseRows, _rung_width, _width_rungs,
+                                    blocked_ell_from_scipy_csr,
                                     from_scipy_csr, last_column_is_intercept,
                                     layout_matvec, layout_matvec_lanes,
                                     matvec, matvec_lanes, rmatvec,
@@ -73,6 +75,53 @@ def _weights_offsets(rng, n):
             rng.normal(size=n).astype(np.float32))
 
 
+# ------------------------------------------------------- the width ladder
+# the ladder written out: every power of two, and 3·2^(j−1) between 2^j
+# and 2^(j+1)
+LADDER = sorted({1 << j for j in range(22)}
+                | {3 << (j - 1) for j in range(1, 21)})
+
+
+def _ladder_width(count):
+    """The smallest width of `LADDER` that holds ``count`` (no layout
+    code)."""
+    return next(w for w in LADDER if w >= max(int(count), 1))
+
+
+@pytest.mark.parametrize("fact", ["monotone", "holds_the_count",
+                                  "rung_below_is_too_small",
+                                  "pow2_or_three_halves", "is_the_ladder"])
+def test_width_ladder(fact):
+    """count → rung → width, the one function pair both tail structures
+    are bucketed by: every count up to 2^12 + 1, and every count within
+    one of a rung up to 3·2^19."""
+    edges = np.asarray(LADDER[:-1], np.int64)
+    counts = np.unique(np.concatenate(
+        [np.arange(0, (1 << 12) + 2), edges - 1, edges, edges + 1]))
+    rungs = _width_rungs(counts)
+    widths = _rung_width(rungs)
+    if fact == "monotone":
+        assert (np.diff(rungs) >= 0).all() and (np.diff(widths) >= 0).all()
+        assert rungs[0] == 0 and (np.diff(np.unique(rungs)) == 1).all()
+    elif fact == "holds_the_count":
+        assert (widths >= counts).all()
+        # under a third of a slot row is padding
+        assert (3 * (widths - np.maximum(counts, 1)) < widths).all()
+    elif fact == "rung_below_is_too_small":
+        up = rungs > 0
+        assert (_rung_width(rungs[up] - 1) < counts[up]).all()
+        assert (counts[~up] <= 1).all() and (widths[~up] == 1).all()
+    elif fact == "pow2_or_three_halves":
+        w = np.unique(widths)
+        pow2 = (w & (w - 1)) == 0
+        three = (w % 3 == 0) & (((w // 3) & (w // 3 - 1)) == 0)
+        assert (pow2 | three).all()
+        assert w[:9].tolist() == [1, 2, 3, 4, 6, 8, 12, 16, 24]
+    else:
+        assert widths.tolist() == [_ladder_width(c) for c in counts]
+        assert _rung_width(np.arange(len(LADDER))).tolist() == LADDER
+
+
 # ------------------------------------------------------------ layout facts
 def test_bell_roundtrip_and_layout(rng):
     X, B = _power_law_sparse(rng)
@@ -87,11 +136,17 @@ def test_bell_roundtrip_and_layout(rng):
     # intercept (original last column, in every row) must be hot
     assert B.last_col_pos < B.d_sel
     assert np.asarray(B.dense)[:, B.last_col_pos].min() == 1.0
-    # ELL widths are a pow2 ladder, ascending, and every real tail nnz is
-    # laid exactly once: padded slots carry value 0 at column 0
+    # ELL widths are rungs of the width ladder, ascending, no bucket —
+    # of rows or of columns — is more than a third padding, and every real
+    # tail nnz is laid exactly once: padded slots carry value 0 at column 0
     widths = [v.shape[1] for v in B.ell_vals]
-    assert widths == sorted(widths)
-    assert all(w & (w - 1) == 0 for w in widths)
+    assert widths == sorted(set(widths)) and set(widths) <= set(LADDER)
+    occ = [v.shape[1] for v in B.bucket_vals]
+    assert occ == sorted(set(occ)) and set(occ) <= set(LADDER)
+    assert {3, 6} <= set(widths) and {3, 6} <= set(occ)
+    for v in B.ell_vals + B.bucket_vals:
+        v = np.asarray(v)
+        assert 3 * int((v == 0.0).sum()) <= v.size
     laid = sum(int((np.asarray(v) != 0.0).sum()) for v in B.ell_vals)
     total = int((np.asarray(X.values) != 0.0).sum())
     # every tail nnz is laid exactly once (tail values are nonzero by
@@ -100,7 +155,7 @@ def test_bell_roundtrip_and_layout(rng):
     assert laid == B.tail_nnz <= total
     assert B.ell_slots >= B.tail_nnz
     assert B.tail_pad_waste >= 0.0
-    # rows are STORED in concatenation order: width exponent ascending,
+    # rows are STORED in concatenation order: width rung ascending,
     # tail-free rows last, original row id within a bucket (stable)
     n = X.shape[0]
     ro, rp = np.asarray(B.row_order), np.asarray(B.row_pos)
@@ -114,7 +169,7 @@ def test_bell_roundtrip_and_layout(rng):
     assert tail_nnz.min() == 0 and (tail_nnz == 1).any() \
         and tail_nnz.max() > 4            # 0 / 1 / many tail nonzeros
     width = np.where(tail_nnz > 0,
-                     2 ** np.ceil(np.log2(np.maximum(tail_nnz, 1))), 1e9)
+                     [_ladder_width(c) for c in tail_nnz], 1e9)
     stored = width[ro]
     assert (np.diff(stored) >= 0).all()
     assert all((np.diff(ro[stored == w]) > 0).all()
@@ -703,28 +758,54 @@ def test_bell_chunked_margins_permuted(rng):
 
 
 # --------------------------------- the wide-bucket matrix against float64
-def _wide_bucket_problem(n=51, d=160, d_dense=8, seed=0, bf16=False):
-    """A blocked-ELL layout exercising MANY width buckets: row i carries
-    (i % 18) + 1 tail nnz on top of 2 hot columns, so the pow2 width
-    ladder spans 1/2/4/8/16/32 and n=51 divides nothing. Returns the
-    layout and the dense float64 matrix of the values it STORES (the COO's
-    own for f32 storage, each rounded to bf16 for bf16 storage), rows in
-    the caller's order, columns in model space."""
+WIDE_ROWS = 51
+CROSSED = {3, 6, 12}    # the rungs between the powers of two
+
+
+def _wide_bucket_coo(n, d, seed):
+    """Row i carries (i % 18) + 1 DISTINCT tail columns on top of 2 hot
+    ones, so the ELL side spans the widths 1/2/3/4/6/8/12/16/24; the tail
+    columns are drawn by a power law, so the occurrence side spans as
+    many."""
     rng = np.random.default_rng(seed)
-    rows_ind, rows_val = [], []
     kmax = 21
+    p = 1.0 / np.arange(1, d - 2) ** 0.9
+    p /= p.sum()
+    ind = np.zeros((n, kmax), np.int32)
+    val = np.zeros((n, kmax), np.float32)
     for i in range(n):
         tail = (i % 18) + 1
-        cols = rng.permutation(np.arange(2, d - 1))[:tail]  # distinct
-        ind = np.concatenate([[0, 1], cols, np.zeros(kmax - 2 - tail,
-                                                     np.int64)])
-        val = np.concatenate([rng.normal(size=2 + tail),
-                              np.zeros(kmax - 2 - tail)])
-        rows_ind.append(ind)
-        rows_val.append(val)
-    ind = np.asarray(rows_ind, np.int32)
-    val = np.asarray(rows_val, np.float32)
-    X = to_blocked_ell(SparseRows(ind, val, d), d_dense)
+        ind[i, :2] = (0, 1)
+        ind[i, 2:2 + tail] = 2 + rng.choice(d - 3, size=tail, replace=False,
+                                            p=p)
+        val[i, :2 + tail] = rng.normal(size=2 + tail)
+    return ind, val
+
+
+def _wide_bucket_problem(view="stored", d=160, d_dense=8, seed=0,
+                         bf16=False):
+    """A blocked-ELL layout whose X passes cross buckets of width 3, 6 and
+    12 on BOTH sides, in one of four views: ``stored`` — `to_blocked_ell`
+    over 51 rows (which divide nothing), rows in bucket order; ``global``
+    — `shard_blocked_ell` over two shards of 51 rows, the plain-jit view;
+    ``local`` / ``chunk`` — shard 0 through `.local()` (as `shard_map`
+    hands it over: every leaf's shard axis sliced to 1) and shard 1
+    through `.chunk(1)`, caller-order rows over the ladder the shards
+    share. Returns the layout and the dense float64 matrix of the values
+    it STORES (the COO's own for f32 storage, each rounded to bf16 for bf16
+    storage), rows in the caller's order, columns in model space."""
+    nl = WIDE_ROWS
+    n = nl if view == "stored" else 2 * nl
+    ind, val = _wide_bucket_coo(n, d, seed)
+    rows = slice(0, n)
+    if view == "stored":
+        X = to_blocked_ell(SparseRows(ind, val, d), d_dense)
+    else:
+        X = shard_blocked_ell(SparseRows(ind, val, d), 2, d_dense)
+        if view == "local":
+            X, rows = X.shard_slice(0, 1).local(), slice(0, nl)
+        elif view == "chunk":
+            X, rows = X.chunk(1), slice(nl, n)
     stored = val
     if bf16:
         bf = jnp.bfloat16
@@ -735,17 +816,24 @@ def _wide_bucket_problem(n=51, d=160, d_dense=8, seed=0, bf16=False):
                               for v in X.bucket_vals))
         stored = np.asarray(jnp.asarray(val).astype(bf))
     D = np.zeros((n, d), np.float64)
-    np.add.at(D, (np.repeat(np.arange(n), kmax), ind.ravel()),
+    np.add.at(D, (np.repeat(np.arange(n), ind.shape[1]), ind.ravel()),
               stored.astype(np.float64).ravel())
-    return X, D
+    return X, D[rows]
 
 
-@pytest.fixture(scope="module", params=[False, True], ids=["f32", "bf16"])
+@pytest.fixture(scope="module",
+                params=[(view, bf16)
+                        for view in ("stored", "global", "local", "chunk")
+                        for bf16 in (False, True)],
+                ids=lambda p: f"{p[0]}-{'bf16' if p[1] else 'f32'}")
 def wide_bucket(request):
-    X, D = _wide_bucket_problem(bf16=request.param)
-    assert len(X.ell_vals) >= 4  # widths 1/2/4/8/16…: real coverage
-    assert X.row_order is not None
-    return X, D, request.param
+    view, bf16 = request.param
+    X, D = _wide_bucket_problem(view, bf16=bf16)
+    assert X.shape[0] == D.shape[0]
+    assert CROSSED <= {v.shape[-1] for v in X.ell_vals}
+    assert CROSSED <= {v.shape[-1] for v in X.bucket_vals}
+    assert (getattr(X, "row_order", None) is not None) == (view == "stored")
+    return X, D, bf16
 
 
 @pytest.mark.parametrize("fn", [matvec, layout_matvec, matvec_lanes,
@@ -753,18 +841,22 @@ def wide_bucket(request):
                                 sq_rmatvec], ids=lambda f: f.__name__)
 def test_wide_bucket_matrix_against_float64(wide_bucket, fn):
     """Every public X pass of the blocked-ELL layout, over every width
-    bucket the pow2 ladder makes, against a dense float64 numpy product
-    of the SAME stored values built from the COO (no layout code): the
-    forward passes in the caller's order (`matvec`) and in the stored one
-    (`layout_matvec`, through `row_order`), the transposed ones from a
-    stored-order cotangent, scalar and lane-minor, f32 and bf16 storage.
+    bucket the ladder makes — the rungs 3, 6 and 12 between the powers of
+    two among them, in the ELL row buckets and in the occurrence buckets —
+    against a dense float64 numpy product of the SAME stored values built
+    from the COO (no layout code): the forward passes in the caller's
+    order (`matvec`) and in the stored one (`layout_matvec`, through
+    `row_order`), the transposed ones from a stored-order cotangent,
+    scalar and lane-minor, f32 and bf16 storage; over `to_blocked_ell`'s
+    layout, the sharded layout's global view, and a shard of it as
+    `.local()` and as `.chunk(i)` (rows in the caller's order).
 
     Tolerance, per output element, c·Σ|x||v| over the terms of its sum.
-    f32 storage: one rounding a product, one an add, ≤ 51 terms (a
-    column's rows) — c = 64·2^-24. bf16 storage multiplies bf16 OPERANDS
-    with f32 accumulation (`_matvec`'s recipe), so the reference rounds
-    the vector to bf16 as the pass does; a bf16 × bf16 product is exact
-    in f32, which leaves the same f32 accumulation and the same c.
+    f32 storage: one rounding a product, one an add, ≤ 51 terms a shard
+    (a column's rows) — c = 64·2^-24 a shard. bf16 storage multiplies bf16
+    OPERANDS with f32 accumulation (`_matvec`'s recipe), so the reference
+    rounds the vector to bf16 as the pass does; a bf16 × bf16 product is
+    exact in f32, which leaves the same f32 accumulation and the same c.
     `sq_rmatvec` over bf16 storage is the exception, in its HOT columns
     only: the hot block squares in bf16 and takes the cotangent in bf16
     (two roundings of 2^-8 a term: c = 2^-7 there); its tail squares in
@@ -773,7 +865,8 @@ def test_wide_bucket_matrix_against_float64(wide_bucket, fn):
     n, d = X.shape
     rng = np.random.default_rng(1)
     perm = np.asarray(X.perm_cols)
-    order = np.asarray(X.row_order)
+    order = np.arange(n) if getattr(X, "row_order", None) is None \
+        else np.asarray(X.row_order)
     G = 3
     op = fn.__name__
     lanes = op.endswith("_lanes")
@@ -797,10 +890,36 @@ def test_wide_bucket_matrix_against_float64(wide_bucket, fn):
         got = np.asarray(fn(X, jnp.asarray(v[order])), np.float64)
         M = D * D if op == "sq_rmatvec" else D
         want, mass = (M.T @ seen(v))[perm], (np.abs(M).T @ np.abs(v))[perm]
-    c = np.full(got.shape[:1], 64 * 2.0 ** -24)
+    c = np.full(got.shape[:1], 64 * (n // WIDE_ROWS) * 2.0 ** -24)
     if bf16 and op == "sq_rmatvec":
         c[:X.d_sel] = 2.0 ** -7
     tol = (c * mass.T).T + 1e-30
     assert got.shape == want.shape
     err = np.abs(got - want)
     assert np.all(err <= tol), float(np.max(err / tol))
+
+
+@pytest.mark.parametrize("sharded", [False, True], ids=["one", "sharded"])
+def test_build_counts_its_tail_slots(sharded):
+    """Counters `layout.tail_nnz` / `layout.ell_slots` / `layout.occ_slots`
+    of a blocked-ELL build are the layout's own: its real tail nonzeros,
+    and the slots of its ELL row buckets and of its occurrence buckets
+    (summed over the shards of a sharded build)."""
+    ind, val = _wide_bucket_coo(2 * WIDE_ROWS, 160, seed=3)
+    rows = SparseRows(ind, val, 160)
+    with telemetry.run("t") as run:
+        X = shard_blocked_ell(rows, 2, 8) if sharded \
+            else to_blocked_ell(rows, 8)
+        c = run.report_compact()["counters"]
+    assert c["layout.tail_nnz"] == X.tail_nnz > 0
+    assert c["layout.ell_slots"] == X.ell_slots \
+        == sum(int(np.prod(v.shape)) for v in X.ell_pcols)
+    assert c["layout.occ_slots"] \
+        == sum(int(np.prod(v.shape)) for v in X.bucket_rows)
+    real = sum(int((np.asarray(v) != 0.0).sum()) for v in X.ell_vals)
+    assert real == X.tail_nnz \
+        == sum(int((np.asarray(v) != 0.0).sum()) for v in X.bucket_vals)
+    assert X.tail_pad_waste == c["layout.ell_slots"] / real - 1.0
+    if not sharded:   # its own ladder: under a third of the slots
+        assert 3 * (c["layout.ell_slots"] - real) < c["layout.ell_slots"]
+        assert 3 * (c["layout.occ_slots"] - real) < c["layout.occ_slots"]

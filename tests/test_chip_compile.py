@@ -37,35 +37,46 @@ import jax.numpy as jnp
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-# The blocked-ELL layout of chip_smoke.py's `glm` phase — bench.py's sparse
-# problem at seed 0, 2^21 rows, 10M features, 1024-column bf16 hot block —
-# as `to_blocked_ell` built it in this sandbox: row count n, tail length
-# U = n_prefix - d_sel, the five pow2 ELL width buckets (rows, W) and the
-# twelve occurrence buckets (columns, k). The smoke prints the same summary
-# on the chip's host, where the ELL row counts came out a few rows
-# different (680022 for 680032, ...; same ladder, same U, same occurrence
-# buckets) — shape structure, not the last digit, is what the compiler
-# sees.
+# The blocked-ELL layout of the benchmark's one-chip GLM cells
+# (`glm-sparse10m.single` / `.sweep8`: `gen/sparse.py`'s fixed pattern,
+# 2^21 rows, 10M features, 1024-column bf16 hot block) as `to_blocked_ell`
+# built it in this sandbox under the width ladder 1, 2, 3, 4, 6, 8, 12, …
+# (the shapes follow from the pattern, so every seed has them): row count
+# n, tail length U = n_prefix - d_sel, the seven ELL width buckets
+# (rows, W) — three quarters of the old width-4 bucket are the rows of
+# width 3 — and the twenty-one occurrence buckets (columns, k): 3,412,948
+# and 3,911,675 slots for 3,377,451 tail nonzeros (powers of two alone:
+# 3,793,085 and 4,607,800). chip_smoke.py's `glm` phase lays bench.py's
+# seed-0 problem, whose counts differ in the third digit — shape
+# structure, not the last digit, is what the compiler sees.
 GLM_N = 1 << 21
-GLM_U = 540082 - 1024
-GLM_ELL = ((680032, 1), (560138, 2), (410297, 4), (43626, 8), (53, 16))
-GLM_BUCKETS = ((380559, 1), (58680, 2), (37970, 4), (23819, 8),
-               (15253, 16), (9252, 32), (5701, 64), (3441, 128),
-               (2104, 256), (1290, 512), (781, 1024), (208, 2048))
+GLM_U = 541054 - 1024
+GLM_ELL = ((683659, 1), (558905, 2), (296641, 3), (113929, 4), (41676, 6),
+           (1919, 8), (36, 12))
+GLM_BUCKETS = ((382064, 1), (58188, 2), (24269, 3), (13789, 4), (15334, 6),
+               (8573, 8), (9639, 12), (5458, 16), (5865, 24), (3304, 32),
+               (3684, 48), (1977, 64), (2228, 96), (1275, 128), (1348, 192),
+               (778, 256), (802, 384), (460, 512), (512, 768), (277, 1024),
+               (206, 1536))
 GLM_FEATURES = 10_000_000
 # The SHARDED blocked-ELL layout of the benchmark's four-chip cell
 # (`glm-sparse10m-mesh4.single`: 4 shards of 2^21 rows) as
 # `shard_blocked_ell` laid it in this sandbox from `gen/sparse_mesh.py`'s
 # draw (the shapes follow from the fixed pattern, so every seed has them):
 # per-shard common shapes, (r_b, W) ELL buckets and (c_b, k) occurrence
-# buckets. Against the one-chip layout above the count-1 occurrence bucket
-# is three times as long: a column's bucket comes from its MAX-LOCAL count.
+# buckets under the same ladder: 3,420,070 and 5,434,375 slots a shard
+# (powers of two alone: 3,798,048 and 6,269,888). Against the one-chip
+# layout above the count-1 occurrence bucket is three times as long: a
+# column's bucket comes from its MAX-LOCAL count.
 MESH4_SHARDS, MESH4_N = 4, 1 << 23
 MESH4_PREFIX = 1421599
-MESH4_ELL = ((683602, 1), (560351, 2), (410696, 4), (43782, 8), (44, 16))
-MESH4_BUCKETS = ((1130732, 1), (131102, 2), (71318, 4), (38804, 8),
-                 (21190, 16), (11900, 32), (6811, 64), (3966, 128),
-                 (2320, 256), (1373, 512), (828, 1024), (231, 2048))
+MESH4_ELL = ((683602, 1), (560351, 2), (296824, 3), (114410, 4), (41973, 6),
+             (1911, 8), (44, 12))
+MESH4_BUCKETS = ((1130732, 1), (131102, 2), (46869, 3), (24449, 4),
+                 (25452, 6), (13352, 8), (13969, 12), (7221, 16), (7779, 24),
+                 (4121, 32), (4463, 48), (2348, 64), (2564, 96), (1402, 128),
+                 (1508, 192), (812, 256), (882, 384), (491, 512), (541, 768),
+                 (287, 1024), (231, 1536))
 # the serve phase's store: the flagship GAME model (benches/_flagship_data)
 SERVE_D_FIXED, SERVE_D_RE = 33, 4
 SERVE_USERS, SERVE_ITEMS = 100_000, 50_000

@@ -124,20 +124,23 @@ def _digest(layout) -> str:
 
 @pytest.mark.parametrize("build,digest", [
     (lambda X, mesh: shard_blocked_ell(X, S, 32),
-     "5ab480f89b20c85a22876b72470e1152e347fef45a05ef5e466ed32d2ce50a06"),
+     "d076d1d752fe436beb18333df2cde881bfacf64a95ab5b4795f9e3365b44a243"),
     (lambda X, mesh: shard_blocked_ell(
         X, S, 32, device_dense_dtype=jnp.bfloat16, mesh=mesh),
-     "b37bf89cd1b8aa7893d168a29a25a7d84d3765600242519033072335fa2d2d2c"),
+     "495925f82c76bf766d5f61e0765d7dbce35ed1d3a11a6b06d48b36897b517fcb"),
     (lambda X, mesh: to_blocked_ell(X, 32, device_dense_dtype=jnp.bfloat16),
-     "8fae201d24800691325dfbb9f9eb3d696003aafcd791c2e0777b07823e2e4a31"),
+     "842826d39bc1ec878f10cc9b85a7e1775d62f899c4ee0c6acb44c83a40d8c574"),
     (lambda X, mesh: to_blocked_ell(X, 32),
-     "a532eb131f3c9dd4fe17bb8b24f2644a0a6d7c900deeabc8b125f888b9d3f885"),
+     "beb6b5223ef21886359ca47a3342f84369e87af9a4ba31bcccfc47a87efab1a8"),
 ], ids=["sharded_host", "sharded_device", "one_device", "one_host"])
 def test_layout_leaves_are_the_parents(mesh4, build, digest):
-    """The host pass no longer makes an (n, k) int64 row-id array; the
-    layouts it lays are, byte for byte, what commit 19d3b58 laid for the
-    same rows (digests taken from a checkout of that commit, whose sharded
-    device build assembled the whole block on one device)."""
+    """The four builds of one set of rows, byte for byte, as the tree of
+    PR 32 laid them: the digests were retaken when the buckets' widths
+    went from powers of two to the width ladder's rungs (PR 31 had taken
+    them from a checkout of 19d3b58, to show that a host pass without an
+    (n, k) int64 row-id array, and a hot block built shard by shard, lay
+    what the parent laid). A pin, not a reference: what the leaves MEAN is
+    held to float64 by tests/test_blocked_ell.py's wide-bucket matrix."""
     assert _digest(build(_problem(), mesh4)) == digest
 
 
