@@ -19,10 +19,12 @@ from photon_tpu.data.matrix import (
     HybridRows,
     Matrix,
     PermutedHybridRows,
+    PinnedRows,
     ShardedBlockedEllRows,
     ShardedHybridRows,
     ShardedPermutedHybridRows,
     SparseRows,
+    _place_chunk,
     rows_from_caller,
     shard_blocked_ell,
     shard_hybrid,
@@ -266,6 +268,73 @@ def total_weight(batch: GLMBatch) -> float:
 # its HBM (BASELINE config 4's 100M-row regime).
 
 
+# One `device_put` of a multi-GB host array is the slow way onto a v5e: the
+# runtime stages the whole array in fresh host memory first (4.29 GB of
+# bf16 went over at 0.44–0.57 GB/s and left three times its bytes in the
+# process's RSS; the same bytes in row pieces of 16–128 MB at 12.8–14.0
+# GB/s, RSS flat: chip runs of PR 34, PERF.md §6). So a large leaf goes up
+# in row pieces of this many bytes, each laid into a preallocated device
+# buffer in place (`data.matrix._place_chunk`), and no more than
+# `_UPLOAD_PIECES_IN_FLIGHT` of them wait on the device for their write at
+# once: a piece's device buffer is allocated when it is issued, so a leaf
+# issued whole would hold its bytes twice. Four in flight reach the rate
+# of all of them on a quiet host (14.0 against 13.95 GB/s); sixteen (0.5
+# GB) cost nothing there and lose less when the issuing thread is late —
+# a solve beside four memory-streaming neighbours took 38.6 / 42.0 s
+# against 39.9 / 53.6, beside 26 spinning ones 67.9 against 107.8 (one
+# process, alternating: the noise round's chip run, PERF.md §6).
+_UPLOAD_PIECE_BYTES = 32 << 20
+_UPLOAD_PIECES_IN_FLIGHT = 16
+
+
+def device_put_in_pieces(tree, device=None):
+    """`jax.device_put(tree, device)` for a tree of HOST arrays, with every
+    leaf of more than two pieces' bytes uploaded in row pieces of
+    `_UPLOAD_PIECE_BYTES` and assembled in place on the device (a
+    `PinnedRows` leaf, a ladder's hot block already in pinned host memory,
+    in the pieces it is kept in: no staging copy). The call
+    returns once all but the last `_UPLOAD_PIECES_IN_FLIGHT` pieces of
+    every large leaf have landed; the result's big leaves are the last
+    in-place write's output, ready when every piece has."""
+    from collections import deque
+
+    from jax.sharding import SingleDeviceSharding
+
+    def put(leaf):
+        if isinstance(leaf, PinnedRows):  # its own pieces, already pinned
+            dev = device or jax.devices()[0]
+            to = SingleDeviceSharding(dev, memory_kind=dev.default_memory(
+            ).kind)
+            pieces = leaf.pieces()
+        elif (not isinstance(leaf, np.ndarray) or leaf.ndim == 0
+                or leaf.nbytes <= 2 * _UPLOAD_PIECE_BYTES):
+            return jax.device_put(leaf, device)
+        else:
+            n, to = leaf.shape[0], device
+            rows = max(1, _UPLOAD_PIECE_BYTES // max(leaf.nbytes // n, 1))
+            pieces = ((r0, leaf[r0:r0 + rows]) for r0 in range(0, n, rows))
+        buf = jnp.zeros(leaf.shape, leaf.dtype, device=device)
+        in_flight: deque = deque()
+        for r0, host_piece in pieces:
+            if len(in_flight) == _UPLOAD_PIECES_IN_FLIGHT:
+                in_flight.popleft().block_until_ready()
+            piece = jax.device_put(host_piece, to)
+            in_flight.append(piece)
+            buf = _place_chunk(buf, piece, np.int32(r0))
+        return buf
+
+    return jax.tree_util.tree_map(put, tree)
+
+
+def _pins_host_blocks() -> bool:
+    """Whether a one-device chunk ladder keeps its hot block in pinned host
+    memory: on an accelerator whose runtime has that memory kind. A CPU
+    backend's device memory IS host memory: nothing crosses a link."""
+    dev = jax.devices()[0]
+    return dev.platform != "cpu" and any(
+        m.kind == "pinned_host" for m in dev.addressable_memories())
+
+
 @dataclasses.dataclass(frozen=True)
 class ChunkedMatrix:
     """A design matrix as HOST-resident uniform row chunks.
@@ -372,6 +441,17 @@ class ChunkedBatch(NamedTuple):
         return GLMBatch(self.X.chunks[i], self.y[sl], self.weights[sl],
                         self.offsets[sl])
 
+    def chunk_nbytes(self) -> int:
+        """Host bytes of ONE chunk as `chunk(i)` hands it to `device_put`:
+        every leaf of its matrix (a blocked-ELL chunk carries the ladder's
+        two (d,) permutation vectors) and its three scalar columns. Chunks
+        are uniform, so chunk 0 speaks for all."""
+        if self.n_chunks == 0:
+            return 0
+        return sum(int(leaf.nbytes if hasattr(leaf, "nbytes")
+                       else np.asarray(leaf).nbytes)
+                   for leaf in jax.tree_util.tree_leaves(self.chunk(0)))
+
     def mesh_chunk_rows(self, mesh) -> int:
         """Per-chunk row count after padding to the mesh (every chunk pads
         to the same height, so the per-chunk device programs still compile
@@ -452,10 +532,12 @@ class ChunkedBatch(NamedTuple):
 
         The iterator times how long it stalls waiting for each prefetched
         chunk's transfer; per-pass totals land in the telemetry counters
-        (`stream.chunk_uploads` / `stream.stall_seconds` /
-        `stream.compute_seconds`), and when total stall exceeds total
-        compute it logs the imbalance at INFO — the signal that a deeper
-        prefetch or a bigger `objective_chunk_rows` would help."""
+        (`stream.chunk_uploads` / `stream.upload_bytes` /
+        `stream.stall_seconds` / `stream.issue_seconds` /
+        `stream.compute_seconds`), and when total
+        stall exceeds total compute it logs the imbalance at INFO — the
+        signal that a deeper prefetch or a bigger `objective_chunk_rows`
+        would help."""
         import time as _time
         from collections import deque
 
@@ -475,18 +557,19 @@ class ChunkedBatch(NamedTuple):
             put = lambda i: self.mesh_chunk(i, mesh,  # noqa: E731
                                             _cache=mesh_cache)
         else:
-            dput = (lambda b: jax.device_put(b, device)) \
-                if device is not None else jax.device_put
-            put = lambda i: dput(self.chunk(i))  # noqa: E731
+            put = lambda i: device_put_in_pieces(  # noqa: E731
+                self.chunk(i), device)
 
         window: deque = deque()
         issued = 0
-        stall = 0.0
+        stall = issue = 0.0
         t_start = _time.perf_counter()
         for i in range(n):
             # keep chunks i..i+depth-1 issued (async) before blocking on i
             while issued < min(i + depth, n):
+                t0 = _time.perf_counter()
                 window.append(put(issued))
+                issue += _time.perf_counter() - t0
                 issued += 1
             cur = window.popleft()
             # fault-injection site: a preemption mid-upload-stream (the
@@ -500,7 +583,9 @@ class ChunkedBatch(NamedTuple):
         compute = (_time.perf_counter() - t_start) - stall
         telemetry.count("stream.passes")
         telemetry.count("stream.chunk_uploads", n)
+        telemetry.count("stream.upload_bytes", n * self.chunk_nbytes())
         telemetry.count("stream.stall_seconds", stall)
+        telemetry.count("stream.issue_seconds", issue)
         telemetry.count("stream.compute_seconds", max(compute, 0.0))
         telemetry.gauge("stream.prefetch_depth", depth)
         from photon_tpu import profiling
@@ -538,11 +623,31 @@ class DeviceChunkRing:
     buffers), peak HBM stays ~`depth` chunks — the two-deep ring never
     holds a third copy.
 
+    The ring owns that depth, and donation alone does not give it: a
+    donated leaf with no output of its size to alias (the hot block, every
+    tail bucket) is not donated at all, so a consumed chunk stays on the
+    device for as long as the consumer's loop variable names it — through
+    the NEXT upload's allocation. So a one-device consumer hands each
+    chunk program's outputs to `consumed()`: before the ring issues
+    another upload it waits for them and frees what the program left of
+    its chunk, and a new chunk is allocated only once the device holds
+    fewer than `depth` — at the north-star chunk (4.45 GB) a third does
+    not fit beside the solver. A solve `close()`s its ring when it ends:
+    what was primed for a pass that never comes is dropped once it has
+    landed, so the next solve's ring never meets it on the chip.
+
     Per-pass semantics are `iter_device`'s exactly: `stream_pass()`
     yields ``(i, device_chunk)`` in order with the same telemetry
-    counters, the same `chunk_upload` fault-injection site per chunk,
-    ledger attribution (``ingest.upload`` stall + ``solve.compute``)
-    and `AdaptivePrefetch` support. A pass abandoned mid-way (an
+    counters (`stream.upload_bytes` is the host bytes of the chunks a pass
+    CONSUMED, `ChunkedBatch.chunk_nbytes` each: chunks primed and then
+    dropped by `close()` are not in it; `stream.issue_seconds` the host
+    seconds the pass spent handing chunks to the runtime: an upload in
+    pieces returns when all but its last pieces have crossed the link, so
+    on a link-bound stream this is the wait for the link), the same
+    `chunk_upload`
+    fault-injection site per chunk, ledger attribution
+    (``ingest.upload`` stall + ``solve.compute``) and `AdaptivePrefetch`
+    support. A pass abandoned mid-way (an
     injected kill, any exception) resets the ring to a clean state — the
     next pass starts at chunk 0 with nothing stale in flight. Mesh mode
     additionally persists the replication cache across passes, so a
@@ -559,24 +664,63 @@ class DeviceChunkRing:
         self._prefetch = prefetch
         self._window: deque = deque()
         self._next = 0  # chunk index the next upload issues (mod n_chunks)
+        self._handed = None  # the chunk the consumer holds
+        self._pending = None  # its program's outputs, once `consumed`
+        self._issue = 0.0  # host seconds inside `_put` this pass
+        self._chunk_nbytes = batch.chunk_nbytes()
         if mesh is not None:
             mesh_cache: dict = {}  # persists across passes: perm uploads once
             self._put = lambda i: batch.mesh_chunk(i, mesh,
                                                    _cache=mesh_cache)
         else:
-            dput = (lambda b: jax.device_put(b, device)) \
-                if device is not None else jax.device_put
-            self._put = lambda i: dput(batch.chunk(i))
+            self._put = lambda i: device_put_in_pieces(batch.chunk(i),
+                                                       device)
 
     @property
     def depth(self) -> int:
         return max(int(self._ctl.depth if self._ctl is not None
                        else self._prefetch), 1)
 
+    def consumed(self, outputs):
+        """The outputs of the program that consumed the chunk just handed
+        out, returned as they are: the consumer is done with that chunk.
+        Before its next upload the ring waits for them and frees the chunk
+        (see the class note); a mesh ring only waits, its chunks share the
+        replicated permutation's buffers."""
+        self._pending = outputs
+        return outputs
+
+    def _release(self) -> None:
+        """Once the program `consumed()` named has run, free its chunk."""
+        if self._pending is None:
+            return
+        jax.block_until_ready(self._pending)
+        if self.mesh is None:
+            for leaf in jax.tree_util.tree_leaves(self._handed):
+                if isinstance(leaf, jax.Array) and not leaf.is_deleted():
+                    leaf.delete()
+        self._pending = self._handed = None
+
     def _fill(self, n: int) -> None:
+        """Top the window up to the ring's depth, the consumed chunk let
+        go first."""
+        import time as _time
+
+        self._release()
         while len(self._window) < min(self.depth, n):
+            t0 = _time.perf_counter()
             self._window.append(self._put(self._next))
+            self._issue += _time.perf_counter() - t0
             self._next = (self._next + 1) % n
+
+    def close(self) -> None:
+        """Drop whatever is still in flight, once it has landed: after
+        this the ring holds nothing on the device."""
+        self._release()
+        if self._window:
+            jax.block_until_ready(list(self._window))
+            self._window.clear()
+        self._next, self._handed = 0, None
 
     def stream_pass(self):
         """One pass: yield (i, device chunk) for every chunk, keeping the
@@ -591,7 +735,7 @@ class DeviceChunkRing:
         if n == 0:
             return
         depth = self.depth
-        stall = 0.0
+        stall, self._issue = 0.0, 0.0
         t_start = _time.perf_counter()
         ok = False
         try:
@@ -602,7 +746,9 @@ class DeviceChunkRing:
                 t0 = _time.perf_counter()
                 jax.block_until_ready(cur)
                 stall += _time.perf_counter() - t0
-                yield i, cur
+                self._handed = cur
+                del cur  # the ring's one name for it is `_handed`
+                yield i, self._handed
             # prime the NEXT pass before the caller closes this one (the
             # in-loop fill already wrapped past chunk n-1; this tops the
             # window back up after the final popleft)
@@ -613,11 +759,13 @@ class DeviceChunkRing:
                 # abandoned mid-pass (kill/exception): drop in-flight
                 # uploads so the next pass starts clean at chunk 0
                 self._window.clear()
-                self._next = 0
+                self._next, self._pending, self._handed = 0, None, None
             compute = (_time.perf_counter() - t_start) - stall
             telemetry.count("stream.passes")
             telemetry.count("stream.chunk_uploads", n)
+            telemetry.count("stream.upload_bytes", n * self._chunk_nbytes)
             telemetry.count("stream.stall_seconds", stall)
+            telemetry.count("stream.issue_seconds", self._issue)
             telemetry.count("stream.compute_seconds", max(compute, 0.0))
             telemetry.gauge("stream.prefetch_depth", depth)
             profiling.attribute("ingest.upload", "upload", max(stall, 0.0))
@@ -800,9 +948,20 @@ def chunk_blocked_ell(batch: GLMBatch, chunk_rows: int,
     still closes with one psum. ``chunk_rows`` must be a multiple of
     ``n_shards``.
 
-    ``feature_dtype`` (e.g. jnp.bfloat16) recasts every chunk's value
-    storage after the build — half the per-pass host→device feature bytes,
-    f32 accumulation unchanged.
+    ``feature_dtype`` (e.g. jnp.bfloat16) is what every chunk's values are
+    STORED as — half the per-pass host→device feature bytes, f32
+    accumulation unchanged. The hot block is built in it piece by piece
+    (`data.matrix._dense_on_host`): the host never holds the ladder's f32
+    form, which at 8.4M rows × 1024 columns would be 34 GB beside the 17
+    that stay. The tail's small value leaves are cast chunk by chunk.
+
+    On an accelerator a one-device ladder's hot block is laid straight
+    into PINNED host memory, in the upload's own row pieces
+    (`data.matrix.PinnedRows`; `_pins_host_blocks`): a chunk then crosses
+    the host link by DMA alone, at the link's rate whatever the host's
+    other tenants do, where pageable memory goes through the runtime's
+    staging copy (see `PinnedRows`). Same values, same shapes, same
+    programs; the mesh ladder and a CPU backend keep numpy blocks.
     """
     X = batch.X
     if not isinstance(X, SparseRows):
@@ -823,15 +982,19 @@ def chunk_blocked_ell(batch: GLMBatch, chunk_rows: int,
                           offsets=np.asarray(batch.offsets))
     padded = pad_batch(host, n_pad)
     S = (n_pad // chunk_rows) * n_shards
-    ladder = shard_blocked_ell(_host_sparse(padded.X), S, d_dense)
+    ladder = shard_blocked_ell(
+        _host_sparse(padded.X), S, d_dense,
+        host_dense_dtype=np.float32 if feature_dtype is None
+        else feature_dtype,
+        host_pinned_piece_bytes=_UPLOAD_PIECE_BYTES
+        if n_shards == 1 and _pins_host_blocks() else 0)
 
     def recast(c):
         if feature_dtype is None:
             return c
         return dataclasses.replace(
-            c, dense=np.asarray(c.dense).astype(feature_dtype),
-            ell_vals=tuple(np.asarray(v).astype(feature_dtype)
-                           for v in c.ell_vals),
+            c, ell_vals=tuple(np.asarray(v).astype(feature_dtype)
+                              for v in c.ell_vals),
             bucket_vals=tuple(np.asarray(v).astype(feature_dtype)
                               for v in c.bucket_vals))
 

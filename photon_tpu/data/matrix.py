@@ -622,9 +622,11 @@ def _dense_scatter(r, p, v, n, d, dtype):
 
 @partial(jax.jit, donate_argnums=(0,))
 def _place_chunk(out, chunk, r0):
-    """Write one scattered chunk into the preallocated result in place
-    (donated buffer: no copy of the full-size block)."""
-    return jax.lax.dynamic_update_slice(out, chunk, (r0, 0))
+    """Write ``chunk`` into rows [r0, r0 + len(chunk)) of the preallocated
+    result in place (donated buffer: nothing full-size is ever live
+    twice). Shared by the device scatter below and the streamed chunk
+    uploads (`data.dataset.device_put_in_pieces`)."""
+    return jax.lax.dynamic_update_slice_in_dim(out, chunk, r0, axis=0)
 
 
 def _dense_scatter_chunked(rows_h, pos_h, vals_h, n, d_sel, dtype):
@@ -719,22 +721,140 @@ def _dense_on_devices(hot, pos, val, d_sel, dtype, mesh):
 
 
 def _hot_cold_split(X: SparseRows, d_dense: int, device_dense_dtype,
-                    mesh=None):
+                    mesh=None, host_dense_dtype=np.float32,
+                    host_pinned=None):
     """Shared front half of the hybrid builders: pick the `d_dense` most
     frequent columns, build the (n, d_sel) hot block (on device when
     `device_dense_dtype` is set — on the devices of ``mesh`` that keep its
-    rows when one is handed in — else host chunked-bincount), and extract
-    the cold nnz as flat row-major COO. Returns
-    (dense, sel, t_rows, t_cols, t_vals) with t_* exact-size (possibly
-    empty) int64/f32 host arrays."""
-    return _split_at(*_hot_positions(X, d_dense), device_dense_dtype, mesh)
+    rows when one is handed in — else on the host, piece by piece, stored
+    as ``host_dense_dtype``), and extract the cold nnz as flat row-major
+    COO. Returns (dense, sel, t_rows, t_cols, t_vals) with t_* exact-size
+    (possibly empty) int64/f32 host arrays. ``host_pinned`` =
+    (group rows, piece bytes) keeps a host-built block in pinned host
+    memory instead (`PinnedRows`)."""
+    return _split_at(*_hot_positions(X, d_dense), device_dense_dtype, mesh,
+                     host_dense_dtype, host_pinned)
 
 
-def _split_at(ind, val, sel, pos, device_dense_dtype, mesh=None):
+_HOST_PIECE_CELLS = 1 << 20  # 8 MB of float64 scratch: stays in cache and
+#                              in the allocator's reused pages (a 1 GB
+#                              piece page-faults its way through fresh
+#                              memory, ten times slower a cell)
+
+
+class PinnedRows:
+    """A host (n, d) block kept in PINNED host memory, as consecutive row
+    pieces (`jax.Array`s of memory kind ``pinned_host``) that never
+    straddle a multiple of ``group_rows`` — a streamed chunk ladder's hot
+    block, whose chunk i is ``block[i * c:(i + 1) * c]``: whole pieces.
+
+    Why pinned: the runtime uploads pageable host memory through a staging
+    copy, and that copy is what a streamed solve's wall time moves with —
+    32 MB pieces cross a v5e's host link at 13.35–13.67 GB/s from numpy
+    memory and 9.8–11.8 beside four memory-streaming neighbours, but at
+    14.13 ± 0.003 GB/s from pinned memory, neighbours or none (chip runs
+    of PR 34, PERF.md §6). It is a leaf to `jax.tree_util` (an opaque
+    object), has numpy's ``shape`` / ``dtype`` / ``nbytes``, slices by
+    whole pieces, and `np.asarray` gives its values (a copy)."""
+
+    def __init__(self, pieces, starts, shape, dtype):
+        self._pieces, self._starts = tuple(pieces), tuple(starts)
+        self.shape, self.dtype = tuple(shape), np.dtype(dtype)
+
+    @property
+    def nbytes(self) -> int:
+        return int(np.prod(self.shape)) * self.dtype.itemsize
+
+    def pieces(self):
+        """(first row within this block, piece) in row order."""
+        return zip(self._starts, self._pieces)
+
+    def __getitem__(self, rows) -> "PinnedRows":
+        if not isinstance(rows, slice) or rows.step not in (None, 1):
+            raise TypeError("PinnedRows slices by contiguous row ranges")
+        lo, hi, _ = rows.indices(self.shape[0])
+        ends = self._starts[1:] + (self.shape[0],)
+        if hi > lo and (lo not in self._starts or hi not in ends):
+            raise ValueError(f"rows {lo}:{hi} cut a pinned piece")
+        keep = [k for k, r0 in enumerate(self._starts) if lo <= r0 < hi]
+        return PinnedRows([self._pieces[k] for k in keep],
+                          [self._starts[k] - lo for k in keep],
+                          (max(hi - lo, 0),) + self.shape[1:], self.dtype)
+
+    def __array__(self, dtype=None, copy=None):
+        out = (np.concatenate([np.asarray(p) for p in self._pieces])
+               if self._pieces else np.empty(self.shape, self.dtype))
+        return out if dtype is None else out.astype(dtype)
+
+    def delete(self) -> None:
+        """Free the pinned memory now (else: when the last view goes)."""
+        for p in self._pieces:
+            if not p.is_deleted():
+                p.delete()
+
+
+def _dense_pinned(hot, pos, val, d_sel, dtype, group_rows, piece_bytes):
+    """`_dense_on_host`'s block, every value the same, laid into PINNED
+    host memory piece by piece (`PinnedRows`): each piece is built as a
+    numpy block of at most ``piece_bytes`` and copied into pinned memory
+    of the first device, so the host holds the block once and a sliver of
+    scratch, as `_dense_on_host` does."""
+    from jax.sharding import SingleDeviceSharding
+
+    n = hot.shape[0]
+    pinned = SingleDeviceSharding(jax.devices()[0],
+                                  memory_kind="pinned_host")
+    rows = max(1, min(group_rows,
+                      piece_bytes // max(d_sel * np.dtype(dtype).itemsize,
+                                         1)))
+    pieces, starts = [], []
+    for g0 in range(0, n, group_rows):
+        for r0 in range(g0, min(n, g0 + group_rows), rows):
+            r1 = min(n, g0 + group_rows, r0 + rows)
+            # a numpy block of its own a piece: one reused buffer races
+            # with the runtime's copy (a ready pinned array has not always
+            # read its source yet: the CPU runtime, 2 runs of 6)
+            pieces.append(jax.device_put(
+                _dense_on_host(hot[r0:r1], pos[r0:r1], val[r0:r1], d_sel,
+                               dtype), pinned, may_alias=False))
+            pieces[-1].block_until_ready()
+            starts.append(r0)
+    return PinnedRows(pieces, starts, (n, d_sel), dtype)
+
+
+def _dense_on_host(hot, pos, val, d_sel, dtype):
+    """The (n, d_sel) hot block built on the HOST straight into its
+    storage ``dtype``: a float64 bincount over flat (row, pos) ids (C-speed
+    accumulation — np.add.at is an order of magnitude slower at the
+    10M-feature bench scale), rounded to f32 and then to ``dtype``, one
+    PIECE of rows at a time. A piece is at most `_HOST_PIECE_CELLS` cells
+    and at most a 64th of the rows, so whatever the size the build holds
+    the block itself and a sliver of scratch — never a second block, and
+    never an f32 one where the storage is narrower (a streamed chunk
+    ladder's bf16 block is 17 GB at 8.4M rows: its f32 form would be 34).
+    Which rows share a piece changes no value: a cell's repeats are added
+    in their row's order either way."""
+    n = hot.shape[0]
+    dense = np.empty((n, d_sel), dtype)
+    piece = max(1, min(_HOST_PIECE_CELLS // max(d_sel, 1), -(-n // 64)))
+    for r0 in range(0, n, piece):
+        r1 = min(n, r0 + piece)
+        h = hot[r0:r1]
+        flat_ids = (np.nonzero(h)[0] * np.int64(d_sel)
+                    + pos[r0:r1][h])
+        dense[r0:r1] = np.bincount(
+            flat_ids, weights=val[r0:r1][h].astype(np.float64),
+            minlength=(r1 - r0) * d_sel,
+        ).astype(np.float32).reshape(r1 - r0, d_sel)
+    return dense
+
+
+def _split_at(ind, val, sel, pos, device_dense_dtype, mesh=None,
+              host_dense_dtype=np.float32, host_pinned=None):
     """`_hot_cold_split` from `_hot_positions`' output on: a builder that
     stores its rows in another order permutes the four row-wise in
     between. No (n, k) row-id array is made: the hot COO's row ids are
-    local to each keeping device's (or each host chunk's) row range, and
+    local to each keeping device's (or each host piece's) row range, and
     the tail's follow from the flat positions."""
     n, k = ind.shape
     d_sel = sel.shape[0]
@@ -743,22 +863,11 @@ def _split_at(ind, val, sel, pos, device_dense_dtype, mesh=None):
     if device_dense_dtype is not None:
         dense = _dense_on_devices(hot, pos, val, d_sel, device_dense_dtype,
                                   mesh)
+    elif host_pinned is not None:
+        dense = _dense_pinned(hot, pos, val, d_sel, host_dense_dtype,
+                              *host_pinned)
     else:
-        # bincount over flat (row, pos) ids: C-speed accumulation —
-        # np.add.at is an order of magnitude slower at the 10M-feature
-        # bench scale. Chunked over row ranges so the float64 bincount
-        # scratch stays bounded (~1 GB) at billion-cell n×d_sel scale.
-        dense = np.empty((n, d_sel), np.float32)
-        row_chunk = max(1, (1 << 27) // max(d_sel, 1))
-        for r0 in range(0, n, row_chunk):
-            r1 = min(n, r0 + row_chunk)
-            h = hot[r0:r1]
-            flat_ids = (np.nonzero(h)[0] * np.int64(d_sel)
-                        + pos[r0:r1][h])
-            dense[r0:r1] = np.bincount(
-                flat_ids, weights=val[r0:r1][h].astype(np.float64),
-                minlength=(r1 - r0) * d_sel,
-            ).astype(np.float32).reshape(r1 - r0, d_sel)
+        dense = _dense_on_host(hot, pos, val, d_sel, host_dense_dtype)
     cold = (~hot) & nnz_mask
     flat = np.flatnonzero(cold)       # row-major → tail rows ascending
     t_rows = flat // k
@@ -1105,8 +1214,9 @@ def blocked_ell_from_scipy_csr(csr, d_dense: int = 1024,
 
 
 def shard_blocked_ell(X: SparseRows, n_shards: int, d_dense: int = 1024,
-                      device_dense_dtype=None,
-                      mesh=None) -> ShardedBlockedEllRows:
+                      device_dense_dtype=None, mesh=None,
+                      host_dense_dtype=np.float32,
+                      host_pinned_piece_bytes=0) -> ShardedBlockedEllRows:
     """Build the SHARDED blocked-ELL hybrid (see ShardedBlockedEllRows)
     from padded COO rows. Rows must already divide ``n_shards``
     (`data.dataset.shard_blocked_ell_batch` pads + builds; the streamed
@@ -1119,8 +1229,13 @@ def shard_blocked_ell(X: SparseRows, n_shards: int, d_dense: int = 1024,
     8.4M × 1024 in bf16 the whole block is 17 GB, more than a chip holds,
     and there is no second route that assembles it on one. A host-built
     block (`device_dense_dtype=None`: the streamed chunk ladder's, with
-    S = n_chunks × D) takes no mesh. Every other leaf is host numpy
-    either way, and no leaf's value depends on the mesh.
+    S = n_chunks × D) takes no mesh and is stored as ``host_dense_dtype``
+    from its first byte (`_dense_on_host`: the ladder's f32 form is never
+    made); with ``host_pinned_piece_bytes`` it is kept in PINNED host
+    memory, in pieces of that many bytes that never straddle a shard
+    (`PinnedRows`: what a one-device chunk ladder streams from on an
+    accelerator). Every other leaf is host numpy either way, and no
+    leaf's value depends on the mesh or on where the block is kept.
 
     One vectorized host pass mirroring `shard_permuted_hybrid`: a GLOBAL
     column permutation (hot prefix from global frequencies, tail ranks by
@@ -1148,8 +1263,11 @@ def shard_blocked_ell(X: SparseRows, n_shards: int, d_dense: int = 1024,
             f"{int(mesh.devices.size)} devices: a mesh keeps one shard a "
             "device")
     with telemetry.span("layout.shard_build", shards=n_shards, rows=n):
-        return _shard_blocked_ell(X, n_shards, d_dense, device_dense_dtype,
-                                  mesh)
+        return _shard_blocked_ell(
+            X, n_shards, d_dense, device_dense_dtype, mesh,
+            host_dense_dtype,
+            (n // n_shards, host_pinned_piece_bytes)
+            if host_pinned_piece_bytes else None)
 
 
 def _count_shard_bytes(S, ell_rows_own, ladder, cs_counts, bucket_rows):
@@ -1172,14 +1290,16 @@ def _count_shard_bytes(S, ell_rows_own, ladder, cs_counts, bucket_rows):
     telemetry.count("layout.shard_bytes_padded", padded * slot)
 
 
-def _shard_blocked_ell(X, n_shards, d_dense, device_dense_dtype, mesh):
+def _shard_blocked_ell(X, n_shards, d_dense, device_dense_dtype, mesh,
+                       host_dense_dtype, host_pinned=None):
     """`shard_blocked_ell` after its checks, inside its span."""
     n = np.asarray(X.indices).shape[0]
     d = X.n_features
     n_local = n // n_shards
     d_sel = min(d_dense, d)
     dense, sel, t_rows, t_cols, t_vals = _hot_cold_split(
-        X, d_dense, device_dense_dtype, mesh)
+        X, d_dense, device_dense_dtype, mesh, host_dense_dtype,
+        host_pinned)
     t_vals = t_vals.astype(np.float32)
     m_tot = t_rows.size
     S = n_shards

@@ -307,9 +307,11 @@ class Objective:
         """(loss_sum, Xᵀr, Σr-or-None) partials from a cached chunk margin
         — one elementwise pass + one Xᵀr pass, no margin recompute."""
         loss, d1, _ = loss_fns(self.task)
-        r = batch.weights * d1(z, batch.y)
+        with device_scope("objective.loss"):
+            r = batch.weights * d1(z, batch.y)
+            local_value = jnp.sum(batch.weights * loss(z, batch.y))
         gX, gsum = self._backprop(batch, r)
-        return jnp.sum(batch.weights * loss(z, batch.y)), gX, gsum
+        return local_value, gX, gsum
 
     @staticmethod
     def add_partials(a, b):
@@ -331,9 +333,10 @@ class Objective:
         by the caller, so a streamed line-search trial uploads 16 bytes/row
         instead of re-streaming the chunk's features."""
         loss, d1, _ = loss_fns(self.task)
-        za = z + a * dz
-        return (jnp.sum(weights * loss(za, y)),
-                jnp.sum(weights * d1(za, y) * dz))
+        with device_scope("objective.loss"):
+            za = z + a * dz
+            return (jnp.sum(weights * loss(za, y)),
+                    jnp.sum(weights * d1(za, y) * dz))
 
     def chunk_value_partials_many(self, W, batch: GLMBatch):
         """(K,) smooth-objective value partials of K candidate coefficient
@@ -348,7 +351,8 @@ class Objective:
 
         def one(wk):
             z = self._margin(wk, batch)
-            return jnp.sum(batch.weights * loss(z, batch.y))
+            with device_scope("objective.loss"):
+                return jnp.sum(batch.weights * loss(z, batch.y))
 
         return jax.vmap(one)(W)
 

@@ -91,24 +91,25 @@ __all__ = ["minimize_lbfgs_streamed", "minimize_owlqn_streamed"]
 #
 # DONATION (the upload/compute-overlap round): each chunk-consuming
 # program has a `_don`-suffixed twin that DONATES its feature-chunk
-# argument — the chunk's buffers are consumed by the call (scalar leaves
-# alias outputs where shapes allow, the rest free at dispatch instead of
-# at the host loop's next refcount drop), which is what lets the
-# persistent `DeviceChunkRing` keep next-pass uploads in flight without a
-# third chunk copy ever going resident. The backends pick the donated
-# twin whenever the chunk has no cross-chunk shared leaves (`_donatable`
-# — the mesh blocked-ELL ladder shares ONE replicated column permutation
-# across chunks, so it keeps the plain programs). Donation never changes
-# the traced program or its signature — the
-# `mesh_stream_donated_no_retrace` contract pins that the ring's
-# rotating dispatches stay ONE signature.
+# argument. What that buys is less than it says: jax donates an input only
+# where an output of its aval or size can take the buffer
+# (`jax/_src/interpreters/mlir.py::_set_up_aliases`), so a chunk's scalar
+# leaves (y/weights/offsets ↔ margins) alias outputs and its feature
+# blocks — the hot block, every tail bucket — are NOT donated at all: they
+# stay on the device until the host loop's last name for them goes (on
+# the chip a third 4.45 GB chunk was live through every upload, PERF.md
+# §6, PR 34). So the one-device backend also hands each program's outputs
+# to `DeviceChunkRing.consumed`, and the ring frees the chunk itself
+# before its next upload. The backends pick the donated twin whenever the
+# chunk has no cross-chunk shared leaves (`_donatable` — the mesh
+# blocked-ELL ladder shares ONE replicated column permutation across
+# chunks, so it keeps the plain programs). Donation never changes the
+# traced program or its signature — the `mesh_stream_donated_no_retrace`
+# contract pins that the ring's rotating dispatches stay ONE signature.
 
 
-# Partial non-aliasability is the donation DESIGN here: a chunk's scalar
-# leaves (y/weights/offsets ↔ margins) alias outputs, its feature blocks
-# cannot (different shapes) and instead free at dispatch — jax would
-# otherwise warn "Some donated buffers were not usable" once per
-# compiled chunk shape for exactly the blocks we donate for early-free.
+# jax warns "Some donated buffers were not usable" once per compiled chunk
+# shape for exactly those feature blocks; the ring frees them.
 import warnings as _warnings  # noqa: E402
 
 _warnings.filterwarnings(
@@ -143,6 +144,7 @@ _chunk_value_many_don = jax.jit(_chunk_value_many_fn, donate_argnums=(2,))
 
 
 @jax.jit
+@telemetry.device_scope("lbfgs.linesearch")
 def _chunk_phi(obj, z, dz, a, y, weights):
     return obj.chunk_phi_partials(z, dz, a, y, weights)
 
@@ -174,6 +176,7 @@ def _ray_coeffs(obj, w, p):
 
 
 @jax.jit
+@telemetry.device_scope("lbfgs.update")
 def _axpy(w, a, p):
     return w + a * p
 
@@ -181,11 +184,12 @@ def _axpy(w, a, p):
 @jax.jit
 def _lbfgs_direction(g, h):
     p = -two_loop(h, g)
-    dphi0 = jnp.dot(p, g)
-    bad = dphi0 >= 0.0
-    p = jnp.where(bad, -g, p)
-    dphi0 = jnp.where(bad, -jnp.dot(g, g), dphi0)
-    return p, dphi0, jnp.linalg.norm(p)
+    with telemetry.device_scope("lbfgs.direction"):
+        dphi0 = jnp.dot(p, g)
+        bad = dphi0 >= 0.0
+        p = jnp.where(bad, -g, p)
+        dphi0 = jnp.where(bad, -jnp.dot(g, g), dphi0)
+        return p, dphi0, jnp.linalg.norm(p)
 
 
 @jax.jit
@@ -434,15 +438,23 @@ class _SingleDeviceStream:
     def iter_chunks(self):
         return self.ring.stream_pass()
 
+    def close(self):
+        self.ring.close()
+
+    # Every chunk program's outputs go through `ring.consumed`: the ring
+    # waits for them and frees the chunk before its next upload (a donated
+    # hot block has no output to alias, so donation does not free it)
+
     def chunk_init(self, obj, w, b):
-        z, parts = self._init(obj, w, b)
+        z, parts = self.ring.consumed(self._init(obj, w, b))
         return np.asarray(z), parts
 
     def chunk_grad(self, obj, z, b):
-        return self._grad(obj, z, b)
+        return self.ring.consumed(self._grad(obj, z, b))
 
     def chunk_dz_phi(self, obj, p, z, a, b):
-        dz, wlwd = self._dz_phi(obj, p, z, np.float32(a), b)
+        dz, wlwd = self.ring.consumed(
+            self._dz_phi(obj, p, z, np.float32(a), b))
         return np.asarray(dz), wlwd
 
     def chunk_phi(self, obj, i, z, dz, a):
@@ -450,7 +462,7 @@ class _SingleDeviceStream:
         return _chunk_phi(obj, z, dz, np.float32(a), b.y, b.weights)
 
     def chunk_value_many(self, obj, W, b):
-        return self._value_many(obj, W, b)
+        return self.ring.consumed(self._value_many(obj, W, b))
 
     def finish(self, obj, w, acc):
         return _finish(obj, w, acc)
@@ -518,6 +530,9 @@ class _MeshStream:
 
     def iter_chunks(self):
         return self.ring.stream_pass()
+
+    def close(self):
+        self.ring.close()
 
     def _fetch(self, arr):
         from photon_tpu.parallel.mesh import fetch_local_rows
@@ -726,6 +741,16 @@ def _convergence_host(ok, f_old, f_new, gnorm, g0norm, dphi0,
     return grad_conv or f_conv or precision_limited
 
 
+def _pass_span(kind: str):
+    """Host span ``stream.pass`` around ONE pass over the chunks, from the
+    first chunk asked of the ring to the readback that closes the pass;
+    ``kind`` says which: "init" (margins, value and gradient at the
+    start), "dz" (the direction's margins, with the first trial),
+    "gradient" (at cached margins), "refresh" (a gradient pass that
+    re-anchors the margins on w), OWL-QN's "value_grad" and "ladder"."""
+    return telemetry.span("stream.pass", kind=kind)
+
+
 def _eval_tick(ck, n: int = 1) -> None:
     """One objective evaluation closed: a fault-injection site (the
     streamed regime's 'evaluation' kill point) + checkpoint cadence
@@ -745,11 +770,12 @@ def _eval_tick(ck, n: int = 1) -> None:
 
 def _pack_stream_state(kind, d, n_chunks, chunk_rows, max_iters, it, f,
                        g0norm, hist, ghist, converged, failed, done, w, g,
-                       hist_st, extra=None) -> dict:
+                       hist_st, trials, extra=None) -> dict:
     st = {
         "kind": kind, "d": int(d), "n_chunks": int(n_chunks),
         "chunk_rows": int(chunk_rows), "max_iters": int(max_iters),
-        "it": int(it), "f": float(f), "g0norm": float(g0norm),
+        "it": int(it), "trials": int(trials), "f": float(f),
+        "g0norm": float(g0norm),
         "hist": np.asarray(hist), "ghist": np.asarray(ghist),
         "converged": bool(converged), "failed": bool(failed),
         "done": bool(done), "w": w, "g": g,
@@ -824,7 +850,8 @@ def _restore_z_cache(st: dict, data, mesh) -> list:
             for i in range(data.n_chunks)]
 
 
-def _result(w, value, gnorm, it, converged, failed, hist, ghist) -> OptResult:
+def _result(w, value, gnorm, it, converged, failed, hist, ghist,
+            trials) -> OptResult:
     return OptResult(
         w=w, value=jnp.asarray(np.float32(value)),
         grad_norm=jnp.asarray(np.float32(gnorm)),
@@ -833,6 +860,7 @@ def _result(w, value, gnorm, it, converged, failed, hist, ghist) -> OptResult:
         failed=jnp.asarray(bool(failed)),
         loss_history=jnp.asarray(hist),
         grad_norm_history=jnp.asarray(ghist),
+        evaluations=jnp.asarray(np.int32(trials)),
     )
 
 
@@ -864,15 +892,20 @@ def minimize_lbfgs_streamed(
     `OptResult.loss_history`), plus feature-stream / evaluation /
     line-search / margin-cache counters (photon_tpu.telemetry; no-ops
     without an attached Run)."""
-    with telemetry.span("solve.lbfgs_streamed", mesh=mesh is not None,
-                        n_chunks=data.n_chunks):
-        return _lbfgs_streamed(obj, data, w0, max_iters, tolerance,
-                               history, max_ls_evals, mesh, prefetch)
+    _check_streamable(obj, mesh)
+    be = _backend(data, mesh, prefetch)
+    try:
+        with telemetry.span("solve.lbfgs_streamed", mesh=mesh is not None,
+                            n_chunks=data.n_chunks):
+            return _lbfgs_streamed(obj, data, w0, max_iters, tolerance,
+                                   history, max_ls_evals, mesh, be)
+    finally:
+        be.close()  # the last pass primed chunks for one that never comes
 
 
 def _pack_lbfgs_state(d, n_chunks, data, mesh, max_iters, it, f, g0norm,
                       hist, ghist, converged, failed, done, w, g, hist_st,
-                      z_cache, z_gen) -> dict:
+                      trials, z_cache, z_gen) -> dict:
     extra: dict = {}
     for i in range(n_chunks):
         extra.update(_ckpt.pack_row_slots(z_cache[i], mesh,
@@ -881,13 +914,11 @@ def _pack_lbfgs_state(d, n_chunks, data, mesh, max_iters, it, f, g0norm,
     return _pack_stream_state("lbfgs_streamed", d, n_chunks,
                               data.chunk_rows, max_iters, it, f, g0norm,
                               hist, ghist, converged, failed, done, w, g,
-                              hist_st, extra)
+                              hist_st, trials, extra)
 
 
 def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
-                    max_ls_evals, mesh, prefetch) -> OptResult:
-    _check_streamable(obj, mesh)
-    be = _backend(data, mesh, prefetch)
+                    max_ls_evals, mesh, be) -> OptResult:
     n_chunks = data.n_chunks
     d = int(jnp.asarray(w0).shape[0])
     ck = _ckpt.current()
@@ -910,7 +941,7 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
         f, g0norm = float(st["f"]), float(st["g0norm"])
         hist = np.array(st["hist"], np.float32)
         ghist = np.array(st["ghist"], np.float32)
-        it = int(st["it"])
+        it, trials = int(st["it"]), int(st.get("trials", 0))
         converged, failed = bool(st["converged"]), bool(st["failed"])
         done = bool(st["done"])
         z_gen = int(st.get("z_gen", 0))
@@ -930,8 +961,8 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
         # ---- initial pass: margins cached per chunk, (f, g) accumulated
         z_cache = [None] * n_chunks
         acc = None
-        with profiling.measure(be.prog + "chunk_init", "lbfgs/init",
-                               calls=n_chunks):
+        with _pass_span("init"), profiling.measure(
+                be.prog + "chunk_init", "lbfgs/init", calls=n_chunks):
             for i, b in be.iter_chunks():
                 be.note("chunk_init", obj, w, b)
                 z_cache[i], parts = be.chunk_init(obj, w, b)
@@ -948,14 +979,14 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
         ghist = np.full(max_iters + 1, np.nan, np.float32)
         hist[0], ghist[0] = f, g0norm
 
-        it, converged, failed = 0, g0norm <= 1e-14, False
+        it, trials, converged, failed = 0, 0, g0norm <= 1e-14, False
         done = converged
         if ck is not None:
             # the it=0 cut: resuming from here is provably == cold start
             ck.update("lbfgs_streamed", _pack_lbfgs_state(
                 d, n_chunks, data, mesh, max_iters, it, f, g0norm, hist,
-                ghist, converged, failed, done, w, g, hist_st, z_cache,
-                z_gen))
+                ghist, converged, failed, done, w, g, hist_st, trials,
+                z_cache, z_gen))
             ck.maybe_snapshot()
     dz_cache: list = [None] * n_chunks
     while not done and it < max_iters:
@@ -971,8 +1002,9 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
         # ---- direction pass (feature stream 1 of 2): dz per chunk, with
         # the FIRST Wolfe trial's φ(a_init) partials riding along.
         phis = None
-        with profiling.measure(be.prog + "chunk_dz_phi", "lbfgs/direction",
-                               calls=n_chunks):
+        with _pass_span("dz"), profiling.measure(
+                be.prog + "chunk_dz_phi", "lbfgs/direction",
+                calls=n_chunks):
             for i, b in be.iter_chunks():
                 be.note("chunk_dz_phi", obj, p, z_cache[i],
                         np.float32(a_init), b)
@@ -1008,6 +1040,7 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
                                                   max_ls_evals,
                                                   first=first_eval)
         telemetry.count("solver.linesearch_trials", n_trials)
+        trials += n_trials
 
         if ok:
             w_new = _axpy(w, np.float32(alpha), p)
@@ -1024,8 +1057,9 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
                 z_gen += 1
             acc = None
             grad_prog = be.prog + ("chunk_init" if refresh else "chunk_grad")
-            with profiling.measure(grad_prog, "lbfgs/gradient",
-                                   calls=n_chunks):
+            with _pass_span("refresh" if refresh else "gradient"), \
+                    profiling.measure(grad_prog, "lbfgs/gradient",
+                                      calls=n_chunks):
                 for i, b in be.iter_chunks():
                     if refresh:  # re-anchor chained margin on w (f32 drift)
                         z_cache[i], parts = be.chunk_init(obj, w_new, b)
@@ -1056,12 +1090,12 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
             # iteration boundary = the crash-consistency cut
             ck.update("lbfgs_streamed", _pack_lbfgs_state(
                 d, n_chunks, data, mesh, max_iters, it, f, g0norm, hist,
-                ghist, converged, failed, done, w, g, hist_st, z_cache,
-                z_gen))
+                ghist, converged, failed, done, w, g, hist_st, trials,
+                z_cache, z_gen))
             ck.maybe_snapshot()
 
     return _result(be.result_w(w), f, float(jnp.linalg.norm(g)), it,
-                   converged, failed, hist, ghist)
+                   converged, failed, hist, ghist, trials)
 
 
 # --------------------------------------------------------- streamed OWL-QN
@@ -1092,27 +1126,30 @@ def minimize_owlqn_streamed(
     Telemetry mirrors the streamed L-BFGS: live `iteration` events plus
     feature-stream / evaluation / ladder-trial counters from the host
     driver loop (no-ops without an attached Run)."""
-    with telemetry.span("solve.owlqn_streamed", mesh=mesh is not None,
-                        n_chunks=data.n_chunks):
-        return _owlqn_streamed(obj, data, w0, l1_weight, max_iters,
-                               tolerance, history, max_ls_evals, reg_mask,
-                               ladder_lanes, mesh, prefetch)
+    _check_streamable(obj, mesh)
+    be = _backend(data, mesh, prefetch)
+    try:
+        with telemetry.span("solve.owlqn_streamed", mesh=mesh is not None,
+                            n_chunks=data.n_chunks):
+            return _owlqn_streamed(obj, data, w0, l1_weight, max_iters,
+                                   tolerance, history, max_ls_evals,
+                                   reg_mask, ladder_lanes, mesh, be)
+    finally:
+        be.close()
 
 
 def _pack_owlqn_state(d, n_chunks, data, max_iters, it, f, F, pg0norm,
                       hist, ghist, converged, failed, done, w, g,
-                      hist_st) -> dict:
+                      hist_st, trials) -> dict:
     return _pack_stream_state("owlqn_streamed", d, n_chunks,
                               data.chunk_rows, max_iters, it, f, pg0norm,
                               hist, ghist, converged, failed, done, w, g,
-                              hist_st, {"F": float(F)})
+                              hist_st, trials, {"F": float(F)})
 
 
 def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
                     history, max_ls_evals, reg_mask, ladder_lanes, mesh,
-                    prefetch) -> OptResult:
-    _check_streamable(obj, mesh)
-    be = _backend(data, mesh, prefetch)
+                    be) -> OptResult:
     n_chunks = data.n_chunks
     d = int(jnp.asarray(w0).shape[0])
     l1 = np.float32(l1_weight)
@@ -1126,8 +1163,9 @@ def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
         telemetry.count("solver.feature_streams")
         telemetry.count("solver.evaluations")
         acc = None
-        with profiling.measure(be.prog + "chunk_init", "owlqn/value_grad",
-                               calls=n_chunks):
+        with _pass_span("value_grad"), profiling.measure(
+                be.prog + "chunk_init", "owlqn/value_grad",
+                calls=n_chunks):
             for i, b in be.iter_chunks():
                 be.note("chunk_init", obj, w_at, b)
                 _, parts = be.chunk_init(obj, w_at, b)
@@ -1155,7 +1193,7 @@ def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
         pg0norm = float(st["g0norm"])
         hist = np.array(st["hist"], np.float32)
         ghist = np.array(st["ghist"], np.float32)
-        it = int(st["it"])
+        it, trials = int(st["it"]), int(st.get("trials", 0))
         converged, failed = bool(st["converged"]), bool(st["failed"])
         done = bool(st["done"])
         telemetry.count("checkpoint.solver_restores")
@@ -1176,12 +1214,12 @@ def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
         ghist = np.full(max_iters + 1, np.nan, np.float32)
         hist[0], ghist[0] = F, pg0norm
 
-        it, converged, failed = 0, pg0norm <= 1e-14, False
+        it, trials, converged, failed = 0, 0, pg0norm <= 1e-14, False
         done = converged
         if ck is not None:
             ck.update("owlqn_streamed", _pack_owlqn_state(
                 d, n_chunks, data, max_iters, it, f, F, pg0norm, hist,
-                ghist, converged, failed, done, w, g, hist_st))
+                ghist, converged, failed, done, w, g, hist_st, trials))
             ck.maybe_snapshot()
     while not done and it < max_iters:
         p, dphi0_dev, xi, pg, pnorm = _owlqn_direction(
@@ -1204,8 +1242,9 @@ def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
             telemetry.count("solver.evaluations", K)
             telemetry.count("solver.linesearch_trials", K)
             acc = None
-            with profiling.measure(be.prog + "chunk_value_many",
-                                   "owlqn/ladder", calls=n_chunks):
+            with _pass_span("ladder"), profiling.measure(
+                    be.prog + "chunk_value_many", "owlqn/ladder",
+                    calls=n_chunks):
                 for _, b in be.iter_chunks():
                     be.note("chunk_value_many", obj, W, b)
                     part = be.chunk_value_many(obj, W, b)
@@ -1221,6 +1260,7 @@ def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
                     ok, w_new = True, W[k]
                     break
             evals += K
+        trials += evals
 
         if ok:
             f_new, g_new = value_grad_pass(w_new)  # gradient stream
@@ -1250,11 +1290,11 @@ def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
         if ck is not None:
             ck.update("owlqn_streamed", _pack_owlqn_state(
                 d, n_chunks, data, max_iters, it, f, F, pg0norm, hist,
-                ghist, converged, failed, done, w, g, hist_st))
+                ghist, converged, failed, done, w, g, hist_st, trials))
             ck.maybe_snapshot()
 
     return _result(be.result_w(w), F, float(_pg_norm(w, g, l1, mask)), it,
-                   converged, failed, hist, ghist)
+                   converged, failed, hist, ghist, trials)
 
 
 # ----------------------------------------------------------------- contracts

@@ -28,8 +28,9 @@ class OptResult(NamedTuple):
     grad_norm_history: jax.Array  # (max_iters + 1,), NaN-padded
     # line-search evaluations taken over the whole solve (the searches' own
     # trial counts, summed; lock-step for a lane solver, so one scalar for
-    # all lanes). Stays on the device like every other field; None where
-    # the solver has no line search (TRON) or does not count (host loops).
+    # all lanes; the streamed host loops sum their Wolfe trials, and the
+    # rungs their OWL-QN ladders priced). Stays on the device like every
+    # other field; None where the solver has no line search (TRON).
     evaluations: Optional[jax.Array] = None
 
     def history(self) -> np.ndarray:

@@ -18,8 +18,14 @@ Three primitives (see `run.Run`): nestable host-side **spans** (also fed
 to `jax.profiler.TraceAnnotation`, so they appear on XProf timelines;
 `utils.timing.PhaseTimers` forwards the drivers' phase blocks here
 automatically), **counters/gauges** (the streamed chunk pipeline's
-`stream.*` family — passes/chunk_uploads/stall_seconds/compute_seconds/
-stalled_passes counters beside the prefetch_depth gauge; the streamed
+`stream.*` family — passes/chunk_uploads/upload_bytes/stall_seconds/
+issue_seconds/compute_seconds/stalled_passes counters beside the
+prefetch_depth gauge (stream.upload_bytes: the host bytes of the chunks a
+pass consumed, every leaf `device_put` is handed; stream.issue_seconds:
+the host seconds a pass spent handing chunks to the runtime), with one
+`stream.pass` span around every pass a streamed solver makes over the
+chunks, its ``kind`` attribute init / dz / gradient / refresh /
+value_grad / ladder; the streamed
 solver loops' `solver.*` family — iterations/evaluations/
 feature_streams/linesearch_trials plus the margin_cache.hits/
 margin_cache.refreshes cache pair; retrace.new_signatures riding
@@ -417,7 +423,8 @@ TELEMETRY_REGISTRY = {
         "ingest.cache_hits", "ingest.cache_misses", "ingest.cache_builds",
         "ingest.cache_commits", "ingest.cache_chunks",
         "ingest.cache_bytes", "ingest.cache_invalid",
-        "stream.passes", "stream.chunk_uploads", "stream.stall_seconds",
+        "stream.passes", "stream.chunk_uploads", "stream.upload_bytes",
+        "stream.stall_seconds", "stream.issue_seconds",
         "stream.compute_seconds", "stream.stalled_passes",
         "stream.prefetch_widened", "stream.prefetch_narrowed",
         "solver.iterations", "solver.evaluations",
@@ -463,7 +470,7 @@ TELEMETRY_REGISTRY = {
     "span_families": (
         "train", "score", "ingest", "solve",
         "game", "game_re", "serving", "checkpoint", "continual",
-        "tuning", "parallel", "layout",
+        "tuning", "parallel", "layout", "stream",
     ),
     "device_scopes": (
         "xpass.fwd", "xpass.fwd.hot", "xpass.fwd.tail",

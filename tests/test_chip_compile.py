@@ -564,3 +564,79 @@ def test_history_step_is_two_passes(one_chip, form):
     for body in _while_bodies(text):
         assert str(d) not in body and f"[{h.S.shape[1]}," not in body, \
             body[:400]
+
+
+# ------------- the streamed cell's chunk programs at 2,097,152 x 10M (PR 34)
+def _stream_chunk(s):
+    """One chunk of `glm-sparse10m-stream.single`'s host ladder as shapes:
+    `chunk_blocked_ell(batch, 2097152, d_dense=1024,
+    feature_dtype=bfloat16, n_shards=1)` over the mesh cell's rows is
+    `shard_blocked_ell` with S = 4, so a chunk has the mesh cell's
+    per-shard common shapes (`MESH4_*`), its rows in the caller's order
+    (`row_order` None: the forward tail keeps its `row_pos` gather) and
+    the ladder's two (d,) permutation vectors as leaves of its own."""
+    from photon_tpu.data.dataset import GLMBatch
+    from photon_tpu.data.matrix import BlockedEllRows
+
+    n = MESH4_N // MESH4_SHARDS
+    rows = _shape((n,), "float32", s)
+    X = BlockedEllRows(
+        dense=_shape((n, 1024), "bfloat16", s),
+        ell_pcols=tuple(_shape(b, "int32", s) for b in MESH4_ELL),
+        ell_vals=tuple(_shape(b, "bfloat16", s) for b in MESH4_ELL),
+        row_pos=_shape((n,), "int32", s),
+        bucket_rows=tuple(_shape(b, "int32", s) for b in MESH4_BUCKETS),
+        bucket_vals=tuple(_shape(b, "bfloat16", s) for b in MESH4_BUCKETS),
+        perm_cols=_shape((GLM_FEATURES,), "int32", s),
+        inv_perm=_shape((GLM_FEATURES,), "int32", s),
+        n_features=GLM_FEATURES, n_prefix=MESH4_PREFIX, last_col_pos=1023,
+        tail_nnz=13509184)
+    return GLMBatch(X, rows, rows, rows)
+
+
+@pytest.mark.parametrize("program", ["chunk_init", "chunk_grad_at_margin",
+                                     "chunk_dz_phi"])
+def test_streamed_chunk_program_compiles(one_chip, program):
+    """The three donated per-chunk programs `_SingleDeviceStream` runs in
+    a streamed L-BFGS solve — margins with partials (the first pass),
+    partials at cached margins (a gradient pass), the direction's margins
+    with the first trial (a dz pass) — at the cell's chunk: 2,097,152 rows
+    x 10M features, bf16. Each compiles for the chip and, with a second
+    chunk of the upload ring beside it and the solver state, fits it: the
+    program's own arguments, outputs and temporaries stay under 5.2 GB (a
+    chunk is 4.45), so the chunk being consumed, the one uploading behind
+    it and 0.7 GB of solver state are 10 GB of the chip's 16 GiB (the ring
+    frees a consumed chunk before it allocates the next; a third would
+    make 14.5 GB)."""
+    from photon_tpu.models.training import make_objective
+    from photon_tpu.ops.losses import TaskType
+    from photon_tpu.optim import streamed
+    from photon_tpu.optim.config import OptimizerConfig
+    from photon_tpu.optim.regularization import l2
+
+    s = one_chip
+    batch = _stream_chunk(s)
+    n, d = batch.y.shape[0], GLM_FEATURES
+    cfg = OptimizerConfig(max_iters=10, tolerance=0.0, reg=l2(),
+                          reg_weight=1e-3, history=5)
+    obj = make_objective(TaskType.LOGISTIC_REGRESSION, cfg, d,
+                         intercept_index=1023)
+    obj = jax.tree_util.tree_map(
+        lambda leaf: _shape(np.shape(leaf), jnp.asarray(leaf).dtype, s), obj)
+    w = _shape((d,), "float32", s)
+    z = _shape((n,), "float32", s)
+    step = _shape((), "float32", s)
+    fn, args = {
+        "chunk_init": (streamed._chunk_init_don, (obj, w, batch)),
+        "chunk_grad_at_margin": (streamed._chunk_grad_at_margin_don,
+                                 (obj, z, batch)),
+        "chunk_dz_phi": (streamed._chunk_dz_phi_don,
+                         (obj, w, z, step, batch)),
+    }[program]
+    with jax.default_matmul_precision("default"):
+        compiled = fn.lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    on_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes)
+    assert 4.4e9 < on_device < 5.2e9, on_device
