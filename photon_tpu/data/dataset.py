@@ -617,24 +617,32 @@ class DeviceChunkRing:
     ring keeps the window primed ACROSS passes instead: chunk indices
     wrap (the streamed solvers re-stream the same chunks every
     evaluation), so while the caller closes pass p — partials, psum,
-    readback — the first `depth` chunks of pass p+1 are already in
-    flight. Paired with the streamed backends' donated chunk programs
-    (optim/streamed.py: the compute program consumes its chunk's
-    buffers), peak HBM stays ~`depth` chunks — the two-deep ring never
-    holds a third copy.
+    readback — the first chunks of pass p+1 are already in flight.
 
-    The ring owns that depth, and donation alone does not give it: a
-    donated leaf with no output of its size to alias (the hot block, every
-    tail bucket) is not donated at all, so a consumed chunk stays on the
-    device for as long as the consumer's loop variable names it — through
-    the NEXT upload's allocation. So a one-device consumer hands each
-    chunk program's outputs to `consumed()`: before the ring issues
-    another upload it waits for them and frees what the program left of
-    its chunk, and a new chunk is allocated only once the device holds
-    fewer than `depth` — at the north-star chunk (4.45 GB) a third does
-    not fit beside the solver. A solve `close()`s its ring when it ends:
-    what was primed for a pass that never comes is dropped once it has
-    landed, so the next solve's ring never meets it on the chip.
+    The ring owns its depth — never more than `depth` chunks allocated on
+    the device — and donation alone does not give it: a donated leaf with
+    no output of its size to alias (the hot block, every tail bucket) is
+    not donated at all, so a consumed chunk stays on the device for as
+    long as the consumer's loop variable names it. So a one-device
+    consumer hands each chunk program's outputs to `consumed()`, straight
+    after the dispatch, and THAT is where the ring acts: it frees the
+    chunk handed out before this one (whose program ran ahead of this
+    chunk's in-place writes on the device's one queue, so it finished
+    before this chunk landed: no wait), then issues the next upload. The
+    program just dispatched therefore runs AHEAD of the next chunk's
+    in-place writes and BESIDE its transfers — an upload call returns when
+    all but its last pieces have crossed the link, and a program
+    dispatched only after that call runs with the link idle (54 ms a
+    4.45 GB chunk on the chip, PERF.md §6, PR 35). With depth 2 the
+    device holds the chunk being computed on and the chunk being
+    uploaded; at the north-star chunk a third does not fit beside the
+    solver. A consumer that says nothing gets the next upload when it
+    resumes the generator, and frees its chunks itself by dropping them —
+    one rule: the ring tops itself up to `depth` chunks, counting the one
+    whose program it was told of, whenever it is given the word. A solve
+    `close()`s its ring when it ends: what was primed for a pass that
+    never comes is dropped once it has landed, so the next solve's ring
+    never meets it on the chip.
 
     Per-pass semantics are `iter_device`'s exactly: `stream_pass()`
     yields ``(i, device_chunk)`` in order with the same telemetry
@@ -643,8 +651,10 @@ class DeviceChunkRing:
     dropped by `close()` are not in it; `stream.issue_seconds` the host
     seconds the pass spent handing chunks to the runtime: an upload in
     pieces returns when all but its last pieces have crossed the link, so
-    on a link-bound stream this is the wait for the link), the same
-    `chunk_upload`
+    on a link-bound stream this is the wait for the link;
+    `stream.uploads_behind_compute` the uploads issued while the outputs
+    `consumed()` was given were not ready yet: the program they hide
+    behind), the same `chunk_upload`
     fault-injection site per chunk, ledger attribution
     (``ingest.upload`` stall + ``solve.compute``) and `AdaptivePrefetch`
     support. A pass abandoned mid-way (an
@@ -665,8 +675,9 @@ class DeviceChunkRing:
         self._window: deque = deque()
         self._next = 0  # chunk index the next upload issues (mod n_chunks)
         self._handed = None  # the chunk the consumer holds
-        self._pending = None  # its program's outputs, once `consumed`
+        self._spoken = None  # (chunk, its program's outputs), once `consumed`
         self._issue = 0.0  # host seconds inside `_put` this pass
+        self._behind = 0  # uploads issued behind a running program, this pass
         self._chunk_nbytes = batch.chunk_nbytes()
         if mesh is not None:
             mesh_cache: dict = {}  # persists across passes: perm uploads once
@@ -683,35 +694,52 @@ class DeviceChunkRing:
 
     def consumed(self, outputs):
         """The outputs of the program that consumed the chunk just handed
-        out, returned as they are: the consumer is done with that chunk.
-        Before its next upload the ring waits for them and frees the chunk
+        out, returned as they are: the consumer is done with that chunk
+        and its program is dispatched. The ring frees the chunk spoken for
+        before this one and issues the next upload behind this program
         (see the class note); a mesh ring only waits, its chunks share the
         replicated permutation's buffers."""
-        self._pending = outputs
+        self._release()
+        self._spoken, self._handed = (self._handed, outputs), None
+        self._top_up()
         return outputs
 
     def _release(self) -> None:
-        """Once the program `consumed()` named has run, free its chunk."""
-        if self._pending is None:
+        """Once the program `consumed()` was told of has run, free its
+        chunk."""
+        if self._spoken is None:
             return
-        jax.block_until_ready(self._pending)
+        chunk, outputs = self._spoken
+        jax.block_until_ready(outputs)
         if self.mesh is None:
-            for leaf in jax.tree_util.tree_leaves(self._handed):
+            for leaf in jax.tree_util.tree_leaves(chunk):
                 if isinstance(leaf, jax.Array) and not leaf.is_deleted():
                     leaf.delete()
-        self._pending = self._handed = None
+        self._spoken = None
 
-    def _fill(self, n: int) -> None:
-        """Top the window up to the ring's depth, the consumed chunk let
-        go first."""
+    def _top_up(self) -> None:
+        """Issue uploads until the ring holds its depth: the window and
+        the chunk whose program is still the ring's to wait for."""
         import time as _time
 
-        self._release()
-        while len(self._window) < min(self.depth, n):
+        n = self.batch.n_chunks
+        held = self._spoken is not None
+        while len(self._window) + held < min(self.depth, n):
+            if held and not _is_ready(self._spoken[1]):
+                self._behind += 1
             t0 = _time.perf_counter()
             self._window.append(self._put(self._next))
             self._issue += _time.perf_counter() - t0
             self._next = (self._next + 1) % n
+
+    def _fill(self) -> None:
+        """The generator's turn: a chunk nobody spoke for is its
+        consumer's to drop, and a window that `consumed()` left empty (a
+        ring one deep) takes the spoken chunk's place."""
+        self._handed = None
+        if not self._window:
+            self._release()
+        self._top_up()
 
     def close(self) -> None:
         """Drop whatever is still in flight, once it has landed: after
@@ -735,12 +763,12 @@ class DeviceChunkRing:
         if n == 0:
             return
         depth = self.depth
-        stall, self._issue = 0.0, 0.0
+        stall, self._issue, self._behind = 0.0, 0.0, 0
         t_start = _time.perf_counter()
         ok = False
         try:
             for i in range(n):
-                self._fill(n)
+                self._fill()
                 cur = self._window.popleft()
                 kill_point("chunk_upload")
                 t0 = _time.perf_counter()
@@ -749,21 +777,23 @@ class DeviceChunkRing:
                 self._handed = cur
                 del cur  # the ring's one name for it is `_handed`
                 yield i, self._handed
-            # prime the NEXT pass before the caller closes this one (the
-            # in-loop fill already wrapped past chunk n-1; this tops the
-            # window back up after the final popleft)
-            self._fill(n)
+            # prime the NEXT pass before the caller closes this one: a
+            # consumer that spoke for the last chunk already has (its
+            # `consumed` wrapped past chunk n-1); this tops the window up
+            # for one that did not
+            self._fill()
             ok = True
         finally:
             if not ok:
                 # abandoned mid-pass (kill/exception): drop in-flight
                 # uploads so the next pass starts clean at chunk 0
                 self._window.clear()
-                self._next, self._pending, self._handed = 0, None, None
+                self._next, self._spoken, self._handed = 0, None, None
             compute = (_time.perf_counter() - t_start) - stall
             telemetry.count("stream.passes")
             telemetry.count("stream.chunk_uploads", n)
             telemetry.count("stream.upload_bytes", n * self._chunk_nbytes)
+            telemetry.count("stream.uploads_behind_compute", self._behind)
             telemetry.count("stream.stall_seconds", stall)
             telemetry.count("stream.issue_seconds", self._issue)
             telemetry.count("stream.compute_seconds", max(compute, 0.0))
@@ -776,6 +806,12 @@ class DeviceChunkRing:
                     stall, max(compute, 0.0), n,
                     self.batch.X.nbytes() // max(self.batch.X.n_chunks, 1))
             _log_stream_stall(stall, compute, n, depth)
+
+
+def _is_ready(tree) -> bool:
+    """Whether every device array of a tree has been computed."""
+    return all(leaf.is_ready() for leaf in jax.tree_util.tree_leaves(tree)
+               if hasattr(leaf, "is_ready"))
 
 
 def mesh_chunk_matrix(X, mesh, _cache: dict | None = None):

@@ -99,10 +99,14 @@ __all__ = ["minimize_lbfgs_streamed", "minimize_owlqn_streamed"]
 # stay on the device until the host loop's last name for them goes (on
 # the chip a third 4.45 GB chunk was live through every upload, PERF.md
 # §6, PR 34). So the one-device backend also hands each program's outputs
-# to `DeviceChunkRing.consumed`, and the ring frees the chunk itself
-# before its next upload. The backends pick the donated twin whenever the
-# chunk has no cross-chunk shared leaves (`_donatable` — the mesh
-# blocked-ELL ladder shares ONE replicated column permutation across
+# to `DeviceChunkRing.consumed`, straight after the dispatch and before it
+# reads anything back: there the ring frees the chunk handed out BEFORE
+# this one (its program has finished) and issues the next upload BEHIND
+# the program just dispatched, which so runs beside that upload's
+# transfers and not after them (PERF.md §6, PR 35); the margin readback
+# that follows finds its program done. The backends pick the donated twin
+# whenever the chunk has no cross-chunk shared leaves (`_donatable` — the
+# mesh blocked-ELL ladder shares ONE replicated column permutation across
 # chunks, so it keeps the plain programs). Donation never changes the
 # traced program or its signature — the `mesh_stream_donated_no_retrace`
 # contract pins that the ring's rotating dispatches stay ONE signature.
@@ -441,8 +445,9 @@ class _SingleDeviceStream:
     def close(self):
         self.ring.close()
 
-    # Every chunk program's outputs go through `ring.consumed`: the ring
-    # waits for them and frees the chunk before its next upload (a donated
+    # Every chunk program's outputs go through `ring.consumed` BEFORE any
+    # readback: the ring issues its next upload there, behind the program
+    # just dispatched, and frees the chunk of the one before (a donated
     # hot block has no output to alias, so donation does not free it)
 
     def chunk_init(self, obj, w, b):

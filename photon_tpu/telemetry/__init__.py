@@ -18,11 +18,14 @@ Three primitives (see `run.Run`): nestable host-side **spans** (also fed
 to `jax.profiler.TraceAnnotation`, so they appear on XProf timelines;
 `utils.timing.PhaseTimers` forwards the drivers' phase blocks here
 automatically), **counters/gauges** (the streamed chunk pipeline's
-`stream.*` family — passes/chunk_uploads/upload_bytes/stall_seconds/
+`stream.*` family — passes/chunk_uploads/upload_bytes/
+uploads_behind_compute/stall_seconds/
 issue_seconds/compute_seconds/stalled_passes counters beside the
 prefetch_depth gauge (stream.upload_bytes: the host bytes of the chunks a
 pass consumed, every leaf `device_put` is handed; stream.issue_seconds:
-the host seconds a pass spent handing chunks to the runtime), with one
+the host seconds a pass spent handing chunks to the runtime;
+stream.uploads_behind_compute: the uploads the ring issued while the
+chunk program it had just been told of was still running), with one
 `stream.pass` span around every pass a streamed solver makes over the
 chunks, its ``kind`` attribute init / dz / gradient / refresh /
 value_grad / ladder; the streamed
@@ -424,7 +427,7 @@ TELEMETRY_REGISTRY = {
         "ingest.cache_commits", "ingest.cache_chunks",
         "ingest.cache_bytes", "ingest.cache_invalid",
         "stream.passes", "stream.chunk_uploads", "stream.upload_bytes",
-        "stream.stall_seconds", "stream.issue_seconds",
+        "stream.uploads_behind_compute", "stream.stall_seconds", "stream.issue_seconds",
         "stream.compute_seconds", "stream.stalled_passes",
         "stream.prefetch_widened", "stream.prefetch_narrowed",
         "solver.iterations", "solver.evaluations",

@@ -249,19 +249,24 @@ def test_one_shot_stream_counts_its_bytes():
 
 
 def test_ring_holds_its_depth_and_ends_empty():
-    """The ring issues an upload only once the program that consumed the
-    last chunk has returned (`consumed`: the donated chunk is free, so the
-    device never holds one more than the ring's depth); `close()` drops
-    what the last pass primed; a solve leaves its ring empty, having
-    uploaded what its passes consumed and the two chunks primed for a pass
-    that never came."""
+    """The ring acts when its consumer speaks: `consumed` registers the
+    handed chunk's program, frees the chunk spoken for BEFORE it (so the
+    device never holds one more than the ring's depth) and only then
+    issues the next upload — behind the program just dispatched, not
+    ahead of it. A consumer that says nothing gets its uploads when it
+    resumes the generator. `close()` drops what the last pass primed; a
+    solve leaves its ring empty, having uploaded what its passes consumed
+    and the one chunk primed for a pass that never came."""
     X, y = _problem()
     cb = chunk_blocked_ell(make_batch(X, y), 128, d_dense=32)
     log = []
 
-    class Output:  # stands for a chunk program's result
+    class Output:  # stands for a chunk program's result, still running
         def __init__(self, i):
             self.i = i
+
+        def is_ready(self):
+            return False
 
         def block_until_ready(self):
             log.append(("ready", self.i))
@@ -271,23 +276,37 @@ def test_ring_holds_its_depth_and_ends_empty():
     put = ring._put
     ring._put = lambda i: (log.append(("put", i)), put(i))[1]
     held = []
-    for i, b in ring.stream_pass():
-        assert not b.X.dense.is_deleted()
-        held.append(b)
-        assert ring.consumed(out := Output(i)) is out
-    # ... and what its program left of chunk i was freed just before
-    assert all(leaf.is_deleted() for b in held
+    with telemetry.run("t") as run:
+        for i, b in ring.stream_pass():
+            assert not b.X.dense.is_deleted()
+            held.append(b)
+            log.append(("spoke", i))
+            assert ring.consumed(out := Output(i)) is out
+        c = run.report_compact()["counters"]
+    # chunk i's program is registered before chunk i + 1 is issued, and
+    # chunk i - 1 is waited for and freed before that upload could make a
+    # third; the last `consumed` primed the next pass's first chunk
+    assert log == [("put", 0), ("put", 1), ("spoke", 0),
+                   ("spoke", 1), ("ready", 0), ("put", 2),
+                   ("spoke", 2), ("ready", 1), ("put", 3),
+                   ("spoke", 3), ("ready", 2), ("put", 0)]
+    assert all(leaf.is_deleted() for b in held[:3]
                for leaf in jax.tree_util.tree_leaves(b))
-    # chunk i + 2 is issued only after chunk i's program was waited for
-    assert log == [("put", 0), ("put", 1),
-                   ("ready", 0), ("put", 2), ("ready", 1), ("put", 3),
-                   ("ready", 2), ("put", 0), ("ready", 3), ("put", 1)]
-    assert len(ring._window) == 2 and ring._pending is None
+    assert not held[3].X.dense.is_deleted()  # its program is the ring's
+    assert len(ring._window) == 1 and ring._spoken[1].i == 3  # to wait for
+    # every upload but the two primed before anything was handed out went
+    # out behind a running program
+    assert c["stream.uploads_behind_compute"] == 3
+    assert c["stream.chunk_uploads"] == 4
     del log[:]
-    held = [b for _, b in ring.stream_pass()]  # a consumer that says nothing
-    assert log == [("put", 2), ("put", 3), ("put", 0), ("put", 1)]
-    assert not any(leaf.is_deleted() for b in held
+    held += [b for _, b in ring.stream_pass()]  # a consumer that says nothing
+    assert log == [("ready", 3), ("put", 1), ("put", 2), ("put", 3),
+                   ("put", 0), ("put", 1)]
+    assert all(leaf.is_deleted()
+               for leaf in jax.tree_util.tree_leaves(held[3]))
+    assert not any(leaf.is_deleted() for b in held[4:]
                    for leaf in jax.tree_util.tree_leaves(b))
+    assert len(ring._window) == 2 and ring._spoken is None
     ring.close()
     assert not ring._window and ring._next == 0
 
@@ -313,9 +332,10 @@ def test_ring_holds_its_depth_and_ends_empty():
     finally:
         streamed._backend = real
     be, uploads = seen[0], seen[1:]
-    assert not be.ring._window and be.ring._pending is None
+    assert not be.ring._window and be.ring._spoken is None
     assert c["stream.chunk_uploads"] == 4 * 7
-    assert len(uploads) == 4 * 7 + 2
+    assert len(uploads) == 4 * 7 + 1
+    assert 0 <= c.get("stream.uploads_behind_compute", 0) <= 4 * 7 - 1
     assert 0.0 < c["stream.issue_seconds"] <= c["stream.compute_seconds"]
 
 

@@ -283,33 +283,172 @@ class TestStreamedSolvers:
 
 
 # ------------------------------------------------------ device chunk ring
+class _Running:
+    """Stands for a chunk program's outputs that are still being computed
+    when the ring asks."""
+
+    def is_ready(self):
+        return False
+
+    def block_until_ready(self):
+        return self
+
+
+def _ring_problem(seed, n=64, d=8, chunk=16):
+    rng = np.random.default_rng(seed)
+    Xd = rng.normal(size=(n, d)).astype(np.float32)
+    return chunk_batch(make_batch(
+        Xd, (rng.uniform(size=n) < 0.5).astype(np.float32)), chunk)
+
+
+def _alive(chunk):
+    return any(not leaf.is_deleted()
+               for leaf in jax.tree_util.tree_leaves(chunk))
+
+
 class TestDeviceChunkRing:
-    def test_rotation_order_and_prearm(self):
-        rng = np.random.default_rng(5)
-        Xd = rng.normal(size=(64, 8)).astype(np.float32)
-        cb = chunk_batch(make_batch(
-            Xd, (rng.uniform(size=64) < 0.5).astype(np.float32)), 16)
+    @pytest.mark.parametrize("speaks", [False, True],
+                             ids=["silent", "speaks"])
+    def test_rotation_order_and_prearm(self, speaks):
+        cb = _ring_problem(5)
         ring = cb.device_ring(prefetch=2)
         for p in range(3):
-            seen = [(i, np.asarray(b.y)) for i, b in ring.stream_pass()]
+            seen = []
+            for i, b in ring.stream_pass():
+                seen.append((i, np.asarray(b.y)))
+                if speaks:
+                    ring.consumed(jnp.sum(b.X))
             assert [i for i, _ in seen] == [0, 1, 2, 3]
             for i, yb in seen:
                 np.testing.assert_array_equal(yb, cb.y[i * 16:(i + 1) * 16])
-            # pre-arm: the next pass's first uploads are already issued
-            assert len(ring._window) == 2
+            # pre-arm: the next pass's first upload(s) are already issued —
+            # a ring that was told of the last chunk's program holds that
+            # chunk and ONE primed chunk, issued behind that program
+            if speaks:
+                assert len(ring._window) == 1 and ring._next == 1
+                assert _alive(ring._spoken[0])
+            else:
+                assert len(ring._window) == 2 and ring._next == 2
+        ring.close()
+        assert not ring._window and ring._spoken is None
 
-    def test_abandoned_pass_resets(self):
+    @pytest.mark.parametrize("speaks", [False, True],
+                             ids=["silent", "speaks"])
+    def test_abandoned_pass_resets(self, speaks):
         rng = np.random.default_rng(6)
         Xd = rng.normal(size=(48, 4)).astype(np.float32)
         cb = chunk_batch(make_batch(
             Xd, np.zeros(48, np.float32)), 16)
         ring = cb.device_ring(prefetch=2)
         it = ring.stream_pass()
-        next(it)  # consume chunk 0, abandon mid-pass
+        _, b = next(it)  # consume chunk 0, abandon mid-pass
+        if speaks:
+            ring.consumed(jnp.sum(b.X))
         it.close()
         assert len(ring._window) == 0 and ring._next == 0
+        assert ring._spoken is None and ring._handed is None
         order = [i for i, _ in ring.stream_pass()]
         assert order == [0, 1, 2]  # restarts at chunk 0, nothing stale
+
+    @pytest.mark.parametrize("depth", [1, 2, 3])
+    def test_never_more_than_depth_chunks_alive_at_a_put(self, depth):
+        """A chunk is freed before the upload that would make one more
+        than the ring's depth: at every `put` of a consumer that speaks,
+        fewer than `depth` of the chunks uploaded so far are alive."""
+        cb = _ring_problem(8)
+        ring = cb.device_ring(prefetch=depth)
+        made, alive_at_put = [], []
+        put = ring._put
+
+        def spy(i):
+            alive_at_put.append(sum(_alive(c) for c in made))
+            made.append(put(i))
+            return made[-1]
+
+        ring._put = spy
+        for _ in range(3):
+            for _, b in ring.stream_pass():
+                out = jnp.sum(b.X)
+                del b  # the ring's name is the only one left
+                ring.consumed(out)
+        assert len(made) == 12 + max(depth - 1, 1)
+        assert max(alive_at_put) == depth - 1
+        ring.close()
+        assert not ring._window and ring._spoken is None
+        # every consumed chunk is freed; what was primed for a fourth pass
+        # lives on under this test's names alone
+        assert sum(_alive(c) for c in made) == len(made) - 12
+
+    @pytest.mark.parametrize("speaks", [False, True],
+                             ids=["silent", "speaks"])
+    def test_uploads_behind_compute_counts_what_a_program_hides(self,
+                                                                speaks):
+        """`stream.uploads_behind_compute` is the uploads issued while the
+        program `consumed` was told of had not finished: every consumed
+        chunk's successor but the very first (primed before anything was
+        handed out) for a consumer that speaks, none for one that does
+        not."""
+        from photon_tpu import telemetry
+
+        cb = _ring_problem(9)
+        ring = cb.device_ring(prefetch=2)
+        with telemetry.run("t") as run:
+            for _ in range(2):
+                for _, b in ring.stream_pass():
+                    if speaks:
+                        ring.consumed(_Running())
+            c = run.report_compact()["counters"]
+        assert c["stream.chunk_uploads"] == 8
+        assert c.get("stream.uploads_behind_compute", 0) == (
+            8 - 1 if speaks else 0)
+
+    def test_outputs_already_computed_are_not_counted_behind(self):
+        from photon_tpu import telemetry
+
+        cb = _ring_problem(10)
+        ring = cb.device_ring(prefetch=2)
+        with telemetry.run("t") as run:
+            for _, b in ring.stream_pass():
+                ring.consumed(jax.block_until_ready(jnp.sum(b.X)))
+            c = run.report_compact()["counters"]
+        assert c.get("stream.uploads_behind_compute", 0) == 0
+
+    @pytest.mark.parametrize("solver", ["lbfgs", "owlqn"])
+    def test_solve_is_the_old_ring_orders_bits(self, solver, monkeypatch):
+        """Only the order of two host calls moved: a streamed solve whose
+        ring uploads behind the chunk program returns the very bits of the
+        old order (upload first), re-enacted by a consumer that tells the
+        ring nothing."""
+        from photon_tpu.data.dataset import DeviceChunkRing
+
+        rng = np.random.default_rng(12)
+        cb = chunk_batch(_problem(rng, TaskType.LOGISTIC_REGRESSION,
+                                  n=512, sparse=True), 128)
+        if solver == "lbfgs":
+            cfg = OptimizerConfig(max_iters=8, tolerance=0.0, reg=l2(),
+                                  reg_weight=1e-2, history=4)
+        else:
+            cfg = OptimizerConfig(max_iters=8, tolerance=0.0,
+                                  reg=elastic_net(0.5), reg_weight=1e-2,
+                                  history=4, optimizer=OptimizerType.OWLQN)
+        new = train_glm(cb, TaskType.LOGISTIC_REGRESSION, cfg)[1]
+        puts = []
+        real_init = DeviceChunkRing.__init__
+
+        def init(self, *a, **kw):
+            real_init(self, *a, **kw)
+            put = self._put
+            self._put = lambda i: (puts.append(i), put(i))[1]
+
+        monkeypatch.setattr(DeviceChunkRing, "__init__", init)
+        monkeypatch.setattr(DeviceChunkRing, "consumed",
+                            lambda self, outputs: outputs)
+        old = train_glm(cb, TaskType.LOGISTIC_REGRESSION, cfg)[1]
+        assert puts[:3] == [0, 1, 2] and len(puts) % 4 == 2  # two primed
+        np.testing.assert_array_equal(np.asarray(new.w), np.asarray(old.w))
+        np.testing.assert_array_equal(np.asarray(new.loss_history),
+                                      np.asarray(old.loss_history))
+        assert int(new.evaluations) == int(old.evaluations)
 
     def test_streamed_solve_unchanged_by_ring(self):
         """The ring + donated programs are pure overlap: streamed ==
