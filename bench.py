@@ -1075,14 +1075,16 @@ def run_ingest(problem) -> dict:
             jax.block_until_ready(b.y)
     telemetry.finish_run()
     stall = float(run.counters.get("stream.stall_seconds", 0.0))
-    compute = float(run.counters.get("stream.compute_seconds", 0.0))
+    # a pass's wall: the waits, the upload calls, the consumer's own time
+    wall = (stall + float(run.counters.get("stream.issue_seconds", 0.0))
+            + float(run.counters.get("stream.compute_seconds", 0.0)))
     stalled = int(run.counters.get("stream.stalled_passes", 0))
     return {
         "rows": rows,
         "cold_rows_per_sec": rows / cold_s,
         "cached_rows_per_sec": rows / best_cached,
         "cached_over_cold": cold_s / best_cached,
-        "upload_stall_pct": 100.0 * stall / max(stall + compute, 1e-9),
+        "upload_stall_pct": 100.0 * stall / max(wall, 1e-9),
         "stalled_passes": stalled,
         "prefetch_depth_final": int(ctl.depth),
     }

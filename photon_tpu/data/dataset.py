@@ -8,12 +8,14 @@ shaped. Padding rows carry weight 0 so all reductions ignore them.
 from __future__ import annotations
 
 import dataclasses
+import time as _time
 from typing import Iterator, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from photon_tpu import telemetry
 from photon_tpu.data.matrix import (
     BlockedEllRows,
     HybridRows,
@@ -531,17 +533,18 @@ class ChunkedBatch(NamedTuple):
         never changes results — it is purely an overlap knob.
 
         The iterator times how long it stalls waiting for each prefetched
-        chunk's transfer; per-pass totals land in the telemetry counters
+        chunk's transfer and how long it spends in the upload calls;
+        per-pass totals land in the telemetry counters
         (`stream.chunk_uploads` / `stream.upload_bytes` /
         `stream.stall_seconds` / `stream.issue_seconds` /
-        `stream.compute_seconds`), and when total
-        stall exceeds total compute it logs the imbalance at INFO — the
+        `stream.compute_seconds`: the pass's wall is the three seconds
+        counters together, the last the consumer's own time), and when
+        the time waited on transfers (stall + issue) exceeds the
+        consumer's it logs the imbalance at INFO — the
         signal that a deeper prefetch or a bigger `objective_chunk_rows`
         would help."""
-        import time as _time
         from collections import deque
 
-        from photon_tpu import telemetry
         from photon_tpu.checkpoint.faults import kill_point
 
         n = self.n_chunks
@@ -580,7 +583,9 @@ class ChunkedBatch(NamedTuple):
             jax.block_until_ready(cur)
             stall += _time.perf_counter() - t0
             yield i, cur
-        compute = (_time.perf_counter() - t_start) - stall
+        # the consumer's own time: the pass's wall less the waits for a
+        # chunk and less the upload calls
+        compute = (_time.perf_counter() - t_start) - stall - issue
         telemetry.count("stream.passes")
         telemetry.count("stream.chunk_uploads", n)
         telemetry.count("stream.upload_bytes", n * self.chunk_nbytes())
@@ -592,9 +597,9 @@ class ChunkedBatch(NamedTuple):
 
         profiling.attribute("ingest.upload", "upload", max(stall, 0.0))
         if ctl is not None:
-            ctl.observe(stall, max(compute, 0.0), n,
+            ctl.observe(stall + issue, max(compute, 0.0), n,
                         self.X.nbytes() // max(self.X.n_chunks, 1))
-        _log_stream_stall(stall, compute, n, depth)
+        _log_stream_stall(stall + issue, compute, n, depth)
 
     def device_ring(self, device=None, mesh=None,
                     prefetch=2) -> "DeviceChunkRing":
@@ -652,12 +657,28 @@ class DeviceChunkRing:
     seconds the pass spent handing chunks to the runtime: an upload in
     pieces returns when all but its last pieces have crossed the link, so
     on a link-bound stream this is the wait for the link;
+    `stream.compute_seconds` the rest of the pass's wall once that and
+    the hand-out waits, `stream.stall_seconds`, are taken off: the
+    consumer's own time;
     `stream.uploads_behind_compute` the uploads issued while the outputs
     `consumed()` was given were not ready yet: the program they hide
     behind), the same `chunk_upload`
     fault-injection site per chunk, ledger attribution
     (``ingest.upload`` stall + ``solve.compute``) and `AdaptivePrefetch`
-    support. A pass abandoned mid-way (an
+    support.
+
+    Where the ring makes the host wait it opens a span, under whatever
+    span its caller holds (a solver's ``stream.pass``): ``stream.upload``
+    around each upload call (``chunk``, the ladder index; ``behind``,
+    whether the program it was issued behind was still running),
+    ``stream.handout`` around the wait for the chunk about to be handed
+    out (``chunk``), ``stream.release`` around the wait for a consumed
+    chunk's program and the freeing of its leaves, and around `close()`'s
+    wait for what was primed (``chunk``). ONE measurement, two sinks: with
+    a run attached `stream.issue_seconds` / `stream.stall_seconds` are
+    the sums of the upload / hand-out spans' own clock readings; with
+    none the spans are `telemetry`'s shared no-op and the ring reads the
+    clock itself, as it always did. A pass abandoned mid-way (an
     injected kill, any exception) resets the ring to a clean state — the
     next pass starts at chunk 0 with nothing stale in flight. Mesh mode
     additionally persists the replication cache across passes, so a
@@ -675,7 +696,9 @@ class DeviceChunkRing:
         self._window: deque = deque()
         self._next = 0  # chunk index the next upload issues (mod n_chunks)
         self._handed = None  # the chunk the consumer holds
-        self._spoken = None  # (chunk, its program's outputs), once `consumed`
+        self._handed_index = None  # and its index in the ladder
+        self._spoken = None  # (chunk, its program's outputs, its index),
+        #                      once `consumed`
         self._issue = 0.0  # host seconds inside `_put` this pass
         self._behind = 0  # uploads issued behind a running program, this pass
         self._chunk_nbytes = batch.chunk_nbytes()
@@ -700,7 +723,8 @@ class DeviceChunkRing:
         (see the class note); a mesh ring only waits, its chunks share the
         replicated permutation's buffers."""
         self._release()
-        self._spoken, self._handed = (self._handed, outputs), None
+        self._spoken = (self._handed, outputs, self._handed_index)
+        self._handed = None
         self._top_up()
         return outputs
 
@@ -709,27 +733,28 @@ class DeviceChunkRing:
         chunk."""
         if self._spoken is None:
             return
-        chunk, outputs = self._spoken
-        jax.block_until_ready(outputs)
-        if self.mesh is None:
-            for leaf in jax.tree_util.tree_leaves(chunk):
-                if isinstance(leaf, jax.Array) and not leaf.is_deleted():
-                    leaf.delete()
+        chunk, outputs, index = self._spoken
+        with telemetry.span("stream.release", chunk=index):
+            jax.block_until_ready(outputs)
+            if self.mesh is None:
+                for leaf in jax.tree_util.tree_leaves(chunk):
+                    if isinstance(leaf, jax.Array) and not leaf.is_deleted():
+                        leaf.delete()
         self._spoken = None
 
     def _top_up(self) -> None:
         """Issue uploads until the ring holds its depth: the window and
         the chunk whose program is still the ring's to wait for."""
-        import time as _time
-
         n = self.batch.n_chunks
         held = self._spoken is not None
         while len(self._window) + held < min(self.depth, n):
-            if held and not _is_ready(self._spoken[1]):
-                self._behind += 1
+            behind = held and not _is_ready(self._spoken[1])
+            self._behind += behind
             t0 = _time.perf_counter()
-            self._window.append(self._put(self._next))
-            self._issue += _time.perf_counter() - t0
+            with telemetry.span("stream.upload", chunk=self._next,
+                                behind=behind) as rec:
+                self._window.append(self._put(self._next))
+            self._issue += _span_seconds(rec, t0)
             self._next = (self._next + 1) % n
 
     def _fill(self) -> None:
@@ -746,17 +771,18 @@ class DeviceChunkRing:
         this the ring holds nothing on the device."""
         self._release()
         if self._window:
-            jax.block_until_ready(list(self._window))
-            self._window.clear()
+            n = self.batch.n_chunks
+            with telemetry.span("stream.release",
+                                chunk=(self._next - len(self._window)) % n):
+                jax.block_until_ready(list(self._window))
+                self._window.clear()
         self._next, self._handed = 0, None
 
     def stream_pass(self):
         """One pass: yield (i, device chunk) for every chunk, keeping the
         upload window full — including past the last chunk, into the
         next pass (the psum/readback overlap)."""
-        import time as _time
-
-        from photon_tpu import profiling, telemetry
+        from photon_tpu import profiling
         from photon_tpu.checkpoint.faults import kill_point
 
         n = self.batch.n_chunks
@@ -772,9 +798,10 @@ class DeviceChunkRing:
                 cur = self._window.popleft()
                 kill_point("chunk_upload")
                 t0 = _time.perf_counter()
-                jax.block_until_ready(cur)
-                stall += _time.perf_counter() - t0
-                self._handed = cur
+                with telemetry.span("stream.handout", chunk=i) as rec:
+                    jax.block_until_ready(cur)
+                stall += _span_seconds(rec, t0)
+                self._handed, self._handed_index = cur, i
                 del cur  # the ring's one name for it is `_handed`
                 yield i, self._handed
             # prime the NEXT pass before the caller closes this one: a
@@ -789,7 +816,9 @@ class DeviceChunkRing:
                 # uploads so the next pass starts clean at chunk 0
                 self._window.clear()
                 self._next, self._spoken, self._handed = 0, None, None
-            compute = (_time.perf_counter() - t_start) - stall
+            # the consumer's own time: the pass's wall less the waits for
+            # a chunk and less the upload calls
+            compute = (_time.perf_counter() - t_start) - stall - self._issue
             telemetry.count("stream.passes")
             telemetry.count("stream.chunk_uploads", n)
             telemetry.count("stream.upload_bytes", n * self._chunk_nbytes)
@@ -803,9 +832,17 @@ class DeviceChunkRing:
                                 max(compute, 0.0))
             if ok and self._ctl is not None:
                 self._ctl.observe(
-                    stall, max(compute, 0.0), n,
+                    stall + self._issue, max(compute, 0.0), n,
                     self.batch.X.nbytes() // max(self.batch.X.n_chunks, 1))
-            _log_stream_stall(stall, compute, n, depth)
+            _log_stream_stall(stall + self._issue, compute, n, depth)
+
+
+def _span_seconds(rec, t0: float) -> float:
+    """Seconds of the block a `telemetry.span` just closed around: the
+    span's own two clock readings where a run recorded it (``rec``), so a
+    counter summed from these and the span are ONE measurement; else the
+    time since the caller's own reading ``t0``."""
+    return rec.seconds if rec is not None else _time.perf_counter() - t0
 
 
 def _is_ready(tree) -> bool:
@@ -859,13 +896,14 @@ def mesh_chunk_matrix(X, mesh, _cache: dict | None = None):
 def _log_stream_stall(stall: float, compute: float, n_chunks: int,
                       prefetch: int) -> None:
     """One INFO line (plus a `stream.stalled_passes` telemetry counter)
-    per streaming pass when transfer stalls exceed compute — the signal
-    that a deeper prefetch or a bigger chunk would overlap the host link
-    better (iter_device calls this at generator exhaustion with its
+    per streaming pass when the time waited on transfers (``stall``: the
+    waits for a chunk AND the upload calls) exceeds the consumer's own —
+    the signal that a deeper prefetch or a bigger chunk would overlap the
+    host link better, and the plain truth of a link-bound stream
+    (iter_device calls this at generator exhaustion with its
     measured per-pass totals). The log rides `photon_logger` with root
     propagation kept ON, so capturing harnesses and a configured root
     logger both see it."""
-    from photon_tpu import telemetry
     from photon_tpu.utils.logging import photon_logger
 
     if n_chunks > 1 and stall > compute:

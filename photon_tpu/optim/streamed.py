@@ -59,6 +59,7 @@ solver whose cost model is wrong for the regime.
 """
 from __future__ import annotations
 
+import itertools
 import math
 from functools import partial
 
@@ -450,24 +451,37 @@ class _SingleDeviceStream:
     # just dispatched, and frees the chunk of the one before (a donated
     # hot block has no output to alias, so donation does not free it)
 
+    def _consume(self, name, program, *args):
+        """One chunk program: span ``stream.dispatch`` around its call
+        ONLY, then the ring is told — its `stream.release` and
+        `stream.upload` are this span's siblings, not its children."""
+        with telemetry.span("stream.dispatch", program=name):
+            out = program(*args)
+        return self.ring.consumed(out)
+
+    @staticmethod
+    def _margins(z):
+        with telemetry.span("stream.readback", what="margins"):
+            return np.asarray(z)
+
     def chunk_init(self, obj, w, b):
-        z, parts = self.ring.consumed(self._init(obj, w, b))
-        return np.asarray(z), parts
+        z, parts = self._consume("init", self._init, obj, w, b)
+        return self._margins(z), parts
 
     def chunk_grad(self, obj, z, b):
-        return self.ring.consumed(self._grad(obj, z, b))
+        return self._consume("grad", self._grad, obj, z, b)
 
     def chunk_dz_phi(self, obj, p, z, a, b):
-        dz, wlwd = self.ring.consumed(
-            self._dz_phi(obj, p, z, np.float32(a), b))
-        return np.asarray(dz), wlwd
+        dz, wlwd = self._consume("dz_phi", self._dz_phi, obj, p, z,
+                                 np.float32(a), b)
+        return self._margins(dz), wlwd
 
     def chunk_phi(self, obj, i, z, dz, a):
         b = self.data.chunk(i)
         return _chunk_phi(obj, z, dz, np.float32(a), b.y, b.weights)
 
     def chunk_value_many(self, obj, W, b):
-        return self.ring.consumed(self._value_many(obj, W, b))
+        return self._consume("value_many", self._value_many, obj, W, b)
 
     def finish(self, obj, w, acc):
         return _finish(obj, w, acc)
@@ -746,14 +760,34 @@ def _convergence_host(ok, f_old, f_new, gnorm, g0norm, dphi0,
     return grad_conv or f_conv or precision_limited
 
 
-def _pass_span(kind: str):
+def _pass_span(kind: str, n: int):
     """Host span ``stream.pass`` around ONE pass over the chunks, from the
     first chunk asked of the ring to the readback that closes the pass;
     ``kind`` says which: "init" (margins, value and gradient at the
     start), "dz" (the direction's margins, with the first trial),
     "gradient" (at cached margins), "refresh" (a gradient pass that
-    re-anchors the margins on w), OWL-QN's "value_grad" and "ladder"."""
-    return telemetry.span("stream.pass", kind=kind)
+    re-anchors the margins on w), OWL-QN's "value_grad" and "ladder";
+    ``n`` is the pass's number in its solve, so a chunk's spans under it
+    (the ring's and the backend's, each with its ``chunk``) are one
+    timeline (n, chunk)."""
+    return telemetry.span("stream.pass", kind=kind, n=n)
+
+
+def _totals_span():
+    """Host span ``stream.readback`` around what closes a pass: the
+    program that sums it up and the scalars read back from it."""
+    return telemetry.span("stream.readback", what="totals")
+
+
+def _host_step(part: str):
+    """Host span ``solve.host_step`` around a stretch of the streamed
+    L-BFGS loop BETWEEN two passes, link and device both waiting on the
+    host: "direction" (two-loop and ray coefficients, before the dz
+    pass), "linesearch" (the Wolfe search over cached margins, the step
+    and the host margin chain, before the gradient pass), "update"
+    (history push, convergence, bookkeeping, after it and after the
+    first pass)."""
+    return telemetry.span("solve.host_step", part=part)
 
 
 def _eval_tick(ck, n: int = 1) -> None:
@@ -899,13 +933,15 @@ def minimize_lbfgs_streamed(
     without an attached Run)."""
     _check_streamable(obj, mesh)
     be = _backend(data, mesh, prefetch)
-    try:
-        with telemetry.span("solve.lbfgs_streamed", mesh=mesh is not None,
-                            n_chunks=data.n_chunks):
+    with telemetry.span("solve.lbfgs_streamed", mesh=mesh is not None,
+                        n_chunks=data.n_chunks):
+        try:
             return _lbfgs_streamed(obj, data, w0, max_iters, tolerance,
                                    history, max_ls_evals, mesh, be)
-    finally:
-        be.close()  # the last pass primed chunks for one that never comes
+        finally:
+            # the last pass primed chunks for one that never comes; the
+            # wait for them is the solve's (`stream.release` under it)
+            be.close()
 
 
 def _pack_lbfgs_state(d, n_chunks, data, mesh, max_iters, it, f, g0norm,
@@ -929,6 +965,7 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
     ck = _ckpt.current()
     st = ck.restore("lbfgs_streamed") if ck is not None else None
     z_gen = 0
+    passes = itertools.count()  # `stream.pass`'s ordinal in this solve
     if st is not None:
         # ---- resume: the full iteration-boundary state rehydrates and
         # the initial pass is skipped (margins come from the snapshot).
@@ -966,40 +1003,48 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
         # ---- initial pass: margins cached per chunk, (f, g) accumulated
         z_cache = [None] * n_chunks
         acc = None
-        with _pass_span("init"), profiling.measure(
+        with _pass_span("init", next(passes)), profiling.measure(
                 be.prog + "chunk_init", "lbfgs/init", calls=n_chunks):
             for i, b in be.iter_chunks():
                 be.note("chunk_init", obj, w, b)
                 z_cache[i], parts = be.chunk_init(obj, w, b)
                 acc = parts if acc is None else _acc(acc, parts)
-            f_dev, g = be.finish(obj, w, acc)
-            f = float(f_dev)  # the host readback closes the measured pass
-        g0norm = float(jnp.linalg.norm(g))
-        telemetry.count("solver.feature_streams")
-        telemetry.count("solver.evaluations")
-        _eval_tick(ck)
-        telemetry.iteration("lbfgs_streamed", 0, f, grad_norm=g0norm)
+            with _totals_span():
+                f_dev, g = be.finish(obj, w, acc)
+                f = float(f_dev)  # the host readback closes the measured
+                # pass
+        with _host_step("update"):
+            g0norm = float(jnp.linalg.norm(g))
+            telemetry.count("solver.feature_streams")
+            telemetry.count("solver.evaluations")
+            _eval_tick(ck)
+            telemetry.iteration("lbfgs_streamed", 0, f, grad_norm=g0norm)
 
-        hist = np.full(max_iters + 1, np.nan, np.float32)
-        ghist = np.full(max_iters + 1, np.nan, np.float32)
-        hist[0], ghist[0] = f, g0norm
+            hist = np.full(max_iters + 1, np.nan, np.float32)
+            ghist = np.full(max_iters + 1, np.nan, np.float32)
+            hist[0], ghist[0] = f, g0norm
 
-        it, trials, converged, failed = 0, 0, g0norm <= 1e-14, False
-        done = converged
-        if ck is not None:
-            # the it=0 cut: resuming from here is provably == cold start
-            ck.update("lbfgs_streamed", _pack_lbfgs_state(
-                d, n_chunks, data, mesh, max_iters, it, f, g0norm, hist,
-                ghist, converged, failed, done, w, g, hist_st, trials,
-                z_cache, z_gen))
-            ck.maybe_snapshot()
+            it, trials, converged, failed = 0, 0, g0norm <= 1e-14, False
+            done = converged
+            if ck is not None:
+                # the it=0 cut: resuming from here is provably == cold
+                # start
+                ck.update("lbfgs_streamed", _pack_lbfgs_state(
+                    d, n_chunks, data, mesh, max_iters, it, f, g0norm,
+                    hist, ghist, converged, failed, done, w, g, hist_st,
+                    trials, z_cache, z_gen))
+                ck.maybe_snapshot()
     dz_cache: list = [None] * n_chunks
+    # Every stretch of the loop between two passes lies under a
+    # `solve.host_step` span: with the passes' own spans the solve's wall
+    # is accounted for, host turn by host turn
     while not done and it < max_iters:
-        p, dphi0_dev, pnorm = _lbfgs_direction(g, hist_st.h)
-        dphi0 = float(dphi0_dev)
-        a_init = (1.0 if hist_st.count > 0
-                  else 1.0 / max(float(pnorm), 1.0))
-        c0, c1r, c2r = (float(v) for v in _ray_coeffs(obj, w, p))
+        with _host_step("direction"):
+            p, dphi0_dev, pnorm = _lbfgs_direction(g, hist_st.h)
+            dphi0 = float(dphi0_dev)
+            a_init = (1.0 if hist_st.count > 0
+                      else 1.0 / max(float(pnorm), 1.0))
+            c0, c1r, c2r = (float(v) for v in _ray_coeffs(obj, w, p))
 
         def reg_ray(a):  # exact quadratic reg along the ray (phi_at_ray)
             return c0 + a * (c1r + 0.5 * a * c2r), c1r + a * c2r
@@ -1007,7 +1052,7 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
         # ---- direction pass (feature stream 1 of 2): dz per chunk, with
         # the FIRST Wolfe trial's φ(a_init) partials riding along.
         phis = None
-        with _pass_span("dz"), profiling.measure(
+        with _pass_span("dz", next(passes)), profiling.measure(
                 be.prog + "chunk_dz_phi", "lbfgs/direction",
                 calls=n_chunks):
             for i, b in be.iter_chunks():
@@ -1016,14 +1061,8 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
                 dz_cache[i], wlwd = be.chunk_dz_phi(obj, p, z_cache[i],
                                                     a_init, b)
                 phis = wlwd if phis is None else _acc(phis, wlwd)
-            wl0, wd0 = be.totals(phis)
-        rv, rd = reg_ray(a_init)
-        first_eval = (wl0 + rv, wd0 + rd)
-        # feature stream 1 of 2; its piggybacked φ(a_init) is both an
-        # evaluation and the line search's first trial
-        telemetry.count("solver.feature_streams")
-        telemetry.count("solver.evaluations")
-        _eval_tick(ck)
+            with _totals_span():
+                wl0, wd0 = be.totals(phis)
 
         def phi(a):
             """Streamed trial: 16 bytes/row of cached margins, no X."""
@@ -1041,28 +1080,37 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
             rv, rd = reg_ray(a)
             return wl + rv, wd + rd
 
-        alpha, f_star, ok, n_trials = _host_wolfe(phi, f, dphi0, a_init,
-                                                  max_ls_evals,
-                                                  first=first_eval)
-        telemetry.count("solver.linesearch_trials", n_trials)
-        trials += n_trials
-
-        if ok:
-            w_new = _axpy(w, np.float32(alpha), p)
-            a32 = np.float32(alpha)
-            for i in range(n_chunks):  # host margin chain: z += α·dz
-                z_cache[i] = z_cache[i] + a32 * dz_cache[i]
-            refresh = (max_iters >= _Z_REFRESH
-                       and (it + 1) % _Z_REFRESH == 0)
-            # ---- gradient pass (feature stream 2 of 2)
+        with _host_step("linesearch"):
+            rv, rd = reg_ray(a_init)
+            first_eval = (wl0 + rv, wd0 + rd)
+            # feature stream 1 of 2; its piggybacked φ(a_init) is both an
+            # evaluation and the line search's first trial
             telemetry.count("solver.feature_streams")
             telemetry.count("solver.evaluations")
-            if refresh:
-                telemetry.count("solver.margin_cache.refreshes")
-                z_gen += 1
+            _eval_tick(ck)
+            alpha, f_star, ok, n_trials = _host_wolfe(
+                phi, f, dphi0, a_init, max_ls_evals, first=first_eval)
+            telemetry.count("solver.linesearch_trials", n_trials)
+            trials += n_trials
+            if ok:
+                w_new = _axpy(w, np.float32(alpha), p)
+                a32 = np.float32(alpha)
+                for i in range(n_chunks):  # host margin chain: z += α·dz
+                    z_cache[i] = z_cache[i] + a32 * dz_cache[i]
+                refresh = (max_iters >= _Z_REFRESH
+                           and (it + 1) % _Z_REFRESH == 0)
+                telemetry.count("solver.feature_streams")
+                telemetry.count("solver.evaluations")
+                if refresh:
+                    telemetry.count("solver.margin_cache.refreshes")
+                    z_gen += 1
+
+        if ok:
+            # ---- gradient pass (feature stream 2 of 2)
             acc = None
             grad_prog = be.prog + ("chunk_init" if refresh else "chunk_grad")
-            with _pass_span("refresh" if refresh else "gradient"), \
+            with _pass_span("refresh" if refresh else "gradient",
+                            next(passes)), \
                     profiling.measure(grad_prog, "lbfgs/gradient",
                                       calls=n_chunks):
                 for i, b in be.iter_chunks():
@@ -1073,31 +1121,36 @@ def _lbfgs_streamed(obj, data, w0, max_iters, tolerance, history,
                         parts = be.chunk_grad(obj, z_cache[i], b)
                     acc = parts if acc is None else _acc(acc, parts)
                 _, g_new = be.finish(obj, w_new, acc)
-            _eval_tick(ck)
-            f_new = f_star  # the accepted trial's value, as the resident
-            # margin solver uses it
-            hist_st.push(w_new - w, g_new - g, g_new)
-        else:
-            w_new, g_new, f_new = w, g, f
 
-        gnorm = float(jnp.linalg.norm(g_new))
-        now = _convergence_host(ok, f, f_new, gnorm, g0norm, dphi0,
-                                tolerance)
-        done, converged, failed = _stop_host(
-            tolerance, (done, converged, failed), now, ok)
-        it += 1
-        hist[it], ghist[it] = f_new, gnorm
-        telemetry.count("solver.iterations")
-        telemetry.iteration("lbfgs_streamed", it, f_new, grad_norm=gnorm,
-                            step=(alpha if ok else 0.0), trials=n_trials)
-        w, g, f = w_new, g_new, f_new
-        if ck is not None:
-            # iteration boundary = the crash-consistency cut
-            ck.update("lbfgs_streamed", _pack_lbfgs_state(
-                d, n_chunks, data, mesh, max_iters, it, f, g0norm, hist,
-                ghist, converged, failed, done, w, g, hist_st, trials,
-                z_cache, z_gen))
-            ck.maybe_snapshot()
+        with _host_step("update"):
+            if ok:
+                _eval_tick(ck)
+                f_new = f_star  # the accepted trial's value, as the
+                # resident margin solver uses it
+                hist_st.push(w_new - w, g_new - g, g_new)
+            else:
+                w_new, g_new, f_new = w, g, f
+
+            gnorm = float(jnp.linalg.norm(g_new))
+            now = _convergence_host(ok, f, f_new, gnorm, g0norm, dphi0,
+                                    tolerance)
+            done, converged, failed = _stop_host(
+                tolerance, (done, converged, failed), now, ok)
+            it += 1
+            hist[it], ghist[it] = f_new, gnorm
+            telemetry.count("solver.iterations")
+            telemetry.iteration("lbfgs_streamed", it, f_new,
+                                grad_norm=gnorm,
+                                step=(alpha if ok else 0.0),
+                                trials=n_trials)
+            w, g, f = w_new, g_new, f_new
+            if ck is not None:
+                # iteration boundary = the crash-consistency cut
+                ck.update("lbfgs_streamed", _pack_lbfgs_state(
+                    d, n_chunks, data, mesh, max_iters, it, f, g0norm,
+                    hist, ghist, converged, failed, done, w, g, hist_st,
+                    trials, z_cache, z_gen))
+                ck.maybe_snapshot()
 
     return _result(be.result_w(w), f, float(jnp.linalg.norm(g)), it,
                    converged, failed, hist, ghist, trials)
@@ -1133,14 +1186,14 @@ def minimize_owlqn_streamed(
     driver loop (no-ops without an attached Run)."""
     _check_streamable(obj, mesh)
     be = _backend(data, mesh, prefetch)
-    try:
-        with telemetry.span("solve.owlqn_streamed", mesh=mesh is not None,
-                            n_chunks=data.n_chunks):
+    with telemetry.span("solve.owlqn_streamed", mesh=mesh is not None,
+                        n_chunks=data.n_chunks):
+        try:
             return _owlqn_streamed(obj, data, w0, l1_weight, max_iters,
                                    tolerance, history, max_ls_evals,
                                    reg_mask, ladder_lanes, mesh, be)
-    finally:
-        be.close()
+        finally:
+            be.close()
 
 
 def _pack_owlqn_state(d, n_chunks, data, max_iters, it, f, F, pg0norm,
@@ -1163,20 +1216,22 @@ def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
     c1 = 1e-4  # optim.owlqn's Armijo constant
     ck = _ckpt.current()
     st = ck.restore("owlqn_streamed") if ck is not None else None
+    passes = itertools.count()  # `stream.pass`'s ordinal in this solve
 
     def value_grad_pass(w_at):
         telemetry.count("solver.feature_streams")
         telemetry.count("solver.evaluations")
         acc = None
-        with _pass_span("value_grad"), profiling.measure(
+        with _pass_span("value_grad", next(passes)), profiling.measure(
                 be.prog + "chunk_init", "owlqn/value_grad",
                 calls=n_chunks):
             for i, b in be.iter_chunks():
                 be.note("chunk_init", obj, w_at, b)
                 _, parts = be.chunk_init(obj, w_at, b)
                 acc = parts if acc is None else _acc(acc, parts)
-            f_dev, g_at = be.finish(obj, w_at, acc)
-            f_host = float(f_dev)  # readback closes the measured pass
+            with _totals_span():
+                f_dev, g_at = be.finish(obj, w_at, acc)
+                f_host = float(f_dev)  # readback closes the measured pass
         _eval_tick(ck)
         return f_host, g_at
 
@@ -1247,14 +1302,15 @@ def _owlqn_streamed(obj, data, w0, l1_weight, max_iters, tolerance,
             telemetry.count("solver.evaluations", K)
             telemetry.count("solver.linesearch_trials", K)
             acc = None
-            with _pass_span("ladder"), profiling.measure(
+            with _pass_span("ladder", next(passes)), profiling.measure(
                     be.prog + "chunk_value_many", "owlqn/ladder",
                     calls=n_chunks):
                 for _, b in be.iter_chunks():
                     be.note("chunk_value_many", obj, W, b)
                     part = be.chunk_value_many(obj, W, b)
                     acc = part if acc is None else _acc(acc, part)
-                vals_total = be.values_total(acc)  # sync: closes the pass
+                with _totals_span():  # sync: closes the pass
+                    vals_total = be.values_total(acc)
             _eval_tick(ck, K)
             F_cand = (vals_total + np.asarray(rv, np.float64)
                       + np.asarray(l1t, np.float64))

@@ -24,11 +24,33 @@ issue_seconds/compute_seconds/stalled_passes counters beside the
 prefetch_depth gauge (stream.upload_bytes: the host bytes of the chunks a
 pass consumed, every leaf `device_put` is handed; stream.issue_seconds:
 the host seconds a pass spent handing chunks to the runtime;
+stream.compute_seconds: the rest of the pass's wall once
+stream.stall_seconds and stream.issue_seconds are taken off, the
+consumer's own time — the three add up to the wall;
 stream.uploads_behind_compute: the uploads the ring issued while the
 chunk program it had just been told of was still running), with one
 `stream.pass` span around every pass a streamed solver makes over the
 chunks, its ``kind`` attribute init / dz / gradient / refresh /
-value_grad / ladder; the streamed
+value_grad / ladder and ``n`` the pass's number in its solve, and under
+it one timeline a consumed chunk, each span opened where the host does
+the work: `stream.upload` (`DeviceChunkRing`: each upload call;
+``chunk``, the ladder index, ``behind``, whether the program it was
+issued behind was still running), `stream.handout` (the wait for the
+chunk about to be handed out; ``chunk``), `stream.release` (the wait for
+a consumed chunk's program and the freeing of its leaves, and `close()`'s
+wait for what a solve primed and never read; ``chunk``),
+`stream.dispatch` (the one-device backend: the chunk program's call
+alone; ``program`` init / grad / dz_phi / value_many — the ring's spans
+are its siblings, not its children), `stream.readback` (``what``:
+margins, a chunk's per-row output read to the host; totals, the program
+and the scalars that close a pass), and between the passes of the
+streamed L-BFGS `solve.host_step` (``part`` direction / linesearch /
+update; OWL-QN's loop opens none). ONE measurement, two sinks:
+stream.issue_seconds and stream.stall_seconds are the sums of the
+`stream.upload` and `stream.handout` spans' own clock readings; with no
+run attached every one of these sites is the shared no-op span. A span's
+scalar attributes go to its `TraceAnnotation` as the event's stats, the
+event's name stays the bare path; the streamed
 solver loops' `solver.*` family — iterations/evaluations/
 feature_streams/linesearch_trials plus the margin_cache.hits/
 margin_cache.refreshes cache pair; retrace.new_signatures riding

@@ -41,6 +41,7 @@ them happens when a report is asked for (`report`, `report_compact`,
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import json
 import os
@@ -68,9 +69,14 @@ class Span:
         end = self.end_ns if self.end_ns is not None else time.perf_counter_ns()
         return (end - self.start_ns) / 1e9
 
-    def to_json(self) -> dict:
+    def to_json(self, t0_ns: Optional[int] = None) -> dict:
+        """The span's record; with the run's start ``t0_ns`` also ``t_s``,
+        the span's start as an offset from it, so that the records are a
+        timeline (name, start, length, parent path)."""
         out = {"type": "span", "name": self.name, "path": self.path,
                "seconds": round(self.seconds, 6), "depth": self.depth}
+        if t0_ns is not None:
+            out["t_s"] = round((self.start_ns - t0_ns) / 1e9, 6)
         if self.attrs:
             out["attrs"] = self.attrs
         if self.error:
@@ -99,7 +105,11 @@ class _SpanCM:
         try:  # profiler annotation is best-effort decoration, never load-bearing
             import jax
 
-            self._ann = jax.profiler.TraceAnnotation(self._rec.path)
+            # the scalar attributes ride along as the event's stats; it
+            # still reads back under its bare path (PERF.md §6, PR 36)
+            self._ann = jax.profiler.TraceAnnotation(
+                self._rec.path, **{k: v for k, v in self._rec.attrs.items()
+                                   if isinstance(v, (bool, int, float, str))})
             self._ann.__enter__()
         except Exception:
             self._ann = None
@@ -197,11 +207,9 @@ class Run:
     def _record_span(self, rec: Span) -> None:
         with self._lock:
             self.spans.append(rec)
-        j = rec.to_json()
-        # run-relative start offset: telemetry.aggregate places the span
-        # on a wall clock as run_start.started_unix + t_s
-        j["t_s"] = round((rec.start_ns - self._t0_ns) / 1e9, 6)
-        self._emit(j)
+        # t_s, the run-relative start offset: telemetry.aggregate places
+        # the span on a wall clock as run_start.started_unix + t_s
+        self._emit(rec.to_json(self._t0_ns))
 
     # ------------------------------------------------------------- primitives
     def span(self, name: str, **attrs) -> _SpanCM:
@@ -319,6 +327,12 @@ class Run:
             totals[s.path] = totals.get(s.path, 0.0) + s.seconds
         return {k: round(v, 6) for k, v in sorted(totals.items())}
 
+    def span_counts(self) -> dict[str, int]:
+        """Closed spans per span path: what turns a total into a mean."""
+        with self._lock:
+            paths = [s.path for s in self.spans]
+        return dict(sorted(collections.Counter(paths).items()))
+
     def report(self) -> dict:
         """The in-memory run report — everything the JSONL stream carries,
         as one dict (bench.py embeds a compact subset in its JSON line)."""
@@ -326,7 +340,7 @@ class Run:
         with self._lock:
             counters = dict(self.counters)
             gauges = dict(self.gauges)
-            spans = [s.to_json() for s in self.spans]
+            spans = [s.to_json(self._t0_ns) for s in self.spans]
             iterations = list(self.iterations)
             n_iter = self._n_iter_events
         hazards = self.trace_log.hazards()
@@ -347,8 +361,8 @@ class Run:
         }
 
     def report_compact(self) -> dict:
-        """Counters + span totals + duration: the piece small enough to
-        embed in a one-line bench JSON."""
+        """Counters + span totals and counts + duration: the piece small
+        enough to embed in a one-line bench JSON."""
         self._resolve_device_counts()
         with self._lock:
             counters = {k: round(v, 6) for k, v in
@@ -358,6 +372,7 @@ class Run:
         return {"duration_s": round(self.duration_s(), 3),
                 "counters": counters, "gauges": gauges,
                 "span_totals": self.span_totals(),
+                "span_counts": self.span_counts(),
                 "n_iteration_events": n_iter}
 
     def summary_lines(self) -> list[str]:

@@ -11,6 +11,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 import tracemalloc
 
 import jax
@@ -20,8 +21,13 @@ import pytest
 
 from benchmark.gen import reference, sparse_stream
 from benchmark.layer_metrics import (stream_chunk_device_ms,
+                                     stream_handout_wait_ms,
+                                     stream_host_step_ms,
+                                     stream_idle_unattributed_share,
                                      stream_link_share, stream_pass_s,
-                                     stream_stall_share)
+                                     stream_stall_share,
+                                     stream_turnaround_ms,
+                                     stream_upload_call_ms)
 from benchmark.lib.stream_bytes import chunk_upload_bytes, ladder_bytes
 from photon_tpu import telemetry
 from photon_tpu.data import dataset, matrix
@@ -318,7 +324,9 @@ def test_ring_holds_its_depth_and_ends_empty():
     def spy(*args):
         be = real(*args)
         put = be.ring._put
-        be.ring._put = lambda i: (seen.append(i), put(i))[1]
+        # a slow link: the upload calls outweigh everything else in a pass
+        be.ring._put = lambda i: (seen.append(i), time.sleep(0.01),
+                                  put(i))[2]
         seen.append(be)
         return be
 
@@ -328,15 +336,125 @@ def test_ring_holds_its_depth_and_ends_empty():
     try:
         with telemetry.run("t") as run:
             train_glm(cb, LOGISTIC, cfg)
-            c = run.report_compact()["counters"]
+            compact = run.report_compact()
     finally:
         streamed._backend = real
+    c = compact["counters"]
     be, uploads = seen[0], seen[1:]
     assert not be.ring._window and be.ring._spoken is None
     assert c["stream.chunk_uploads"] == 4 * 7
     assert len(uploads) == 4 * 7 + 1
     assert 0 <= c.get("stream.uploads_behind_compute", 0) <= 4 * 7 - 1
-    assert 0.0 < c["stream.issue_seconds"] <= c["stream.compute_seconds"]
+    # a pass's wall = the waits for a chunk + the upload calls + the
+    # consumer's own time: `stream.compute_seconds` no longer holds the
+    # upload calls (it was the wall less the waits alone, so the three
+    # added up to the wall AND the 0.29 s slept in `_put`)
+    assert c["stream.issue_seconds"] >= 0.01 * (4 * 7 + 1)
+    parts = (c["stream.stall_seconds"] + c["stream.issue_seconds"]
+             + c["stream.compute_seconds"])
+    walls = compact["span_totals"]["solve.lbfgs_streamed/stream.pass"]
+    assert parts <= walls < parts + c["stream.issue_seconds"]
+
+
+# ------------------------------ a chunk's timeline from inside the solve
+NEW_SPANS = ("stream.upload", "stream.handout", "stream.release",
+             "stream.dispatch", "stream.readback", "solve.host_step")
+SOLVE, PASS = "solve.lbfgs_streamed", "solve.lbfgs_streamed/stream.pass"
+
+
+@pytest.fixture(scope="module")
+def timeline():
+    """One small streamed L-BFGS solve (3 iterations: 7 passes of 4
+    chunks) under a telemetry run: (the run's report, its compact one)."""
+    X, y = _problem()
+    cb = chunk_blocked_ell(make_batch(X, y), 128, d_dense=32)
+    cfg = OptimizerConfig(max_iters=3, tolerance=0.0, reg=l2(),
+                          reg_weight=0.3, history=5)
+    with telemetry.run("t") as run:
+        train_glm(cb, LOGISTIC, cfg)
+        return run.report(), run.report_compact()
+
+
+def _named(report, name):
+    return [s for s in report["spans"] if s["name"] == name]
+
+
+@pytest.mark.parametrize("name", NEW_SPANS)
+def test_timeline_has_every_span(timeline, name):
+    report, compact = timeline
+    spans = _named(report, name)
+    assert spans
+    # the reports are a timeline: a start beside every length, a count
+    # beside every total
+    assert all(s["t_s"] >= 0.0 and s["seconds"] >= 0.0 for s in spans)
+    paths = {s["path"] for s in spans}
+    assert sum(compact["span_counts"][p] for p in paths) == len(spans)
+    assert set(compact["span_counts"]) == set(compact["span_totals"])
+
+
+def test_timeline_counts_a_span_a_chunk(timeline):
+    report, compact = timeline
+    c = compact["counters"]
+    assert c["stream.chunk_uploads"] == 4 * 7
+    # every consumed chunk was handed out once and dispatched once; one
+    # more was uploaded: primed for a pass that never came, dropped by
+    # `close()` — the last `stream.release`, the solve span's own child
+    assert len(_named(report, "stream.handout")) == 4 * 7
+    assert len(_named(report, "stream.dispatch")) == 4 * 7
+    assert len(_named(report, "stream.upload")) == 4 * 7 + 1
+    releases = _named(report, "stream.release")
+    assert [s["path"] for s in releases].count(
+        SOLVE + "/stream.release") == 2  # the last program's chunk, the
+    #                                      primed one
+    assert len(releases) == 4 * 7 + 1
+    assert [s["attrs"]["chunk"] for s in _named(report, "stream.handout")
+            ] == list(range(4)) * 7
+    assert [s["attrs"]["chunk"] for s in _named(report, "stream.upload")
+            ] == (list(range(4)) * 8)[:4 * 7 + 1]
+    assert [s["attrs"]["n"] for s in _named(report, "stream.pass")
+            ] == list(range(7))
+    assert {s["attrs"]["program"] for s in _named(report, "stream.dispatch")
+            } == {"init", "dz_phi", "grad"}
+    assert {s["attrs"]["what"] for s in _named(report, "stream.readback")
+            } == {"margins", "totals"}
+    # every stretch between two passes: after the first pass, then three
+    # an iteration
+    assert [s["attrs"]["part"] for s in _named(report, "solve.host_step")
+            ] == ["update"] + ["direction", "linesearch", "update"] * 3
+
+
+def test_timeline_nests_the_ring_under_the_pass(timeline):
+    """The ring's spans are the pass's own children — siblings of
+    `stream.dispatch`, never under it — so a chunk's dispatch, the upload
+    issued behind it and the release of the chunk before read as one
+    sequence."""
+    report, _ = timeline
+    for name in ("stream.upload", "stream.handout", "stream.dispatch",
+                 "stream.readback"):
+        assert {s["path"] for s in _named(report, name)} == {
+            PASS + "/" + name}
+    assert {s["path"] for s in _named(report, "stream.release")} == {
+        PASS + "/stream.release", SOLVE + "/stream.release"}
+    assert {s["path"] for s in _named(report, "solve.host_step")} == {
+        SOLVE + "/solve.host_step"}
+
+
+def test_timeline_spans_are_the_counters(timeline):
+    """One measurement, two sinks: the seconds counters are the sums of
+    the spans' own clock readings."""
+    report, compact = timeline
+    c = compact["counters"]
+    for counter, name in (("stream.issue_seconds", "stream.upload"),
+                          ("stream.stall_seconds", "stream.handout")):
+        # `report()` rounds a span to the microsecond
+        assert c[counter] == pytest.approx(
+            sum(s["seconds"] for s in _named(report, name)),
+            abs=1e-6 * len(_named(report, name)))
+    # the pass's spans leave little of a pass unnamed, and the host steps
+    # and the passes little of the solve
+    totals = compact["span_totals"]
+    assert totals[PASS] + totals[SOLVE + "/solve.host_step"] + totals[
+        SOLVE + "/stream.release"] <= totals[SOLVE]
 
 
 def test_chunk_programs_carry_the_shared_scopes():
@@ -452,6 +570,51 @@ def test_stream_readers_on_hand_made_input(monkeypatch):
         stream_link_share.read(ctx)
 
 
+def test_timeline_readers_on_hand_made_input(monkeypatch):
+    """A solve of 10 iterations, 21 passes of 4 chunks, 85 uploads."""
+    counters = {"stream.passes": 21.0, "stream.chunk_uploads": 84.0}
+    spans = {SOLVE: 28.6, PASS: 28.0,
+             PASS + "/stream.upload": 25.2,
+             "probe/stream.upload": 0.3,  # one no pass encloses
+             PASS + "/stream.handout": 1.68,
+             PASS + "/stream.dispatch": 0.5,
+             SOLVE + "/solve.host_step": 0.5}
+    counts = {SOLVE: 1, PASS: 21, PASS + "/stream.upload": 84,
+              "probe/stream.upload": 1, PASS + "/stream.handout": 84,
+              PASS + "/stream.dispatch": 84,
+              SOLVE + "/solve.host_step": 31}
+    ctx = _ctx(counters, spans)
+    ctx["telemetry"]["span_counts"] = counts
+    ctx["results"] = {"unit": [{"steps": 10}]}
+    assert stream_upload_call_ms.read(ctx) == pytest.approx(300.0)
+    assert stream_handout_wait_ms.read(ctx) == pytest.approx(20.0)
+    # (28.0 - 25.2 - 1.68) s over 84 chunks: the upload no pass encloses
+    # is not taken off the passes
+    assert stream_turnaround_ms.read(ctx) == pytest.approx(1120.0 / 84)
+    assert stream_host_step_ms.read(ctx) == pytest.approx(50.0)
+    assert (stream_upload_call_ms.read(ctx) * 85 / 84
+            + stream_handout_wait_ms.read(ctx)
+            + stream_turnaround_ms.read(ctx)) == pytest.approx(
+        (28.0 + 0.3) / 84 * 1e3)
+    table = {"spans": {"stream.upload": {}}, "idle_s": 21.0,
+             "uncovered_s": 0.42}
+    monkeypatch.setattr(stream_idle_unattributed_share, "unit_host_spans",
+                        lambda rehearse: table)
+    assert stream_idle_unattributed_share.read(ctx) == pytest.approx(2.0)
+    # the parent's program: `stream.pass` and the solve span alone, no
+    # counts in its report, no ring span in its trace — nothing, no error
+    bare = _ctx(counters, {SOLVE: 28.6, PASS: 28.0})
+    bare["results"] = ctx["results"]
+    table = {"spans": {"stream.pass": {}}, "idle_s": 21.0,
+             "uncovered_s": 0.4}
+    for reader in (stream_upload_call_ms, stream_handout_wait_ms,
+                   stream_turnaround_ms, stream_host_step_ms,
+                   stream_idle_unattributed_share):
+        assert reader.read(bare) is None
+    table = None
+    assert stream_idle_unattributed_share.read(ctx) is None
+
+
 def test_benchmark_json_lists_the_cell():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         spec = json.load(f)
@@ -468,7 +631,10 @@ def test_benchmark_json_lists_the_cell():
                     "solve_iter_device_ms", "solve_state_ms",
                     "solve_linesearch_ms",
                     "stream_pass_s", "stream_link_share",
-                    "stream_stall_share", "stream_chunk_device_ms"}
+                    "stream_stall_share", "stream_chunk_device_ms",
+                    "stream_upload_call_ms", "stream_handout_wait_ms",
+                    "stream_turnaround_ms", "stream_host_step_ms",
+                    "stream_idle_unattributed_share"}
     for name in mine:
         assert os.path.exists(os.path.join(BENCH, "layer_metrics",
                                            f"{name}.py"))
@@ -868,3 +1034,30 @@ def test_cell_rehearses_to_its_rehearsal_line():
     assert last["event"] == "rehearsal" and last["correct"] is True
     assert last["failed"] == 0 and last["attempted"] >= 1
     assert {"rows_iters_per_s", "setup_s"} <= set(last["metric_names"])
+
+
+def test_cell_rehearses_traced_with_its_timeline_metrics():
+    """`--rehearse --trace 1` reads the five timeline metrics from the
+    program's spans (the CPU's stand-in device for the idle split) and
+    prints the two log lines they come with; the idle split adds up."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", CELL,
+         "--seed", "2147483693", "--seconds", "0.5", "--trace", "1",
+         "--rehearse"],
+        cwd=ROOT, capture_output=True, text=True, timeout=600,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = [json.loads(line) for line in out.stdout.strip().splitlines()]
+    last = lines[-1]
+    assert last["event"] == "rehearsal" and last["correct"] is True
+    assert {"stream_upload_call_ms", "stream_handout_wait_ms",
+            "stream_turnaround_ms", "stream_host_step_ms",
+            "stream_idle_unattributed_share", "stream_pass_s"} <= set(
+        last["metric_names"])
+    by_event = {line["event"]: line for line in lines}
+    assert set(NEW_SPANS) <= set(by_event["host_spans"]["spans"])
+    idle = by_event["idle_by_span"]
+    assert sum(idle["idle_by_span"].values()) == pytest.approx(
+        idle["idle_s"])
+    assert idle["frames"] == ["solve.lbfgs_streamed", "stream.pass"]
+

@@ -450,6 +450,37 @@ class TestDeviceChunkRing:
                                       np.asarray(old.loss_history))
         assert int(new.evaluations) == int(old.evaluations)
 
+    @pytest.mark.parametrize("solver", ["lbfgs", "owlqn"])
+    def test_solve_is_the_same_bits_under_a_run(self, solver):
+        """The chunk timeline's spans are host bookkeeping around the host
+        loop's own turns: a solve with a telemetry run attached (every
+        span recorded, every clock reading taken by the span) returns the
+        very bits of one with none (every site the shared no-op)."""
+        from photon_tpu import telemetry
+
+        rng = np.random.default_rng(13)
+        cb = chunk_batch(_problem(rng, TaskType.LOGISTIC_REGRESSION,
+                                  n=512, sparse=True), 128)
+        if solver == "lbfgs":
+            cfg = OptimizerConfig(max_iters=8, tolerance=0.0, reg=l2(),
+                                  reg_weight=1e-2, history=4)
+        else:
+            cfg = OptimizerConfig(max_iters=8, tolerance=0.0,
+                                  reg=elastic_net(0.5), reg_weight=1e-2,
+                                  history=4, optimizer=OptimizerType.OWLQN)
+        assert telemetry.current_run() is None
+        bare = train_glm(cb, TaskType.LOGISTIC_REGRESSION, cfg)[1]
+        with telemetry.run("t") as run:
+            seen = train_glm(cb, TaskType.LOGISTIC_REGRESSION, cfg)[1]
+            counts = run.report_compact()["span_counts"]
+        assert any(p.endswith("stream.pass/stream.dispatch")
+                   for p in counts)
+        np.testing.assert_array_equal(np.asarray(bare.w),
+                                      np.asarray(seen.w))
+        np.testing.assert_array_equal(np.asarray(bare.loss_history),
+                                      np.asarray(seen.loss_history))
+        assert int(bare.evaluations) == int(seen.evaluations)
+
     def test_streamed_solve_unchanged_by_ring(self):
         """The ring + donated programs are pure overlap: streamed ==
         resident at the documented tolerance, twice in a row (ring state

@@ -314,6 +314,31 @@ class TestStreamedIterationStream:
         _, res = train_glm(cb, TaskType.LOGISTIC_REGRESSION, _CFG)
         assert int(res.iterations) > 0  # solve unaffected, nothing raised
 
+    def test_chunk_timeline_sites_get_the_shared_null_span(self, rng,
+                                                           monkeypatch):
+        """With no run attached every span site of the streamed solve —
+        the ring's upload, hand-out and release, the backend's dispatch
+        and readback, the host loop's steps and passes — is handed the ONE
+        shared no-op: no record, no clock reading, no annotation."""
+        X, y = _problem(rng)
+        cb = chunk_batch(make_batch(X, y), 64)
+        real, handed = telemetry.span, []
+
+        def spy(name, **attrs):
+            cm = real(name, **attrs)
+            handed.append((name, cm))
+            return cm
+
+        monkeypatch.setattr(telemetry, "span", spy)
+        assert telemetry.current_run() is None
+        train_glm(cb, TaskType.LOGISTIC_REGRESSION, _CFG)
+        assert {name for name, _ in handed} >= {
+            "solve.lbfgs_streamed", "stream.pass", "stream.upload",
+            "stream.handout", "stream.release", "stream.dispatch",
+            "stream.readback", "solve.host_step"}
+        assert all(cm is telemetry._NULL_SPAN for _, cm in handed)
+        assert telemetry._NULL_SPAN.__enter__() is None
+
 
 # --------------------------------------------------- resident solver tap
 class TestResidentTap:
