@@ -46,7 +46,14 @@ class CoordinateDescentResult:
 # On the COMMON path (no prior/projection/normalization, single device) the
 # whole update — offsets, solve, score, objective — fuses into ONE program
 # per coordinate (see _fused_fixed_update / RandomEffectCoordinate.
-# fused_update_program), ≤1 dispatch per update. Every OTHER random-effect
+# fused_update_program), ≤1 dispatch per update. Inside a descent a fused
+# random-effect coordinate's (E, d) table is WRITTEN in place and never
+# read back by an update: each bucket's solution, in the bucket's own
+# projected space, is carried to that bucket's next update as its warm
+# start (`carried` below). A caller's table (initial_models, a grid's
+# previous point, a checkpoint restore) is adopted ONCE, where it enters
+# the descent (random_effect.adopt_table: warm starts read through the
+# index maps, columns outside them cleared). Every OTHER random-effect
 # update (mesh, projection, normalization, prior, straggler_budget — the
 # last returns None from fused_update_program because the compacted
 # re-solve needs a host repack between passes) goes through the PIPELINED
@@ -346,7 +353,11 @@ def coordinate_descent(
         make_objective,
     )
 
-    from photon_tpu.game.random_effect import RETrainStats
+    from photon_tpu.game.random_effect import (
+        RETrainStats,
+        adopt_table,
+        cold_warm_starts,
+    )
 
     ck = _ckpt.current()
     cd_scope = contextlib.nullcontext()
@@ -356,7 +367,10 @@ def coordinate_descent(
         cd_scope = ck.scope(f"game-{fp}-{ck.invocation(fp)}")
 
     deferred_re: list = []  # (stats-list index slot fillers for fused REs)
-    own_tables: set = set()  # coordinates whose (E, d) table this call made
+    # coordinate name -> the buckets' solutions its last fused update left,
+    # each in its bucket's own space: the next update's warm starts. A name
+    # in here also says that this call made the coordinate's (E, d) table.
+    carried: dict = {}
     update_log: list = []  # (sweep, coordinate) per objective_history entry
     done_updates = 0
     stats_entries: list = []
@@ -460,22 +474,29 @@ def coordinate_descent(
                             fn, blocks_args, plan, objs, lam = fused
                             ds = coord.dataset
                             E, d = ds.n_entities, ds.dim
-                            # the update writes the table in place (the
-                            # program donates it): a table this descent
-                            # made is handed over as it is, a caller's
-                            # model is copied first
-                            if name in own_tables:
+                            # the update writes the table in place and
+                            # takes the buckets' warm starts beside it
+                            # (the program donates both): a table this
+                            # descent made is handed over as it is, with
+                            # the solutions its last update left; a
+                            # caller's model is copied and adopted first
+                            if name in carried:
                                 coeffs0 = warm.coefficients
+                                warm_b = carried.pop(name)
+                                telemetry.count("game_re.warm_carried")
                             elif (warm is not None
                                     and warm.coefficients.shape == (E, d)):
-                                coeffs0 = jnp.array(warm.coefficients,
-                                                    jnp.float32)
+                                coeffs0, warm_b = adopt_table(
+                                    jnp.array(warm.coefficients,
+                                              jnp.float32), blocks_args)
+                                telemetry.count("game_re.warm_adopted")
                             else:
                                 coeffs0 = jnp.zeros((E, d), jnp.float32)
-                            own_tables.add(name)
+                                warm_b = cold_warm_starts(blocks_args, d)
+                                telemetry.count("game_re.warm_cold")
                             (coeffs, variances, margin, objective, st,
-                             values) = fn(
-                                coeffs0, base, others, objs, lam,
+                             values, carried[name]) = fn(
+                                coeffs0, warm_b, base, others, objs, lam,
                                 blocks_args, plan, y, weights)
                             # the ONE dispatch solved every block of the
                             # coordinate (the pipelined loop in

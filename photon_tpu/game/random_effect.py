@@ -32,6 +32,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
+from functools import partial
 from typing import Optional
 
 import jax
@@ -632,14 +633,25 @@ class RandomEffectCoordinate:
     def fused_update_program(self):
         """ONE-dispatch whole-coordinate update for the no-prior /
         no-normalization / single-device case, unprojected or INDEX_MAP:
-        offsets sum, every bucket's warm starts read from the (E, d) table
-        (through the bucket's index map where it has one), its
-        (chunk-scanned) solves, the coefficient/variance write-back, the
-        full-row margins, and the objective — one jitted program, where the
-        unfused train()+score()+objective route pays ~4+ device dispatches
-        and, projected, carries the (E, d) table through the host. The path
-        is chosen by what the dataset carries: a RANDOM projection (its
-        dense matrix lives on the host) keeps the block loop.
+        offsets sum, every bucket's (chunk-scanned) solves from the warm
+        starts it is HANDED in the bucket's own space, the
+        coefficient/variance write into the (E, d) table (through the
+        bucket's index map where it has one), the full-row margins, and
+        the objective — one jitted program, where the unfused
+        train()+score()+objective route pays ~4+ device dispatches and,
+        projected, carries the (E, d) table through the host. The path is
+        chosen by what the dataset carries: a RANDOM projection (its dense
+        matrix lives on the host) keeps the block loop.
+
+        The table is WRITTEN in place and never read by the solves: a
+        bucket's solution, in the bucket's projected space, is that
+        bucket's warm start at its next update (Photon-ML's
+        RandomEffectModelInProjectedSpace), so the program takes the
+        buckets' warm starts as arguments and returns their solutions
+        beside the table. A table enters that regime once, by
+        `cold_warm_starts` (zeros, with a table of zeros) or by
+        `adopt_table` (a caller's model): from then on a column outside an
+        entity's index map is 0 and stays 0, because nothing writes it.
 
         The margins come from where a row's features already lie: a row a
         bucket holds is one element of `X_b · w_b`, the bucket's block
@@ -649,17 +661,20 @@ class RandomEffectCoordinate:
         lays both over the rows (`dataset.ScoringPlan`).
 
         Returns (fn, blocks_args, plan, objs, lam) — call
-        ``fn(coeffs, base, scores_tuple, objs, lam, blocks_args, plan, y,
-        weights)``, which DONATES ``coeffs`` (the table is updated in
-        place: pass a buffer nothing else reads) →
+        ``fn(coeffs, warm, base, scores_tuple, objs, lam, blocks_args,
+        plan, y, weights)``, which DONATES ``coeffs`` and ``warm`` (the
+        table is updated in place: pass buffers nothing else reads);
+        ``warm`` is one (E_b, width_b) f32 array a bucket, 0 on a bucket's
+        padding columns →
         (coeffs', variances', margins, objective, (n_conv, n_fail,
         n_iters, row_iters, block_steps, moved_row_iters, ls_trials),
-        values) — `row_iters` / `block_steps` the update's work: Σ
+        values, warm') — `row_iters` / `block_steps` the update's work: Σ
         weight-carrying rows × iterations over the entities, and Σ
         lock-step iterations over the blocks; `moved_row_iters` the part of
         `row_iters` whose iteration lowered its lane's loss; `ls_trials` Σ
         line-search evaluations over the entities; `values` (E,) each
-        entity's own final objective, as its solve computed it — or None
+        entity's own final objective, as its solve computed it; `warm'` the
+        buckets' solutions, the next update's ``warm`` — or None
         when this coordinate needs the general train() path.
         """
         cached = getattr(self, "_fused_cache", None)
@@ -745,14 +760,54 @@ def _solve_lanes(raw_fn, head: tuple, args: tuple, chunk: int, e_real: int):
             [x[:-1].reshape((-1,) + x.shape[2:]), x[-1, overlap:]]), outs)
 
 
+def _table_index(ents, cols):
+    """Where a bucket's (E_b, width_b) coefficients lie in the (E, d) table:
+    whole rows, or through the bucket's index map (a padding column points
+    past the table: a read there fills 0, a write drops)."""
+    return (ents,) if cols is None else (ents[:, None], cols)
+
+
+def cold_warm_starts(blocks_args: tuple, d: int) -> tuple:
+    """The buckets' warm starts of a cold start: zeros in each bucket's own
+    space, to go with an (E, d) table of zeros."""
+    return tuple(
+        jnp.zeros((ents.shape[0], d if cols is None else cols.shape[1]),
+                  jnp.float32)
+        for _, ents, cols, _ in blocks_args)
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def adopt_table(coeffs, blocks_args: tuple):
+    """A caller's (E, d) table enters a descent: → (coeffs', warm), the
+    buckets' warm starts read through their index maps and the columns
+    outside an entity's map cleared — what every fused update did before
+    the buckets' solutions were carried from one update to the next. It
+    DONATES ``coeffs``: pass a copy of a model somebody else holds."""
+    warm = []
+    with telemetry.device_scope("game_re.adopt"):
+        for _, ents, cols, _ in blocks_args:
+            at = _table_index(ents, cols)
+            w0 = coeffs.at[at].get(mode="fill", fill_value=0)
+            if cols is not None:
+                coeffs = coeffs.at[ents].set(0.0).at[at].set(w0, mode="drop")
+            warm.append(w0)
+    return coeffs, tuple(warm)
+
+
 def _fused_re_fn(solver_fns, meta: tuple, task, variance):
+    """The jitted one-dispatch update of `fused_update_program` (which says
+    what it takes and returns), one compiled program a (solver, block
+    shapes, task, variance). It writes the (E, d) table and never reads a
+    warm start out of it, nor clears a row: the table it is given is 0
+    outside the buckets' index maps (`cold_warm_starts` / `adopt_table`)."""
     key = (solver_fns[1], meta, task, variance)
     fn = _FUSED_RE.get(key)
     if fn is not None:
         return fn
     raw_fn = solver_fns[1]
 
-    def run(coeffs, base, scores, objs, lam, blocks_args, plan, y, weights):
+    def run(coeffs, warm, base, scores, objs, lam, blocks_args, plan, y,
+            weights):
         from photon_tpu.data.matrix import layout_matvec
         from photon_tpu.game.model import score_entities
         from photon_tpu.game.scoring import _sum_scores
@@ -761,32 +816,31 @@ def _fused_re_fn(solver_fns, meta: tuple, task, variance):
         loss, _, _ = loss_fns(task)
         with telemetry.device_scope("game.objective"):
             offs = _sum_scores(base, scores)
-        # The table is updated IN PLACE (its buffer is donated): the
-        # buckets partition the entities, so a bucket reads its own rows'
-        # warm starts before it, and nothing after it, writes them.
+        # The table is written IN PLACE (its buffer is donated): the
+        # buckets partition the entities, so each writes rows no other
+        # touches, over the index map its last solution was written through.
         variances = (jnp.zeros_like(coeffs)
                      if variance is not VarianceComputationType.NONE
                      else None)
         conv = fail = iters = row_iters = steps = moved = trials = 0
         values = jnp.zeros((coeffs.shape[0],), jnp.float32)
         scored = []  # the buckets' (E_b · m,) block margins, then the table's
-        for (row_index, ents, cols, batch_base), (chunk, e_real), obj in \
-                zip(blocks_args, meta, objs):
-            at = (ents,) if cols is None else (ents[:, None], cols)
+        carried = []  # the buckets' solutions: the next update's `warm`
+        for (row_index, ents, cols, batch_base), w0, (chunk, e_real), obj in \
+                zip(blocks_args, warm, meta, objs):
             with telemetry.device_scope("game_re.gather"):
                 batch = batch_base._replace(offsets=offs[row_index])
-                w0 = coeffs.at[at].get(mode="fill", fill_value=0)
             with telemetry.device_scope("game_re.solve"):
                 res, var = _solve_lanes(raw_fn, (obj, lam), (batch, w0),
                                         chunk, e_real)
+            carried.append(res.w)
             with telemetry.device_scope("game_re.score"):
                 # the rows this bucket holds, by the forward pass its
                 # objective runs, at the solution in the block's own space
                 scored.append(jax.vmap(layout_matvec)(
                     batch_base.X, res.w).reshape(-1))
             with telemetry.device_scope("game_re.scatter"):
-                if cols is not None:  # columns outside the map go to 0
-                    coeffs = coeffs.at[ents].set(0.0)
+                at = _table_index(ents, cols)
                 coeffs = coeffs.at[at].set(res.w, mode="drop")
                 if var is not None and variances is not None:
                     variances = variances.at[at].set(var, mode="drop")
@@ -814,9 +868,10 @@ def _fused_re_fn(solver_fns, meta: tuple, task, variance):
         with telemetry.device_scope("game.objective"):
             objective = jnp.sum(weights * loss(offs + margins, y))
         return coeffs, variances, margins, objective, (
-            conv, fail, iters, row_iters, steps, moved, trials), values
+            conv, fail, iters, row_iters, steps, moved, trials), values, \
+            tuple(carried)
 
-    fn = jax.jit(run, donate_argnums=(0,))
+    fn = jax.jit(run, donate_argnums=(0, 1))
     _FUSED_RE[key] = fn
     return fn
 

@@ -75,7 +75,10 @@ of line-search evaluations) and block_steps (Σ over blocks of the
 block's lock-step iterations: the largest over its lanes), and per
 update on the host block_scored_rows / table_scored_rows (the training
 rows whose margin came from a bucket's block pass, and from the table
-gather: together the rows × the updates), beside the
+gather: together the rows × the updates) and warm_carried / warm_cold /
+warm_adopted (the one-dispatch updates by where their warm starts came
+from: the buckets' solutions of the descent's previous update, zeros, or
+a caller's table through the `game_re.adopt` program), beside the
 fixed effect's game_fixed.row_iterations (rows × iterations taken);
 the blocked-ELL builds' `layout.*` family — tail_nnz / ell_slots /
 occ_slots (`data.matrix.to_blocked_ell` and `shard_blocked_ell`: the
@@ -169,11 +172,15 @@ phases lbfgs.two_loop, lbfgs.push, lbfgs.linesearch, lbfgs.direction
 history write); solve.prologue / solve.epilogue (before and after the
 `while_loop`, and the lane-minor → lane-major transpose); the phases of a
 coordinate-descent update — game_re.gather (offsets laid into a bucket's
-rows, warm starts read from the (E, d) table through the bucket's index
-map), game_re.solve (the bucket's vmapped per-entity solves: the L-BFGS
-and X-pass scopes nest under it), game_re.scatter (results written back
-to the table), game_re.score (per-row margins: block passes for the rows
+rows; the warm starts are handed to the update in the buckets' own space,
+not read from the table), game_re.solve (the bucket's vmapped per-entity
+solves: the L-BFGS and X-pass scopes nest under it), game_re.scatter
+(results written to the (E, d) table through the bucket's index map),
+game_re.score (per-row margins: block passes for the rows
 the buckets hold, the table gather for the rest, one reassembly gather),
+game_re.adopt (a program of its own, once a coordinate a descent at most:
+a CALLER's table enters the descent — warm starts read through the index
+maps, the columns outside them cleared; never in a cold-start fit),
 game_fixed.solve (the fixed effect's solve, same nesting) and
 game.objective (offsets sum and the tracking objective); and mesh.psum
 (the objective's all-reduces over the mesh axis — `Objective._psum` /
@@ -471,6 +478,7 @@ TELEMETRY_REGISTRY = {
         "game_re.moved_row_iterations", "game_re.iterations",
         "game_re.linesearch_trials",
         "game_re.block_scored_rows", "game_re.table_scored_rows",
+        "game_re.warm_carried", "game_re.warm_cold", "game_re.warm_adopted",
         "game_fixed.row_iterations",
         "layout.shard_bytes_real", "layout.shard_bytes_padded",
         "layout.tail_nnz", "layout.ell_slots", "layout.occ_slots",
@@ -506,7 +514,8 @@ TELEMETRY_REGISTRY = {
         "lbfgs.direction", "lbfgs.update",
         "solve.prologue", "solve.epilogue",
         "game_re.gather", "game_re.solve", "game_re.scatter",
-        "game_re.score", "game_fixed.solve", "game.objective",
+        "game_re.score", "game_re.adopt", "game_fixed.solve",
+        "game.objective",
         "mesh.psum",
     ),
 }

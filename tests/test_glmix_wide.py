@@ -19,7 +19,8 @@ from photon_tpu.game.coordinate_descent import coordinate_descent
 from photon_tpu.game.dataset import GameData, RandomEffectDataset, plan_buckets
 from photon_tpu.game.estimator import GameEstimator, RandomEffectConfig
 from photon_tpu.game.projector import ProjectionConfig, ProjectorType
-from photon_tpu.game.random_effect import RandomEffectCoordinate
+from photon_tpu.game.random_effect import (RandomEffectCoordinate,
+                                           cold_warm_starts)
 from photon_tpu.ops.losses import TaskType
 from photon_tpu.optim import regularization as reg
 from photon_tpu.optim.config import OptimizerConfig
@@ -370,7 +371,8 @@ def test_descent_scopes_reach_the_compiled_updates():
     fn, blocks_args, plan, objs, lam = coord.fused_update_program()
     n = data.n
     zeros = jnp.zeros((n,), jnp.float32)
-    text = fn.lower(jnp.zeros((ds.n_entities, ds.dim), jnp.float32), zeros,
+    text = fn.lower(jnp.zeros((ds.n_entities, ds.dim), jnp.float32),
+                    cold_warm_starts(blocks_args, ds.dim), zeros,
                     (zeros,), objs, lam, blocks_args, plan, zeros,
                     zeros).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', text)
@@ -497,3 +499,52 @@ def test_cell_comparison_refuses_planted_faults(descent_cell, fault,
     else:
         assert not verdict["ok"]
         assert refused_by in verdict["refused_by"]
+
+
+@pytest.mark.parametrize("start", ["cold", "warm_started"])
+def test_cell_fit_hands_every_update_what_its_table_holds(descent_cell, start,
+                                                          monkeypatch):
+    """At the cell's rehearse sizes: every one-dispatch update of a fit is
+    handed, as its buckets' warm starts, bit for bit what a gather out of
+    the table it is handed beside them reads through the index maps (the
+    rule every update followed until the solutions were carried), and a
+    table that is 0 outside those maps — so the carried solve is that
+    solve. A cold fit repeats the warm-up fit's bits."""
+    _, state, evidence = descent_cell
+    plain = RandomEffectCoordinate.fused_update_program
+    handed = []
+
+    def checking(self):
+        fn, blocks_args, *rest = plain(self)
+
+        def call(table, warm, *args):
+            left = table
+            for (_, ents, cols, _), w0 in zip(blocks_args, warm):
+                np.testing.assert_array_equal(
+                    np.asarray(table.at[ents[:, None], cols].get(
+                        mode="fill", fill_value=0)), np.asarray(w0))
+                left = left.at[ents[:, None], cols].set(0.0, mode="drop")
+            assert not np.any(np.asarray(left))  # nothing outside a map
+            handed.append(bool(np.any(np.asarray(table))))
+            return fn(table, warm, *args)
+
+        return (call, blocks_args, *rest)
+
+    monkeypatch.setattr(RandomEffectCoordinate, "fused_update_program",
+                        checking)
+    initial = None
+    if start == "warm_started":
+        (first,) = state.estimator.fit(state.data)
+        initial = dict(first.model.coordinates)
+        handed.clear()
+    (result,) = state.estimator.fit(state.data, initial_models=initial)
+    # 2 sweeps x 2 random-effect coordinates; a cold fit's first two
+    # updates start from tables of zeros
+    assert handed == ([False, False, True, True] if start == "cold"
+                      else [True] * 4)
+    if start == "cold":
+        for name, table in evidence["tables"].items():
+            np.testing.assert_array_equal(
+                np.asarray(result.model[name].coefficients), table)
+        assert [float(v) for v in result.descent.objective_history] \
+            == evidence["history"]

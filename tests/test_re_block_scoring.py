@@ -18,7 +18,8 @@ from photon_tpu.game.dataset import GameData, RandomEffectDataset
 from photon_tpu.game.estimator import GameEstimator, RandomEffectConfig
 from photon_tpu.game.model import score_entities
 from photon_tpu.game.projector import ProjectionConfig, ProjectorType
-from photon_tpu.game.random_effect import RandomEffectCoordinate
+from photon_tpu.game.random_effect import (RandomEffectCoordinate,
+                                           cold_warm_starts)
 from photon_tpu.ops.losses import TaskType
 from photon_tpu.optim import regularization as reg
 from photon_tpu.optim.config import OptimizerConfig
@@ -113,6 +114,7 @@ def _update(ds, data, iters=6):
     fn, blocks_args, plan, objs, lam = coord.fused_update_program()
     zeros = jnp.zeros((data.n,), jnp.float32)
     out = fn(jnp.zeros((ds.n_entities, ds.dim), jnp.float32),
+             cold_warm_starts(blocks_args, ds.dim),
              jnp.asarray(data.offsets), (zeros,), objs, lam, blocks_args,
              plan, jnp.asarray(data.y), jnp.asarray(data.weights))
     return out[0], out[2]
@@ -199,7 +201,8 @@ def test_compiled_update_gathers_the_table_for_passive_rows_only(name):
         max_iters=3, tolerance=0.0, reg=reg.l2(), reg_weight=L2))
     fn, blocks_args, plan, objs, lam = coord.fused_update_program()
     zeros = jnp.zeros((data.n,), jnp.float32)
-    text = fn.lower(jnp.zeros((ds.n_entities, ds.dim), jnp.float32), zeros,
+    text = fn.lower(jnp.zeros((ds.n_entities, ds.dim), jnp.float32),
+                    cold_warm_starts(blocks_args, ds.dim), zeros,
                     (zeros,), objs, lam, blocks_args, plan, zeros,
                     zeros).compile().as_text()
     k = NNZ + 1
@@ -249,20 +252,16 @@ def test_scored_row_counters_sum_to_rows_times_updates(cap):
 
 
 # ------------------------------- (e) a descent trained on block-scored margins
-@pytest.mark.parametrize("sparse", [False, True], ids=["dense", "sparse"])
-def test_two_coordinate_descent_matches_the_block_loop(sparse):
-    """Each coordinate trains against the other's margins as offsets: the
-    one-dispatch descent (block-scored) and the block loop's `train` +
-    `score` (table-scored) agree on every update's margins and objective
-    within what `test_projected_one_dispatch_update_matches_block_loop`
-    grants the pair of solvers."""
-    prob = _problem(seed=4)
+def _two_coordinates(sparse=True, iters=6, seed=4):
+    """Two random-effect coordinates over one shard, each with passive
+    rows: "a" through INDEX_MAP buckets, "b" unprojected."""
+    prob = _problem(seed=seed)
     X = (SparseRows(prob["ind"], prob["val"], FEATURES + 1) if sparse
          else prob["dense"])
     data = GameData.build(prob["y"], {"s": X},
                           {"e": prob["ent"], "g": (prob["ent"] * 5) % 11},
                           offsets=prob["offsets"])
-    cfg = OptimizerConfig(max_iters=25, tolerance=0.0, reg=reg.l2(),
+    cfg = OptimizerConfig(max_iters=iters, tolerance=0.0, reg=reg.l2(),
                           reg_weight=L2)
     coords = {
         "a": RandomEffectCoordinate(RandomEffectDataset.build(
@@ -270,20 +269,46 @@ def test_two_coordinate_descent_matches_the_block_loop(sparse):
         "b": RandomEffectCoordinate(RandomEffectDataset.build(
             data, "g", "s", active_cap=4 * CAP), TASK, cfg)}
     assert all(c.dataset.n_passive > 0 for c in coords.values())
-    seen = []  # (table, margins) each one-dispatch update returned, in order
+    return prob, data, coords
 
-    def recording(fn):
+
+def _recorded(coords):
+    """Wrap every coordinate's one-dispatch program: the list this returns
+    fills with what each update of a descent returned, in order, copied to
+    the host before the next update is handed (and overwrites) the same
+    buffers."""
+    seen = []
+
+    def recording(name, fn):
         def call(*args):
             out = fn(*args)
-            seen.append((np.asarray(out[0]), np.asarray(out[2])))
+            seen.append({
+                "name": name, "table": np.array(out[0]),
+                "margins": np.array(out[2]), "objective": np.array(out[3]),
+                "values": np.array(out[5]),
+                "carried": [np.array(w) for w in out[6]]})
             return out
         return call
 
-    for coord in coords.values():
+    for name, coord in coords.items():
         fn, *rest = coord.fused_update_program()
-        coord._fused_cache = (recording(fn), *rest)
+        coord._fused_cache = (recording(name, fn), *rest)
+    return seen
+
+
+@pytest.mark.parametrize("sparse", [False, True], ids=["dense", "sparse"])
+def test_two_coordinate_descent_matches_the_block_loop(sparse):
+    """Each coordinate trains against the other's margins as offsets: the
+    one-dispatch descent (block-scored) and the block loop's `train` +
+    `score` (table-scored) agree on every update's margins and objective
+    within what `test_projected_one_dispatch_update_matches_block_loop`
+    grants the pair of solvers."""
+    prob, data, coords = _two_coordinates(sparse, iters=25)
+    updates = _recorded(coords)
     out = coordinate_descent(coords, data.y, data.weights, data.offsets,
                              TASK, n_sweeps=2)
+    # (table, margins) each one-dispatch update returned, in order
+    seen = [(u["table"], u["margins"]) for u in updates]
 
     y, weights = jnp.asarray(data.y), jnp.asarray(data.weights)
     base = jnp.asarray(data.offsets)
@@ -326,3 +351,253 @@ def test_two_coordinate_descent_matches_the_block_loop(sparse):
     # the tracked loss moves 1e-5 with the solver's wander
     np.testing.assert_allclose(
         [float(h) for h in out.objective_history], history, rtol=1e-4)
+
+
+# ----------- (f) a bucket's solution is carried to its next update (PR 37)
+def _parents_rule(table, blocks_args):
+    """What every update did with the table it was given until the buckets'
+    solutions were carried: each bucket's warm starts gathered through its
+    index map (a padding column reads 0), the rows of a projected bucket
+    cleared before its solution is written over them."""
+    warm = []
+    for _, ents, cols, _ in blocks_args:
+        at = (ents,) if cols is None else (ents[:, None], cols)
+        warm.append(table.at[at].get(mode="fill", fill_value=0))
+        if cols is not None:
+            table = table.at[ents].set(0.0)
+    return table, tuple(warm)
+
+
+def _descent_by_the_parents_rule(data, coords, programs, initial=None,
+                                 n_sweeps=2):
+    """The descent's updates, each through the coordinate's own one-dispatch
+    program, with every update's warm starts read back out of the (E, d)
+    table → what each update returned, in order."""
+    y, weights = jnp.asarray(data.y), jnp.asarray(data.weights)
+    base = jnp.asarray(data.offsets)
+    zeros = jnp.zeros((data.n,), jnp.float32)
+    tables, scores = {}, {}
+    for name, coord in coords.items():
+        ds = coord.dataset
+        tables[name] = jnp.zeros((ds.n_entities, ds.dim), jnp.float32)
+        if initial is not None:
+            tables[name] = jnp.array(initial[name].coefficients, jnp.float32)
+            scores[name] = coord.score(initial[name])
+    updates = []
+    for _ in range(n_sweeps):
+        for name in coords:
+            fn, blocks_args, plan, objs, lam = programs[name]
+            (other,) = [scores.get(o, zeros) for o in coords if o != name]
+            table, warm = _parents_rule(tables[name], blocks_args)
+            out = fn(table, warm, base, (other,), objs, lam, blocks_args,
+                     plan, y, weights)
+            tables[name], scores[name] = out[0], out[2]
+            updates.append({"name": name, "table": np.array(out[0]),
+                            "margins": np.array(out[2]),
+                            "objective": np.array(out[3]),
+                            "values": np.array(out[5])})
+    return updates
+
+
+def _assert_same_bits(got, want):
+    assert [u["name"] for u in got] == [u["name"] for u in want]
+    for at, (g, w) in enumerate(zip(got, want)):
+        for key in ("table", "margins", "objective", "values"):
+            np.testing.assert_array_equal(
+                g[key], w[key], err_msg=f"update {at} ({g['name']}): {key}")
+
+
+def _random_models(coords, seed=11):
+    """A caller's model a coordinate: nonzeros in EVERY column, so also
+    outside the entities' index maps."""
+    from photon_tpu.game.model import RandomEffectModel
+
+    rng = np.random.default_rng(seed)
+    models = {}
+    for name, coord in coords.items():
+        ds = coord.dataset
+        table = rng.normal(size=(ds.n_entities, ds.dim)).astype(np.float32)
+        assert np.all(table != 0.0)
+        models[name] = RandomEffectModel(
+            entity_name=ds.entity_name, feature_shard=ds.shard_name,
+            task=TASK, coefficients=jnp.asarray(table),
+            entity_keys=ds.entity_keys, key_to_index=ds.key_to_index)
+    return models
+
+
+def _inside_the_maps(ds):
+    """(E, d) bool: the columns an entity's index map names."""
+    inside = np.zeros((ds.n_entities, ds.dim), bool)
+    for block in ds.blocks:
+        ents = np.asarray(block.entity_index)
+        real = np.asarray(block.proj.proj_mask) > 0
+        idx = np.asarray(block.proj.proj_idx)
+        rows = np.broadcast_to(ents[:, None], idx.shape)
+        inside[rows[real], idx[real]] = True
+    return inside
+
+
+@pytest.mark.parametrize("start", ["cold", "warm_started"])
+@pytest.mark.parametrize("sparse", [False, True], ids=["dense", "sparse"])
+def test_carried_descent_has_the_bits_of_warm_starts_read_from_the_table(
+        sparse, start):
+    """A 2-sweep descent whose updates are handed the previous update's
+    solutions returns, update by update, the tables, margins, objectives and
+    per-entity values of one that gathers every warm start out of the table
+    — from zeros, and from the model of an earlier fit (a grid's previous
+    point)."""
+    _, data, coords = _two_coordinates(sparse)
+    programs = {name: c.fused_update_program() for name, c in coords.items()}
+    initial = None
+    if start == "warm_started":
+        initial = coordinate_descent(
+            coords, data.y, data.weights, data.offsets, TASK,
+            n_sweeps=1).model.coordinates
+    seen = _recorded(coords)
+    out = coordinate_descent(coords, data.y, data.weights, data.offsets,
+                             TASK, n_sweeps=2, initial_models=initial)
+    want = _descent_by_the_parents_rule(data, coords, programs, initial)
+    assert len(seen) == 4 and np.abs(want[-1]["table"]).max() > 0.1
+    _assert_same_bits(seen, want)
+    # and what the descent hands back is what its updates returned
+    for name in coords:
+        last = [u for u in want if u["name"] == name]
+        np.testing.assert_array_equal(
+            np.asarray(out.model[name].coefficients), last[-1]["table"])
+        for stats, u in zip(out.coordinate_stats[name], last):
+            np.testing.assert_array_equal(
+                np.asarray(stats.entity_values), u["values"])
+    assert out.objective_history == [float(u["objective"]) for u in want]
+
+
+def test_callers_table_is_adopted_with_zeros_outside_the_maps():
+    """A caller's table with nonzeros outside the index maps: the descent
+    solves from the mapped columns only, hands back 0 in every other
+    column, the same bits as the parent's gather-and-clear gives, and
+    leaves the caller's own arrays as they were."""
+    _, data, coords = _two_coordinates()
+    programs = {name: c.fused_update_program() for name, c in coords.items()}
+    initial = _random_models(coords)
+    kept = {name: np.array(m.coefficients) for name, m in initial.items()}
+    seen = _recorded(coords)
+    out = coordinate_descent(coords, data.y, data.weights, data.offsets,
+                             TASK, n_sweeps=2, initial_models=initial)
+    _assert_same_bits(seen, _descent_by_the_parents_rule(
+        data, coords, programs, initial))
+    inside = _inside_the_maps(coords["a"].dataset)
+    table = np.asarray(out.model["a"].coefficients)
+    assert 0 < inside.sum() < inside.size
+    assert np.all(table[~inside] == 0.0) and np.all(table[inside] != 0.0)
+    for name, m in initial.items():  # the copy was donated, not the model
+        np.testing.assert_array_equal(np.asarray(m.coefficients), kept[name])
+
+
+def test_adopt_table_reads_the_maps_and_clears_the_rest():
+    from photon_tpu.game.random_effect import adopt_table
+
+    _, _, coords = _two_coordinates()
+    initial = _random_models(coords)
+    for name, coord in coords.items():
+        _, blocks_args, *_ = coord.fused_update_program()
+        given = np.array(initial[name].coefficients)
+        _, want = _parents_rule(jnp.asarray(given), blocks_args)
+        table, warm = adopt_table(jnp.asarray(given), blocks_args)
+        for got, w in zip(warm, want):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(w))
+        keep = (_inside_the_maps(coord.dataset) if name == "a"
+                else np.ones(given.shape, bool))
+        np.testing.assert_array_equal(np.asarray(table),
+                                      np.where(keep, given, 0.0))
+
+
+@pytest.mark.parametrize("start", ["cold", "adopted"])
+def test_padding_columns_of_a_carried_solution_are_exactly_zero(start):
+    """A bucket's padding column has no feature and an L2 gradient of 0 at
+    0: the solver never moves it, so a carried solution reads there what
+    the table gather filled in."""
+    _, data, coords = _two_coordinates()
+    initial = _random_models(coords) if start == "adopted" else None
+    seen = _recorded(coords)
+    coordinate_descent(coords, data.y, data.weights, data.offsets, TASK,
+                       n_sweeps=2, initial_models=initial)
+    blocks = coords["a"].dataset.blocks
+    padded = 0
+    for update in (u for u in seen if u["name"] == "a"):
+        assert len(update["carried"]) == len(blocks)
+        for block, w in zip(blocks, update["carried"]):
+            pad = np.asarray(block.proj.proj_mask) == 0
+            assert w.shape == pad.shape and np.abs(w[~pad]).max() > 0.0
+            assert np.all(w[pad] == 0.0)
+            padded += int(pad.sum())
+    assert padded > 0
+
+
+@pytest.mark.parametrize("start, want", [("cold", (2, 2, 0)),
+                                         ("warm_started", (2, 0, 2))])
+def test_warm_start_counters_say_where_the_warm_starts_came_from(start,
+                                                                 want):
+    _, data, coords = _two_coordinates(iters=3)
+    initial = None
+    if start == "warm_started":
+        initial = coordinate_descent(
+            coords, data.y, data.weights, data.offsets, TASK,
+            n_sweeps=1).model.coordinates
+    with telemetry.run("warm") as run:
+        coordinate_descent(coords, data.y, data.weights, data.offsets, TASK,
+                           n_sweeps=2, initial_models=initial)
+        counters = run.report_compact()["counters"]
+    assert tuple(counters.get(f"game_re.warm_{kind}", 0)
+                 for kind in ("carried", "cold", "adopted")) == want
+    assert counters["game.coordinate_updates"] == 4
+
+
+def test_update_program_is_traced_once_across_sweeps_and_starts():
+    """Cold zeros, the carried solutions and an adopted table's warm starts
+    reach the update as the same avals: ONE compiled program a coordinate
+    for both sweeps of a cold descent and of a warm-started one."""
+    _, data, coords = _two_coordinates(iters=7)  # a solver no other test has
+    fns = {name: c.fused_update_program()[0] for name, c in coords.items()}
+    assert all(fn._cache_size() == 0 for fn in fns.values())
+    out = coordinate_descent(coords, data.y, data.weights, data.offsets,
+                             TASK, n_sweeps=2)
+    assert all(fn._cache_size() == 1 for fn in fns.values())
+    coordinate_descent(coords, data.y, data.weights, data.offsets, TASK,
+                       n_sweeps=2, initial_models=out.model.coordinates)
+    assert all(fn._cache_size() == 1 for fn in fns.values())
+
+
+def test_restored_descent_finishes_with_the_uninterrupted_bits(tmp_path):
+    """Killed after its third update and restored, the descent adopts the
+    restored tables where the uninterrupted run carried its solutions: the
+    same tables and objective history, bit for bit."""
+    from photon_tpu import checkpoint
+
+    _, data, coords = _two_coordinates()
+
+    def run():
+        return coordinate_descent(coords, data.y, data.weights,
+                                  data.offsets, TASK, n_sweeps=2)
+
+    def session(path):
+        return checkpoint.session(str(path), every_evals=1, every_s=None,
+                                  async_writer=False)
+
+    ref = run()
+    with session(tmp_path / "rec"), checkpoint.record_sites() as rec:
+        run()
+    assert dict(rec.hits)["commit"] == 4  # one progress cut an update
+    with pytest.raises(checkpoint.InjectedFault):
+        with session(tmp_path / "kill"), checkpoint.fault_plan(
+                checkpoint.FaultPlan.kill_at("commit", 4)):
+            run()
+    with session(tmp_path / "kill"), telemetry.run("restored") as trun:
+        out = run()
+        counters = trun.report_compact()["counters"]
+    assert counters["checkpoint.descent_restores"] == 1
+    assert counters["game.coordinate_updates"] == 1
+    assert counters["game_re.warm_adopted"] == 1
+    for name in coords:
+        np.testing.assert_array_equal(
+            np.asarray(ref.model[name].coefficients),
+            np.asarray(out.model[name].coefficients))
+    assert ref.objective_history == out.objective_history
