@@ -43,20 +43,24 @@ class CoordinateDescentResult:
 # The descent loop's glue is jitted so each coordinate update costs a fixed
 # handful of device dispatches (train, score, offsets, objective) instead
 # of one launch per eager primitive. The offsets sum is game.scoring._sum_scores (one shared jit cache).
-# On the COMMON path (no prior/projection/normalization, single device) the
-# whole update — offsets, solve, score, objective — fuses into ONE program
-# per coordinate (see _fused_fixed_update / RandomEffectCoordinate.
+# On the COMMON path (single device, no normalization, unprojected or
+# INDEX_MAP, with or without an incremental prior) the whole update —
+# offsets, solve, variances, score, objective — fuses into ONE program per
+# coordinate (see _fused_fixed_update / RandomEffectCoordinate.
 # fused_update_program), ≤1 dispatch per update. Inside a descent a fused
 # random-effect coordinate's (E, d) table is WRITTEN in place and never
 # read back by an update: each bucket's solution, in the bucket's own
 # projected space, is carried to that bucket's next update as its warm
 # start (`carried` below). A caller's table (initial_models, a grid's
 # previous point, a checkpoint restore) is adopted ONCE, where it enters
-# the descent (random_effect.adopt_table: warm starts read through the
-# index maps, columns outside them cleared). Every OTHER random-effect
-# update (mesh, projection, normalization, prior, straggler_budget — the
-# last returns None from fused_update_program because the compacted
-# re-solve needs a host repack between passes) goes through the PIPELINED
+# the descent (random_effect.initial_table + adopt_table: each entity's row
+# found by key, warm starts read through the index maps, columns outside
+# them cleared); an incremental coordinate's prior is gathered ONCE into
+# the buckets' own spaces (random_effect.bucket_priors) and handed to
+# every update of the descent. Every OTHER random-effect update (mesh,
+# RANDOM projection, normalization, straggler_budget — the last returns
+# None from fused_update_program because the compacted re-solve needs a
+# host repack between passes) goes through the PIPELINED
 # RandomEffectCoordinate.train(): bucket k+1's upload/solve dispatched
 # before bucket k's readback, so the per-coordinate wall is
 # max(device solve, host scatter) per bucket instead of their sum.
@@ -232,14 +236,28 @@ def _fused_fixed_update(batch, base, scores, w0, obj, l1, y, weights,
     b = batch._replace(offsets=offs)
     with telemetry.device_scope("game_fixed.solve"):
         res = solve(obj, b, w0, config, l1_weight=l1)
+    with telemetry.device_scope("game_fixed.variance"):
         var = compute_variances(obj, res.w, b, variance)
+    with telemetry.device_scope("game_fixed.solve"):
         margin = matvec(batch.X, res.w)
     with telemetry.device_scope("game.objective"):
         objective = jnp.sum(weights * loss(offs + margin, y))
     return res, var, margin, objective
 
 
-def _fixed_fusable(coord: FixedEffectCoordinate, prior) -> bool:
+def _fixed_prior_objective(obj, coord: FixedEffectCoordinate, prior):
+    """``obj`` regularized toward the coordinate's prior, as
+    `FixedEffectCoordinate.train` regularizes it; ``obj`` where there is
+    none."""
+    dist = coord.prior_distribution(prior)
+    if dist is None:
+        return obj
+    return dataclasses.replace(
+        obj, prior_mean=jnp.asarray(dist.mean),
+        prior_precision=jnp.asarray(dist.precision_diag))
+
+
+def _fixed_fusable(coord: FixedEffectCoordinate) -> bool:
     from photon_tpu.data.dataset import ChunkedMatrix
     from photon_tpu.data.matrix import (BlockedEllRows, PermutedHybridRows,
                                         ShardedHybridRows)
@@ -251,7 +269,7 @@ def _fixed_fusable(coord: FixedEffectCoordinate, prior) -> bool:
     # model and scoring would re-permute them (silently wrong margins).
     # ChunkedMatrix keeps it too: the streamed solve is a host loop, not a
     # jittable solve() call.
-    return (prior is None and coord.mesh is None
+    return (coord.mesh is None
             and not isinstance(coord.dataset.X,
                                (ShardedHybridRows, PermutedHybridRows,
                                 BlockedEllRows, ChunkedMatrix))
@@ -347,6 +365,7 @@ def coordinate_descent(
         RandomEffectModel,
     )
     from photon_tpu.models.glm import Coefficients, GeneralizedLinearModel
+    from photon_tpu.models.variance import VarianceComputationType
     from photon_tpu.models.training import (
         _l1_lam,
         _static_config,
@@ -356,7 +375,9 @@ def coordinate_descent(
     from photon_tpu.game.random_effect import (
         RETrainStats,
         adopt_table,
+        bucket_priors,
         cold_warm_starts,
+        initial_table,
     )
 
     ck = _ckpt.current()
@@ -371,6 +392,10 @@ def coordinate_descent(
     # each in its bucket's own space: the next update's warm starts. A name
     # in here also says that this call made the coordinate's (E, d) table.
     carried: dict = {}
+    # coordinate name -> its prior in the form its fused update takes (the
+    # fixed effect's Objective, the buckets' (mean, precision) pairs),
+    # made once a descent: the prior stays the caller's across sweeps
+    fused_priors: dict = {}
     update_log: list = []  # (sweep, coordinate) per objective_history entry
     done_updates = 0
     stats_entries: list = []
@@ -427,14 +452,18 @@ def coordinate_descent(
                     # is still one train dispatch + one scoring stream.
                     if (isinstance(coord, FixedEffectCoordinate)
                             and not streamed
-                            and _fixed_fusable(coord, prior)):
+                            and _fixed_fusable(coord)):
                         ds = coord.dataset
                         w0 = jnp.zeros((ds.dim,), jnp.float32)
                         if warm is not None and \
                                 warm.model.weights.shape[0] == ds.dim:
                             w0 = jnp.asarray(warm.model.weights)
                         batch = GLMBatch(ds.X, ds.y, ds.weights, base)
-                        obj = make_objective(task, coord.config, ds.dim)
+                        obj = fused_priors.get(name)
+                        if obj is None:
+                            obj = fused_priors[name] = _fixed_prior_objective(
+                                make_objective(task, coord.config, ds.dim),
+                                coord, prior)
                         res, var, margin, objective = _fused_fixed_update(
                             batch, base, others, w0, obj,
                             _l1_lam(coord.config), y, weights,
@@ -461,15 +490,14 @@ def coordinate_descent(
                                 "failed": bool(res.failed)}
                     else:
                         # fused_update_program gates itself: it returns
-                        # None for mesh / projection / normalization /
-                        # straggler-budget coordinates (the budget gate
-                        # logs once at INFO and counts on
+                        # None for mesh / RANDOM projection /
+                        # normalization / straggler-budget coordinates
+                        # (the budget gate logs once at INFO and counts on
                         # game_re.fused_gate_offs), which then train on
                         # the pipelined block loop below.
                         fused = (coord.fused_update_program()
                                  if isinstance(coord, RandomEffectCoordinate)
-                                 and prior is None and not streamed
-                                 else None)
+                                 and not streamed else None)
                         if fused is not None:
                             fn, blocks_args, plan, objs, lam = fused
                             ds = coord.dataset
@@ -479,25 +507,39 @@ def coordinate_descent(
                             # (the program donates both): a table this
                             # descent made is handed over as it is, with
                             # the solutions its last update left; a
-                            # caller's model is copied and adopted first
+                            # caller's model is copied (each entity's row
+                            # by key) and adopted first
                             if name in carried:
                                 coeffs0 = warm.coefficients
                                 warm_b = carried.pop(name)
                                 telemetry.count("game_re.warm_carried")
-                            elif (warm is not None
-                                    and warm.coefficients.shape == (E, d)):
+                            elif warm is not None and warm.dim == d:
                                 coeffs0, warm_b = adopt_table(
-                                    jnp.array(warm.coefficients,
-                                              jnp.float32), blocks_args)
+                                    initial_table(warm, ds.entity_keys),
+                                    blocks_args)
                                 telemetry.count("game_re.warm_adopted")
                             else:
                                 coeffs0 = jnp.zeros((E, d), jnp.float32)
                                 warm_b = cold_warm_starts(blocks_args, d)
                                 telemetry.count("game_re.warm_cold")
+                            bucket_prior = None
+                            if prior is not None and prior.dim == d:
+                                if name not in fused_priors:
+                                    fused_priors[name] = bucket_priors(
+                                        prior, ds.entity_keys, blocks_args)
+                                bucket_prior = fused_priors[name]
+                                telemetry.count(
+                                    "game_re.fused_prior_updates")
+                            if coord.variance is not \
+                                    VarianceComputationType.NONE:
+                                telemetry.count(
+                                    "game_re.variance_lanes",
+                                    sum(int(ents.shape[0])
+                                        for _, ents, _, _ in blocks_args))
                             (coeffs, variances, margin, objective, st,
                              values, carried[name]) = fn(
                                 coeffs0, warm_b, base, others, objs, lam,
-                                blocks_args, plan, y, weights)
+                                blocks_args, plan, y, weights, bucket_prior)
                             # the ONE dispatch solved every block of the
                             # coordinate (the pipelined loop in
                             # RandomEffectCoordinate.train counts its own)

@@ -221,6 +221,9 @@ class REBlock:
     # proj = the per-entity index map behind it (INDEX_MAP only).
     dim: Optional[int] = None
     proj: Optional[object] = None  # projector.BlockProjection
+    # lanes of one chunk of this bucket's FULL variances (the bucket plan's
+    # `variance_lanes`: width² a lane, counted against the device)
+    variance_lanes: int = 1
 
     @property
     def n_entities(self) -> int:
@@ -303,6 +306,15 @@ _SHAPE_WORTH = 128
 _BLOCK_SHARE = 2
 _DEFAULT_SHAPES = 3
 _NOMINAL_DEVICE_BYTES = 16 << 30  # where the backend reports no limit (CPU)
+# FULL coefficient variances factor one (width, width) Hessian a lane: the
+# Gram, its Cholesky factor and the factor's inverse, width² f32 each, and
+# what the compiler's blocked factorization keeps beside them — 2.2 to 5.6
+# such matrices a lane in all, by the v5e compiler's own count at GLMix's
+# widths 128 to 1,280 (PERF.md section 4). The lanes of one variance chunk
+# are a power of two whose workspace holds at most 1/_VARIANCE_SHARE of
+# the device, beside the resident fit.
+_VARIANCE_SHARE = 16
+_VARIANCE_MATRICES = 6
 
 
 def _device_memory_bytes() -> Optional[int]:
@@ -326,23 +338,35 @@ def _width_class(width: np.ndarray) -> np.ndarray:
                     -(-width // 128) * 128)
 
 
+def variance_lanes(width: int, device_bytes: Optional[int] = None) -> int:
+    """Lanes of one chunk of a bucket's FULL variances: the largest power of
+    two whose `_VARIANCE_MATRICES` (width, width) f32 matrices a lane fit in
+    1/`_VARIANCE_SHARE` of the device (at least one lane)."""
+    budget = (device_bytes or _NOMINAL_DEVICE_BYTES) // _VARIANCE_SHARE
+    lanes = budget // (_VARIANCE_MATRICES * int(width) ** 2 * 4)
+    return 1 << max(int(lanes).bit_length() - 1, 0)
+
+
 @dataclasses.dataclass(frozen=True)
 class BucketPlan:
     """Block shapes for one random-effect coordinate: ``buckets`` is a list
     of (m rows, w solve width, entity ids) in (m, w) order; the two byte
     counts are of the blocks' feature values (rows × width × 4, or a sparse
-    block's k slots × 8)."""
+    block's k slots × 8); ``variance_lanes`` the lanes of one chunk of each
+    bucket's FULL variances (`variance_lanes`)."""
 
     buckets: list
     bytes_real: int     # Σ entities: active rows × own width
     bytes_padded: int   # Σ buckets: entities × m × w
+    variance_lanes: tuple = ()
 
 
 def plan_buckets(rows: np.ndarray, widths: np.ndarray, slot_bytes: int,
                  width_classes: bool = False, min_block_rows: int = 4,
                  max_blocks: Optional[int] = None,
                  device_bytes: Optional[int] = None,
-                 name: str = "") -> BucketPlan:
+                 name: str = "",
+                 solve_width: Optional[int] = None) -> BucketPlan:
     """Plan block shapes by bytes. ``rows`` / ``widths``: each entity's
     active rows and solve width; ``width_classes``: widths differ by entity
     (an INDEX_MAP projection) and a bucket's is a `_width_class`;
@@ -351,7 +375,10 @@ def plan_buckets(rows: np.ndarray, widths: np.ndarray, slot_bytes: int,
     when the cheapest merge costs more than a shape is worth on this device.
     ``max_blocks``, where a caller passes it, is an upper limit that is met
     whatever it costs. A plan over the device's share raises here, before
-    anything is allocated, naming its largest bucket."""
+    anything is allocated, naming its largest bucket. The plan also sizes
+    the chunks a bucket's FULL variances are computed in, from the same
+    device bytes (`variance_lanes`), at the bucket's width or, where a
+    planned width is a count of sparse slots, at ``solve_width``."""
     rows = np.asarray(rows, np.int64)
     widths = np.asarray(widths, np.int64)
     m_of = _pow2_ceil(rows, min_block_rows)
@@ -391,7 +418,9 @@ def plan_buckets(rows: np.ndarray, widths: np.ndarray, slot_bytes: int,
             f"{m} rows x {w} columns ({max(sizes):,} bytes). Lower "
             "active_cap, or shard the coordinate over a mesh")
     real = int((rows * widths).sum()) * slot_bytes
-    return BucketPlan(buckets, real, padded)
+    lanes = tuple(min(variance_lanes(solve_width or w, device_bytes), len(g))
+                  for _, w, g in buckets)
+    return BucketPlan(buckets, real, padded, lanes)
 
 
 class ScoringPlan(NamedTuple):
@@ -600,12 +629,15 @@ class RandomEffectDataset:
                             min_block_rows=min_block_rows,
                             max_blocks=max_blocks,
                             device_bytes=_device_memory_bytes(),
-                            name=entity_name)
+                            name=entity_name,
+                            solve_width=(_shard_dim(X) if slot_bytes == 8
+                                         else None))
         telemetry.count("game_re.block_bytes_real", plan.bytes_real)
         telemetry.count("game_re.block_bytes_padded", plan.bytes_padded)
 
         blocks = []
-        for m, width, ents in plan.buckets:
+        for (m, width, ents), var_lanes in zip(plan.buckets,
+                                               plan.variance_lanes):
             # Difficulty-sorted chunk packing: lanes that share a vmapped
             # lax.while_loop chunk all run until the SLOWEST lane converges
             # (random_effect dispatches buckets in fixed-size lane chunks),
@@ -653,6 +685,7 @@ class RandomEffectDataset:
                     X=Xb,
                     dim=block_dim,
                     proj=block_proj,
+                    variance_lanes=var_lanes,
                 )
             )
 

@@ -52,14 +52,6 @@ class FixedEffectCoordinate:
         if (warm_start is not None
                 and warm_start.model.weights.shape[0] == self.dataset.dim):
             w0 = warm_start.model.weights
-        prior_dist = None
-        if (prior is not None
-                and prior.model.weights.shape[0] == self.dataset.dim):
-            from photon_tpu.optim.prior import PriorDistribution
-
-            coeffs = prior.model.coefficients
-            prior_dist = PriorDistribution.from_coefficients(
-                coeffs.means, coeffs.variances)
         model, res = train_glm(
             self.dataset.batch(offsets_full),
             self.task,
@@ -68,9 +60,21 @@ class FixedEffectCoordinate:
             w0=w0,
             variance=self.variance,
             normalization=self.normalization,
-            prior=prior_dist,
+            prior=self.prior_distribution(prior),
         )
         return FixedEffectModel(model, self.dataset.shard_name), res
+
+    def prior_distribution(self, prior: Optional[FixedEffectModel]):
+        """A previous run's model as this coordinate's Gaussian prior
+        (`PriorDistribution.from_coefficients` of its means and variances);
+        None where there is none, or it is over another feature space."""
+        if prior is None or prior.model.weights.shape[0] != self.dataset.dim:
+            return None
+        from photon_tpu.optim.prior import PriorDistribution
+
+        coeffs = prior.model.coefficients
+        return PriorDistribution.from_coefficients(coeffs.means,
+                                                   coeffs.variances)
 
     def score(self, model: FixedEffectModel):
         """Margin contribution of this coordinate alone (no offsets) —

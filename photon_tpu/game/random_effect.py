@@ -122,6 +122,32 @@ def _re_solver(with_prior: bool, cfg, variance):
     return fns
 
 
+# vmapped per-lane variances, keyed on (with_prior, variance type): the
+# one-dispatch update computes a bucket's variances apart from its solves,
+# in chunks of the bucket plan's `variance_lanes` (width² a lane), where a
+# solve's chunk holds up to _MAX_SOLVE_LANES lanes.
+_RE_VARIANCES: dict = {}
+
+
+def _re_variances(with_prior: bool, variance):
+    import dataclasses as _dc
+
+    key = (with_prior, variance)
+    raw = _RE_VARIANCES.get(key)
+    if raw is not None:
+        return raw
+
+    def one(obj, batch, w, pm=None, pp=None):
+        if with_prior:
+            obj = _dc.replace(obj, prior_mean=pm, prior_precision=pp)
+        return compute_variances(obj, w, batch, variance)
+
+    raw = jax.vmap(one, in_axes=(None, 0, 0) + ((0, 0) if with_prior
+                                                 else ()))
+    _RE_VARIANCES[key] = raw
+    return raw
+
+
 # jitted scan-over-chunks wrappers, keyed on the raw vmapped solver: a block
 # bigger than one lane chunk runs as lax.scan over its equal-shape chunks —
 # ONE device dispatch per block (launch latency paid once, not once per
@@ -631,13 +657,16 @@ class RandomEffectCoordinate:
         return model.score(self.dataset.X, self.dataset.entity_dense)
 
     def fused_update_program(self):
-        """ONE-dispatch whole-coordinate update for the no-prior /
-        no-normalization / single-device case, unprojected or INDEX_MAP:
-        offsets sum, every bucket's (chunk-scanned) solves from the warm
-        starts it is HANDED in the bucket's own space, the
-        coefficient/variance write into the (E, d) table (through the
-        bucket's index map where it has one), the full-row margins, and
-        the objective — one jitted program, where the unfused
+        """ONE-dispatch whole-coordinate update for the no-normalization /
+        single-device case, unprojected or INDEX_MAP, with or without a
+        prior: offsets sum, every bucket's (chunk-scanned) solves from the
+        warm starts it is HANDED in the bucket's own space — each lane
+        regularized toward the prior it is handed, where one is
+        (`bucket_priors`) —, the variances of the solution, a chunk of the
+        bucket plan's `variance_lanes` at a time, the coefficient/variance
+        write into the (E, d) table (through the bucket's index map where
+        it has one), the full-row margins, and the objective — one jitted
+        program, where the unfused
         train()+score()+objective route pays ~4+ device dispatches and,
         projected, carries the (E, d) table through the host. The path is
         chosen by what the dataset carries: a RANDOM projection (its dense
@@ -662,10 +691,12 @@ class RandomEffectCoordinate:
 
         Returns (fn, blocks_args, plan, objs, lam) — call
         ``fn(coeffs, warm, base, scores_tuple, objs, lam, blocks_args,
-        plan, y, weights)``, which DONATES ``coeffs`` and ``warm`` (the
-        table is updated in place: pass buffers nothing else reads);
-        ``warm`` is one (E_b, width_b) f32 array a bucket, 0 on a bucket's
-        padding columns →
+        plan, y, weights[, priors])``, which DONATES ``coeffs`` and
+        ``warm`` (the table is updated in place: pass buffers nothing else
+        reads); ``warm`` is one (E_b, width_b) f32 array a bucket, 0 on a
+        bucket's padding columns; ``priors``, where given, one (mean,
+        precision) pair of such arrays a bucket (`bucket_priors`, fixed
+        across a descent, not donated) →
         (coeffs', variances', margins, objective, (n_conv, n_fail,
         n_iters, row_iters, block_steps, moved_row_iters, ls_trials),
         values, warm') — `row_iters` / `block_steps` the update's work: Σ
@@ -703,15 +734,15 @@ class RandomEffectCoordinate:
                 or (self.normalization is not None
                     and not self.normalization.is_identity)):
             return None
-        fns = self._solver_for(False)
-        meta = []       # (lane chunk, entities) per block — static
+        meta = []  # (lane chunk, entities, variance chunk) per block — static
         blocks_args = []  # (row_index, ents, cols, batch_base) — arrays
         objs = []
         d = ds.dim
         for block in ds.blocks:
             e_real = block.n_entities
             base_batch = ds.block_batch(block)
-            meta.append((min(e_real, _MAX_SOLVE_LANES), e_real))
+            meta.append((min(e_real, _MAX_SOLVE_LANES), e_real,
+                         min(block.variance_lanes, e_real)))
             # the bucket's index map as table columns; a padding column
             # points past the table, where a read fills 0 and a write drops
             cols = (None if block.proj is None else jnp.asarray(
@@ -722,7 +753,8 @@ class RandomEffectCoordinate:
                                 base_batch))
             objs.append(self._block_objective(
                 block.dim if block.dim is not None else d))
-        out = (_fused_re_fn(fns, tuple(meta), self.task, self.variance),
+        out = (_fused_re_fn(_static_config(self.config), tuple(meta),
+                            self.task, self.variance),
                tuple(blocks_args), ds.scoring_plan, tuple(objs),
                _l1_lam(self.config))
         self._fused_cache = out
@@ -794,45 +826,127 @@ def adopt_table(coeffs, blocks_args: tuple):
     return coeffs, tuple(warm)
 
 
-def _fused_re_fn(solver_fns, meta: tuple, task, variance):
+@jax.jit
+def _rows_by_key(coeffs, pid):
+    with telemetry.device_scope("game_re.adopt"):
+        return coeffs.at[pid].get(mode="fill", fill_value=0)
+
+
+def initial_table(model: RandomEffectModel, entity_keys) -> jax.Array:
+    """A caller's model as a FRESH (E, d) f32 table over ``entity_keys``
+    (for `adopt_table`, which donates it): the model's own rows where it
+    was fit over these entities, else each entity's row found by key — an
+    entity the model never saw (new since it was fit) gets zeros."""
+    keys = np.asarray(entity_keys)
+    if np.array_equal(np.asarray(model.entity_keys), keys):
+        return jnp.array(model.coefficients, jnp.float32)
+    return _rows_by_key(jnp.asarray(model.coefficients, jnp.float32),
+                        jnp.asarray(model.dense_ids(keys), jnp.int32))
+
+
+# A non-positive variance is a dimension the prior model never estimated:
+# no prior there (optim.prior.PriorDistribution.from_variances, whose
+# floor this is).
+_PRIOR_MIN_VARIANCE = 1e-12
+
+
+@jax.jit
+def _bucket_priors(means, variances, pid, maps):
+    out = []
+    with telemetry.device_scope("game_re.prior"):
+        for ents, cols in maps:
+            rows = pid[ents]
+            at = _table_index(rows, cols)
+            mu = means.at[at].get(mode="fill", fill_value=0)
+            if variances is None:  # the flat-default incremental weight
+                known = (rows < means.shape[0])[:, None]
+                if cols is not None:
+                    known = known & (cols < means.shape[1])
+                tau = jnp.broadcast_to(known, mu.shape).astype(jnp.float32)
+            else:
+                var = variances.at[at].get(mode="fill", fill_value=0)
+                tau = jnp.where(
+                    var > 0.0,
+                    1.0 / jnp.maximum(var, _PRIOR_MIN_VARIANCE), 0.0)
+            out.append((mu, tau))
+    return tuple(out)
+
+
+def bucket_priors(prior: RandomEffectModel, entity_keys,
+                  blocks_args: tuple) -> tuple:
+    """A previous run's model as each bucket's Gaussian prior, in the
+    bucket's own projected space: ((mean (E_b, width_b), precision
+    (E_b, width_b)) a bucket), gathered ON THE DEVICE through the buckets'
+    index maps from the model's (E', d) coefficient and variance tables,
+    each entity's row found by key. The semantics of `align_entity_priors`
+    without its two host (E, d) arrays: an entity the prior never saw, a
+    column it never estimated (variance ≤ 0) and a bucket's padding column
+    get precision 0 — no prior there; without variances every column of a
+    seen entity gets unit precision."""
+    pid = prior.dense_ids(entity_keys)
+    seen = int(np.count_nonzero(pid < prior.n_entities))
+    telemetry.count("game_re.prior_seen", seen)
+    telemetry.count("game_re.prior_unseen", int(pid.shape[0]) - seen)
+    variances = (None if prior.variances is None
+                 else jnp.asarray(prior.variances, jnp.float32))
+    return _bucket_priors(jnp.asarray(prior.coefficients, jnp.float32),
+                          variances, jnp.asarray(pid, jnp.int32),
+                          tuple((ents, cols)
+                                for _, ents, cols, _ in blocks_args))
+
+
+def _fused_re_fn(cfg, meta: tuple, task, variance):
     """The jitted one-dispatch update of `fused_update_program` (which says
-    what it takes and returns), one compiled program a (solver, block
-    shapes, task, variance). It writes the (E, d) table and never reads a
-    warm start out of it, nor clears a row: the table it is given is 0
-    outside the buckets' index maps (`cold_warm_starts` / `adopt_table`)."""
-    key = (solver_fns[1], meta, task, variance)
+    what it takes and returns), one compiled program a (solver config,
+    block shapes, task, variance) and a prior or none. It writes the (E, d)
+    table and never reads a warm start out of it, nor clears a row: the
+    table it is given is 0 outside the buckets' index maps
+    (`cold_warm_starts` / `adopt_table`)."""
+    key = (cfg, meta, task, variance)
     fn = _FUSED_RE.get(key)
     if fn is not None:
         return fn
-    raw_fn = solver_fns[1]
 
     def run(coeffs, warm, base, scores, objs, lam, blocks_args, plan, y,
-            weights):
+            weights, priors=None):
         from photon_tpu.data.matrix import layout_matvec
         from photon_tpu.game.model import score_entities
         from photon_tpu.game.scoring import _sum_scores
         from photon_tpu.ops.losses import loss_fns
 
         loss, _, _ = loss_fns(task)
+        with_prior = priors is not None
+        raw_fn = _re_solver(with_prior, cfg, VarianceComputationType.NONE)[1]
+        var_fn = (None if variance is VarianceComputationType.NONE
+                  else _re_variances(with_prior, variance))
         with telemetry.device_scope("game.objective"):
             offs = _sum_scores(base, scores)
         # The table is written IN PLACE (its buffer is donated): the
         # buckets partition the entities, so each writes rows no other
         # touches, over the index map its last solution was written through.
-        variances = (jnp.zeros_like(coeffs)
-                     if variance is not VarianceComputationType.NONE
-                     else None)
+        variances = jnp.zeros_like(coeffs) if var_fn is not None else None
         conv = fail = iters = row_iters = steps = moved = trials = 0
         values = jnp.zeros((coeffs.shape[0],), jnp.float32)
         scored = []  # the buckets' (E_b · m,) block margins, then the table's
         carried = []  # the buckets' solutions: the next update's `warm`
-        for (row_index, ents, cols, batch_base), w0, (chunk, e_real), obj in \
-                zip(blocks_args, warm, meta, objs):
+        for (row_index, ents, cols, batch_base), w0, (chunk, e_real,
+                                                     var_chunk), obj, prior \
+                in zip(blocks_args, warm, meta, objs,
+                       priors or ((),) * len(meta)):
             with telemetry.device_scope("game_re.gather"):
                 batch = batch_base._replace(offsets=offs[row_index])
             with telemetry.device_scope("game_re.solve"):
-                res, var = _solve_lanes(raw_fn, (obj, lam), (batch, w0),
-                                        chunk, e_real)
+                res, _ = _solve_lanes(raw_fn, (obj, lam),
+                                      (batch, w0) + tuple(prior), chunk,
+                                      e_real)
+            var = None
+            if var_fn is not None:
+                # the variances of the solution this update returns, a
+                # chunk of width² workspaces at a time
+                with telemetry.device_scope("game_re.variance"):
+                    var = _solve_lanes(var_fn, (obj,),
+                                       (batch, res.w) + tuple(prior),
+                                       var_chunk, e_real)
             carried.append(res.w)
             with telemetry.device_scope("game_re.score"):
                 # the rows this bucket holds, by the forward pass its
@@ -842,7 +956,7 @@ def _fused_re_fn(solver_fns, meta: tuple, task, variance):
             with telemetry.device_scope("game_re.scatter"):
                 at = _table_index(ents, cols)
                 coeffs = coeffs.at[at].set(res.w, mode="drop")
-                if var is not None and variances is not None:
+                if var is not None:
                     variances = variances.at[at].set(var, mode="drop")
             conv += jnp.sum(res.converged)
             fail += jnp.sum(res.failed)

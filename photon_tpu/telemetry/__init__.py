@@ -78,8 +78,12 @@ rows whose margin came from a bucket's block pass, and from the table
 gather: together the rows × the updates) and warm_carried / warm_cold /
 warm_adopted (the one-dispatch updates by where their warm starts came
 from: the buckets' solutions of the descent's previous update, zeros, or
-a caller's table through the `game_re.adopt` program), beside the
-fixed effect's game_fixed.row_iterations (rows × iterations taken);
+a caller's table through the `game_re.adopt` program), and of an
+incremental fit's prior and variances: variance_lanes (entities whose
+variances an update computed), prior_seen / prior_unseen (entities with
+and without a row in the prior model, once a coordinate a descent) and
+fused_prior_updates (one-dispatch updates that carried a prior), beside
+the fixed effect's game_fixed.row_iterations (rows × iterations taken);
 the blocked-ELL builds' `layout.*` family — tail_nnz / ell_slots /
 occ_slots (`data.matrix.to_blocked_ell` and `shard_blocked_ell`: the
 tail's real nonzeros and the slots the ELL row buckets and the occurrence
@@ -181,7 +185,12 @@ the buckets hold, the table gather for the rest, one reassembly gather),
 game_re.adopt (a program of its own, once a coordinate a descent at most:
 a CALLER's table enters the descent — warm starts read through the index
 maps, the columns outside them cleared; never in a cold-start fit),
-game_fixed.solve (the fixed effect's solve, same nesting) and
+game_re.prior (a program of its own, once an incremental coordinate a
+descent: the prior model's means and variances gathered into the buckets'
+own spaces), game_re.variance (the buckets' per-entity variances: Gram,
+Cholesky factor, the diagonal of the inverse), game_fixed.solve (the
+fixed effect's solve, same nesting), game_fixed.variance (its variances)
+and
 game.objective (offsets sum and the tracking objective); and mesh.psum
 (the objective's all-reduces over the mesh axis — `Objective._psum` /
 `_psum_many`, so the scalar and the lane objective alike — entered only
@@ -479,6 +488,8 @@ TELEMETRY_REGISTRY = {
         "game_re.linesearch_trials",
         "game_re.block_scored_rows", "game_re.table_scored_rows",
         "game_re.warm_carried", "game_re.warm_cold", "game_re.warm_adopted",
+        "game_re.variance_lanes", "game_re.prior_seen",
+        "game_re.prior_unseen", "game_re.fused_prior_updates",
         "game_fixed.row_iterations",
         "layout.shard_bytes_real", "layout.shard_bytes_padded",
         "layout.tail_nnz", "layout.ell_slots", "layout.occ_slots",
@@ -514,7 +525,8 @@ TELEMETRY_REGISTRY = {
         "lbfgs.direction", "lbfgs.update",
         "solve.prologue", "solve.epilogue",
         "game_re.gather", "game_re.solve", "game_re.scatter",
-        "game_re.score", "game_re.adopt", "game_fixed.solve",
+        "game_re.score", "game_re.adopt", "game_re.prior",
+        "game_re.variance", "game_fixed.solve", "game_fixed.variance",
         "game.objective",
         "mesh.psum",
     ),

@@ -620,11 +620,10 @@ def test_benchmark_json_lists_the_cell():
         spec = json.load(f)
     entry = next(w for w in spec["workloads"] if w["name"] == CELL)
     assert entry["chips"] == 1 and entry["config"] == "glm-sparse10m-stream"
-    assert spec["workloads"][-1] is entry
-    assert spec["configs"][-1]["name"] == "glm-sparse10m-stream"
+    assert "glm-sparse10m-stream" in {c["name"] for c in spec["configs"]}
     rate = next(m for m in spec["end_to_end"]
                 if m["name"] == "rows_iters_per_s")
-    assert rate["workloads"][-1] == CELL
+    assert CELL in rate["workloads"]
     mine = {m["name"] for m in spec["per_layer"] if CELL in m["workloads"]}
     assert mine == {"layout_build_s", "solve_xpass_ms",
                     "solve_xpass_tail_ms", "linesearch_evals_per_iter",
