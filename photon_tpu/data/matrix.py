@@ -606,6 +606,40 @@ class ShardedBlockedEllRows:
         return jnp.asarray(w)[self.inv_perm]
 
 
+@partial(jax.tree_util.register_dataclass, data_fields=("blocks",),
+         meta_fields=())
+@dataclasses.dataclass(frozen=True)
+class EntityBlocks:
+    """E entities' (m, w) blocks, solved as the lanes of ONE lane-minor
+    solve (`game.random_effect`'s regularization-grid update): the
+    (w, G·E) coefficients hold entity e's lane g at column g·E + e, and the
+    (m, G·E) margins of its block's rows at the same column. Each block is
+    read once a pass for its G lanes, an (m, w)·(w, G) product. Only the
+    lane products take it (`layout_matvec_lanes`, `rmatvec_lanes`)."""
+    blocks: jax.Array | SparseRows  # (E, m, w) dense or (E, m, k) slots
+
+
+def _entity_lanes(X: EntityBlocks, W, rows: bool):
+    """X's per-entity products with lane-minor ``W``: the forward pass
+    (W (w, G·E) → (m, G·E)) or, with ``rows``, the transposed one (W the
+    rows' (m, G·E) cotangents → (w, G·E)). f32 dense blocks multiply at
+    HIGHEST precision, as `_dense_precision` says why; sparse ones as
+    `_sparse_matvec_lanes` does, an entity at a time."""
+    B = X.blocks
+    if isinstance(B, SparseRows):
+        E = B.indices.shape[0]
+        W_e = jnp.moveaxis(W.reshape(W.shape[0], -1, E), -1, 0)  # (E, ·, G)
+        out = jax.vmap(_sparse_rmatvec_lanes if rows
+                       else _sparse_matvec_lanes)(B, W_e)
+        return jnp.moveaxis(out, 0, -1).reshape(out.shape[1], -1)
+    E = B.shape[0]
+    spec = "emw,mge->wge" if rows else "emw,wge->mge"
+    out = jnp.einsum(spec, B, W.reshape(W.shape[0], -1, E).astype(B.dtype),
+                     precision=_dense_precision(B),
+                     preferred_element_type=jnp.float32)
+    return out.reshape(out.shape[0], -1)
+
+
 Matrix = (jax.Array | SparseRows | HybridRows | ShardedHybridRows
           | PermutedHybridRows | ShardedPermutedHybridRows
           | BlockedEllRows | ShardedBlockedEllRows)
@@ -1978,6 +2012,8 @@ def _matvec_lanes(X: Matrix, W: jax.Array) -> jax.Array:
     (lane-MAJOR (G, d)) pays both per lane: measured ~3.5× slower at G=4
     on the 10M-feature headline problem (docs/PERF.md).
     """
+    if isinstance(X, EntityBlocks):
+        return _entity_lanes(X, W, rows=False)
     if isinstance(X, BlockedEllRows):
         return _bell_matvec(X, W)
     if isinstance(X, ShardedBlockedEllRows):
@@ -2003,11 +2039,15 @@ def _matvec_lanes(X: Matrix, W: jax.Array) -> jax.Array:
             X.dense, W[X.dense_cols].astype(X.dense.dtype),
             preferred_element_type=jnp.float32)
     if isinstance(X, SparseRows):
-        # (n, k, G) gather then contraction over k on the VPU; storage bf16
-        # upcasts in registers as in matvec.
-        return jnp.einsum("nk,nkg->ng", X.values.astype(jnp.float32),
-                          W[X.indices])
+        return _sparse_matvec_lanes(X, W)
     return jnp.matmul(X, W.astype(X.dtype), preferred_element_type=jnp.float32)
+
+
+def _sparse_matvec_lanes(X: SparseRows, W):
+    # (n, k, G) gather then contraction over k on the VPU; storage bf16
+    # upcasts in registers as in matvec.
+    return jnp.einsum("nk,nkg->ng", X.values.astype(jnp.float32),
+                      W[X.indices])
 
 
 @device_scope("xpass.t")
@@ -2018,6 +2058,8 @@ def rmatvec_lanes(X: Matrix, R: jax.Array) -> jax.Array:
     contiguous floats per segment id (one scatter row of width G instead of
     G scalar scatters), the hot block is one (d_sel, n) × (n, G) matmul.
     """
+    if isinstance(X, EntityBlocks):
+        return _entity_lanes(X, R, rows=True)
     if isinstance(X, BlockedEllRows):
         return _bell_rmatvec(X, R)
     if isinstance(X, ShardedBlockedEllRows):
@@ -2042,13 +2084,17 @@ def rmatvec_lanes(X: Matrix, R: jax.Array) -> jax.Array:
                          preferred_element_type=jnp.float32)
         return out.at[X.dense_cols].add(hot)
     if isinstance(X, SparseRows):
-        contrib = (X.values.astype(jnp.float32)[:, :, None]
-                   * R[:, None, :])  # (n, k, G)
-        G = R.shape[1]
-        return jax.ops.segment_sum(
-            contrib.reshape(-1, G), X.indices.reshape(-1),
-            num_segments=X.n_features)
+        return _sparse_rmatvec_lanes(X, R)
     return jnp.matmul(X.T, R.astype(X.dtype), preferred_element_type=jnp.float32)
+
+
+def _sparse_rmatvec_lanes(X: SparseRows, R):
+    contrib = (X.values.astype(jnp.float32)[:, :, None]
+               * R[:, None, :])  # (n, k, G)
+    G = R.shape[1]
+    return jax.ops.segment_sum(
+        contrib.reshape(-1, G), X.indices.reshape(-1),
+        num_segments=X.n_features)
 
 
 @device_scope("xpass.t")

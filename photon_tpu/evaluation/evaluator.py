@@ -82,6 +82,12 @@ class Evaluator:
             return True
         return a > b if self.higher_is_better else a < b
 
+    def lane_metric(self):
+        """The metric as a traceable ``fn(scores, labels, weights)`` of one
+        set of scores — what a regularization grid's lanes are ranked by
+        on the device — or None for one that needs groups or a k."""
+        return None if self.needs_groups else _METRIC_FNS.get(self.kind)
+
     def evaluate(self, scores, labels, weights=None, groups=None) -> float:
         if self.needs_groups:
             if groups is None or self.num_groups is None:

@@ -35,9 +35,16 @@ Coordinate = FixedEffectCoordinate | RandomEffectCoordinate
 
 @dataclasses.dataclass
 class CoordinateDescentResult:
-    model: GameModel
+    """A descent's model, objective history and per-update stats. A descent
+    of ``lanes`` (a regularization grid's) gives them lane by lane — a list
+    of histories and a list of stats dicts, one a lane — and no model:
+    ``lane_state`` holds a fixed effect's (G, d) coefficients and a random
+    effect's buckets' (E_b, width_b, G) solutions (`game.grid` makes the
+    lanes' models from them)."""
+    model: Optional[GameModel]
     objective_history: list  # total weighted loss after each coordinate update
     coordinate_stats: dict  # name -> list of per-update OptResult / RETrainStats
+    lane_state: Optional[dict] = None
 
 
 # The descent loop's glue is jitted so each coordinate update costs a fixed
@@ -248,6 +255,26 @@ def _fused_fixed_update(batch, base, scores, w0, obj, l1, y, weights,
     return res, var, margin, objective
 
 
+@partial(jax.jit, static_argnames=("config", "task"))
+def _fixed_lane_update(batch, base, scores, w0s, obj, l2s, l1s, config,
+                       task):
+    """`_fused_fixed_update` for a regularization grid's G lanes: the
+    vmapped solve of `game.grid._fixed_grid_update` on lane-minor offsets
+    and margins, (n, G), as a random effect's lane update takes and gives
+    them."""
+    from photon_tpu.game.grid import _fixed_grid_update
+    from photon_tpu.models.variance import VarianceComputationType
+
+    with telemetry.device_scope("game.objective"):
+        offs = _sum_scores(base[:, None], scores)
+        offs = jnp.broadcast_to(offs, base.shape + (w0s.shape[0],))
+    with telemetry.device_scope("game_fixed.solve"):
+        res, _, margin, objective = _fixed_grid_update(
+            batch, offs.T, w0s, obj, l2s, l1s, config,
+            VarianceComputationType.NONE, task)
+    return res, margin.T, objective
+
+
 def _fixed_prior_objective(obj, coord: FixedEffectCoordinate, prior):
     """``obj`` regularized toward the coordinate's prior, as
     `FixedEffectCoordinate.train` regularizes it; ``obj`` where there is
@@ -297,6 +324,7 @@ def coordinate_descent(
     initial_models: Optional[dict] = None,
     incremental: frozenset = frozenset(),
     priors: Optional[dict] = None,
+    lanes: Optional[dict] = None,
 ) -> CoordinateDescentResult:
     """Run `n_sweeps` passes of the update sequence and return the GameModel.
 
@@ -308,8 +336,18 @@ def coordinate_descent(
     informative Gaussian prior for every retrain (reference: incremental
     training via PriorDistribution) — the prior stays the ORIGINAL initial
     model across sweeps, not the previous sweep's update.
+
+    ``lanes``: a regularization grid run as G lanes of this one descent
+    (`game.grid.fit_game_grid`), name -> the coordinate's lanes' (l2s, l1s,
+    weight-normalized configuration) (`models.training.
+    lane_weight_arrays`). Every update is then the one-dispatch update
+    with a lane axis — scores (n, G), objectives (G,) — every descent
+    counter is summed over the lanes, and the result comes lane by lane
+    (`CoordinateDescentResult`). One device, from zeros: no locked,
+    initial or prior model, no host stream, no checkpoint.
     """
     update_sequence = update_sequence or list(coordinates)
+    G = 1 if lanes is None else len(next(iter(lanes.values()))[0])
     models = dict(initial_models or {})
     if priors is None:
         priors = {name: models[name] for name in incremental if name in models}
@@ -335,6 +373,10 @@ def coordinate_descent(
         if isinstance(getattr(c.dataset, "X", None), ChunkedMatrix)
     }
     streamed = bool(chunked_coords)
+    if lanes is not None and (streamed or locked or models or priors):
+        raise ValueError("a descent of lanes starts every lane from zeros "
+                         "on one device: no locked, initial or prior "
+                         "model, no host stream")
     if streamed:
         y = np.asarray(y, np.float32)
         weights = np.asarray(weights, np.float32)
@@ -345,7 +387,8 @@ def coordinate_descent(
         y = jnp.asarray(y, jnp.float32)
         weights = jnp.asarray(weights, jnp.float32)
         base = jnp.asarray(base_offsets, jnp.float32)
-        no_score = jnp.zeros_like(base)
+        no_score = jnp.zeros(base.shape + ((G,) if lanes else ()),
+                             jnp.float32)
 
     # Scores of any pre-existing models participate as offsets from the start
     # (reference: CoordinateDescent seeds offsets from the initial GameModel).
@@ -384,7 +427,7 @@ def coordinate_descent(
         initial_table,
     )
 
-    ck = _ckpt.current()
+    ck = _ckpt.current() if lanes is None else None
     cd_scope = contextlib.nullcontext()
     if ck is not None:
         fp = _descent_fingerprint(coordinates, update_sequence, n_sweeps,
@@ -394,7 +437,8 @@ def coordinate_descent(
     deferred_re: list = []  # (stats-list index slot fillers for fused REs)
     # coordinate name -> the buckets' solutions its last fused update left,
     # each in its bucket's own space: the next update's warm starts. A name
-    # in here also says that this call made the coordinate's (E, d) table.
+    # in here also says that this call made the coordinate's (E, d) table
+    # (lanes make none). A lane fixed effect's (G, d) coefficients beside.
     carried: dict = {}
     # coordinate name -> its prior in the form its fused update takes (the
     # fixed effect's Objective, the buckets' (mean, precision) pairs),
@@ -424,7 +468,7 @@ def coordinate_descent(
 
         upd = -1
         for sweep in range(n_sweeps):
-            telemetry.count("game.sweeps")
+            telemetry.count("game.sweeps", G)
             for name in update_sequence:
                 if name in locked:
                     continue
@@ -432,8 +476,9 @@ def coordinate_descent(
                 update_log.append((sweep, name))
                 if upd < done_updates:
                     continue  # restored from the checkpoint image above
-                telemetry.count("game.coordinate_updates")
+                telemetry.count("game.coordinate_updates", G)
                 coord = coordinates[name]
+                lane = None if lanes is None else lanes[name]
                 warm = models.get(name)
                 prior = priors.get(name)
                 others = tuple(s for o, s in scores.items() if o != name)
@@ -456,7 +501,7 @@ def coordinate_descent(
                     # is still one train dispatch + one scoring stream.
                     if (isinstance(coord, FixedEffectCoordinate)
                             and not streamed
-                            and _fixed_fusable(coord)):
+                            and (lane or _fixed_fusable(coord))):
                         ds = coord.dataset
                         w0 = jnp.zeros((ds.dim,), jnp.float32)
                         if warm is not None and \
@@ -468,15 +513,25 @@ def coordinate_descent(
                             obj = fused_priors[name] = _fixed_prior_objective(
                                 make_objective(task, coord.config, ds.dim),
                                 coord, prior)
-                        res, var, margin, objective = _fused_fixed_update(
-                            batch, base, others, w0, obj,
-                            _l1_lam(coord.config), y, weights,
-                            _static_config(coord.config), task,
-                            coord.variance, l2_positive(coord.config))
-                        models[name] = FixedEffectModel(
-                            GeneralizedLinearModel(
-                                Coefficients(res.w, var), task),
-                            ds.shard_name)
+                        if lane is not None:
+                            res, margin, objective = _fixed_lane_update(
+                                batch, base, others,
+                                carried.get(name, jnp.zeros(
+                                    (G, ds.dim), jnp.float32)),
+                                obj, *lane, task)
+                            carried[name] = res.w
+                        else:
+                            res, var, margin, objective = \
+                                _fused_fixed_update(
+                                    batch, base, others, w0, obj,
+                                    _l1_lam(coord.config), y, weights,
+                                    _static_config(coord.config), task,
+                                    coord.variance,
+                                    l2_positive(coord.config))
+                            models[name] = FixedEffectModel(
+                                GeneralizedLinearModel(
+                                    Coefficients(res.w, var), task),
+                                ds.shard_name)
                         scores[name] = margin
                         coordinate_stats[name].append(res)
                         objective_history.append(objective)
@@ -499,13 +554,22 @@ def coordinate_descent(
                         # (the budget gate logs once at INFO and counts on
                         # game_re.fused_gate_offs), which then train on
                         # the pipelined block loop below.
-                        fused = (coord.fused_update_program()
+                        fused = (coord.fused_update_program(
+                            *(() if lane is None else (G, lane[2])))
                                  if isinstance(coord, RandomEffectCoordinate)
                                  and not streamed else None)
+                        if fused is None and lane is not None:
+                            raise ValueError(
+                                f"coordinate {name!r} has no one-dispatch "
+                                "update (variances, a RANDOM projection, "
+                                "normalization or a straggler budget): its "
+                                "grid is fitted a point at a time")
                         if fused is not None:
                             fn, blocks_args, plan, objs, lam = fused
                             ds = coord.dataset
                             E, d = ds.n_entities, ds.dim
+                            if lane is not None:  # the lanes' (l2s, l1s)
+                                lam = lane[:2]
                             # the update writes the table in place and
                             # takes the buckets' warm starts beside it
                             # (the program donates both): a table this
@@ -514,18 +578,20 @@ def coordinate_descent(
                             # caller's model is copied (each entity's row
                             # by key) and adopted first
                             if name in carried:
-                                coeffs0 = warm.coefficients
+                                coeffs0 = (None if lane is not None
+                                           else warm.coefficients)
                                 warm_b = carried.pop(name)
-                                telemetry.count("game_re.warm_carried")
+                                telemetry.count("game_re.warm_carried", G)
                             elif warm is not None and warm.dim == d:
                                 coeffs0, warm_b = adopt_table(
                                     initial_table(warm, ds.entity_keys),
                                     blocks_args)
                                 telemetry.count("game_re.warm_adopted")
                             else:
-                                coeffs0 = jnp.zeros((E, d), jnp.float32)
-                                warm_b = cold_warm_starts(blocks_args, d)
-                                telemetry.count("game_re.warm_cold")
+                                coeffs0 = (None if lane is not None else
+                                           jnp.zeros((E, d), jnp.float32))
+                                warm_b = cold_warm_starts(blocks_args, d, G)
+                                telemetry.count("game_re.warm_cold", G)
                             bucket_prior = None
                             if prior is not None and prior.dim == d:
                                 if name not in fused_priors:
@@ -549,33 +615,36 @@ def coordinate_descent(
                                 blocks_args, plan, y, weights, bucket_prior)
                             # the ONE dispatch solved every block of the
                             # coordinate (the pipelined loop in
-                            # RandomEffectCoordinate.train counts its own)
+                            # RandomEffectCoordinate.train counts its own);
+                            # a block's G lanes step together, so each
+                            # counts the block's lock-step iterations
                             telemetry.count("game_re.blocks",
-                                            len(blocks_args))
+                                            G * len(blocks_args))
                             # where the update read its margins from
                             telemetry.count("game_re.block_scored_rows",
-                                            plan.n_block_rows)
+                                            G * plan.n_block_rows)
                             telemetry.count("game_re.table_scored_rows",
-                                            plan.n_table_rows)
+                                            G * plan.n_table_rows)
                             telemetry.count_device(
                                 "game_re.row_iterations", st[3])
                             telemetry.count_device(
-                                "game_re.block_steps", st[4])
+                                "game_re.block_steps", st[4], scale=G)
                             telemetry.count_device(
                                 "game_re.moved_row_iterations", st[5])
                             telemetry.count_device(
                                 "game_re.iterations", st[2])
                             telemetry.count_device(
                                 "game_re.linesearch_trials", st[6])
-                            models[name] = RandomEffectModel(
-                                entity_name=ds.entity_name,
-                                feature_shard=ds.shard_name,
-                                task=task,
-                                coefficients=coeffs,
-                                entity_keys=ds.entity_keys,
-                                key_to_index=ds.key_to_index,
-                                variances=variances,
-                            )
+                            if lane is None:
+                                models[name] = RandomEffectModel(
+                                    entity_name=ds.entity_name,
+                                    feature_shard=ds.shard_name,
+                                    task=task,
+                                    coefficients=coeffs,
+                                    entity_keys=ds.entity_keys,
+                                    key_to_index=ds.key_to_index,
+                                    variances=variances,
+                                )
                             scores[name] = margin
                             if ck is None:
                                 # device scalars; finalized into
@@ -670,6 +739,9 @@ def coordinate_descent(
     # entry would block on one device→host readback each)
     objective_history, re_stats = jax.device_get(
         (objective_history, [st for *_, st in deferred_re]))
+    if lanes is not None:
+        return _by_lane(G, objective_history, coordinate_stats, deferred_re,
+                        re_stats, carried)
     objective_history = [float(v) for v in objective_history]
     if telemetry.enabled():
         # the GAME iteration stream: one event per coordinate update, in
@@ -693,6 +765,31 @@ def coordinate_descent(
     return CoordinateDescentResult(
         GameModel(ordered, task), objective_history, coordinate_stats
     )
+
+
+def _by_lane(G: int, history, stats: dict, deferred_re: list, re_stats,
+             state: dict) -> CoordinateDescentResult:
+    """A descent of G lanes' result, lane by lane: each lane's objective
+    history and per-update stats as a descent of its own gives them (one
+    readback for the fixed effects' stats and the entities' values), and
+    the lanes' last state."""
+    from photon_tpu.game.random_effect import RETrainStats
+
+    stats, values = jax.device_get(
+        (stats, [v for _, _, _, v, _ in deferred_re]))
+    for (name, slot, E, _, _), (c, f, it, ri, *_), v in zip(
+            deferred_re, re_stats, values):
+        stats[name][slot] = [
+            RETrainStats(E, int(c[g]), int(f[g]), int(it[g]),
+                         row_iterations=float(ri[g]),
+                         entity_values=v[:, g]) for g in range(G)]
+    return CoordinateDescentResult(
+        None, [[float(h[g]) for h in history] for g in range(G)],
+        [{name: [s[g] if isinstance(s, list) else
+                 jax.tree_util.tree_map(lambda x, g=g: x[g], s)
+                 for s in per] for name, per in stats.items()}
+         for g in range(G)],
+        lane_state=state)
 
 
 # ----------------------------------------------------------------- contracts

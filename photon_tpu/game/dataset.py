@@ -462,6 +462,48 @@ class ScoringPlan(NamedTuple):
         return int(self.slot_of_row.shape[0]) - self.n_table_rows
 
 
+class LaneScoringPlan(NamedTuple):
+    """`ScoringPlan` for a regularization grid's lanes, whose update keeps
+    no (E, d) table: the passive rows read their k coefficients out of the
+    buckets' (E_b, width_b, G) solutions laid end to end, ONE index a slot
+    for all G lanes (`RandomEffectDataset.lane_positions`)."""
+
+    slot_of_row: jax.Array   # (n,) int32, as `ScoringPlan`'s
+    values: jax.Array        # (n_passive, k) the passive rows' values
+    positions: jax.Array     # (n_passive, k) int32 where their slots lie
+
+    @property
+    def n_table_rows(self) -> int:
+        return int(self.positions.shape[0])
+
+    @property
+    def n_block_rows(self) -> int:
+        return int(self.slot_of_row.shape[0]) - self.n_table_rows
+
+
+@partial(jax.jit, static_argnames=("shape",))
+def _lane_positions(maps: tuple, dense_ids, cols, shape: tuple):
+    """`RandomEffectDataset.lane_positions`: every bucket's flat positions,
+    numbered end to end in block order, written through its index map
+    (``maps``: `RandomEffectDataset.table_maps`) into an (E, d) int32
+    table of one past the end, and read back at (entity, feature) — a
+    dense id E, no entity, reads one past the end too."""
+    d = shape[1]
+    widths = [d if cols_b is None else cols_b.shape[1] for _, cols_b in maps]
+    total = sum(ents.shape[0] * w for (ents, _), w in zip(maps, widths))
+    table = jnp.full(shape, total, jnp.int32)
+    base = 0
+    for (ents, cols_b), w in zip(maps, widths):
+        n = ents.shape[0]
+        at = (ents,) if cols_b is None else (ents[:, None], cols_b)
+        table = table.at[at].set(
+            base + jnp.arange(n * w, dtype=jnp.int32).reshape(n, w),
+            mode="drop")
+        base += n * w
+    return table.at[dense_ids[:, None], cols].get(mode="fill",
+                                                   fill_value=total)
+
+
 @dataclasses.dataclass(frozen=True)
 class RandomEffectDataset:
     """Entity-bucketed random-effect data (reference: RandomEffectDataset).
@@ -520,6 +562,51 @@ class RandomEffectDataset:
                      if isinstance(X, SparseRows) else X[rows])
         return ScoringPlan(jnp.asarray(slot.astype(np.int32)), X_passive,
                            jnp.asarray(self.entity_dense[passive]))
+
+    @functools.cached_property
+    def table_maps(self) -> tuple:
+        """Each bucket's (entity ids, index map as table columns or None),
+        on the device: where its (E_b, width_b) coefficients lie in the
+        (E, d) table. A padding column points past the table, where a read
+        fills 0 and a write drops."""
+        d = self.dim
+        return tuple(
+            (jnp.asarray(block.entity_index),
+             None if block.proj is None else jnp.asarray(
+                 np.where(block.proj.proj_mask > 0, block.proj.proj_idx, d)
+                 .astype(np.int32)))
+            for block in self.blocks)
+
+    def lane_positions(self, dense_ids, cols) -> jax.Array:
+        """Where the coefficient of each (row, slot) — the entity of dense
+        id ``dense_ids[i]`` at feature ``cols[i, j]`` — lies in the
+        buckets' solutions laid end to end in block order: its flat
+        position, or one past the end (a read there fills 0) where the
+        entity's bucket has no such column, or there is no such entity.
+        Found on the device (`_lane_positions`)."""
+        return _lane_positions(self.table_maps,
+                               jnp.asarray(dense_ids, jnp.int32),
+                               jnp.asarray(cols, jnp.int32),
+                               (self.n_entities, self.dim))
+
+    @functools.cached_property
+    def lane_scoring_plan(self) -> LaneScoringPlan:
+        """The `LaneScoringPlan` of these blocks, built and placed on the
+        device once a dataset."""
+        plan = self.scoring_plan
+        X = self.X
+        # the passive rows, in rank order: the slots past the blocks'
+        held = sum(block.n_entities * block.m for block in self.blocks)
+        passive = np.nonzero(np.asarray(plan.slot_of_row) >= held)[0]
+        rows = jnp.asarray(passive)
+        if isinstance(X, SparseRows):
+            cols, values = X.indices[rows], X.values[rows]
+        else:
+            cols = jnp.broadcast_to(jnp.arange(X.shape[1]),
+                                    (passive.shape[0], X.shape[1]))
+            values = X[rows]
+        return LaneScoringPlan(plan.slot_of_row, values, self.lane_positions(
+            self.entity_dense[passive], cols))
 
     @staticmethod
     def build(

@@ -10,12 +10,16 @@ best by the task's primary evaluator.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
 from photon_tpu import telemetry
+from photon_tpu.data.matrix import SparseRows
 from photon_tpu.evaluation.evaluator import Evaluator, default_evaluator
 from photon_tpu.game.coordinate_descent import (
     CoordinateDescentResult,
@@ -68,8 +72,10 @@ from photon_tpu.data.matrix import last_column_is_intercept as _last_column_is_i
 
 # Auto-mode lane-axis gate: reg-weight spread (max/min across lanes) above
 # which lock-step lanes are assumed to lose to the per-lane-adaptive
-# sequential path (docs/PERF.md's masking A/B: spread 1e5 → lane-axis 3.7×
-# WORSE; spread ≤1e2 grids — every headline sweep — win on lanes).
+# sequential path. A chunk of lanes steps until its slowest lane stops
+# (`optim.config.stop_state`; masked lanes still execute), and the weakest
+# weight's lane is the slowest: the further the weights spread, the more of
+# every chunk's steps the strong lanes only repeat (see `_grid_reg_skew`).
 _GRID_SKEW_MAX = 1e4
 
 
@@ -81,6 +87,35 @@ class GameFitResult:
     descent: CoordinateDescentResult
     configs: dict  # name -> CoordinateConfig actually used
     validation_score: Optional[float] = None
+
+
+@partial(jax.jit, static_argnames=("metric", "higher"))
+def _grid_select(offsets, fixed: tuple, random: tuple, labels, weights,
+                 metric, higher: bool):
+    """A grid's lanes on the validation rows: their (n, G) margins — a
+    fixed effect's (G, d) coefficients through the shard, a random
+    effect's buckets' lane solutions by k coefficients a row
+    (`game.model.score_lanes`: ``random`` holds (solutions, the rows'
+    values, where their slots lie) a coordinate) — and, where ``metric``
+    (`Evaluator.lane_metric`) is given, each lane's metric and the best
+    lane, NaN ranked last; else the margins."""
+    from photon_tpu.game.model import score_lanes
+    from photon_tpu.models.glm import _score_many
+
+    total = offsets[:, None]
+    for W, X in fixed:
+        total = total + _score_many(W, X, 0.0).T
+    for solutions, values, positions in random:
+        total = total + score_lanes(solutions, values, positions)
+    if metric is None:
+        return total
+    with telemetry.device_scope("game_grid.select"):
+        values = jax.vmap(lambda s: metric(s, labels, weights),
+                          in_axes=1)(total)
+        worst = -jnp.inf if higher else jnp.inf
+        ranked = jnp.where(jnp.isnan(values), worst, values)
+        best = jnp.argmax(ranked) if higher else jnp.argmin(ranked)
+    return values, best
 
 
 @dataclasses.dataclass
@@ -361,12 +396,11 @@ class GameEstimator:
     def _grid_reg_skew(self, grid) -> float:
         """Max over coordinates of the grid's reg-weight spread
         (max/min across lanes). The lane-axis grid runs every chunk to its
-        SLOWEST lane's convergence (masked lanes still execute —
-        docs/PERF.md's masking A/B), so a strongly skewed grid pays
-        ~G × the hardest lane where the sequential path pays the sum of
-        adaptive per-lane costs (measured 3.7× worse lane-axis at spread
-        1e5). Auto mode (`vectorized_grid=None`) falls back to sequential
-        above ``_GRID_SKEW_MAX``; the explicit tri-state always wins. A
+        SLOWEST lane's convergence (masked lanes still execute), so a
+        strongly skewed grid pays ~G × the hardest lane where the
+        sequential path pays the sum of adaptive per-lane costs. Auto mode
+        (`vectorized_grid=None`) falls back to sequential above
+        ``_GRID_SKEW_MAX``; the explicit tri-state always wins. A
         zero weight among positive ones counts as ≤1e-4 (zero-reg lanes
         are the least-conditioned, slowest converging — strictly slower
         than any positive-reg lane)."""
@@ -391,15 +425,32 @@ class GameEstimator:
         """{name: [reg_weight per grid point]} when the grid is expressible
         as lane weights over the base configs — every override varies ONLY
         its coordinate's reg weight — and nothing on the model needs the
-        sequential path (no projection, no normalization); None otherwise."""
+        sequential path; None otherwise. On one device a random effect's
+        lanes ride its one-dispatch update (`game.grid`), so what that
+        update cannot run is sequential: normalization (the per-entity
+        solves would need the shard's factors under every lane), a RANDOM
+        projection (its dense matrix lives on the host), variances and a
+        straggler budget (the budgeted re-solve repacks on the host). The
+        mesh form keeps its block loop, which has no projection at all."""
+        from photon_tpu.game.projector import ProjectorType
+
         if any(v is not None for v in self.normalization.values()):
             return None
         names = set(self.coordinate_configs)
         if self.update_sequence is not None and \
                 set(self.update_sequence) - names:
             return None
+        if self.mesh is None and \
+                self.variance is not VarianceComputationType.NONE:
+            return None
         for cfg in self.coordinate_configs.values():
-            if isinstance(cfg, RandomEffectConfig) and cfg.projection is not None:
+            if not isinstance(cfg, RandomEffectConfig):
+                continue
+            if cfg.straggler_budget is not None and self.mesh is None:
+                return None
+            if cfg.projection is not None and (
+                    self.mesh is not None
+                    or cfg.projection.projector is ProjectorType.RANDOM):
                 return None
         lanes: dict = {n: [] for n in names}
         for overrides in grid:
@@ -456,11 +507,10 @@ class GameEstimator:
                        evaluator: Evaluator, dataset_cache,
                        coord_cache) -> list:
         """The lane-axis GAME grid (game.grid.fit_game_grid): every grid
-        point is a lane of one vectorized coordinate descent."""
-        import jax.numpy as jnp
-
-        from photon_tpu.game.grid import fit_game_grid, lane_re_margins
-        from photon_tpu.models.glm import _score_many
+        point is a lane of one vectorized coordinate descent, and the lanes
+        are scored on the validation data and ranked there by one device
+        program (`_grid_select`)."""
+        from photon_tpu.game.grid import fit_game_grid
 
         configs = self.coordinate_configs
         datasets = {}
@@ -470,31 +520,16 @@ class GameEstimator:
                 dataset_cache[key] = self._build_dataset(data, cfg)
             datasets[name] = dataset_cache[key]
         coords = self._build_coordinates(datasets, configs, coord_cache)
-        with telemetry.span("game.grid_vectorized",
-                            lanes=len(next(iter(lanes.values())))):
+        G = len(next(iter(lanes.values())))
+        with telemetry.span("game.grid_vectorized", lanes=G):
             outcome = fit_game_grid(
                 coords, lanes, data.y, data.weights, data.offsets,
                 self.task, update_sequence=self.update_sequence,
                 n_sweeps=self.n_sweeps, mesh=self.mesh)
-
-        G = len(next(iter(lanes.values())))
-        val_scores = None
+        scores = None
         if validation is not None:
-            total = jnp.asarray(validation.offsets, jnp.float32)[None, :]
-            for name in outcome.lane_models[0].names():
-                cfg = configs[name]
-                Xv = validation.shards[cfg.feature_shard]
-                if isinstance(cfg, FixedEffectConfig):
-                    total = total + _score_many(
-                        jnp.asarray(outcome.stacked[name]), Xv, 0.0)
-                else:
-                    model0 = outcome.lane_models[0].coordinates[name]
-                    ids = model0.dense_ids(
-                        np.asarray(validation.entity_ids[cfg.entity_name]))
-                    total = total + lane_re_margins(
-                        jnp.asarray(outcome.stacked[name]), Xv,
-                        jnp.asarray(ids))
-            val_scores = np.asarray(total)
+            scores = self._validate_lanes(outcome, coords, validation,
+                                          evaluator, dataset_cache)
 
         results = []
         for g in range(G):
@@ -510,11 +545,54 @@ class GameEstimator:
                 coordinate_stats=outcome.coordinate_stats[g],
             )
             r = GameFitResult(outcome.lane_models[g], descent, configs_g)
-            if val_scores is not None:
-                r.validation_score = self._evaluate(
-                    evaluator, val_scores[g], validation)
+            if scores is not None:
+                r.validation_score = scores[g]
             results.append(r)
         return results
+
+    def _validate_lanes(self, outcome, coords: dict, validation: GameData,
+                        evaluator: Evaluator, cache: dict) -> list:
+        """Each lane's validation metric, from its lane state: one program
+        scores every lane (k coefficients a row for a random effect) and,
+        for a metric the device computes, ranks the lanes — the pick is
+        `best_model`'s. A metric that needs the entity groups or a k is
+        computed on the host from the program's (n, G) margins."""
+        from photon_tpu.game.model import entity_dense_ids
+
+        fixed, random = [], []
+        for name, coord in coords.items():
+            ds = coord.dataset
+            Xv = validation.shards[ds.shard_name]
+            if isinstance(coord, FixedEffectCoordinate):
+                fixed.append((outcome.stacked[name], Xv))
+                continue
+            # where the validation rows' slots lie in the lanes' solutions,
+            # found on the host once a (dataset, validation shard) pair:
+            # the key's objects are pinned by the entry, so no id is reused
+            raw_ids = validation.entity_ids[ds.entity_name]
+            sparse = isinstance(Xv, SparseRows)
+            values = Xv.values if sparse else Xv
+            key = ("lane_positions", id(ds), id(raw_ids), id(values))
+            if key not in cache:
+                ids = entity_dense_ids(ds.entity_keys, raw_ids)
+                cols = (Xv.indices if sparse else jnp.broadcast_to(
+                    jnp.arange(Xv.shape[1]), Xv.shape))
+                cache[key] = (ds, raw_ids, values,
+                              ds.lane_positions(ids, cols))
+            positions = cache[key][-1]
+            random.append((outcome.stacked[name], values, positions))
+        metric = evaluator.lane_metric()
+        out = _grid_select(jnp.asarray(validation.offsets, jnp.float32),
+                           tuple(fixed), tuple(random), validation.y,
+                           validation.weights, metric,
+                           evaluator.higher_is_better)
+        if metric is None:
+            total = np.asarray(out)
+            return [self._evaluate(evaluator, total[:, g], validation)
+                    for g in range(total.shape[1])]
+        values, best = jax.device_get(out)
+        telemetry.gauge("game_grid.best_lane", int(best))
+        return [float(v) for v in values]
 
     def _fixed_only_reg_grid(self, grid):
         """(name, base_config, [reg_weight per grid point]) when the model

@@ -4,18 +4,26 @@ Reference parity: com.linkedin.photon.ml.estimators.GameEstimator's grid
 mode trains one full Spark job per GameOptimizationConfiguration. Here every
 grid point becomes a LANE: the whole coordinate-descent state (fixed-effect
 coefficients, per-entity random-effect coefficients, per-coordinate scores)
-carries a leading lane axis, and each coordinate update solves ALL lanes in
-one vmapped device program sharing every pass over the lane-invariant design
-matrices — the fixed effect's per-lane matvec becomes one (n, d)×(d, G)
-matmul, and the per-entity random-effect solves vmap over (entity × lane)
-with each entity's (m, d) block shared by its G lanes.
+carries a lane axis, and each coordinate update solves ALL lanes in one
+device program sharing every pass over the lane-invariant design matrices —
+the fixed effect's per-lane matvec becomes one (n, d)×(d, G) matmul, and a
+random effect's update is its one-dispatch update with a lane axis
+(`random_effect._fused_re_fn`, driven by `coordinate_descent`'s own loop):
+a bucket chunk's (entity × lane) solves are ONE lane-minor solve on the
+entities' blocks, each block read once for its G lanes, and the descent
+keeps no (E, d) table — the buckets' (E_b, width_b, G) solutions are its
+state, and one gathered index reads all G lanes of a coefficient out of
+them.
 
 Semantics vs the sequential path: identical per grid point — each lane runs
 the same sweeps, warm-starting every coordinate update from that lane's own
 previous state — EXCEPT that warm starts cannot chain ACROSS grid points
 (lanes run concurrently; every lane starts from zeros), the same contract as
-models.training.train_glm_grid. Feature-space projection and non-identity
-normalization keep the sequential path (game.estimator gates them).
+models.training.train_glm_grid. INDEX_MAP projection runs on lanes; what
+has no one-dispatch update keeps the sequential path (game.estimator gates
+it): a RANDOM projection (its dense matrix lives on the host), non-identity
+normalization, variances, a straggler budget — and projection on a mesh,
+whose grid keeps its block loop.
 """
 from __future__ import annotations
 
@@ -28,21 +36,22 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from photon_tpu import telemetry
 from photon_tpu.data.dataset import GLMBatch, pad_batch
-from photon_tpu.data.matrix import matvec
+from photon_tpu.data.matrix import matvec, next_pow2
+from photon_tpu.game.coordinate_descent import coordinate_descent
 from photon_tpu.game.fixed_effect import FixedEffectCoordinate
 from photon_tpu.game.model import (
     FixedEffectModel,
     GameModel,
     RandomEffectModel,
-    _padded_coeffs,
-    score_rows,
+    score_entities,
 )
-from photon_tpu.data.matrix import next_pow2
 from photon_tpu.game.random_effect import (
     _MAX_SOLVE_LANES,
     RETrainStats,
     _pad_axis0,
+    _table_index,
     dispatch_chunked,
 )
 from photon_tpu.models.glm import Coefficients, GeneralizedLinearModel
@@ -175,79 +184,46 @@ def _grid_block_stats(acc, conv, fail, iters):
     return s if acc is None else acc + s
 
 
-# Fused single-dispatch block update (single-device path): per-lane offset
-# gather, warm-start gather, the chunk-scanned (entity × lane) solves, the
-# coefficient/variance scatter, and the stats reduction — ONE jitted program
-# per block per update instead of ~9 eager dispatches. Cached on (raw solver, chunk, e_pad): the jit inside
-# re-keys on shapes.
-_BLOCK_UPDATE: dict = {}
-
-
-def _block_update_fn(raw_fn, chunk: int, e_pad: int):
-    key = (raw_fn, chunk, e_pad)
-    fn = _BLOCK_UPDATE.get(key)
-    if fn is not None:
-        return fn
-
-    @jax.jit
-    def run(C, V, acc, offs, row_index, ents, batch_base, head):
-        off_b = jnp.transpose(offs[:, row_index], (1, 0, 2))
-        w0_b = jnp.transpose(C[:, ents, :], (1, 0, 2))
-        batch = batch_base._replace(offsets=off_b)
-        args = _pad_axis0((batch, w0_b), e_pad)
-        if e_pad == chunk:
-            res, var = raw_fn(*head, *args)
-        else:
-            k = e_pad // chunk
-            stacked = jax.tree_util.tree_map(
-                lambda x: x.reshape((k, chunk) + x.shape[1:]), args)
-
-            def body(_, part):
-                return None, raw_fn(*head, *part)
-
-            _, (res, var) = jax.lax.scan(body, None, stacked)
-            res, var = jax.tree_util.tree_map(
-                lambda x: x.reshape((e_pad,) + x.shape[2:]), (res, var))
-        e_real = ents.shape[0]
-        C = C.at[:, ents, :].set(
-            jnp.transpose(res.w[:e_real], (1, 0, 2)))
-        if var is not None and V is not None:
-            V = V.at[:, ents, :].set(
-                jnp.transpose(var[:e_real], (1, 0, 2)))
-        acc = _grid_block_stats(acc, res.converged[:e_real],
-                                res.failed[:e_real], res.iterations[:e_real])
-        return C, V, acc
-
-    fn = run
-    _BLOCK_UPDATE[key] = fn
-    return fn
-
-
 @partial(jax.jit, static_argnames=("task",))
-def _re_lane_scores(task, C, X, dense_ids, y, w, offs):
-    """(G, n) random-effect margins for every lane + per-lane total
-    objective, one program."""
+def _mesh_re_scores(task, C, X, dense_ids, y, w, offs):
+    """(G, n) random-effect margins of the mesh route's (G, E, d) lane
+    state, k coefficients a row for each lane, + the per-lane objective."""
     margins = jax.vmap(
-        lambda c: score_rows(X, _padded_coeffs(c, dense_ids)))(C)
+        lambda c: score_entities(X, c, dense_ids, exact=True))(C)
     loss, _, _ = loss_fns(task)
     objective = jnp.sum(w * loss(offs + margins, y), axis=-1)
     return margins, objective
 
 
-@jax.jit
-def lane_re_margins(C, X, dense_ids):
-    """(G, n) random-effect margins (validation scoring)."""
-    return jax.vmap(lambda c: score_rows(X, _padded_coeffs(c, dense_ids)))(C)
+@partial(jax.jit, static_argnames=("shape",))
+def _lane_models_tables(solutions: tuple, maps: tuple, shape: tuple):
+    """G (E, d) coefficient tables from a coordinate's buckets' (E_b,
+    width_b, G) lane solutions, each written through its bucket's index
+    map (`maps`: (entity ids, columns or None) a bucket) into zeros."""
+    G = solutions[0].shape[-1]
+    tables = []
+    with telemetry.device_scope("game_re.scatter"):
+        for g in range(G):
+            table = jnp.zeros(shape, jnp.float32)
+            for w, (ents, cols) in zip(solutions, maps):
+                table = table.at[_table_index(ents, cols)].set(
+                    w[..., g], mode="drop")
+            tables.append(table)
+    return tuple(tables)
 
 
 @dataclasses.dataclass
 class GridFitOutcome:
-    """Per-lane results of a vectorized GAME grid fit."""
+    """Per-lane results of a vectorized GAME grid fit. ``stacked`` holds
+    the lanes' state for batched scoring: a fixed effect's (G, d)
+    coefficients, a random effect's buckets' (E_b, width_b, G) solutions
+    in its dataset's block order (`RandomEffectDataset.lane_positions`
+    says where a coefficient lies)."""
 
     lane_models: list  # [GameModel] in lane order
     objective_histories: list  # [[float]] per lane, one entry per update
     coordinate_stats: list  # [{name: [OptResult | RETrainStats]}] per lane
-    stacked: dict  # name -> (G, d) W or (G, E, d) C, for batched scoring
+    stacked: dict
 
 
 def fit_game_grid(
@@ -267,7 +243,61 @@ def fit_game_grid(
     built from the BASE configs (reg weights are per-lane runtime values).
     ``lane_weights``: name -> G reg weights, one per grid point (constant
     lists for coordinates the grid doesn't vary).
+
+    On one device this is `coordinate_descent` with ``lanes``: every
+    random-effect update is the coordinate's one-dispatch update with a
+    lane axis (`RandomEffectCoordinate.fused_update_program(lanes=G)`),
+    every fixed-effect update a vmapped solve over the lanes (`coordinate_
+    descent._fixed_lane_update`): unprojected or INDEX_MAP buckets, each
+    bucket's (E_b, width_b, G) solutions carried to its next update and
+    read by the passive rows' scoring — the descent holds no (E, d) table;
+    the lanes' tables are written once, from the last solutions, for the
+    lanes' models. No variances. Under a mesh the grid keeps its
+    block loop over (G, E, d) state, unprojected buckets only.
     """
+    if mesh is not None:
+        return _fit_game_grid_mesh(coordinates, lane_weights, y, weights,
+                                   base_offsets, task, update_sequence,
+                                   n_sweeps, mesh)
+    G = len(next(iter(lane_weights.values())))
+    telemetry.count("game_grid.lanes", G)
+    seq = list(update_sequence) if update_sequence else list(coordinates)
+    trained = list(dict.fromkeys(seq))
+    result = coordinate_descent(
+        coordinates, y, weights, base_offsets, task, seq, n_sweeps,
+        lanes={name: lane_weight_arrays(coordinates[name].config,
+                                        lane_weights[name])
+               for name in trained})
+    state = result.lane_state
+    models: list = [{} for _ in range(G)]
+    for name in trained:
+        coord, lanes = coordinates[name], state[name]
+        ds = coord.dataset
+        if isinstance(coord, FixedEffectCoordinate):
+            for g in range(G):
+                models[g][name] = FixedEffectModel(
+                    GeneralizedLinearModel(Coefficients(lanes[g], None),
+                                           task), ds.shard_name)
+            continue
+        for g, table in enumerate(_lane_models_tables(
+                lanes, ds.table_maps, (ds.n_entities, ds.dim))):
+            models[g][name] = RandomEffectModel(
+                entity_name=ds.entity_name, feature_shard=ds.shard_name,
+                task=task, coefficients=table, entity_keys=ds.entity_keys,
+                key_to_index=ds.key_to_index)
+    return GridFitOutcome(
+        lane_models=[GameModel(m, task) for m in models],
+        objective_histories=result.objective_history,
+        coordinate_stats=result.coordinate_stats, stacked=state)
+
+
+def _fit_game_grid_mesh(coordinates, lane_weights, y, weights, base_offsets,
+                        task, update_sequence, n_sweeps,
+                        mesh: Mesh) -> GridFitOutcome:
+    """The grid under a mesh: lane-leading (G, E, d) state, every bucket's
+    (entity × lane) solves sharded over the mesh's entity axis, a host-
+    driven loop of gathers, solves and scatters a bucket; unprojected
+    random effects only."""
     seq = list(update_sequence) if update_sequence else list(coordinates)
     trained = list(dict.fromkeys(seq))
     G = len(next(iter(lane_weights.values())))
@@ -275,6 +305,7 @@ def fit_game_grid(
     weights = jnp.asarray(weights, jnp.float32)
     base = jnp.asarray(base_offsets, jnp.float32)
     n = int(y.shape[0])
+    telemetry.count("game_grid.lanes", G)
 
     # Per-coordinate preparation: lane weight arrays, objectives, batches.
     prep: dict = {}
@@ -286,42 +317,28 @@ def fit_game_grid(
         ds = coord.dataset
         if isinstance(coord, FixedEffectCoordinate):
             d = ds.dim
-            batch = GLMBatch(ds.X, ds.y, ds.weights,
-                             jnp.zeros((n,), jnp.float32))
-            n_pad = n
-            if mesh is not None:
-                n_pad = pad_to_multiple(n, mesh.devices.size)
-                batch = pad_batch(batch, n_pad)
-                batch = jax.device_put(batch, data_sharding(mesh))
+            n_pad = pad_to_multiple(n, mesh.devices.size)
+            batch = pad_batch(GLMBatch(ds.X, ds.y, ds.weights,
+                                       jnp.zeros((n,), jnp.float32)), n_pad)
+            batch = jax.device_put(batch, data_sharding(mesh))
             obj = make_objective(task, coord.config, d)
             prep[name] = ("fixed", batch, obj, l2s, l1s, static_cfg, n_pad)
             state[name] = jnp.zeros((G, d), jnp.float32)
         else:
             if ds.projection is not None:
                 raise ValueError(
-                    "fit_game_grid does not support projected random-effect "
-                    "coordinates (the estimator routes them sequentially)")
+                    "fit_game_grid on a mesh does not support projected "
+                    "random-effect coordinates (the estimator routes them "
+                    "sequentially)")
             d = ds.dim
             obj = coord._block_objective(d)
             solver = _re_grid_solver(l1s is not None, static_cfg,
                                      coord.variance)
             # Per-block batches (X/y/weights are sweep- and lane-invariant)
             # built ONCE; only the per-lane offsets are replaced per update.
-            # Chunk sizing mirrors _run_block_grid; the fused single-device
-            # update program is resolved here too.
-            n_dev = mesh.devices.size if mesh is not None else 1
-            cap = max(1, _MAX_SOLVE_LANES // max(G, 1))
-            blocks = []
-            for block in ds.blocks:
-                chunk = min(cap, next_pow2(max(block.n_entities, 1), 1))
-                chunk = pad_to_multiple(chunk, n_dev)
-                e_pad = pad_to_multiple(block.n_entities, chunk)
-                fused = (None if mesh is not None
-                         else _block_update_fn(solver[1], chunk, e_pad))
-                blocks.append((block, jnp.asarray(block.entity_index),
-                               ds.block_batch(block,
-                                              np.zeros((n,), np.float32)),
-                               fused))
+            blocks = [(block, jnp.asarray(block.entity_index),
+                       ds.block_batch(block, np.zeros((n,), np.float32)))
+                      for block in ds.blocks]
             prep[name] = ("random", ds, obj, l2s, l1s, solver, blocks)
             state[name] = jnp.zeros((G, ds.n_entities, d), jnp.float32)
     var_state = {
@@ -335,10 +352,7 @@ def fit_game_grid(
     scores: dict = {}
     history: list = []  # (G,) device scalars per update, device_get at end
     stats_acc: dict = {name: [] for name in trained}
-
-    lane_sharding = None
-    if mesh is not None:
-        lane_sharding = NamedSharding(mesh, P(None, tuple(mesh.axis_names)))
+    lane_sharding = NamedSharding(mesh, P(None, tuple(mesh.axis_names)))
 
     for _ in range(n_sweeps):
         for name in seq:
@@ -350,11 +364,8 @@ def fit_game_grid(
                 offs_in = offs
                 if n_pad != n:
                     offs_in = jnp.pad(offs, ((0, 0), (0, n_pad - n)))
-                if lane_sharding is not None:
-                    offs_in = jax.device_put(offs_in, lane_sharding)
-                    w0s = jax.device_put(state[name], replicated(mesh))
-                else:
-                    w0s = state[name]
+                offs_in = jax.device_put(offs_in, lane_sharding)
+                w0s = jax.device_put(state[name], replicated(mesh))
                 res, var, margin, objective = _fixed_grid_update(
                     batch, offs_in, w0s, obj, l2s, l1s, static_cfg,
                     coord.variance, task)
@@ -365,14 +376,8 @@ def fit_game_grid(
                 history.append(objective)
             else:
                 _, ds, obj, l2s, l1s, solver, blocks = prep[name]
-                head = (obj, l2s) + (() if l1s is None else (l1s,))
                 acc = None
-                for block, ents, batch_base, fused in blocks:
-                    if fused is not None:  # single-device: one dispatch
-                        state[name], var_state[name], acc = fused(
-                            state[name], var_state[name], acc, offs,
-                            block.row_index, ents, batch_base, head)
-                        continue
+                for block, ents, batch_base in blocks:
                     off_b, w0_b = _gather_block_inputs(
                         offs, block.row_index, state[name], ents)
                     batch_b = batch_base._replace(offsets=off_b)
@@ -387,7 +392,7 @@ def fit_game_grid(
                     acc = _grid_block_stats(
                         acc, res.converged[:e_real], res.failed[:e_real],
                         res.iterations[:e_real])
-                margins, objective = _re_lane_scores(
+                margins, objective = _mesh_re_scores(
                     task, state[name], ds.X,
                     jnp.asarray(ds.entity_dense), y, weights, offs)
                 scores[name] = margins
@@ -439,9 +444,12 @@ def fit_game_grid(
         lane_models.append(GameModel(coords_g, task))
         lane_stats.append(stats_g)
 
-    return GridFitOutcome(
-        lane_models=lane_models,
-        objective_histories=histories,
-        coordinate_stats=lane_stats,
-        stacked={name: state_h[name] for name in trained},
-    )
+    # the single-device form of a random effect's lane state: its buckets'
+    # (E_b, d, G) solutions, in block order
+    stacked = {name: (jnp.asarray(s) if prep[name][0] == "fixed" else tuple(
+        jnp.asarray(np.moveaxis(s[:, block.entity_index], 0, -1))
+        for block in coordinates[name].dataset.blocks))
+        for name, s in state_h.items()}
+    return GridFitOutcome(lane_models=lane_models,
+                          objective_histories=histories,
+                          coordinate_stats=lane_stats, stacked=stacked)

@@ -79,6 +79,23 @@ def score_entities(X: Matrix, coefficients: jax.Array,
         return score_rows(X, _padded_coeffs(coefficients, dense_ids))
 
 
+def score_lanes(solutions: tuple, values: jax.Array,
+                positions: jax.Array) -> jax.Array:
+    """(n, G) margins of G regularization-grid lanes out of a coordinate's
+    buckets' (E_b, width_b, G) solutions laid end to end: a row's k slots
+    (``values``) read their coefficients at ``positions``
+    (`RandomEffectDataset.lane_positions`), ONE gathered index a slot for
+    all G lanes; one past the end reads 0. An f32 multiply and sum, as
+    `score_entities` with ``exact``. No (n, d) copy: a row reads k
+    coefficients (a dense shard: its d)."""
+    with telemetry.device_scope("game_re.score"):
+        G = solutions[0].shape[-1]
+        flat = jnp.concatenate([w.reshape(-1, G) for w in solutions])
+        gathered = flat.at[positions].get(mode="fill", fill_value=0)
+        return jnp.sum(values.astype(jnp.float32)[..., None] * gathered,
+                       axis=1)
+
+
 def score_rows(X: Matrix, coeff_rows: jax.Array) -> jax.Array:
     """Rowwise margin x_i · c_i with a per-row coefficient vector (n, d)."""
     if isinstance(X, SparseRows):
@@ -117,21 +134,7 @@ class RandomEffectModel:
         Vectorized via searchsorted — entity_keys comes from np.unique and is
         sorted, so the lookup is O(n log E) numpy, not an O(n) Python loop.
         """
-        raw = np.asarray(raw_ids)
-        keys = np.asarray(self.entity_keys)
-        if raw.dtype.kind != keys.dtype.kind:
-            # Cross-kind lookup (e.g. int ids vs str keys): promote to str
-            # rather than casting into keys' dtype — a fixed-width unicode
-            # cast would TRUNCATE unseen longer ids into colliding with real
-            # entities. Same-kind strings compare fine across widths.
-            if keys.dtype.kind in "US":
-                raw = raw.astype(np.str_)
-            else:
-                raw = raw.astype(keys.dtype)
-        pos = np.searchsorted(keys, raw)
-        pos_c = np.clip(pos, 0, len(keys) - 1)
-        found = keys[pos_c] == raw
-        return np.where(found, pos_c, self.n_entities).astype(np.int32)
+        return entity_dense_ids(self.entity_keys, raw_ids)
 
     def coeffs_for(self, dense_ids) -> jax.Array:
         """(n, d) per-row coefficients; id == E selects the zero row."""
@@ -145,6 +148,26 @@ class RandomEffectModel:
         i = self.key_to_index[key]
         var = None if self.variances is None else self.variances[i]
         return GeneralizedLinearModel(Coefficients(self.coefficients[i], var), self.task)
+
+
+def entity_dense_ids(entity_keys, raw_ids) -> np.ndarray:
+    """`RandomEffectModel.dense_ids` over the sorted ``entity_keys`` of a
+    table: raw keys → dense row ids, unseen keys → E (the zero row)."""
+    raw = np.asarray(raw_ids)
+    keys = np.asarray(entity_keys)
+    if raw.dtype.kind != keys.dtype.kind:
+        # Cross-kind lookup (e.g. int ids vs str keys): promote to str
+        # rather than casting into keys' dtype — a fixed-width unicode
+        # cast would TRUNCATE unseen longer ids into colliding with real
+        # entities. Same-kind strings compare fine across widths.
+        if keys.dtype.kind in "US":
+            raw = raw.astype(np.str_)
+        else:
+            raw = raw.astype(keys.dtype)
+    pos = np.searchsorted(keys, raw)
+    pos_c = np.clip(pos, 0, len(keys) - 1)
+    found = keys[pos_c] == raw
+    return np.where(found, pos_c, len(keys)).astype(np.int32)
 
 
 CoordinateModel = Union[FixedEffectModel, RandomEffectModel]

@@ -124,6 +124,49 @@ def _re_solver(with_prior: bool, cfg, variance):
     return fns
 
 
+def _re_lane_solve(cfg, obj, l2s, l1s, batch, w0):
+    """The (entity × grid lane) form of `_re_solver`'s raw solves: ONE
+    lane-minor solve (`models.training._lane_solve`) whose lanes are the
+    batch's E entities × the grid's G lanes, each entity's block, labels
+    and weights shared by its G lanes (`data.matrix.EntityBlocks`: a block
+    read once a pass for all of them) and each lane with its own offsets,
+    warm start, L2 weight and (OWL-QN) L1 weight. Entity-leading in and
+    out, the lane axis last: offsets (E, m, G), warm starts (E, width, G),
+    every result (E, ..., G) — the layout in which one gathered index reads
+    or writes all G lanes of an entity's coefficient. Nothing is vmapped:
+    a vmap over the lanes inside one over the entities batched each of the
+    solver's while loops at both levels, and tracing that took most of a
+    grid's set-up (PERF.md §6)."""
+    from photon_tpu.data.dataset import GLMBatch
+    from photon_tpu.data.matrix import EntityBlocks
+    from photon_tpu.models.training import _lane_solve
+
+    E, m = batch.y.shape
+    G = l2s.shape[0]
+
+    def lanes(x):  # (E, r, G) -> (r, G·E), entity e's lane g at g·E + e
+        return jnp.moveaxis(x, 0, -1).reshape(x.shape[1], -1)
+
+    def rows(x):  # (E, m) -> (m, G·E): a row's one value for its G lanes
+        return jnp.broadcast_to(x.T[:, None], (m, G, E)).reshape(m, -1)
+
+    def back(x):  # (..., G·E) -> (E, ..., G)
+        return jnp.moveaxis(x.reshape(x.shape[:-1] + (G, E)), -1, 0)
+
+    res = _lane_solve(
+        obj, GLMBatch(EntityBlocks(batch.X), rows(batch.y),
+                      rows(batch.weights), lanes(batch.offsets)),
+        lanes(w0).T, jnp.repeat(l2s, E),
+        None if l1s is None else jnp.repeat(l1s, E), cfg,
+        lane_evaluations=True)
+    if res.evaluations is not None:
+        # the OWL-QN lanes count their searches in lock step: every lane of
+        # the solve took each of them (TRON has none)
+        res = res._replace(evaluations=jnp.broadcast_to(res.evaluations,
+                                                        res.value.shape))
+    return jax.tree_util.tree_map(back, res), None
+
+
 # vmapped per-lane variances, keyed on (with_prior, variance type, whether
 # the objective's l2 is positive): the one-dispatch update computes a
 # bucket's variances apart from its solves, in chunks of the bucket plan's
@@ -678,7 +721,8 @@ class RandomEffectCoordinate:
         per-entity models back onto the data)."""
         return model.score(self.dataset.X, self.dataset.entity_dense)
 
-    def fused_update_program(self):
+    def fused_update_program(self, lanes: int = 1,
+                             config: Optional[OptimizerConfig] = None):
         """ONE-dispatch whole-coordinate update for the no-normalization /
         single-device case, unprojected or INDEX_MAP, with or without a
         prior: offsets sum, every bucket's (chunk-scanned) solves from the
@@ -729,10 +773,36 @@ class RandomEffectCoordinate:
         entity's own final objective, as its solve computed it; `warm'` the
         buckets' solutions, the next update's ``warm`` — or None
         when this coordinate needs the general train() path.
+
+        ``lanes`` G > 1: the same update for G regularization-grid lanes
+        (`game.grid.fit_game_grid`) under ``config``, the lanes'
+        weight-normalized configuration (`models.training.
+        lane_weight_arrays`). Every bucket's block is shared by its G lanes
+        and everything a lane owns carries a trailing lane axis: the warm
+        starts and solutions (E_b, width_b, G), the other coordinates'
+        scores and the margins (n, G), the objective and the stats' sums
+        (G,), ``values`` (E, G); ``lam`` is the lanes' (l2s, l1s) weights.
+        There is no (E, d) table (pass None; None comes back): the passive
+        rows read the buckets' solutions (`dataset.LaneScoringPlan`), and
+        the lanes' tables are made from the last solutions, once
+        (`game.grid`). No variances and no priors: None for a coordinate
+        with either.
         """
+        if lanes > 1:
+            cache = self.__dict__.setdefault("_fused_lanes_cache", {})
+            if (lanes, config) not in cache:
+                cache[lanes, config] = (
+                    None if self.variance is not VarianceComputationType.NONE
+                    else self._fused_program(config, lanes))
+            return cache[lanes, config]
         cached = getattr(self, "_fused_cache", None)
         if cached is not None:
             return cached
+        out = self._fused_program(_static_config(self.config), 1)
+        self._fused_cache = out
+        return out
+
+    def _fused_program(self, cfg, lanes: int):
         ds = self.dataset
         if self._effective_budget() is not None:
             # the compacted straggler re-solve needs the host repack
@@ -760,31 +830,24 @@ class RandomEffectCoordinate:
         blocks_args = []  # (row_index, ents, cols, batch_base) — arrays
         objs = []
         d = ds.dim
-        for block in ds.blocks:
+        for block, (ents, cols) in zip(ds.blocks, ds.table_maps):
             e_real = block.n_entities
             base_batch = ds.block_batch(block)
             var_lanes = (block.variance_lanes_rows
                          if self._variance_rows(block)
                          else block.variance_lanes)
-            meta.append((min(e_real, _MAX_SOLVE_LANES), e_real,
-                         min(var_lanes, e_real)))
-            # the bucket's index map as table columns; a padding column
-            # points past the table, where a read fills 0 and a write drops
-            cols = (None if block.proj is None else jnp.asarray(
-                np.where(block.proj.proj_mask > 0, block.proj.proj_idx, d)
-                .astype(np.int32)))
-            blocks_args.append((block.row_index,
-                                jnp.asarray(block.entity_index), cols,
-                                base_batch))
+            # a chunk holds up to _MAX_SOLVE_LANES (entity, lane) solves
+            meta.append((min(e_real, max(_MAX_SOLVE_LANES // lanes, 1)),
+                         e_real, min(var_lanes, e_real)))
+            blocks_args.append((block.row_index, ents, cols, base_batch))
             objs.append(self._block_objective(
                 block.dim if block.dim is not None else d))
-        out = (_fused_re_fn(_static_config(self.config), tuple(meta),
-                            self.task, self.variance,
-                            l2_positive(self.config)),
-               tuple(blocks_args), ds.scoring_plan, tuple(objs),
-               _l1_lam(self.config))
-        self._fused_cache = out
-        return out
+        return (_fused_re_fn(cfg, tuple(meta), self.task, self.variance,
+                             l2_positive(self.config), lanes,
+                             ds.n_entities if lanes > 1 else 0),
+                tuple(blocks_args),
+                ds.scoring_plan if lanes == 1 else ds.lane_scoring_plan,
+                tuple(objs), _l1_lam(self.config))
 
 
 # Module-level cache for the fused RE update (cf. _RE_SOLVERS): keyed on the
@@ -825,12 +888,13 @@ def _table_index(ents, cols):
     return (ents,) if cols is None else (ents[:, None], cols)
 
 
-def cold_warm_starts(blocks_args: tuple, d: int) -> tuple:
+def cold_warm_starts(blocks_args: tuple, d: int, lanes: int = 1) -> tuple:
     """The buckets' warm starts of a cold start: zeros in each bucket's own
-    space, to go with an (E, d) table of zeros."""
+    space, to go with an (E, d) table of zeros — with ``lanes`` > 1, one
+    (E_b, width_b, G) array a bucket."""
     return tuple(
-        jnp.zeros((ents.shape[0], d if cols is None else cols.shape[1]),
-                  jnp.float32)
+        jnp.zeros((ents.shape[0], d if cols is None else cols.shape[1])
+                  + ((lanes,) if lanes > 1 else ()), jnp.float32)
         for _, ents, cols, _ in blocks_args)
 
 
@@ -921,14 +985,19 @@ def bucket_priors(prior: RandomEffectModel, entity_keys,
                                 for _, ents, cols, _ in blocks_args))
 
 
-def _fused_re_fn(cfg, meta: tuple, task, variance, l2_positive=False):
+def _fused_re_fn(cfg, meta: tuple, task, variance, l2_positive=False,
+                 lanes: int = 1, n_entities: int = 0):
     """The jitted one-dispatch update of `fused_update_program` (which says
     what it takes and returns), one compiled program a (solver config,
-    block shapes, task, variance, whether l2 > 0) and a prior or none. It
-    writes the (E, d) table and never reads a warm start out of it, nor
-    clears a row: the table it is given is 0 outside the buckets' index
-    maps (`cold_warm_starts` / `adopt_table`)."""
-    key = (cfg, meta, task, variance, l2_positive)
+    block shapes, task, variance, whether l2 > 0, grid lanes) and a prior
+    or none. It writes the (E, d) table and never reads a warm start out of
+    it, nor clears a row: the table it is given is 0 outside the buckets'
+    index maps (`cold_warm_starts` / `adopt_table`). With ``lanes`` > 1
+    every solve runs over (entity × lane) on the entity's one block, no
+    table is written, and the passive rows' gathers read all G lanes of a
+    coefficient through one index of the buckets' solutions (``values``:
+    (``n_entities``, G))."""
+    key = (cfg, meta, task, variance, l2_positive, lanes, n_entities)
     fn = _FUSED_RE.get(key)
     if fn is not None:
         return fn
@@ -936,23 +1005,40 @@ def _fused_re_fn(cfg, meta: tuple, task, variance, l2_positive=False):
     def run(coeffs, warm, base, scores, objs, lam, blocks_args, plan, y,
             weights, priors=None):
         from photon_tpu.data.matrix import layout_matvec
-        from photon_tpu.game.model import score_entities
+        from photon_tpu.game.model import score_entities, score_lanes
         from photon_tpu.game.scoring import _sum_scores
         from photon_tpu.ops.losses import loss_fns
 
         loss, _, _ = loss_fns(task)
         with_prior = priors is not None
-        raw_fn = _re_solver(with_prior, cfg, VarianceComputationType.NONE)[1]
+        if lanes == 1:
+            raw_fn = _re_solver(with_prior, cfg,
+                                VarianceComputationType.NONE)[1]
+            head = (lam,)
+        else:  # lam: the lanes' (l2s, l1s)
+            raw_fn = partial(_re_lane_solve, cfg)
+            head = tuple(lam)
         var_fn = (None if variance is VarianceComputationType.NONE
                   else _re_variances(with_prior, variance, l2_positive))
         with telemetry.device_scope("game.objective"):
-            offs = _sum_scores(base, scores)
+            if lanes == 1:
+                offs = _sum_scores(base, scores)
+            else:  # the other coordinates' (n, G) lane scores
+                offs = jnp.broadcast_to(_sum_scores(base[:, None], scores),
+                                        base.shape + (lanes,))
         # The table is written IN PLACE (its buffer is donated): the
         # buckets partition the entities, so each writes rows no other
         # touches, over the index map its last solution was written through.
         variances = jnp.zeros_like(coeffs) if var_fn is not None else None
         conv = fail = iters = row_iters = steps = moved = trials = 0
-        values = jnp.zeros((coeffs.shape[0],), jnp.float32)
+        if lanes == 1:
+            values = jnp.zeros((coeffs.shape[0],), jnp.float32)
+            total, matvec = jnp.sum, jax.vmap(layout_matvec)
+        else:  # per-lane sums; a block pass for every lane of its entity
+            values = jnp.zeros((n_entities, lanes), jnp.float32)
+            total = partial(jnp.sum, axis=0)
+            matvec = jax.vmap(jax.vmap(layout_matvec), in_axes=(None, -1),
+                              out_axes=-1)
         scored = []  # the buckets' (E_b · m,) block margins, then the table's
         carried = []  # the buckets' solutions: the next update's `warm`
         for (row_index, ents, cols, batch_base), w0, (chunk, e_real,
@@ -962,7 +1048,7 @@ def _fused_re_fn(cfg, meta: tuple, task, variance, l2_positive=False):
             with telemetry.device_scope("game_re.gather"):
                 batch = batch_base._replace(offsets=offs[row_index])
             with telemetry.device_scope("game_re.solve"):
-                res, _ = _solve_lanes(raw_fn, (obj, lam),
+                res, _ = _solve_lanes(raw_fn, (obj,) + head,
                                       (batch, w0) + tuple(prior), chunk,
                                       e_real)
             var = None
@@ -978,36 +1064,46 @@ def _fused_re_fn(cfg, meta: tuple, task, variance, l2_positive=False):
             with telemetry.device_scope("game_re.score"):
                 # the rows this bucket holds, by the forward pass its
                 # objective runs, at the solution in the block's own space
-                scored.append(jax.vmap(layout_matvec)(
-                    batch_base.X, res.w).reshape(-1))
-            with telemetry.device_scope("game_re.scatter"):
-                at = _table_index(ents, cols)
-                coeffs = coeffs.at[at].set(res.w, mode="drop")
-                if var is not None:
-                    variances = variances.at[at].set(var, mode="drop")
-            conv += jnp.sum(res.converged)
-            fail += jnp.sum(res.failed)
-            iters += jnp.sum(res.iterations)
+                scored.append(matvec(batch_base.X, res.w).reshape(
+                    (-1,) + offs.shape[1:]))
+            if lanes == 1:
+                with telemetry.device_scope("game_re.scatter"):
+                    at = _table_index(ents, cols)
+                    coeffs = coeffs.at[at].set(res.w, mode="drop")
+                    if var is not None:
+                        variances = variances.at[at].set(var, mode="drop")
+            conv += total(res.converged)
+            fail += total(res.failed)
+            iters += total(res.iterations)
             rows = jnp.sum(batch_base.weights != 0.0, axis=1)
-            row_iters += jnp.sum(rows.astype(jnp.float32)
-                                 * res.iterations.astype(jnp.float32))
+            if lanes > 1:
+                rows = rows[:, None]
+            row_iters += total(rows.astype(jnp.float32)
+                               * res.iterations.astype(jnp.float32))
             steps += jnp.max(res.iterations)
             # iterations that lowered the lane's loss: the rest repeated
             # a point (a fixed-depth solve past its stall)
             lower = res.loss_history[:, 1:] < res.loss_history[:, :-1]
-            moved += jnp.sum(rows.astype(jnp.float32)
-                             * jnp.sum(lower, axis=1).astype(jnp.float32))
+            moved += total(rows.astype(jnp.float32)
+                           * jnp.sum(lower, axis=1).astype(jnp.float32))
             if res.evaluations is not None:
-                trials += jnp.sum(res.evaluations)
+                trials += total(res.evaluations)
             values = values.at[ents].set(res.value)
-        slot_of_row, X_passive, ids_passive = plan
-        if ids_passive.shape[0]:  # rows no block holds: the updated table
-            scored.append(score_entities(X_passive, coeffs, ids_passive,
-                                         exact=True))
+        slot_of_row = plan.slot_of_row
+        if plan.n_table_rows:  # rows no block holds: the updated table
+            scored.append(
+                score_entities(plan.X_passive, coeffs, plan.ids_passive,
+                               exact=True) if lanes == 1 else
+                score_lanes(tuple(carried), plan.values, plan.positions))
         with telemetry.device_scope("game_re.score"):
             margins = jnp.concatenate(scored)[slot_of_row]
         with telemetry.device_scope("game.objective"):
-            objective = jnp.sum(weights * loss(offs + margins, y))
+            if lanes == 1:
+                objective = jnp.sum(weights * loss(offs + margins, y))
+            else:
+                objective = jnp.sum(
+                    weights[:, None] * loss(offs + margins, y[:, None]),
+                    axis=0)
         return coeffs, variances, margins, objective, (
             conv, fail, iters, row_iters, steps, moved, trials), values, \
             tuple(carried)

@@ -424,7 +424,8 @@ def _count_mesh_psum(res: OptResult, d: int) -> None:
                            scale=gradient)
 
 
-def _lane_solve(obj, batch, w0, l2s, l1s, config):
+def _lane_solve(obj, batch, w0, l2s, l1s, config,
+                lane_evaluations: bool = False):
     """The one place a lane-minor solve is dispatched: smooth L2 sweeps on
     the margin-cached L-BFGS or TRON lanes (optim/lane_lbfgs.py,
     optim/lane_tron.py), L1/elastic-net sweeps on the OWL-QN lanes
@@ -435,7 +436,9 @@ def _lane_solve(obj, batch, w0, l2s, l1s, config):
 
     ``w0`` is either a shared (d,) start broadcast to every lane, or a
     lane-MAJOR (G, d) per-lane warm start (the tuner's compacted survivor
-    re-solve) transposed into the solvers' lane-minor (d, G) layout."""
+    re-solve) transposed into the solvers' lane-minor (d, G) layout.
+    ``lane_evaluations``: the L-BFGS lanes count each lane's own line-search
+    evaluations (the OWL-QN and TRON lanes count theirs in lock step)."""
     if w0.ndim == 2:
         W0 = w0.T
     else:
@@ -452,7 +455,8 @@ def _lane_solve(obj, batch, w0, l2s, l1s, config):
     return minimize_lbfgs_margin_lanes(
         obj, l2s, batch, W0, max_iters=config.max_iters,
         tolerance=config.tolerance, history=config.history,
-        history_dtype=config.lane_history_dtype)
+        history_dtype=config.lane_history_dtype,
+        lane_evaluations=lane_evaluations)
 
 
 @partial(jax.jit, static_argnames=("config",))

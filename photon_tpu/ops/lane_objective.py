@@ -41,6 +41,13 @@ def supports_lanes(obj: Objective) -> bool:
             and obj.prior_full_precision is None)
 
 
+def _per_lane(v):
+    """A batch's per-row column as an (n, G)-broadcastable array: a row's
+    one value for every lane, or — where it is already (n, G) — each
+    lane's own (`data.matrix.EntityBlocks`' lanes, each an entity's)."""
+    return v if v.ndim == 2 else v[:, None]
+
+
 def _eff_w_lanes(obj: Objective, W):
     return W if obj.norm_factors is None else W * obj.norm_factors[:, None]
 
@@ -48,7 +55,7 @@ def _eff_w_lanes(obj: Objective, W):
 def margin_lanes(obj: Objective, W, batch: GLMBatch):
     """z(W): (n, G) per-row margins, LOCAL to this shard."""
     Wt = _eff_w_lanes(obj, W)
-    z = layout_matvec_lanes(batch.X, Wt) + batch.offsets[:, None]
+    z = layout_matvec_lanes(batch.X, Wt) + _per_lane(batch.offsets)
     if obj.norm_shifts is not None:
         z = z - (obj.norm_shifts @ Wt)[None, :]
     return z
@@ -105,8 +112,8 @@ def phi_at_ray_lanes(obj: Objective, z, dz, a, coeffs, batch: GLMBatch):
     loss, d1, _ = loss_fns(obj.task)
     with device_scope("objective.loss"):
         za = z + a[None, :] * dz
-        y = batch.y[:, None]
-        wt = batch.weights[:, None]
+        y = _per_lane(batch.y)
+        wt = _per_lane(batch.weights)
         wl = wt * loss(za, y)
         wd = wt * d1(za, y) * dz
         f, dphi = obj._psum_many(jnp.sum(wl, axis=0), jnp.sum(wd, axis=0))
@@ -124,7 +131,7 @@ def hvp_at_margin_lanes(obj: Objective, l2s, z, batch: GLMBatch, V,
     _, _, d2 = loss_fns(obj.task)
     if dZv is None:
         dZv = direction_margin_lanes(obj, V, batch)
-    r = batch.weights[:, None] * d2(z, batch.y[:, None]) * dZv
+    r = _per_lane(batch.weights) * d2(z, _per_lane(batch.y)) * dZv
     gX, gsum = _backprop_lanes(obj, batch, r)
     hv = _finish_backprop_lanes(obj, *obj._psum_many(gX, gsum))
     masked = V if obj.reg_mask is None else obj.reg_mask[:, None] * V
@@ -140,8 +147,8 @@ def value_at_margin_lanes(obj: Objective, l2s, W, z, batch: GLMBatch):
     the line search's X traffic for nothing."""
     loss, _, _ = loss_fns(obj.task)
     with device_scope("objective.loss"):
-        y = batch.y[:, None]
-        wt = batch.weights[:, None]
+        y = _per_lane(batch.y)
+        wt = _per_lane(batch.weights)
         value = obj._psum_many(jnp.sum(wt * loss(z, y), axis=0))[0]
     rv, _ = _reg_terms_lanes(obj, l2s, W)
     return value + rv
@@ -151,7 +158,7 @@ def grad_at_margin_lanes(obj: Objective, l2s, W, z, batch: GLMBatch):
     """Per-lane gradient from cached margins — ONE lane-stacked Xᵀ pass."""
     _, d1, _ = loss_fns(obj.task)
     with device_scope("objective.loss"):
-        r = batch.weights[:, None] * d1(z, batch.y[:, None])
+        r = _per_lane(batch.weights) * d1(z, _per_lane(batch.y))
     gX, gsum = _backprop_lanes(obj, batch, r)
     grad = _finish_backprop_lanes(obj, *obj._psum_many(gX, gsum))
     _, rg = _reg_terms_lanes(obj, l2s, W)
@@ -163,8 +170,8 @@ def value_and_grad_at_margin_lanes(obj: Objective, l2s, W, z,
     """(f (G,), g (d, G)) from cached margins."""
     loss, d1, _ = loss_fns(obj.task)
     with device_scope("objective.loss"):
-        y = batch.y[:, None]
-        wt = batch.weights[:, None]
+        y = _per_lane(batch.y)
+        wt = _per_lane(batch.weights)
         r = wt * d1(z, y)
         local_value = jnp.sum(wt * loss(z, y), axis=0)
     gX, gsum = _backprop_lanes(obj, batch, r)

@@ -63,17 +63,21 @@ class _LaneLSState(NamedTuple):
     d_hi: jax.Array
     a_star: jax.Array
     f_star: jax.Array
+    n: jax.Array | None  # (G,) each lane's own evaluations, if counted
 
 
 def wolfe_line_search_lanes(
     phi: Callable,  # (G,) alphas -> ((G,) f, (G,) dphi)
     f0, dphi0, a_init, max_evals: int = 12, done0=None,
+    count_lanes: bool = False,
 ):
     """Per-lane strong-Wolfe search, lock-step: every loop iteration
     evaluates phi once for ALL lanes (one (n, G) elementwise pass); lanes
     that satisfy Wolfe freeze while the rest keep bracketing/zooming.
     Returns (alpha, f_alpha, ok), each (G,), and the lock-step number of
-    `phi` evaluations taken, ().
+    `phi` evaluations taken, () — or, with ``count_lanes``, each lane's
+    own (G,): the evaluations it took before it froze, what a scalar
+    search of that lane counts.
 
     ``done0``: lanes already finished in the OUTER solver — seeded as done
     so a converged lane's frozen state can't drag every remaining search to
@@ -144,6 +148,7 @@ def wolfe_line_search_lanes(
             d_lo=frz(s.d_lo, d_lo), a_hi=frz(s.a_hi, a_hi),
             f_hi=frz(s.f_hi, f_hi), d_hi=frz(s.d_hi, d_hi),
             a_star=frz(s.a_star, a_star), f_star=frz(s.f_star, f_star),
+            n=None if s.n is None else s.n + (~s.done).astype(jnp.int32),
         )
 
     def cond(s: _LaneLSState):
@@ -158,12 +163,13 @@ def wolfe_line_search_lanes(
         a_prev=zero, f_prev=f0, d_prev=dphi0,
         a_lo=zero, f_lo=f0, d_lo=dphi0, a_hi=inf, f_hi=inf, d_hi=inf,
         a_star=zero, f_star=f0,
+        n=jnp.zeros((G,), jnp.int32) if count_lanes else None,
     )
     out = lax.while_loop(cond, body, init)
     # Seeded-done lanes stay ok=False (alpha 0, nothing accepted) — the
     # caller's own done mask is what keeps them frozen.
     ok = (out.done & ~done_init) | (out.a_star > 0.0)
-    return out.a_star, out.f_star, ok, out.i
+    return out.a_star, out.f_star, ok, out.i if out.n is None else out.n
 
 
 class LaneHistory(NamedTuple):
@@ -278,7 +284,8 @@ class _LaneState(NamedTuple):
     g: jax.Array       # (d, G)
     h: LaneHistory     # its sv / yv are against g
     it: jax.Array      # () global iteration counter
-    evals: jax.Array   # () lock-step line-search evaluations so far
+    evals: jax.Array   # () lock-step line-search evaluations so far, or
+    # (G,) each lane's (`lane_evaluations`)
     its: jax.Array     # (G,) per-lane iterations taken
     done: jax.Array    # (G,)
     converged: jax.Array
@@ -297,6 +304,7 @@ def minimize_lbfgs_margin_lanes(
     history: int = 10,
     max_ls_evals: int = 12,
     history_dtype=None,
+    lane_evaluations: bool = False,
 ) -> OptResult:
     """Margin-cached L-BFGS over G lanes, lock-step, lane-minor.
 
@@ -311,6 +319,9 @@ def minimize_lbfgs_margin_lanes(
     curvature acceptance) are computed f32 from the unrounded pair at
     push time and cached, so rounding touches only the two-loop
     direction, which the Wolfe search then vets as usual.
+
+    ``lane_evaluations``: ``evaluations`` counts each lane's own line-
+    search evaluations, (G,), not the lock-step searches' ().
     """
     W0 = jnp.asarray(W0, jnp.float32)
     d, G = W0.shape
@@ -350,7 +361,8 @@ def minimize_lbfgs_margin_lanes(
 
         with device_scope("lbfgs.linesearch"):
             alpha, f_star, ok, ls_evals = wolfe_line_search_lanes(
-                phi, s.f, dphi0, a_init, max_ls_evals, done0=s.done)
+                phi, s.f, dphi0, a_init, max_ls_evals, done0=s.done,
+                count_lanes=lane_evaluations)
 
         with device_scope("lbfgs.update"):
             step = active & ok
@@ -397,7 +409,7 @@ def minimize_lbfgs_margin_lanes(
             W=W0, z=z0, f=f0, g=g0,
             h=empty_lane_history(m, d, G, hdtype),
             it=jnp.zeros((), jnp.int32),
-            evals=jnp.zeros((), jnp.int32),
+            evals=jnp.zeros((G,) if lane_evaluations else (), jnp.int32),
             its=jnp.zeros((G,), jnp.int32),
             done=g0norm <= 1e-14, converged=g0norm <= 1e-14,
             failed=jnp.zeros((G,), bool),

@@ -85,6 +85,10 @@ whose FULL variances took the rows form), prior_seen / prior_unseen (entities wi
 and without a row in the prior model, once a coordinate a descent) and
 fused_prior_updates (one-dispatch updates that carried a prior), beside
 the fixed effect's game_fixed.row_iterations (rows × iterations taken);
+a regularization grid run as lanes of one descent (`game.grid`):
+game_grid.lanes (G, once a grid fit; every descent counter above is then
+summed over the lanes) and the game_grid.best_lane gauge (the lane its
+validation program ranked best);
 the blocked-ELL builds' `layout.*` family — tail_nnz / ell_slots /
 occ_slots (`data.matrix.to_blocked_ell` and `shard_blocked_ell`: the
 tail's real nonzeros and the slots the ELL row buckets and the occurrence
@@ -192,7 +196,8 @@ own spaces), game_re.variance (the buckets' per-entity variances: Gram,
 Cholesky factor, the diagonal of the inverse), game_fixed.solve (the
 fixed effect's solve, same nesting), game_fixed.variance (its variances)
 and
-game.objective (offsets sum and the tracking objective); and mesh.psum
+game.objective (offsets sum and the tracking objective); game_grid.select
+(a lane grid's per-lane validation metric and best lane); and mesh.psum
 (the objective's all-reduces over the mesh axis — `Objective._psum` /
 `_psum_many`, so the scalar and the lane objective alike — entered only
 where an axis name is set: a one-device solve traces none). The resident
@@ -492,7 +497,7 @@ TELEMETRY_REGISTRY = {
         "game_re.variance_lanes", "game_re.variance_rows_lanes",
         "game_re.prior_seen",
         "game_re.prior_unseen", "game_re.fused_prior_updates",
-        "game_fixed.row_iterations",
+        "game_fixed.row_iterations", "game_grid.lanes",
         "layout.shard_bytes_real", "layout.shard_bytes_padded",
         "layout.tail_nnz", "layout.ell_slots", "layout.occ_slots",
         "mesh.psum_bytes",
@@ -506,7 +511,7 @@ TELEMETRY_REGISTRY = {
     "gauges": (
         "stream.prefetch_depth", "ingest.workers",
         "train.dataset_estimate_bytes", "train.hbm_budget_bytes",
-        "game_re.blocks_in_flight",
+        "game_re.blocks_in_flight", "game_grid.best_lane",
         "serving.queue_depth", "serving.batch_fill",
         "serving.latency_*", "serving.fleet_replicas",
         "hbm.bytes_in_use.max*", "hbm.peak_bytes_in_use.max*",
@@ -529,7 +534,7 @@ TELEMETRY_REGISTRY = {
         "game_re.gather", "game_re.solve", "game_re.scatter",
         "game_re.score", "game_re.adopt", "game_re.prior",
         "game_re.variance", "game_fixed.solve", "game_fixed.variance",
-        "game.objective",
+        "game.objective", "game_grid.select",
         "mesh.psum",
     ),
 }

@@ -683,3 +683,52 @@ def test_rows_form_variances_compile(one_chip, m, w):
         factors
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes <= (16 << 30) // _VARIANCE_SHARE
+
+
+# `glmix-grid.sweep8`'s lane update at G = 8: one solve chunk (4096 solver
+# lanes = 512 entities × 8 grid lanes) of its widest per-job bucket
+# (·, 256, 1280) and of its narrowest per-member one (·, 8, 128), with the
+# block pass that scores the rows the chunk holds for every lane.
+@pytest.mark.parametrize("m,w", [(256, 1280), (8, 128)],
+                         ids=["job-widest", "member-narrowest"])
+def test_lane_update_compiles(one_chip, m, w):
+    """The (entity × lane) L-BFGS solve of a chunk — one lane-minor solve
+    over its e × 8 lanes — and its block pass read its ONE (e, m, w) block
+    for all 8 lanes — products against a (w, 8) right-hand side, never 8
+    copies of the block — within the share of the chip a grid fit leaves
+    them."""
+    from photon_tpu.data.dataset import GLMBatch
+    from photon_tpu.data.matrix import layout_matvec
+    from photon_tpu.game.random_effect import _MAX_SOLVE_LANES, _re_lane_solve
+    from photon_tpu.models.training import _static_config, make_objective
+    from photon_tpu.ops.losses import TaskType
+    from photon_tpu.optim import regularization as reg
+    from photon_tpu.optim.config import OptimizerConfig
+
+    G, s = 8, one_chip
+    e = _MAX_SOLVE_LANES // G
+    cfg = _static_config(OptimizerConfig(max_iters=20, tolerance=0.0,
+                                         reg=reg.l2(), reg_weight=10.0,
+                                         history=10))
+    obj = make_objective(TaskType.LOGISTIC_REGRESSION, cfg, w)
+    passes = jax.vmap(jax.vmap(layout_matvec), in_axes=(None, -1),
+                      out_axes=-1)
+
+    def update(X, y, wt, off, w0, l2s):
+        res, _ = _re_lane_solve(cfg, obj, l2s, None,
+                                GLMBatch(X, y, wt, off), w0)
+        return res.w, res.iterations, passes(X, res.w)
+
+    rows = _shape((e, m), "float32", s)
+    compiled = _compile(update, _shape((e, m, w), "float32", s), rows, rows,
+                        _shape((e, m, G), "float32", s),
+                        _shape((e, w, G), "float32", s),
+                        _shape((G,), "float32", s))
+    text = compiled.as_text()
+    assert f"f32[{e},{m},{w}]" in text
+    assert f"f32[{G},{e},{m},{w}]" not in text and \
+        f"f32[{e},{G},{m},{w}]" not in text  # no per-lane copy of the block
+    # beside a resident grid fit of ~9 GB (the blocks, the flat shards, the
+    # lanes' solutions): 2.1 GB for the widest chunk
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (16 << 30) // 6, mem.temp_size_in_bytes
